@@ -178,19 +178,10 @@ class recompile_guard:
 def no_implicit_transfers():
     """``jax.transfer_guard("disallow")`` scope: implicit host↔device
     transfers raise; explicit ones (jnp.asarray / device_put /
-    device_get) pass. No-op (with a debug log) on jax builds without
-    transfer guards."""
+    device_get) pass."""
     import jax
 
-    guard = getattr(jax, "transfer_guard", None)
-    if guard is None:  # pragma: no cover - ancient jax
-        import logging
-
-        logging.getLogger(__name__).debug(
-            "jax.transfer_guard unavailable; transfer audit skipped")
-        yield
-        return
-    with guard("disallow"):
+    with jax.transfer_guard("disallow"):
         yield
 
 
@@ -214,10 +205,7 @@ def _ensure_compile_listener() -> bool:
     global _compile_listener_registered
     if _compile_listener_registered:
         return True
-    try:
-        from jax import monitoring
-    except Exception:  # pragma: no cover - ancient jax
-        return False
+    from jax import monitoring
 
     def _on_event(event: str, duration: float, **kw) -> None:
         if event == _COMPILE_EVENT:

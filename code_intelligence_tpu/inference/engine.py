@@ -63,16 +63,17 @@ class InferenceEngine:
         mesh=None,
         precision: str = "f32",
     ):
-        # Serve-time kernel override: the weights-resident Pallas cell
-        # measured 1.2-1.8x the scan at the flagship serve shape (RUNBOOK
-        # §11) and is numerically the same layer (parity-tested), so an
-        # encoder trained on the scan can still SERVE on the fused cell.
+        # Serve-time kernel override: the weights-resident Pallas cell is
+        # numerically the same layer (parity-tested), so an encoder
+        # trained on the scan can still SERVE on the fused cell.
         if lstm_pallas is not None:
             config = dataclasses.replace(config, lstm_use_pallas=lstm_pallas)
-        # TPU-only kernel (no CPU lowering outside interpret mode): demote
-        # rather than crash on the first embed — loudly, whether the flag
+        # Off the TPU the kernel has no compiled lowering (interpret mode
+        # is for tests, orders of magnitude slower than the scan): a CPU
+        # host serves the parity-identical scan — loudly, whether the flag
         # came from the caller or from an exported config (e.g. a distilled
-        # student trained with lstm_use_pallas=True, served on a CPU host).
+        # student trained with lstm_use_pallas=True). On the TPU a
+        # requested kernel is never swapped: what cannot run raises below.
         if config.lstm_use_pallas and jax.default_backend() != "tpu":
             logging.getLogger(__name__).warning(
                 "lstm_use_pallas requested but backend is %s, not tpu — "
@@ -88,13 +89,15 @@ class InferenceEngine:
 
             mesh = build_serve_mesh(mesh)
         if mesh is not None and config.lstm_use_pallas:
-            # a Pallas call inside a GSPMD-partitioned program would need
-            # shard_map plumbing the serve path doesn't have — demote to
-            # the (parity-identical) XLA scan rather than miscompile
-            logging.getLogger(__name__).warning(
-                "lstm_use_pallas does not compose with --mesh yet — "
-                "serving the sharded step on the XLA scan instead")
-            config = dataclasses.replace(config, lstm_use_pallas=False)
+            # a Mosaic call inside a GSPMD-partitioned program is opaque
+            # to the partitioner and the serve step has no shard_map
+            # around it (ROADMAP S9/D6). Only reachable on the TPU — off
+            # it the flag was already dropped above.
+            raise ValueError(
+                "lstm_pallas (and with it the int8-fused kernel) does not "
+                "compose with --mesh: the sharded serve step has no "
+                "shard_map around the Pallas call. Serve the mesh with "
+                "--no-lstm_pallas, or one chip with the kernel.")
         # Serve-path weight precision (RUNBOOK §28): "int8" quantizes the
         # encoder weights AT LOAD (ops/quantize.py) — int8 leaves + f32
         # per-channel scales replace the f32 matmul weights, and the
@@ -393,8 +396,8 @@ class InferenceEngine:
         for start in range(0, n, self.batch_size):
             idx = order[start : start + self.batch_size]
             # enqueue the group's device programs; defer the host fetch so
-            # a remote-attached chip pipelines groups instead of blocking
-            # on a round-trip every batch_size docs
+            # the device pipelines groups instead of idling on a host
+            # round-trip every batch_size docs
             pending.append(
                 (idx, self._embed_group_device([id_seqs[i] for i in idx])))
             if len(pending) >= self._FLUSH_GROUPS:

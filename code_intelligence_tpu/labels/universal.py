@@ -380,8 +380,8 @@ def train_universal_model(
         return optax.apply_updates(params, updates), opt_state, loss
 
     # k batches scanned per device dispatch (training/dispatch.py): this
-    # small model's steps are fast, so on a remote-attached chip the
-    # per-dispatch RPC dominates a naive per-batch loop. Chunking is
+    # small model's steps are fast, so the per-dispatch host cost
+    # dominates a naive per-batch loop. Chunking is
     # per-epoch; the tail chunk's size is the same every epoch, so at
     # most two program shapes compile.
     from code_intelligence_tpu.training.dispatch import scan_dispatch

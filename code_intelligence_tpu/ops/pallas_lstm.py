@@ -75,10 +75,7 @@ _W_HH_BUDGET = 52 * 1024 * 1024
 # limit 16.00M" while the SAME kernel compiled standalone (whole-module
 # budget) in bench_pallas_lstm. _VMEM_BUDGET already keeps the real
 # usage under the ~64MB Mosaic ceiling; this just tells XLA so.
-# jax renamed TPUCompilerParams -> CompilerParams across releases; accept
-# either so the module imports on every toolchain jax in the image.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-_COMPILER_PARAMS = _CompilerParams(
+_COMPILER_PARAMS = pltpu.CompilerParams(
     vmem_limit_bytes=_VMEM_BUDGET + 8 * 1024 * 1024)
 
 

@@ -59,18 +59,18 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _LANE = 128  # last-dim tile (all dtypes)
-# Streamed-VMEM budget per grid program (same ceiling family as
-# ops/pallas_lstm.py's _STREAM_TILE_BUDGET): bounds the batch tile so
-# long-T windows (sequence-parallel locals) still fit.
+# Streamed-VMEM budget per grid program: one copy of every streamed
+# ``(T, bt, 128)`` block; bounds the batch tile so long-T windows
+# (sequence-parallel locals) still fit.
 _STREAM_BUDGET = 12 * 1024 * 1024
-# Scoped-VMEM limit: embedded in jit(train_step) the kernel would
-# otherwise inherit XLA's 16MB default (the exact failure the fused LSTM
-# hit on chip — RUNBOOK §11); these kernels stream ≤ ~_STREAM_BUDGET.
-# jax renamed TPUCompilerParams -> CompilerParams across releases; accept
-# either so the module imports on every toolchain jax in the image.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-_COMPILER_PARAMS = _CompilerParams(
-    vmem_limit_bytes=_STREAM_BUDGET + 8 * 1024 * 1024)
+# Scoped-VMEM limit. The Pallas pipeline double-buffers every streamed
+# block, so the kernel needs twice the budget plus its f32 temporaries:
+# on the v5e the backward at the flagship train shape (6 streams,
+# T=67, bt=112, bf16: 11.5 MB counted) asked Mosaic for 22.09 MB and was
+# refused under a budget+8 MB limit (PR 21 chip run). Embedded in
+# jit(train_step) the kernel would otherwise inherit XLA's 16 MB default.
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    vmem_limit_bytes=2 * _STREAM_BUDGET + 8 * 1024 * 1024)
 
 
 def _sublane(itemsize: int) -> int:
@@ -93,8 +93,8 @@ def _pick_block_b(batch_padded: int, seq_len: int, itemsize: int,
     ``n_streams`` ``(T, bt, 128)`` blocks fit the stream budget.
 
     Raises when nothing fits: silently returning the smallest tile let
-    Mosaic fail compilation downstream on long-T bf16 inputs (ADVICE
-    round 5) — callers gate on :func:`fits_stream_budget` and fall back
+    Mosaic fail compilation downstream on long-T bf16 inputs — callers
+    gate on :func:`fits_stream_budget` and fall back
     to the associative scan instead of reaching this error.
     """
     sub = _sublane(itemsize)
@@ -372,7 +372,7 @@ def forget_mult_pallas(
     Differentiable via the fused Pallas adjoint.
 
     Shapes whose streamed blocks cannot fit the VMEM budget even at the
-    minimum batch tile (long-T bf16 — ADVICE round 5) fall back to the
+    minimum batch tile (long-T bf16) fall back to the
     associative scan instead of failing Mosaic compilation; the decision
     is static in T/dtype, so it is jit-trace safe.
 
@@ -414,8 +414,8 @@ def forget_mult_auto(z, f, h0=None, prefer_pallas: bool = False,
                      time_major: bool = False):
     """Select the forget-mult implementation.
 
-    The associative scan stays the default (log-depth but fully parallel;
-    at small T the relay-measured gap was inside noise); ``prefer_pallas``
+    The associative scan stays the default (log-depth but fully
+    parallel); ``prefer_pallas``
     opts into the single-pass fused kernel (reachable via
     ``AWDLSTMConfig(qrnn_use_pallas=True)``) — compiled on TPU, interpret
     mode elsewhere, the SAME routing as ``qrnn_layer``'s fused branch so

@@ -40,20 +40,6 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 
-def _shard_map(body, *, mesh, in_specs, out_specs, check_vma=False):
-    """jax.shard_map across toolchain versions: older jax exposes it at
-    jax.experimental.shard_map with ``check_rep`` instead of
-    ``check_vma`` (same role: disable the replication/varying-axes
-    checker, which can't type the carry fold's replicated/gathered mix)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(body, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=check_vma)
-
-
 def _local_prefix(z: jnp.ndarray, f: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Per-position (A_t, B_t) of the affine composition over the local
     block, from zero initial state: ``h_t = B_t + A_t * h_in``."""
@@ -116,7 +102,7 @@ def _forget_mult_program(mesh: Mesh, axis: str, batch_axis: Optional[str] = None
         # check_vma=False: the carry fold mixes replicated (h0) and
         # gathered values, which the varying-axes checker can't type
         return jax.jit(
-            _shard_map(
+            jax.shard_map(
                 body, mesh=mesh, in_specs=(spec, spec, P(batch_axis, None)),
                 out_specs=spec, check_vma=False,
             )
@@ -157,7 +143,7 @@ def _qrnn_program(mesh: Mesh, axis: str, window: int,
 
         spec = P(batch_axis, axis, None)
         return jax.jit(
-            _shard_map(
+            jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(spec, P(None, None), P(None,),
                           P(batch_axis, None), P(batch_axis, None)),

@@ -50,7 +50,7 @@ def refit_model_dir(workdir: Path, best_params: dict, arch: dict) -> Path:
     stale run) when a later sweep's winner has different model dimensions
     than the checkpoint an earlier refit left behind — so key the dir by the
     hyperparameters + architecture. Re-running the SAME winner still resumes
-    (the relay can die mid-refit); a different winner gets a fresh dir.
+    (a refit can be killed mid-run); a different winner gets a fresh dir.
     """
     sig = json.dumps({"p": best_params, "a": arch}, sort_keys=True)
     digest = hashlib.sha256(sig.encode()).hexdigest()[:12]
@@ -66,7 +66,7 @@ def refit_argv(best_params: dict, corpus_dir: Path, model_dir: Path,
         "--model_dir", str(model_dir),
         "--cycle_len", str(cycle_len),
         "--seed", str(seed),
-        "--resume",  # the relay can die mid-refit; resume like stage_lm does
+        "--resume",  # a killed refit resumes, like stage_lm does
     ]
     for key in ("lr", "wd"):
         argv += [f"--{key}", str(best_params.get(key, REFIT_FALLBACKS[key]))]
@@ -144,7 +144,7 @@ def merge_into_report(report_path: Path, section: dict) -> dict:
 
     report = json.loads(report_path.read_text())
     report["sweep"] = section
-    # tmp+rename: the relay watchdog SIGKILLs whole stage process groups;
+    # tmp+rename: a stage timeout SIGKILLs whole stage process groups;
     # an in-place write here could truncate the accumulated report
     _atomic_write_json(report_path, report)
     return report

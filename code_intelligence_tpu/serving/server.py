@@ -726,8 +726,12 @@ def make_server(
     )
 
 
-def main(argv=None) -> None:
-    """CLI: ``python -m code_intelligence_tpu.serving.server --model_dir ...``"""
+def build_server(argv=None) -> EmbeddingServer:
+    """Parse the CLI, load and warm the engine(s), bind the socket.
+    Returns the server ready for ``serve_forever`` — :func:`main` is this
+    plus the SIGTERM drain and the serve loop (``chip_smoke.py`` drives
+    the same function from a thread, where a signal handler cannot be
+    installed)."""
     import argparse
 
     p = argparse.ArgumentParser(description=__doc__)
@@ -866,17 +870,19 @@ def main(argv=None) -> None:
                 "groups path runs unsharded compiled forwards)")
     logging.basicConfig(level=logging.INFO, format="%(asctime)s %(message)s")
 
-    import signal
-
     from code_intelligence_tpu.inference import InferenceEngine
     from code_intelligence_tpu.serving.rollout import RolloutManager
+    from code_intelligence_tpu.utils import devices
 
+    log.info("compile cache: %s", devices.enable_compile_cache())
+    log.info("devices: %s", devices.describe())
     engine = InferenceEngine.from_export(
         args.model_dir, batch_size=args.batch_size,
         lstm_pallas=args.lstm_pallas, version=args.model_version,
         mesh=args.mesh, precision=args.precision)
-    # Warm the compile cache so the first request isn't a 30s compile.
-    engine.embed_issue("warmup", "warmup body")
+    # Compile the step of the scheduler this server serves, so the first
+    # request isn't a flagship-shape compile.
+    engine.warmup(scheduler=args.scheduler)
     rollout = RolloutManager(engine, version=args.model_version,
                              ring_capacity=args.shadow_ring)
     cache = None
@@ -904,9 +910,17 @@ def main(argv=None) -> None:
             lstm_pallas=args.lstm_pallas, version=args.candidate_version,
             mesh=args.mesh,  # the canary serves on the SAME mesh
             precision=args.precision)  # ...and the same precision
-        candidate.embed_issue("warmup", "warmup body")  # compile off-path
+        candidate.warmup(scheduler=args.scheduler)  # compile off-path
         rollout.start_canary(args.candidate_version, candidate,
                              args.canary_pct)
+    return srv
+
+
+def main(argv=None) -> None:
+    """CLI: ``python -m code_intelligence_tpu.serving.server --model_dir ...``"""
+    import signal
+
+    srv = build_server(argv)
 
     def _sigterm(signum, frame):
         # drain in a worker thread: the handler must not block the main
@@ -919,7 +933,7 @@ def main(argv=None) -> None:
         threading.Thread(target=_go, daemon=True).start()
 
     signal.signal(signal.SIGTERM, _sigterm)
-    log.info("embedding server listening on %s:%d", args.host, args.port)
+    log.info("embedding server listening on %s:%d", *srv.server_address[:2])
     srv.serve_forever()
 
 

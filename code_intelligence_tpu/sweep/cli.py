@@ -93,6 +93,9 @@ def main(argv=None):
     from code_intelligence_tpu.parallel import make_mesh
     from code_intelligence_tpu.sweep import SweepConfig, SweepRunner
     from code_intelligence_tpu.training import LMTrainer, TrainConfig
+    from code_intelligence_tpu.utils import devices
+
+    devices.enable_compile_cache()
 
     out_dir = Path(args.out_dir)
     out_dir.mkdir(parents=True, exist_ok=True)

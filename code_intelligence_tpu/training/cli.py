@@ -48,8 +48,9 @@ def build_parser() -> argparse.ArgumentParser:
                    help="Pallas forget-mult kernel for the QRNN recurrence")
     p.add_argument("--lstm_pallas", action="store_true",
                    help="Pallas weights-resident fused LSTM cell for layers "
-                        "whose W_hh fits VMEM (H<=1024); larger layers keep "
-                        "the XLA scan")
+                        "whose W_hh fits VMEM (ops.pallas_lstm.fits_resident: "
+                        "the flagship H=2500 in bf16, not in f32); larger "
+                        "layers keep the XLA scan")
     p.add_argument("--seq_parallel", type=int, default=1, metavar="N",
                    help="shard the QRNN recurrence's TIME axis over N "
                         "devices (context parallelism; requires --qrnn and "
@@ -67,9 +68,9 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--early_stop_patience", type=int, default=2)
     p.add_argument("--steps_per_dispatch", type=int, default=20, metavar="K",
                    help="train K bptt windows per device dispatch "
-                        "(lax.scan inside one jit) — amortizes dispatch "
-                        "latency on remote-attached chips; semantics "
-                        "identical to K=1 (the classic loop)")
+                        "(lax.scan inside one jit) — amortizes per-dispatch "
+                        "host latency; semantics identical to K=1 (the "
+                        "classic loop)")
     p.add_argument("--data_parallel", type=int, default=None, help="mesh data axis (default: all devices)")
     p.add_argument("--model_parallel", type=int, default=1, help="mesh model axis (TP)")
     p.add_argument("--resume", action="store_true", help="resume from latest checkpoint")
@@ -134,6 +135,11 @@ def main(argv=None) -> dict:
         TrainConfig,
     )
     from code_intelligence_tpu.training import checkpoint as ckpt
+
+    from code_intelligence_tpu.utils import devices
+
+    log.info("compile cache: %s", devices.enable_compile_cache())
+    log.info("devices: %s", devices.describe())
 
     corpus_dir = Path(args.corpus_dir)
     train_corpus = TokenCorpus(corpus_dir / "train")

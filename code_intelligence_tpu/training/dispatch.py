@@ -1,8 +1,8 @@
 """Shared k-steps-per-dispatch scan wrapper.
 
-On a remote-attached chip every program invocation is an RPC; fast
-training steps (the universal kind model, the distiller) are dominated
-by that per-dispatch cost in a naive per-batch loop. This helper builds
+Every program invocation pays a fixed host dispatch cost; fast training
+steps (the universal kind model, the distiller) are dominated by it in a
+naive per-batch loop. This helper builds
 the one construct they share: a jit-compiled ``lax.scan`` that chains k
 optimizer steps over stacked batches with the ``(params, opt_state)``
 carry donated.

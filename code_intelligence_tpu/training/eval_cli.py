@@ -114,6 +114,9 @@ def main(argv=None):
     mlp.set_defaults(fn=cmd_mlp)
     args = p.parse_args(argv)
     logging.basicConfig(level=logging.WARNING)
+    from code_intelligence_tpu.utils import devices
+
+    devices.enable_compile_cache()
     return args.fn(args)
 
 

@@ -69,7 +69,7 @@ class FineTuneConfig:
     wd: float = 0.01
     # batches scanned per device dispatch (training/dispatch.py): the old
     # loop additionally blocked on float(loss) EVERY step — a full host
-    # round-trip per batch on a remote-attached chip
+    # round-trip per batch
     steps_per_dispatch: int = 8
     seed: int = 0
 
@@ -222,7 +222,7 @@ class FineTuner:
         # scan_dispatch donates (variables, opt_state): commit the result
         # to self.variables only AFTER the dispatch returned, so a raise
         # during trace/compile leaves the instance on live buffers and a
-        # failed fit_gradual stays retryable (ADVICE round 5)
+        # failed fit_gradual stays retryable
         new_vars, opt_state, losses = step_fn(
             self.variables, opt_state, subs, toks, lens, ys)
         self.variables = new_vars
@@ -256,7 +256,7 @@ class FineTuner:
             step_fn = self._make_step(optimizer)
             # k batches scanned per device program; losses stay on device
             # until the stage ends (the old loop blocked on float(loss)
-            # every step — one host round-trip per batch on a remote chip)
+            # every step — one host round-trip per batch)
             k = max(1, self.ft.steps_per_dispatch)
             loss_chunks = []
             for _ in range(epochs):
@@ -296,7 +296,7 @@ class FineTuner:
             raise ValueError("not initialized")
         out = []
         # inference carries no backward activations: default to 4x the
-        # training batch — fewer dispatches matters on remote-attached chips
+        # training batch, for fewer dispatches
         bs = batch_size or 4 * self.ft.batch_size
         for i in range(0, len(X), bs):
             idx = np.arange(i, min(i + bs, len(X)))

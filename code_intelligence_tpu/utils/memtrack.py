@@ -66,11 +66,22 @@ MEMORY_RECORD_KIND = "memory"
 #: the catch-all owner row: live bytes no registered provider claims
 UNATTRIBUTED = "unattributed"
 
-#: default per-device budget for the capacity planner when the caller
-#: doesn't pass one (a 16 GiB HBM class device, e.g. TPU v5e); on the
-#: CPU backend this is a planning fiction — pass the real budget on
-#: real hardware
+#: per-device budget for the capacity planner when neither the caller
+#: nor the device supplies one (a 16 GiB HBM class device). Only the CPU
+#: backend lands here — there it is a planning fiction; an accelerator
+#: reports its own ``memory_stats()["bytes_limit"]``
 DEFAULT_DEVICE_BUDGET_BYTES = 16 * (1 << 30)
+
+
+def device_budget_bytes() -> Optional[int]:
+    """What the runtime says one device can hold
+    (``memory_stats()["bytes_limit"]``: 16,909,336,064 B on the TPU v5e,
+    PR 21 chip run), or None where the backend reports nothing (CPU)."""
+    import jax
+
+    stats = jax.devices()[0].memory_stats()
+    limit = (stats or {}).get("bytes_limit")
+    return int(limit) if limit else None
 
 
 def _fmt_bytes(n: float) -> str:
@@ -381,8 +392,10 @@ class DeviceMemoryLedger:
         and per-tenant heads against the per-device budget, plus the
         paged-arena geometry when the scheduler noted one.
 
-        ``budget_bytes`` is PER DEVICE; headroom is measured on the
-        fullest device (the one that OOMs first). ``version_bytes``
+        ``budget_bytes`` is PER DEVICE (default: what the device reports,
+        ``budget_source: "device"``; the 16 GiB constant only where it
+        reports nothing); headroom is measured on the fullest device (the
+        one that OOMs first). ``version_bytes``
         defaults to the largest ``engine.params*`` owner row — the
         observed cost of one resident model version; ``head_bytes`` to
         the geometry's ``head_bytes`` note when present.
@@ -390,12 +403,12 @@ class DeviceMemoryLedger:
         snap = snap or self.snapshot()
         with self._lock:
             geometry = dict(self._geometry)
-        if budget_bytes is None:
-            budget = DEFAULT_DEVICE_BUDGET_BYTES
-            budget_source = "default"
+        if budget_bytes is not None:
+            budget, budget_source = int(budget_bytes), "caller"
         else:
-            budget = int(budget_bytes)
-            budget_source = "caller"
+            budget, budget_source = device_budget_bytes(), "device"
+            if budget is None:
+                budget, budget_source = DEFAULT_DEVICE_BUDGET_BYTES, "default"
         used = max((d["total_bytes"] for d in snap["devices"].values()),
                    default=snap["total_bytes"])
         headroom = max(0, budget - used)

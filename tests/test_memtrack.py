@@ -418,6 +418,22 @@ class TestCapacityReport:
         assert cap2["versions_fit"] == 3
         assert cap2["heads_fit"] == cap2["headroom_bytes"] // 1024
 
+    def test_budget_comes_from_the_device_where_it_reports_one(
+            self, monkeypatch):
+        from code_intelligence_tpu.utils import memtrack
+
+        # the CPU backend reports nothing: the 16 GiB planning constant
+        assert memtrack.device_budget_bytes() is None
+        # an accelerator reports bytes_limit (16,909,336,064 on the v5e)
+        monkeypatch.setattr(memtrack, "device_budget_bytes",
+                            lambda: 16909336064)
+        cap = DeviceMemoryLedger().capacity_report()
+        assert cap["budget_source"] == "device"
+        assert cap["budget_bytes"] == 16909336064
+        # a caller's figure still wins
+        cap = DeviceMemoryLedger().capacity_report(budget_bytes=1 << 20)
+        assert cap["budget_source"] == "caller"
+
     def test_debug_memory_response_body(self):
         ledger = DeviceMemoryLedger()
         code, body, ctype = debug_memory_response(ledger, "")

@@ -248,7 +248,7 @@ class TestStreamBudgetFallback:
         from code_intelligence_tpu.ops import pallas_qrnn as pq
 
         # bf16 long-T: even the minimum sublane tile exceeds the budget
-        # (ADVICE round 5: silently returning the smallest tile let
+        # (silently returning the smallest tile let
         # Mosaic compilation fail downstream)
         t_over = pq._STREAM_BUDGET // (3 * 16 * pq._LANE * 2) + 1
         with pytest.raises(ValueError, match="associative scan"):

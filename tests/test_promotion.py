@@ -748,8 +748,8 @@ class TestHotSwapPin:
                         with lock:
                             errors.append(repr(e)[:200])
                     k += 1
-                    if k >= 40:
-                        break
+                    if k >= 5000:  # runaway bound only: a fast host
+                        break      # must still be posting at the swap
 
             with audit.recompile_guard(fn="slots.step", budget=0):
                 threads = [threading.Thread(target=client, args=(c,))

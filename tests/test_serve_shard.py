@@ -291,6 +291,19 @@ class TestMeshedScheduler:
         out = sched.embed_ids([np.array([40, 41], np.int32)])
         assert out.shape == (1, eng.embed_dim)
 
+    def test_mesh_with_the_pallas_cell_is_refused_on_tpu(self, mesh1,
+                                                         monkeypatch):
+        # on the TPU a requested kernel is never swapped for the scan
+        # behind a log line: the sharded step has no shard_map around
+        # the Pallas call, so the combination raises at start-up
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.raises(ValueError, match="does not compose with "
+                                             "--mesh"):
+            make_engine(mesh=mesh1, lstm_pallas=True)
+        with pytest.raises(ValueError, match="--mesh"):
+            make_engine(mesh=mesh1, lstm_pallas=True, precision="int8")
+        make_engine(mesh=mesh1)  # the scan under a mesh: fine
+
     def test_uneven_batch_raises_at_construction(self, mesh1):
         stub = types.SimpleNamespace(shape={"data": 3, "model": 1})
         with pytest.raises(ServeMeshError, match="evenly"):

@@ -432,3 +432,73 @@ class TestRaggedScheduler:
         again = eng.embed_ids_batch(mixed_seqs(n=5, seed=2),
                                     scheduler="ragged")
         np.testing.assert_array_equal(good, again)
+
+
+class _AlignedNumpy:
+    """numpy, except ``full`` returns 64-byte-aligned arrays: the CPU
+    backend aliases such a buffer zero-copy on ``jnp.asarray`` /
+    ``device_put``, so the staging race below does not hinge on where
+    malloc happened to put the block."""
+
+    def __getattr__(self, name):
+        return getattr(np, name)
+
+    @staticmethod
+    def full(shape, fill_value, dtype=None):
+        dt = np.dtype(dtype)
+        n = int(np.prod(shape)) * dt.itemsize
+        raw = np.zeros(n + 64, np.uint8)
+        off = (-raw.ctypes.data) % 64
+        out = raw[off:off + n].view(dt).reshape(shape)
+        out[...] = fill_value
+        return out
+
+
+class TestStagingUnderAsyncDispatch:
+    """The device must never read a host block the host can still write.
+
+    Dispatch is asynchronous (always on TPU; the jax default on CPU), so
+    the scheduler's host loop runs many steps ahead of the device. Here
+    the first step is made to wait on a deliberately slow computation:
+    every later step is staged while none has run. A staging buffer that
+    is reused (the parent's double buffer) has been rewritten by the
+    time the steps that were handed it execute."""
+
+    @pytest.mark.parametrize("scheduler", ["slots", "ragged"])
+    def test_steps_queued_behind_a_slow_step_read_their_own_block(
+            self, scheduler, monkeypatch):
+        import jax.numpy as jnp
+
+        from code_intelligence_tpu.inference import slots as slots_mod
+
+        assert jax.config.read("jax_cpu_enable_async_dispatch")
+        monkeypatch.setattr(slots_mod, "np", _AlignedNumpy())
+        eng = make_engine()
+        seqs = mixed_seqs(n=25, seed=7)  # > slots: refills mid-drain
+        want = eng.embed_ids_batch(seqs, scheduler="groups")
+        sched = eng.slot_scheduler(ragged=scheduler == "ragged")
+        sched.embed_ids(seqs[:3])  # compile the step off the slow path
+
+        @jax.jit
+        def slow(x):
+            # ~1 s of dependent matmuls, far longer than the host needs
+            # to stage the whole drain (a host callback would not do:
+            # its dispatch is synchronous on CPU)
+            m = jnp.eye(384) * 0.5 + 0.001
+            a = jax.lax.fori_loop(0, 3000, lambda i, a: jnp.tanh(a @ m),
+                                  jnp.ones((384, 384)))
+            return x + 0.0 * a[0, 0]
+
+        slow(sched._pool).block_until_ready()  # compile  # graft: measure
+        with sched._lock:
+            # step 1 consumes the pool, so it (and every step after it)
+            # waits for the slow computation
+            sched._pool = slow(sched._pool)
+            tickets = [sched.submit(ids) for ids in seqs]
+            sched.drain()
+            # the host finished staging EVERY step before the device
+            # finished the slow one — the regime the chip always runs in
+            assert not sched._pool.is_ready()
+            assert sched.steps_run > 4
+            got = sched.materialize(tickets)
+        np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)

@@ -298,6 +298,47 @@ class TestMeshExecution:
             state, ms = trainer.train_steps(state, np.stack(xs), np.stack(ys))
         assert np.isfinite(np.asarray(jax.device_get(ms["loss"]))).all()
 
+    def test_pallas_under_a_mesh_is_refused_by_name_on_tpu(
+            self, monkeypatch):
+        # On the chip JAX refuses a Mosaic call inside the partitioned
+        # step ("cannot be automatically partitioned", seen on 4x v5e at
+        # the first dispatch, PR 21): the trainer refuses the combination
+        # at construction instead, naming the flags. One chip keeps it.
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.raises(ValueError, match="lstm_pallas.*shard_map"):
+            LMTrainer(tiny_model(lstm_use_pallas=True),
+                      TrainConfig(batch_size=16, bptt=6),
+                      mesh=make_mesh({"data": 8}))
+        with pytest.raises(ValueError, match="qrnn_pallas"):
+            LMTrainer(tiny_model(qrnn=True, qrnn_use_pallas=True),
+                      TrainConfig(batch_size=16, bptt=6),
+                      mesh=make_mesh({"data": 4, "model": 2}))
+        LMTrainer(tiny_model(lstm_use_pallas=True),
+                  TrainConfig(batch_size=16, bptt=6),
+                  mesh=make_mesh({"data": 1}, devices=jax.devices()[:1]))
+
+    def test_mp_dispatches_reuse_one_program(self):
+        # GSPMD hands the carried states back split over 'model' unless
+        # they are pinned; the changed input signature then recompiled
+        # the second dispatch (train.steps compiled twice under
+        # --model_parallel 2 before PR 21)
+        from code_intelligence_tpu.analysis import runtime as audit
+
+        mesh = make_mesh({"data": 2, "model": 2}, devices=jax.devices()[:4])
+        trainer = LMTrainer(tiny_model(),
+                            TrainConfig(batch_size=8, bptt=6), mesh=mesh)
+        dl = LMStreamLoader(repeating_corpus(), 8, 6)
+        state = trainer.init_state(jax.random.PRNGKey(0))
+        it = dl.epoch(0)
+
+        def take(k):
+            xs, ys = zip(*(next(it) for _ in range(k)))
+            return np.stack(xs), np.stack(ys)
+
+        with mesh, audit.recompile_guard(fn="train.steps", budget=1):
+            for _ in range(3):
+                state, _ = trainer.train_steps(state, *take(2))
+
     def test_dp_matches_single_device(self):
         # Same seed, same data: an 8-way DP step must equal the 1-device step.
         tok = repeating_corpus()
