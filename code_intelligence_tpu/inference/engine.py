@@ -41,9 +41,16 @@ import numpy as np
 from code_intelligence_tpu.models import AWDLSTMConfig, AWDLSTMEncoder, init_lstm_states
 from code_intelligence_tpu.text import Tokenizer, Vocab, build_issue_text
 from code_intelligence_tpu.text.rules import TK_UNK
-from code_intelligence_tpu.utils import resilience, tracing
+from code_intelligence_tpu.utils import profiling, resilience, tracing
 
 from code_intelligence_tpu.constants import EMBED_TRUNCATE_DIM  # noqa: F401 (re-export)
+
+
+def _first_traced(ctxs):
+    """The first sampled SpanContext of ``ctxs`` (None when there is
+    none): where a span that belongs to several documents at once — a
+    group, a flush — is recorded, once."""
+    return next((c for c in ctxs if c is not None and c.sampled), None)
 
 
 class InferenceEngine:
@@ -233,20 +240,21 @@ class InferenceEngine:
         both batching paths compile (the group fwd above and the slot
         step in inference/slots.py); the slots-vs-groups parity contract
         rests on them sharing it."""
-        raw = raw.astype(jnp.float32)  # (B, T, E)
-        T = raw.shape[1]
-        mask = (jnp.arange(T)[None, :] < lengths[:, None]).astype(jnp.float32)
-        m3 = mask[:, :, None]
-        psum, pmax, plast, pcount = pool_state
-        psum = psum + jnp.sum(raw * m3, axis=1)
-        pmax = jnp.maximum(pmax, jnp.max(jnp.where(m3 > 0, raw, -jnp.inf), axis=1))
-        # last valid position in THIS chunk (if any); else keep previous.
-        has = lengths > 0
-        idx = jnp.clip(lengths - 1, 0, T - 1)
-        last_here = jnp.take_along_axis(raw, idx[:, None, None], axis=1)[:, 0]
-        plast = jnp.where(has[:, None], last_here, plast)
-        pcount = pcount + lengths.astype(jnp.float32)
-        return (psum, pmax, plast, pcount)
+        with jax.named_scope("pool"):  # device ops read pool/... in a capture
+            raw = raw.astype(jnp.float32)  # (B, T, E)
+            T = raw.shape[1]
+            mask = (jnp.arange(T)[None, :] < lengths[:, None]).astype(jnp.float32)
+            m3 = mask[:, :, None]
+            psum, pmax, plast, pcount = pool_state
+            psum = psum + jnp.sum(raw * m3, axis=1)
+            pmax = jnp.maximum(pmax, jnp.max(jnp.where(m3 > 0, raw, -jnp.inf), axis=1))
+            # last valid position in THIS chunk (if any); else keep previous.
+            has = lengths > 0
+            idx = jnp.clip(lengths - 1, 0, T - 1)
+            last_here = jnp.take_along_axis(raw, idx[:, None, None], axis=1)[:, 0]
+            plast = jnp.where(has[:, None], last_here, plast)
+            pcount = pcount + lengths.astype(jnp.float32)
+            return (psum, pmax, plast, pcount)
 
     def _finalize(self, pool_state) -> np.ndarray:
         # the ONE intended host sync of the bulk path, made explicit so
@@ -353,7 +361,13 @@ class InferenceEngine:
         attributes queue-wait/device/emit per document; the group path
         records one ``engine.group_embed`` interval per traced doc (the
         lock-step group pays its whole group's time — exactly the
-        latency behavior the slot scheduler exists to fix)."""
+        latency behavior the slot scheduler exists to fix). Inside that
+        interval it records ONE ``engine.group`` per length-sorted group
+        (host assembly + enqueue, with the group's padding counts) on
+        the group's first traced doc, and ONE ``engine.finalize`` per
+        flush (the host blocked on the chip) on the call's first traced
+        doc; the same two names go into a profiler capture as
+        TraceAnnotations."""
         # resilience backstop: a caller whose ambient deadline is already
         # spent gets DeadlineExceeded HERE, before any device program is
         # enqueued — budget-dead work must never occupy the chip. (Scoped
@@ -387,10 +401,17 @@ class InferenceEngine:
         # inference.py:191-212) into fixed buckets.
         order = np.argsort([len(s) for s in id_seqs], kind="stable")
         pending = []
+        call_ctx = _first_traced(ctxs or ())
 
         def flush():
-            for idx, pool in pending:
-                out[idx] = self._finalize(pool)[: len(idx)]
+            if not pending:
+                return
+            tf0 = time.perf_counter()
+            with profiling.annotate("engine.finalize"):
+                for idx, pool in pending:
+                    out[idx] = self._finalize(pool)[: len(idx)]
+            tracing.record_span("engine.finalize", tf0, time.perf_counter(),
+                                call_ctx, groups=len(pending))
             pending.clear()
 
         for start in range(0, n, self.batch_size):
@@ -398,8 +419,16 @@ class InferenceEngine:
             # enqueue the group's device programs; defer the host fetch so
             # the device pipelines groups instead of idling on a host
             # round-trip every batch_size docs
-            pending.append(
-                (idx, self._embed_group_device([id_seqs[i] for i in idx])))
+            tg0 = time.perf_counter()
+            with profiling.annotate("engine.group"):
+                pool, counts = self._embed_group_device(
+                    [id_seqs[i] for i in idx])
+            tg1 = time.perf_counter()
+            if ctxs is not None:
+                tracing.record_span(
+                    "engine.group", tg0, tg1,
+                    _first_traced(ctxs[i] for i in idx), **counts)
+            pending.append((idx, pool))
             if len(pending) >= self._FLUSH_GROUPS:
                 flush()
         flush()
@@ -421,7 +450,11 @@ class InferenceEngine:
 
     def _embed_group_device(self, seqs: List[np.ndarray]):  # graft: hot
         """Enqueue one group's forward passes; returns the DEVICE pool
-        state (no host sync — ``_finalize`` materializes it)."""
+        state (no host sync — ``_finalize`` materializes it) and the
+        group's counts: what it holds (``rows``, ``valid_tokens``) and
+        what the device is asked to run for it (``batch`` x ``bucket`` x
+        ``chunks`` = ``lane_steps``) — the ``engine.group`` span's
+        attributes, counted here where the padding is made."""
         B = self.batch_size  # fixed batch shape; pad the remainder
         max_len = max(len(s) for s in seqs)
         # Short groups run in one pass at the smallest fitting bucket; long
@@ -444,7 +477,12 @@ class InferenceEngine:
             pool, h_leaves = fwd(
                 self._enc_params, jnp.asarray(tokens), jnp.asarray(lengths), tuple(h_leaves), pool
             )
-        return pool
+        return pool, {
+            "rows": len(seqs), "batch": B, "bucket": bucket,
+            "chunks": n_chunks,
+            "valid_tokens": sum(len(s) for s in seqs),
+            "lane_steps": B * bucket * n_chunks,
+        }
 
     def embed_text(self, text: str) -> np.ndarray:
         """(3*emb_sz,) embedding of one pre-processed document string —
@@ -479,16 +517,27 @@ class InferenceEngine:
             # a short ctxs would silently drop documents via zip below
             raise ValueError(
                 f"ctxs has {len(ctxs)} entries for {len(issues)} issues")
-        texts = [build_issue_text(d.get("title", ""), d.get("body", "")) for d in issues]
-        if ctxs is None:
-            ids = [self.numericalize(t) for t in texts]
-        else:
-            ids = []
-            for t, ctx in zip(texts, ctxs):
-                tt0 = time.perf_counter()
-                ids.append(self.numericalize(t))
-                tracing.record_span("engine.tokenize", tt0,
-                                    time.perf_counter(), ctx,
-                                    n_tokens=len(ids[-1]))
+        # all of a call's host work before its first dispatch, under one
+        # name in a profiler capture; per document only when traced
+        with profiling.annotate("engine.host_prep"):
+            if ctxs is None:
+                texts = [build_issue_text(d.get("title", ""), d.get("body", "")) for d in issues]
+                ids = [self.numericalize(t) for t in texts]
+            else:
+                texts = []
+                for d, ctx in zip(issues, ctxs):
+                    tt0 = time.perf_counter()
+                    texts.append(build_issue_text(d.get("title", ""),
+                                                  d.get("body", "")))
+                    tracing.record_span("engine.text_rules", tt0,
+                                        time.perf_counter(), ctx,
+                                        n_chars=len(texts[-1]))
+                ids = []
+                for t, ctx in zip(texts, ctxs):
+                    tt0 = time.perf_counter()
+                    ids.append(self.numericalize(t))
+                    tracing.record_span("engine.tokenize", tt0,
+                                        time.perf_counter(), ctx,
+                                        n_tokens=len(ids[-1]))
         emb = self.embed_ids_batch(ids, scheduler=scheduler, ctxs=ctxs)
         return emb[:, :truncate] if truncate else emb

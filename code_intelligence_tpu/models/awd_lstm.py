@@ -174,14 +174,17 @@ class AWDLSTMEncoder(nn.Module):
             keep = jax.random.bernoulli(rng, 1.0 - cfg.embed_p, (cfg.vocab_size, 1))
             emb_table = embedding * keep / (1.0 - cfg.embed_p)
 
-        x = jnp.take(emb_table, tokens, axis=0).astype(cfg.dtype)  # (B, T, E)
-        if int8:
-            # dequant AFTER the gather: only the (B, T, E) activation is
-            # dequantized — the full f32 table never materializes
-            emb_scale = self.param(
-                "embedding_scale", nn.initializers.ones,
-                (cfg.emb_sz,), jnp.float32)
-            x = x * emb_scale.astype(cfg.dtype)
+        # jax.named_scope on each part: the module is ONE compact body, so
+        # without them a profiler capture names its device ops by number
+        with jax.named_scope("embedding"):
+            x = jnp.take(emb_table, tokens, axis=0).astype(cfg.dtype)  # (B, T, E)
+            if int8:
+                # dequant AFTER the gather: only the (B, T, E) activation is
+                # dequantized — the full f32 table never materializes
+                emb_scale = self.param(
+                    "embedding_scale", nn.initializers.ones,
+                    (cfg.emb_sz,), jnp.float32)
+                x = x * emb_scale.astype(cfg.dtype)
 
         if not deterministic and cfg.input_p > 0.0:
             mask = _locked_dropout_mask(
@@ -192,150 +195,151 @@ class AWDLSTMEncoder(nn.Module):
         new_states = []
         raw_output = x
         for li in range(cfg.n_layers):
-            in_dim = cfg.emb_sz if li == 0 else cfg.n_hid
-            H = cfg.layer_size(li)
-            # torch LSTM init: U(-1/sqrt(H), 1/sqrt(H)) on all weights.
-            winit = _centered_uniform(1.0 / float(np.sqrt(H)))
+            with jax.named_scope(f"{'qrnn' if cfg.qrnn else 'lstm'}_{li}"):
+                in_dim = cfg.emb_sz if li == 0 else cfg.n_hid
+                H = cfg.layer_size(li)
+                # torch LSTM init: U(-1/sqrt(H), 1/sqrt(H)) on all weights.
+                winit = _centered_uniform(1.0 / float(np.sqrt(H)))
 
-            if cfg.qrnn:
-                window = 2 if li == 0 else 1
-                w = self.param(f"qrnn_{li}_w", winit, (3 * H, window * in_dim))
-                b = self.param(f"qrnn_{li}_b", nn.initializers.zeros, (3 * H,))
-                w_c = w.astype(cfg.dtype)
-                if int8:
-                    # The QRNN's int8 fusion point IS this gate projection:
-                    # the ragged forget-mult kernel is weight-free
-                    # (ops/pallas_qrnn.py only runs h = f*h + (1-f)*z), so
-                    # dequant feeds the einsum and XLA fuses convert+scale
-                    # into the matmul (ops/quantize.py module docs).
-                    w_scale = self.param(
-                        f"qrnn_{li}_w{SCALE_SUFFIX}", nn.initializers.ones,
-                        (3 * H,), jnp.float32)
-                    w_c = w_c * w_scale.astype(cfg.dtype)[:, None]
-                if not deterministic and cfg.weight_p > 0.0:
-                    # AWD weight-drop on the QRNN gate weights (fastai wraps
-                    # the QRNN linear in WeightDropout too).
-                    keep = jax.random.bernoulli(
-                        self.make_rng("dropout"), 1.0 - cfg.weight_p, w.shape
-                    )
-                    w_c = w_c * keep.astype(cfg.dtype) / (1.0 - cfg.weight_p)
-                h0, x_prev = states[li]
-                if cfg.seq_axis is not None and self.mesh is not None:
-                    # time-sharded recurrence (context parallelism): each
-                    # device scans its time block; block summaries compose
-                    # over ICI (parallel/seq_parallel.py)
-                    from code_intelligence_tpu.parallel.seq_parallel import (
-                        qrnn_layer_seq_parallel,
-                    )
+                if cfg.qrnn:
+                    window = 2 if li == 0 else 1
+                    w = self.param(f"qrnn_{li}_w", winit, (3 * H, window * in_dim))
+                    b = self.param(f"qrnn_{li}_b", nn.initializers.zeros, (3 * H,))
+                    w_c = w.astype(cfg.dtype)
+                    if int8:
+                        # The QRNN's int8 fusion point IS this gate projection:
+                        # the ragged forget-mult kernel is weight-free
+                        # (ops/pallas_qrnn.py only runs h = f*h + (1-f)*z), so
+                        # dequant feeds the einsum and XLA fuses convert+scale
+                        # into the matmul (ops/quantize.py module docs).
+                        w_scale = self.param(
+                            f"qrnn_{li}_w{SCALE_SUFFIX}", nn.initializers.ones,
+                            (3 * H,), jnp.float32)
+                        w_c = w_c * w_scale.astype(cfg.dtype)[:, None]
+                    if not deterministic and cfg.weight_p > 0.0:
+                        # AWD weight-drop on the QRNN gate weights (fastai wraps
+                        # the QRNN linear in WeightDropout too).
+                        keep = jax.random.bernoulli(
+                            self.make_rng("dropout"), 1.0 - cfg.weight_p, w.shape
+                        )
+                        w_c = w_c * keep.astype(cfg.dtype) / (1.0 - cfg.weight_p)
+                    h0, x_prev = states[li]
+                    if cfg.seq_axis is not None and self.mesh is not None:
+                        # time-sharded recurrence (context parallelism): each
+                        # device scans its time block; block summaries compose
+                        # over ICI (parallel/seq_parallel.py)
+                        from code_intelligence_tpu.parallel.seq_parallel import (
+                            qrnn_layer_seq_parallel,
+                        )
 
-                    batch_axis = (
-                        "data" if "data" in self.mesh.axis_names else None
-                    )
-                    out, h_t = qrnn_layer_seq_parallel(
-                        raw_output,
-                        {"w": w_c, "b": b.astype(cfg.dtype)},
-                        h0=h0,
-                        mesh=self.mesh,
-                        axis=cfg.seq_axis,
-                        window=window,
-                        x_prev=x_prev if window == 2 else None,
-                        batch_axis=batch_axis,
-                    )
-                else:
-                    out, h_t = qrnn_layer(
-                        raw_output,
-                        {"w": w_c, "b": b.astype(cfg.dtype)},
-                        h0=h0,
-                        window=window,
-                        x_prev=x_prev if window == 2 else None,
-                        use_pallas=cfg.qrnn_use_pallas,
-                        valid_lens=valid_lens,
-                    )
-                st: LSTMState = (h_t, raw_output[:, -1])
-            else:
-                w_ih = self.param(f"lstm_{li}_w_ih", winit, (4 * H, in_dim))
-                w_hh = self.param(f"lstm_{li}_w_hh", winit, (4 * H, H))
-                bias = self.param(f"lstm_{li}_bias", winit, (4 * H,))
-                if int8:
-                    w_ih_scale = self.param(
-                        f"lstm_{li}_w_ih{SCALE_SUFFIX}", nn.initializers.ones,
-                        (4 * H,), jnp.float32)
-                    w_hh_scale = self.param(
-                        f"lstm_{li}_w_hh{SCALE_SUFFIX}", nn.initializers.ones,
-                        (4 * H,), jnp.float32)
-                    if (cfg.lstm_use_pallas and valid_lens is not None
-                            and fits_resident_int8(H)):
-                        # int8-resident fused serve kernel: W_hh stays int8
-                        # in VMEM and dequantizes in-register, one gate
-                        # slice at a time — fits resident where f32 didn't.
-                        out, st = lstm_layer_fused_ragged_int8(
+                        batch_axis = (
+                            "data" if "data" in self.mesh.axis_names else None
+                        )
+                        out, h_t = qrnn_layer_seq_parallel(
                             raw_output,
-                            states[li],
-                            w_ih,
-                            w_ih_scale,
-                            w_hh,
-                            w_hh_scale,
-                            bias.astype(cfg.dtype),
-                            valid_lens,
+                            {"w": w_c, "b": b.astype(cfg.dtype)},
+                            h0=h0,
+                            mesh=self.mesh,
+                            axis=cfg.seq_axis,
+                            window=window,
+                            x_prev=x_prev if window == 2 else None,
+                            batch_axis=batch_axis,
+                        )
+                    else:
+                        out, h_t = qrnn_layer(
+                            raw_output,
+                            {"w": w_c, "b": b.astype(cfg.dtype)},
+                            h0=h0,
+                            window=window,
+                            x_prev=x_prev if window == 2 else None,
+                            use_pallas=cfg.qrnn_use_pallas,
+                            valid_lens=valid_lens,
+                        )
+                    st: LSTMState = (h_t, raw_output[:, -1])
+                else:
+                    w_ih = self.param(f"lstm_{li}_w_ih", winit, (4 * H, in_dim))
+                    w_hh = self.param(f"lstm_{li}_w_hh", winit, (4 * H, H))
+                    bias = self.param(f"lstm_{li}_bias", winit, (4 * H,))
+                    if int8:
+                        w_ih_scale = self.param(
+                            f"lstm_{li}_w_ih{SCALE_SUFFIX}", nn.initializers.ones,
+                            (4 * H,), jnp.float32)
+                        w_hh_scale = self.param(
+                            f"lstm_{li}_w_hh{SCALE_SUFFIX}", nn.initializers.ones,
+                            (4 * H,), jnp.float32)
+                        if (cfg.lstm_use_pallas and valid_lens is not None
+                                and fits_resident_int8(H)):
+                            # int8-resident fused serve kernel: W_hh stays int8
+                            # in VMEM and dequantizes in-register, one gate
+                            # slice at a time — fits resident where f32 didn't.
+                            out, st = lstm_layer_fused_ragged_int8(
+                                raw_output,
+                                states[li],
+                                w_ih,
+                                w_ih_scale,
+                                w_hh,
+                                w_hh_scale,
+                                bias.astype(cfg.dtype),
+                                valid_lens,
+                            )
+                            new_states.append(st)
+                            raw_output = out
+                            continue
+                        # XLA reference: dequant feeds the scan's matmuls and
+                        # fuses (used by dense bucket/slot paths and off-TPU —
+                        # there is no int8 dense-fused Pallas variant).
+                        w_ih_d = w_ih.astype(cfg.dtype) * w_ih_scale.astype(
+                            cfg.dtype)[:, None]
+                        w_hh_d = w_hh.astype(cfg.dtype) * w_hh_scale.astype(
+                            cfg.dtype)[:, None]
+                        out, st = lstm_layer(
+                            raw_output, states[li], w_ih_d, w_hh_d,
+                            bias.astype(cfg.dtype), None,
                         )
                         new_states.append(st)
                         raw_output = out
                         continue
-                    # XLA reference: dequant feeds the scan's matmuls and
-                    # fuses (used by dense bucket/slot paths and off-TPU —
-                    # there is no int8 dense-fused Pallas variant).
-                    w_ih_d = w_ih.astype(cfg.dtype) * w_ih_scale.astype(
-                        cfg.dtype)[:, None]
-                    w_hh_d = w_hh.astype(cfg.dtype) * w_hh_scale.astype(
-                        cfg.dtype)[:, None]
-                    out, st = lstm_layer(
-                        raw_output, states[li], w_ih_d, w_hh_d,
-                        bias.astype(cfg.dtype), None,
-                    )
-                    new_states.append(st)
-                    raw_output = out
-                    continue
-                w_hh_mask = None
-                if not deterministic and cfg.weight_p > 0.0:
-                    # DropConnect on recurrent weights, one mask per window.
-                    keep = jax.random.bernoulli(
-                        self.make_rng("dropout"), 1.0 - cfg.weight_p, w_hh.shape
-                    )
-                    w_hh_mask = keep.astype(cfg.dtype) / (1.0 - cfg.weight_p)
-                w_hh_c = w_hh.astype(cfg.dtype)
-                if cfg.lstm_use_pallas and fits_resident(
-                    H, jnp.dtype(cfg.dtype).itemsize
-                ):
-                    if w_hh_mask is not None:
-                        w_hh_c = w_hh_c * w_hh_mask
-                    if valid_lens is not None:
-                        # length-aware serve kernel: exhausted tiles skip
-                        # their matmuls (inference only — no VJP)
-                        out, st = lstm_layer_fused_ragged(
-                            raw_output,
-                            states[li],
-                            w_ih.astype(cfg.dtype),
-                            w_hh_c,
-                            bias.astype(cfg.dtype),
-                            valid_lens,
+                    w_hh_mask = None
+                    if not deterministic and cfg.weight_p > 0.0:
+                        # DropConnect on recurrent weights, one mask per window.
+                        keep = jax.random.bernoulli(
+                            self.make_rng("dropout"), 1.0 - cfg.weight_p, w_hh.shape
                         )
+                        w_hh_mask = keep.astype(cfg.dtype) / (1.0 - cfg.weight_p)
+                    w_hh_c = w_hh.astype(cfg.dtype)
+                    if cfg.lstm_use_pallas and fits_resident(
+                        H, jnp.dtype(cfg.dtype).itemsize
+                    ):
+                        if w_hh_mask is not None:
+                            w_hh_c = w_hh_c * w_hh_mask
+                        if valid_lens is not None:
+                            # length-aware serve kernel: exhausted tiles skip
+                            # their matmuls (inference only — no VJP)
+                            out, st = lstm_layer_fused_ragged(
+                                raw_output,
+                                states[li],
+                                w_ih.astype(cfg.dtype),
+                                w_hh_c,
+                                bias.astype(cfg.dtype),
+                                valid_lens,
+                            )
+                        else:
+                            out, st = lstm_layer_fused(
+                                raw_output,
+                                states[li],
+                                w_ih.astype(cfg.dtype),
+                                w_hh_c,
+                                bias.astype(cfg.dtype),
+                            )
                     else:
-                        out, st = lstm_layer_fused(
+                        out, st = lstm_layer(
                             raw_output,
                             states[li],
                             w_ih.astype(cfg.dtype),
                             w_hh_c,
                             bias.astype(cfg.dtype),
+                            w_hh_mask,
                         )
-                else:
-                    out, st = lstm_layer(
-                        raw_output,
-                        states[li],
-                        w_ih.astype(cfg.dtype),
-                        w_hh_c,
-                        bias.astype(cfg.dtype),
-                        w_hh_mask,
-                    )
             new_states.append(st)
             raw_output = out
             if li < cfg.n_layers - 1 and not deterministic and cfg.hidden_p > 0.0:
@@ -391,7 +395,8 @@ class AWDLSTMLM(nn.Module):
             dec_w = self.encoder.variables["params"]["embedding"]
         else:
             dec_w = self.decoder_w
-        logits = jnp.einsum("bte,ve->btv", dropped, dec_w.astype(cfg.dtype))
-        if cfg.out_bias:
-            logits = logits + self.decoder_b.astype(cfg.dtype)
+        with jax.named_scope("decoder"):
+            logits = jnp.einsum("bte,ve->btv", dropped, dec_w.astype(cfg.dtype))
+            if cfg.out_bias:
+                logits = logits + self.decoder_b.astype(cfg.dtype)
         return logits, raw, dropped, new_states

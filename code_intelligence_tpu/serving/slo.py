@@ -62,8 +62,11 @@ from code_intelligence_tpu.utils.flight_recorder import (
 log = logging.getLogger(__name__)
 
 #: span names that count as attributable pipeline stages (everything
-#: else a request spends lands in ``unattributed``)
+#: else a request spends lands in ``unattributed``). ``engine.group`` and
+#: ``engine.finalize`` are NOT stages: they lie inside
+#: ``engine.group_embed`` and would count its time twice.
 DEFAULT_STAGE_SPANS: Tuple[str, ...] = (
+    "engine.text_rules",
     "engine.tokenize",
     "batcher.queue_wait",
     "cache.lookup",

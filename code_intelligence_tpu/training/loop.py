@@ -207,15 +207,19 @@ class LMTrainer:
             deterministic=False,
             rngs={"dropout": dropout_rng},
         )
-        ce = optax.softmax_cross_entropy_with_integer_labels(
-            logits.astype(jnp.float32), y
-        ).mean()
-        # fastai RNNRegularizer (alpha=AR on dropped, beta=TAR on raw).
-        ar = self.tcfg.alpha * jnp.mean(jnp.square(dropped.astype(jnp.float32)))
-        tar = self.tcfg.beta * jnp.mean(
-            jnp.square((raw[:, 1:] - raw[:, :-1]).astype(jnp.float32))
-        )
-        acc = jnp.mean((jnp.argmax(logits, -1) == y).astype(jnp.float32))
+        # named like the model's own parts (models/awd_lstm.py), so a
+        # capture shows the step as embedding / lstm_i / decoder / loss /
+        # optimizer instead of fusion numbers
+        with jax.named_scope("loss"):
+            ce = optax.softmax_cross_entropy_with_integer_labels(
+                logits.astype(jnp.float32), y
+            ).mean()
+            # fastai RNNRegularizer (alpha=AR on dropped, beta=TAR on raw).
+            ar = self.tcfg.alpha * jnp.mean(jnp.square(dropped.astype(jnp.float32)))
+            tar = self.tcfg.beta * jnp.mean(
+                jnp.square((raw[:, 1:] - raw[:, :-1]).astype(jnp.float32))
+            )
+            acc = jnp.mean((jnp.argmax(logits, -1) == y).astype(jnp.float32))
         return ce + ar + tar, (new_states, ce, acc)
 
     def _pin_carry(self, lstm_states):
@@ -251,9 +255,10 @@ class LMTrainer:
             (loss, (new_states, ce, acc)), grads = jax.value_and_grad(
                 self._loss, has_aux=True
             )(state.params, x, y, state.lstm_states, step_rng)
-            updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
-            updates = jax.tree.map(lambda u: u * state.lr_scale, updates)
-            new_params = optax.apply_updates(state.params, updates)
+            with jax.named_scope("optimizer"):
+                updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
+                updates = jax.tree.map(lambda u: u * state.lr_scale, updates)
+                new_params = optax.apply_updates(state.params, updates)
             new_states = jax.lax.stop_gradient(new_states)
             metrics = {
                 "loss": loss,
@@ -391,8 +396,9 @@ class LMTrainer:
     # ------------------------------------------------------------------
 
     def evaluate(self, state: TrainState, valid_loader) -> Dict[str, float]:
-        # ambient span: attaches to fit()'s trace when called from there,
-        # free no-op when evaluate() runs standalone with no trace open
+        # ambient span: attaches to the caller's open trace, free no-op
+        # when there is none (fit() records its own train.eval trace
+        # around _evaluate)
         with tracing.span("train.eval"):
             return self._evaluate(state, valid_loader)
 
@@ -480,14 +486,26 @@ class LMTrainer:
                 rng if rng is not None else jax.random.PRNGKey(0),
                 local_batch_size=train_loader.local_bs,
             )
-        # spans on the process-global tracer: one trace per fit() with
-        # epoch/dispatch/eval children — the first dispatch of each
-        # compiled shape is flagged compile=True, separating XLA compile
-        # time from steady-state step time. Bounded and guarded
-        # (utils/tracing.py): the hot loop never pays more than a few
-        # dict ops per DISPATCH (k steps), and never raises.
+        # spans on the process-global tracer. A trace holds
+        # MAX_SPANS_PER_TRACE spans and reaches /debug/traces and the
+        # on_trace observers only when its root ends, so a fit is NOT one
+        # trace: every train.dispatch / train.step / train.eval is a trace
+        # of its own, delivered as it finishes, and train.fit /
+        # train.epoch are one-span records (started explicitly, never on
+        # the thread's ambient stack, so the spans inside them start new
+        # traces). All carry ``fit_id`` (the train.fit record's trace id)
+        # and the epoch. A caller that holds its own span open around
+        # fit() has chosen one trace for it, by the tracer's parent rule.
+        # The first dispatch of each compiled shape is flagged
+        # compile=True, separating XLA compile time from steady-state
+        # step time. Bounded and guarded (utils/tracing.py): the hot loop
+        # never pays more than a few dict ops per DISPATCH (k steps), and
+        # never raises.
         tracer = tracing.get_tracer()
-        with self.mesh, tracer.span("train.fit", epochs=epochs) as fit_span:
+        fit_span = tracer.start_span("train.fit", epochs=epochs)
+        fit_id = fit_span.trace_id
+        ep_span = None
+        with self.mesh:
             for cb in callbacks:
                 cb.on_train_begin(self)
             history: List[Dict[str, float]] = []
@@ -516,7 +534,7 @@ class LMTrainer:
             try:
                 for epoch in range(epochs):
                     ep_span = tracer.start_span(
-                        "train.epoch", parent=fit_span.context, epoch=epoch)
+                        "train.epoch", fit_id=fit_id, epoch=epoch)
                     state = self.reset_lstm_states(state)
                     t0 = time.time()
                     losses = []
@@ -524,11 +542,11 @@ class LMTrainer:
                     buf: List[Tuple[np.ndarray, np.ndarray]] = []
                     halt = False
 
-                    def run_single(state, x, y, step0, _ep=ep_span):
+                    def run_single(state, x, y, step0, _epoch=epoch):
                         compiled = self._train_step is not None
                         timer.start()
-                        with tracer.span("train.step", parent=_ep.context,
-                                         compile=not compiled):
+                        with tracer.span("train.step", fit_id=fit_id,
+                                         epoch=_epoch, compile=not compiled):
                             state, metrics = self.train_step(state, x, y)
                         dt = timer.stop()
                         if not compiled:
@@ -546,14 +564,18 @@ class LMTrainer:
                         losses.append(metrics)
                         return state, step0, notify(step0, metrics)
 
-                    def flush(state, step0, _ep=ep_span):
+                    def flush(state, step0, _epoch=epoch):
                         xs = np.stack([x for x, _ in buf])
                         ys = np.stack([y for _, y in buf])
                         n = len(buf)
                         compiled = self._train_steps is not None
                         timer.start()
-                        with tracer.span("train.dispatch", parent=_ep.context,
-                                         windows=n, compile=not compiled):
+                        # the same name in a profiler capture's host
+                        # timeline, on the device trace's own clock
+                        with tracer.span("train.dispatch", fit_id=fit_id,
+                                         epoch=_epoch, windows=n,
+                                         compile=not compiled), \
+                                profiling.annotate("train.dispatch"):
                             state, ms = self.train_steps(state, xs, ys)
                             # ONE transfer for the whole chunk — per-element
                             # device slicing would enqueue ~4k tiny programs,
@@ -639,7 +661,10 @@ class LMTrainer:
                         epoch_metrics["dispatch_p50_s"] = ts["p50_s"]
                         epoch_metrics["dispatch_p99_s"] = ts["p99_s"]
                     if valid_loader is not None:
-                        epoch_metrics.update(self.evaluate(state, valid_loader))
+                        with tracer.span("train.eval", fit_id=fit_id,
+                                         epoch=epoch):
+                            epoch_metrics.update(
+                                self._evaluate(state, valid_loader))
                     history.append(epoch_metrics)
                     for cb in callbacks:
                         action = cb.on_epoch_end(epoch, epoch_metrics, state, self)
@@ -664,7 +689,14 @@ class LMTrainer:
                         fn(step0, exc)
                     except Exception:
                         log.exception("on_crash callback failed")
+                fit_span.set(error=type(exc).__name__)
                 raise
+            finally:
+                # both idempotent: an epoch cut short by a crash still
+                # closes its record instead of leaking a live trace
+                if ep_span is not None:
+                    ep_span.end()
+                fit_span.end()
             for cb in callbacks:
                 cb.on_train_end(history)
         return state, history

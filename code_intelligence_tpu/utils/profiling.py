@@ -102,13 +102,18 @@ def trace(log_dir, enabled: bool = True) -> Iterator[None]:
 
 @contextlib.contextmanager
 def annotate(name: str) -> Iterator[None]:
-    """Named sub-region inside a trace (TraceAnnotation); no-op when
-    the profiler is unavailable (same degrade rule as :func:`trace`)."""
-    profiler = _get_profiler()
-    if profiler is None:
+    """Named sub-region inside a trace (TraceAnnotation): the engine's
+    ``engine.host_prep`` / ``engine.group`` / ``engine.finalize`` and
+    the trainer's ``train.dispatch`` phases, on the device trace's own
+    clock. Written whether or not a capture runs (a flag test when none
+    does); no-op when the profiler, or its annotation, is unavailable
+    (same degrade rule as :func:`trace`) — callers sit on the request
+    path."""
+    annotation = getattr(_get_profiler(), "TraceAnnotation", None)
+    if annotation is None:
         yield
         return
-    with profiler.TraceAnnotation(name):
+    with annotation(name):
         yield
 
 

@@ -600,12 +600,6 @@ def to_chrome(traces: List[Dict[str, Any]]) -> Dict[str, Any]:
     return {"traceEvents": events, "displayTimeUnit": "ms"}
 
 
-def dump_chrome(traces: List[Dict[str, Any]], path: str) -> None:
-    """Write a Perfetto-loadable trace dump to ``path``."""
-    with open(path, "w") as f:
-        json.dump(to_chrome(traces), f)
-
-
 # ---------------------------------------------------------------------
 # /debug/traces (shared by the embedding server and MetricsServer)
 # ---------------------------------------------------------------------

@@ -1,0 +1,257 @@
+"""The program's own names for its time (PR 24): the groups path of
+``InferenceEngine.embed_issues`` records text-rule, tokenise, group and
+device-wait spans that tile a traced call and cost an untraced one no
+per-document clock read; ``LMTrainer.fit`` delivers every dispatch as a
+trace of its own, however long the fit; and the compiled forward and
+train step carry ``jax.named_scope`` names for each of their parts."""
+
+import re
+import time
+import types
+
+import jax
+import numpy as np
+import pytest
+
+from test_slot_scheduler import make_engine
+from test_training import repeating_corpus, tiny_model
+
+from code_intelligence_tpu.data import LMStreamLoader
+from code_intelligence_tpu.inference import engine as engine_mod
+from code_intelligence_tpu.parallel import make_mesh
+from code_intelligence_tpu.training import LMTrainer, TrainConfig
+from code_intelligence_tpu.utils import tracing
+from code_intelligence_tpu.utils.tracing import Tracer
+
+B, BUCKETS = 4, (8, 16)
+PHASES = ("engine.text_rules", "engine.tokenize", "engine.group",
+          "engine.finalize")
+
+
+def issues(lengths):
+    """One issue per entry, ``n`` body words each (plus the field marks
+    the text rules add)."""
+    return [{"title": f"w{i % 7}", "body": " ".join(
+        f"w{(i + j) % 140}" for j in range(n))} for i, n in enumerate(lengths)]
+
+
+def traced_call(engine, docs):
+    """``embed_issues`` the way the benchmark's bulk driver calls it: one
+    root span a document on a tracer of its own, every finished trace
+    through ``on_trace``. Returns the spans by name (as the traces
+    rendered them, on the wall clock) and the call's wall interval."""
+    tracer = Tracer(max_live=4 * len(docs))
+    got = []
+    tracer.on_trace(got.append)
+    roots = [tracer.start_span("bench.doc") for _ in docs]
+    t0 = time.time()
+    engine.embed_issues(docs, scheduler="groups",
+                        ctxs=[r.context for r in roots])
+    t1 = time.time()
+    for r in roots:
+        r.end()
+    assert len(got) == len(docs)
+    assert all(t["dropped_spans"] == 0 for t in got)
+    by_name = {}
+    for doc, t in enumerate(got):
+        for s in t["spans"]:
+            lo = t["start_unix"] + s["start_s"]
+            by_name.setdefault(s["name"], []).append(
+                dict(s, doc=doc, lo=lo, hi=lo + s["duration_s"]))
+    return by_name, (t0, t1)
+
+
+@pytest.fixture(scope="module")
+def engine():
+    eng = make_engine(batch_size=B, buckets=BUCKETS)
+    eng.embed_issues(issues([3, 12, 40]), scheduler="groups")  # compile
+    return eng
+
+
+class TestGroupsPathSpans:
+    def test_counts_per_document_per_group_per_flush(self, engine):
+        n, groups = 10, 3  # 4 + 4 + 2 documents
+        spans, _ = traced_call(engine, issues([2, 30, 5, 9, 1, 14, 3, 40, 7, 4]))
+        assert len(spans["engine.text_rules"]) == n
+        assert len(spans["engine.tokenize"]) == n
+        assert len(spans["engine.group_embed"]) == n
+        assert len(spans["engine.group"]) == groups
+        assert len(spans["engine.finalize"]) == 1
+        assert spans["engine.finalize"][0]["attrs"]["groups"] == groups
+        assert all(s["attrs"]["n_chars"] > 0
+                   for s in spans["engine.text_rules"])
+        # one group span per group, each on a document of that group;
+        # the flush on the call's first document
+        assert len({s["doc"] for s in spans["engine.group"]}) == groups
+        assert spans["engine.finalize"][0]["doc"] == 0
+
+    @pytest.mark.parametrize("lengths,chunks", [
+        ([1, 2, 3, 2], 1),            # one pass at the smallest bucket
+        ([14, 2, 20, 35], 5),         # streams chunk_len windows
+    ], ids=["short", "streamed"])
+    def test_group_counts_are_what_the_device_runs(self, engine, lengths,
+                                                   chunks):
+        spans, _ = traced_call(engine, issues(lengths))
+        (group,) = spans["engine.group"]
+        a = group["attrs"]
+        n_tokens = [s["attrs"]["n_tokens"] for s in spans["engine.tokenize"]]
+        assert a["rows"] == len(lengths) and a["batch"] == B
+        assert a["valid_tokens"] == sum(n_tokens)
+        assert a["bucket"] in BUCKETS
+        assert a["chunks"] == -(-max(n_tokens) // a["bucket"]) == chunks
+        assert a["lane_steps"] == B * a["bucket"] * a["chunks"]
+
+    def test_groups_hold_the_length_sorted_documents(self, engine):
+        spans, _ = traced_call(
+            engine, issues([2, 30, 5, 9, 1, 14, 3, 40, 7, 4]))
+        n_tokens = sorted(s["attrs"]["n_tokens"]
+                          for s in spans["engine.tokenize"])
+        want = [sum(n_tokens[i:i + B]) for i in range(0, len(n_tokens), B)]
+        got = [s["attrs"]["valid_tokens"] for s in sorted(
+            spans["engine.group"], key=lambda s: s["lo"])]
+        assert got == want
+
+    def test_spans_tile_the_call(self, engine):
+        rng = np.random.RandomState(3)
+        spans, (t0, t1) = traced_call(
+            engine, issues(rng.randint(40, 200, 40).tolist()))
+        phases = sorted((s for name in PHASES for s in spans[name]),
+                        key=lambda s: s["lo"])
+        covered = sum(s["hi"] - s["lo"] for s in phases)
+        assert covered >= 0.90 * (t1 - t0)
+        # none overlaps the next (rendered times are rounded to 1 us)
+        for a, b in zip(phases, phases[1:]):
+            assert b["lo"] >= a["hi"] - 5e-6, (a["name"], b["name"])
+        # groups and flushes lie inside the documents' group_embed interval
+        whole = spans["engine.group_embed"][0]
+        for s in spans["engine.group"] + spans["engine.finalize"]:
+            assert whole["lo"] - 5e-6 <= s["lo"] and s["hi"] <= whole["hi"] + 5e-6
+
+    def test_untraced_call_records_nothing_and_reads_no_clock_per_doc(
+            self, engine, monkeypatch):
+        reads, finished = [], []
+        monkeypatch.setattr(engine_mod, "time", types.SimpleNamespace(
+            perf_counter=lambda: reads.append(1) or time.perf_counter()))
+        monkeypatch.setattr(Tracer, "_finish_span",
+                            lambda self, span: finished.append(span.name))
+        assert tracing.current_context() is None
+        docs = issues([3] * 41)  # 11 groups, one flush
+        rows = engine.embed_issues(docs, scheduler="groups")
+        assert rows.shape == (41, engine.embed_dim)
+        assert finished == []
+        # two reads a group and two a flush, none per document
+        assert len(reads) == 2 * 11 + 2 < len(docs)
+
+    def test_ambient_trace_gets_the_same_spans(self, engine):
+        tracer = Tracer()
+        with tracer.span("request"):
+            engine.embed_issues(issues([3, 20, 6, 2, 9]), scheduler="groups")
+        names = [s["name"] for s in tracer.traces()[0]["spans"]]
+        assert names.count("engine.text_rules") == 5
+        assert names.count("engine.group") == 2
+        assert names.count("engine.finalize") == 1
+
+
+class TestFitTraces:
+    def test_every_dispatch_is_delivered_as_it_finishes(self, monkeypatch):
+        monkeypatch.setattr(tracing, "MAX_SPANS_PER_TRACE", 8)
+        tracer = Tracer()
+        monkeypatch.setattr(tracing, "_default", tracer)
+        k = 2
+        mesh = make_mesh({"data": 1}, devices=jax.devices()[:1])
+        tcfg = TrainConfig(batch_size=8, bptt=6, lr=5e-3, cycle_len=1,
+                           steps_per_dispatch=k)
+        trainer = LMTrainer(tiny_model(), tcfg, mesh=mesh, steps_per_epoch=41)
+        dl = LMStreamLoader(repeating_corpus(n=8 * (6 * 41 + 1)), 8, 6,
+                            shuffle_offsets=False)
+        vl = LMStreamLoader(repeating_corpus(n=8 * (6 * 4 + 1), seed=1), 8, 6,
+                            shuffle_offsets=False)
+        windows = len(dl)
+        assert windows // k >= 20 and windows % k == 1
+
+        got = []
+        tracer.on_trace(got.append)
+        late = []
+
+        class Watch:
+            seen = 0
+
+            def on_train_begin(self, tr): ...
+
+            def on_step_end(self, step, metrics):
+                # every step reported so far lies in a delivered trace
+                Watch.seen += 1
+                done = sum(k if t["root"] == "train.dispatch" else 1
+                           for t in got
+                           if t["root"] in ("train.dispatch", "train.step"))
+                if done < Watch.seen:
+                    late.append(step)
+
+            def on_epoch_end(self, epoch, metrics, state, tr):
+                Watch.at_epoch_end = [t["root"] for t in got]
+
+            def on_train_end(self, h): ...
+
+        trainer.fit(dl, vl, epochs=1, callbacks=[Watch()],
+                    rng=jax.random.PRNGKey(0))
+        assert late == []
+        roots = [t["root"] for t in got]
+        assert roots.count("train.dispatch") == windows // k
+        assert roots.count("train.step") == 1     # the tail window
+        assert roots.count("train.eval") == 1
+        assert roots.count("train.epoch") == 1 and roots[-1] == "train.fit"
+        # all but the epoch's and the fit's own record before the epoch ended
+        assert Watch.at_epoch_end == roots[:-2]
+        assert all(t["dropped_spans"] == 0 for t in got)
+        fit = got[-1]
+        for t in got[:-1]:
+            attrs = t["spans"][0]["attrs"]
+            assert attrs["fit_id"] == fit["trace_id"] and attrs["epoch"] == 0
+        first = next(t for t in got if t["root"] == "train.dispatch")
+        assert first["spans"][0]["attrs"]["compile"] is True
+        assert first["spans"][0]["attrs"]["windows"] == k
+
+
+def scope_names(lowered):
+    """Every name on the op_name paths of a lowered program's location
+    metadata: ``jit(fwd)/AWDLSTMEncoder/lstm_0/while/...``, and under a
+    gradient ``jit(train_step)/transpose(jvp(loss))/...``."""
+    text = lowered.as_text(debug_info=True)
+    return {part for path in re.findall(r'"(jit\([^"]*)"', text)
+            for part in re.split(r"[/()]", path)}
+
+
+class TestNamedScopes:
+    @pytest.mark.parametrize("qrnn", [False, True], ids=["lstm", "qrnn"])
+    def test_forward_names_its_parts(self, qrnn):
+        from code_intelligence_tpu.models import (
+            AWDLSTMConfig, AWDLSTMEncoder, init_lstm_states)
+        from code_intelligence_tpu.text import SPECIALS, Vocab
+
+        cfg = AWDLSTMConfig(vocab_size=200, emb_sz=8, n_hid=12, n_layers=3,
+                            qrnn=qrnn)
+        states = init_lstm_states(cfg, B)
+        params = AWDLSTMEncoder(cfg).init(
+            {"params": jax.random.PRNGKey(0)}, np.zeros((1, 4), np.int32),
+            init_lstm_states(cfg, 1))["params"]
+        eng = engine_mod.InferenceEngine(
+            params, cfg, Vocab(SPECIALS + [f"w{i}" for i in range(150)]),
+            buckets=BUCKETS, batch_size=B)
+        lowered = eng._fwd(B, 8).lower(
+            eng._enc_params, np.zeros((B, 8), np.int32),
+            np.zeros((B,), np.int32), tuple(jax.tree.leaves(states)),
+            eng._init_pool_state(B))
+        kind = "qrnn" if qrnn else "lstm"
+        assert {"embedding", "pool", f"{kind}_0", f"{kind}_1",
+                f"{kind}_2"} <= scope_names(lowered)
+
+    def test_train_step_names_its_parts(self):
+        mesh = make_mesh({"data": 1}, devices=jax.devices()[:1])
+        trainer = LMTrainer(tiny_model(), TrainConfig(batch_size=8, bptt=6),
+                            mesh=mesh)
+        state = trainer.init_state(jax.random.PRNGKey(0), local_batch_size=8)
+        x = np.zeros((8, 6), np.int32)
+        with mesh:
+            lowered = trainer._make_train_step().lower(state, x, x)
+        assert {"embedding", "lstm_0", "lstm_1", "decoder", "loss",
+                "optimizer"} <= scope_names(lowered)
