@@ -46,6 +46,13 @@ from code_intelligence_tpu.utils import profiling, resilience, tracing
 from code_intelligence_tpu.constants import EMBED_TRUNCATE_DIM  # noqa: F401 (re-export)
 
 
+def _raw_size(issue) -> int:
+    """``len(title) + len(body)`` of a raw issue: the free proxy for its
+    token count that decides WHEN ``embed_issues`` prepares it."""
+    return sum(len(v) for v in (issue.get("title"), issue.get("body"))
+               if isinstance(v, str))
+
+
 def _first_traced(ctxs):
     """The first sampled SpanContext of ``ctxs`` (None when there is
     none): where a span that belongs to several documents at once — a
@@ -359,25 +366,75 @@ class InferenceEngine:
 
         ``ctxs`` — optional per-doc tracing SpanContexts: the slots path
         attributes queue-wait/device/emit per document; the group path
-        records one ``engine.group_embed`` interval per traced doc (the
-        lock-step group pays its whole group's time — exactly the
-        latency behavior the slot scheduler exists to fix). Inside that
-        interval it records ONE ``engine.group`` per length-sorted group
-        (host assembly + enqueue, with the group's padding counts) on
-        the group's first traced doc, and ONE ``engine.finalize`` per
-        flush (the host blocked on the chip) on the call's first traced
-        doc; the same two names go into a profiler capture as
-        TraceAnnotations."""
-        # resilience backstop: a caller whose ambient deadline is already
-        # spent gets DeadlineExceeded HERE, before any device program is
-        # enqueued — budget-dead work must never occupy the chip. (Scoped
-        # deadlines are per-thread, so a batcher/scheduler thread serving
-        # a mixed batch is unaffected.)
+        records the spans :meth:`_embed_groups` lists. It feeds that
+        grouper the documents in ascending true length, so its groups
+        are the length-sorted slabs of ``batch_size``."""
+        policy = self._check_scheduler(scheduler or self.scheduler)
+        if policy == "groups":
+            # Length-sorted grouping (reference sorts by length too,
+            # inference.py:191-212) into fixed buckets.
+            order = np.argsort([len(s) for s in id_seqs], kind="stable")
+            return self._embed_groups(
+                order.tolist(), lambda i, overlapped: id_seqs[i], ctxs)
+        self._check_deadline()
+        return self.slot_scheduler(ragged=policy == "ragged").embed_ids(
+            id_seqs, ctxs=ctxs)
+
+    @staticmethod
+    def _check_deadline() -> None:
+        """Resilience backstop: a caller whose ambient deadline is already
+        spent gets DeadlineExceeded HERE, before any device program is
+        enqueued — budget-dead work must never occupy the chip. (Scoped
+        deadlines are per-thread, so a batcher/scheduler thread serving
+        a mixed batch is unaffected.)"""
         dl = resilience.current_deadline()
         if dl is not None:
             dl.check("engine.embed_ids_batch")
-        policy = self._check_scheduler(scheduler or self.scheduler)
-        if policy == "groups" and self.mesh is not None \
+
+    def _embed_groups(self, order, prepare, ctxs) -> np.ndarray:  # graft: hot
+        """The groups path's one grouper: rows of the ``len(order)``
+        documents of a call, written back by document index.
+
+        ``order`` is the feed: document indices in the order they are to
+        be prepared; ``prepare(i, overlapped)`` returns document ``i``'s
+        token ids (``overlapped``: a group of this call is already
+        enqueued, so the chip works while this document is prepared).
+        Prepared documents wait in a buffer; whenever it holds
+        ``batch_size + batch_size // 4`` of them the ``batch_size``
+        SHORTEST BY TRUE TOKEN LENGTH are enqueued as one group, and the
+        host goes on preparing while the device runs it (dispatch is
+        asynchronous; this is all on the calling thread). When the feed
+        ends, what is left goes out length-sorted in slabs of
+        ``batch_size``. A feed in ascending true length therefore gives
+        exactly the length-sorted slabs; a feed that only roughly
+        ascends (``embed_issues``' free proxy) gives the same slabs as
+        long as no document arrives more than the quarter-batch
+        look-ahead late, and costs padding, never a wrong row, when one
+        does. A call of at most ``batch_size + batch_size // 4``
+        documents never fills the buffer: it is prepared whole, sorted
+        exactly and sent as it always was.
+
+        Spans, when ``ctxs`` carries per-doc SpanContexts: ONE
+        ``engine.group`` per group (host assembly + enqueue, no device
+        sync; the group's padding counts, and ``late_docs`` = its
+        documents shorter than the longest document of a group this call
+        had already enqueued: 0 when the feed was ordered well, the
+        field signal that a corpus defeats the proxy) on the group's
+        first traced doc; ONE ``engine.finalize`` per flush (the host
+        blocked on the chip) on the call's first traced doc; and per
+        traced doc one ``engine.group_embed`` from the end of the
+        preparation slab the document was prepared in to the call's last
+        flush (the lock-step group pays its whole call's device time —
+        the latency behavior the slot scheduler exists to fix). On a
+        multi-group call a document's ``engine.text_rules`` /
+        ``engine.tokenize`` spans may therefore lie inside OTHER
+        documents' ``engine.group_embed`` interval, never inside its
+        own: the stages overlap by design. In a profiler capture
+        ``engine.host_prep`` is one TraceAnnotation per preparation slab
+        (the feed between two enqueues), beside ``engine.group`` and
+        ``engine.finalize``."""
+        self._check_deadline()
+        if self.mesh is not None \
                 and not getattr(self, "_warned_mesh_groups", False):
             # the groups path's (batch, bucket) forwards never shard —
             # a mesh engine serving through it silently runs single-chip
@@ -387,21 +444,17 @@ class InferenceEngine:
                 "engine has a serve mesh but the 'groups' path runs "
                 "UNSHARDED compiled forwards — use scheduler='slots' or "
                 "'ragged' for the sharded step (RUNBOOK §26)")
-        if policy == "slots":
-            return self.slot_scheduler().embed_ids(id_seqs, ctxs=ctxs)
-        if policy == "ragged":
-            return self.slot_scheduler(ragged=True).embed_ids(
-                id_seqs, ctxs=ctxs)
-        n = len(id_seqs)
+        n = len(order)
         out = np.zeros((n, self.embed_dim), np.float32)
         if n == 0:
             return out
-        t_groups0 = time.perf_counter() if ctxs is not None else 0.0
-        # Length-sorted grouping (reference sorts by length too,
-        # inference.py:191-212) into fixed buckets.
-        order = np.argsort([len(s) for s in id_seqs], kind="stable")
-        pending = []
+        B = self.batch_size
+        lookahead = B + B // 4
         call_ctx = _first_traced(ctxs or ())
+        embed_from = [0.0] * n if ctxs is not None else None
+        buf: List[Tuple[int, int, np.ndarray]] = []  # (true length, doc, ids)
+        pending = []
+        longest_sent = -1  # no group enqueued yet
 
         def flush():
             if not pending:
@@ -414,28 +467,50 @@ class InferenceEngine:
                                 call_ctx, groups=len(pending))
             pending.clear()
 
-        for start in range(0, n, self.batch_size):
-            idx = order[start : start + self.batch_size]
-            # enqueue the group's device programs; defer the host fetch so
-            # the device pipelines groups instead of idling on a host
-            # round-trip every batch_size docs
-            tg0 = time.perf_counter()
-            with profiling.annotate("engine.group"):
-                pool, counts = self._embed_group_device(
-                    [id_seqs[i] for i in idx])
-            tg1 = time.perf_counter()
-            if ctxs is not None:
-                tracing.record_span(
-                    "engine.group", tg0, tg1,
-                    _first_traced(ctxs[i] for i in idx), **counts)
-            pending.append((idx, pool))
-            if len(pending) >= self._FLUSH_GROUPS:
-                flush()
+        feed, feeding = iter(order), True
+        while feeding:
+            held = len(buf)
+            with profiling.annotate("engine.host_prep"):
+                for i in feed:
+                    ids = prepare(i, longest_sent >= 0)
+                    buf.append((len(ids), i, ids))
+                    if len(buf) >= lookahead:
+                        break
+                else:
+                    feeding = False
+            if embed_from is not None:
+                now = time.perf_counter()
+                for _, i, _ in buf[held:]:
+                    embed_from[i] = now
+            buf.sort()  # by true length; ties by document index, never ids
+            take = B if feeding else len(buf)
+            for start in range(0, take, B):
+                group = buf[start : start + B]
+                idx = [i for _, i, _ in group]
+                # enqueue the group's device programs; defer the host
+                # fetch so the device pipelines groups instead of idling
+                # on a host round-trip every batch_size docs
+                tg0 = time.perf_counter()
+                with profiling.annotate("engine.group"):
+                    pool, counts = self._embed_group_device(
+                        [ids for _, _, ids in group])
+                tg1 = time.perf_counter()
+                if ctxs is not None:
+                    tracing.record_span(
+                        "engine.group", tg0, tg1,
+                        _first_traced(ctxs[i] for i in idx), **counts,
+                        late_docs=sum(
+                            length < longest_sent for length, _, _ in group))
+                longest_sent = max(longest_sent, group[-1][0])
+                pending.append((idx, pool))
+                if len(pending) >= self._FLUSH_GROUPS:
+                    flush()
+            del buf[:take]
         flush()
         if ctxs is not None:
             t1 = time.perf_counter()
-            for ctx in ctxs:
-                tracing.record_span("engine.group_embed", t_groups0, t1, ctx)
+            for ctx, t0 in zip(ctxs, embed_from):
+                tracing.record_span("engine.group_embed", t0, t1, ctx)
         return out
 
     @staticmethod
@@ -514,30 +589,44 @@ class InferenceEngine:
             if amb is not None:
                 ctxs = [amb] * len(issues)
         elif len(ctxs) != len(issues):
-            # a short ctxs would silently drop documents via zip below
+            # one context a document, by index
             raise ValueError(
                 f"ctxs has {len(ctxs)} entries for {len(issues)} issues")
-        # all of a call's host work before its first dispatch, under one
-        # name in a profiler capture; per document only when traced
-        with profiling.annotate("engine.host_prep"):
-            if ctxs is None:
-                texts = [build_issue_text(d.get("title", ""), d.get("body", "")) for d in issues]
-                ids = [self.numericalize(t) for t in texts]
-            else:
-                texts = []
-                for d, ctx in zip(issues, ctxs):
-                    tt0 = time.perf_counter()
-                    texts.append(build_issue_text(d.get("title", ""),
-                                                  d.get("body", "")))
-                    tracing.record_span("engine.text_rules", tt0,
-                                        time.perf_counter(), ctx,
-                                        n_chars=len(texts[-1]))
-                ids = []
-                for t, ctx in zip(texts, ctxs):
-                    tt0 = time.perf_counter()
-                    ids.append(self.numericalize(t))
-                    tracing.record_span("engine.tokenize", tt0,
-                                        time.perf_counter(), ctx,
-                                        n_tokens=len(ids[-1]))
-        emb = self.embed_ids_batch(ids, scheduler=scheduler, ctxs=ctxs)
+
+        def text_of(i):
+            d = issues[i]
+            return build_issue_text(d.get("title", ""), d.get("body", ""))
+
+        if ctxs is None:
+            # no clock read and no record per document
+            def prepare(i, overlapped):
+                return self.numericalize(text_of(i))
+        else:
+            def prepare(i, overlapped):
+                tt0 = time.perf_counter()
+                text = text_of(i)
+                tracing.record_span("engine.text_rules", tt0,
+                                    time.perf_counter(), ctxs[i],
+                                    n_chars=len(text))
+                tt0 = time.perf_counter()
+                ids = self.numericalize(text)
+                tracing.record_span(
+                    "engine.tokenize", tt0, time.perf_counter(), ctxs[i],
+                    n_tokens=len(ids),
+                    n_tokens_overlapped=len(ids) if overlapped else 0)
+                return ids
+
+        if self._check_scheduler(scheduler or self.scheduler) == "groups":
+            # shortest first by the free proxy, each document prepared as
+            # it is fed: the first group is on the chip once a batch and
+            # a quarter of short documents exist, and the long documents
+            # are tokenised while the short groups run
+            order = np.argsort([_raw_size(d) for d in issues], kind="stable")
+            emb = self._embed_groups(order.tolist(), prepare, ctxs)
+        else:
+            # the slot schedulers take a list: all of the call's host
+            # work first, then the hand-over
+            with profiling.annotate("engine.host_prep"):
+                ids = [prepare(i, False) for i in range(len(issues))]
+            emb = self.embed_ids_batch(ids, scheduler=scheduler, ctxs=ctxs)
         return emb[:, :truncate] if truncate else emb

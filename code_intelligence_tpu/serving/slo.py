@@ -64,7 +64,16 @@ log = logging.getLogger(__name__)
 #: span names that count as attributable pipeline stages (everything
 #: else a request spends lands in ``unattributed``). ``engine.group`` and
 #: ``engine.finalize`` are NOT stages: they lie inside
-#: ``engine.group_embed`` and would count its time twice.
+#: ``engine.group_embed`` and would count its time twice. On the groups
+#: path a document's ``engine.group_embed`` opens when the preparation
+#: slab it was prepared in ends, so its own ``engine.text_rules`` /
+#: ``engine.tokenize`` always lie before it and one request's stages
+#: still sum to its time; on a call of several groups those two spans of
+#: LATER documents lie inside EARLIER documents' ``engine.group_embed``
+#: interval (the chip runs the short groups while the long documents are
+#: tokenised): across the documents of such a call the stages overlap by
+#: design, and a trace that holds several of them (one batched request)
+#: can cover more than its root — ``unattributed`` is floored at 0.
 DEFAULT_STAGE_SPANS: Tuple[str, ...] = (
     "engine.text_rules",
     "engine.tokenize",

@@ -103,9 +103,10 @@ def trace(log_dir, enabled: bool = True) -> Iterator[None]:
 @contextlib.contextmanager
 def annotate(name: str) -> Iterator[None]:
     """Named sub-region inside a trace (TraceAnnotation): the engine's
-    ``engine.host_prep`` / ``engine.group`` / ``engine.finalize`` and
-    the trainer's ``train.dispatch`` phases, on the device trace's own
-    clock. Written whether or not a capture runs (a flag test when none
+    ``engine.host_prep`` (one per preparation slab of a groups-path
+    call: the text rules and tokenising between two group enqueues) /
+    ``engine.group`` / ``engine.finalize`` and the trainer's
+    ``train.dispatch`` phases, on the device trace's own clock. Written whether or not a capture runs (a flag test when none
     does); no-op when the profiler, or its annotation, is unavailable
     (same degrade rule as :func:`trace`) — callers sit on the request
     path."""

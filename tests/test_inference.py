@@ -133,6 +133,79 @@ class TestPooling:
         np.testing.assert_allclose(emb, manual, rtol=1e-4, atol=1e-5)
 
 
+def worded_issues(lengths, adversarial=False):
+    """``n`` body words of three characters each; with ``adversarial`` a
+    one-character-repeated title (three tokens at any length) that grows
+    as the body shrinks, so the raw size misorders the documents."""
+    return [{"title": "x" * (600 - 8 * n) if adversarial else "w7",
+             "body": " ".join(f"w{10 + (i + j) % 90}" for j in range(n))}
+            for i, n in enumerate(lengths)]
+
+
+class TestStreamedBulk:
+    """``embed_issues`` on the groups path prepares a call's documents
+    shortest first by their raw size and enqueues groups as they fill:
+    the rows are ``embed_ids_batch``'s whatever that order was."""
+
+    LENGTHS = [2, 30, 5, 9, 1, 14, 3, 40, 7, 4, 22, 11, 6, 17, 50, 8, 13]
+
+    @pytest.mark.parametrize("order,adversarial", [
+        ("shuffled", False), ("sorted", False), ("shuffled", True),
+        ("sorted", True)],
+        ids=["shuffled", "sorted", "adversarial", "adversarial-descending"])
+    def test_rows_are_those_of_the_ids(self, engine, order, adversarial):
+        from code_intelligence_tpu.text import build_issue_text
+
+        lengths = sorted(self.LENGTHS) if order == "sorted" else self.LENGTHS
+        docs = worded_issues(lengths, adversarial)
+        ids = [engine.numericalize(build_issue_text(d["title"], d["body"]))
+               for d in docs]
+        assert len(docs) > 2 * engine.batch_size  # several groups
+        if adversarial:  # the raw size really is the wrong way round
+            raw = [len(d["title"]) + len(d["body"]) for d in docs]
+            assert np.argsort(raw).tolist() == np.argsort(
+                [-len(s) for s in ids]).tolist()
+        want = engine.embed_ids_batch(ids, scheduler="groups")
+        got = engine.embed_issues(docs, scheduler="groups")
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+        for row, seq in zip(got, ids):  # and each row is its own document's
+            np.testing.assert_allclose(
+                row, engine.embed_ids_batch([seq])[0], rtol=1e-5, atol=1e-6)
+
+    @pytest.mark.parametrize("n", [0, 1], ids=["empty", "one"])
+    def test_tiny_calls(self, engine, n):
+        docs = worded_issues(self.LENGTHS[:n])
+        rows = engine.embed_issues(docs, scheduler="groups")
+        assert rows.shape == (n, engine.embed_dim)
+        assert np.all(np.isfinite(rows))
+        assert engine.embed_ids_batch([], scheduler="groups").shape \
+            == (0, engine.embed_dim)
+
+    def test_fields_that_are_not_strings_still_embed(self, engine):
+        docs = [{"title": None, "body": "w11 w12"}, {"body": 7}, {}]
+        rows = engine.embed_issues(docs + worded_issues(self.LENGTHS),
+                                   scheduler="groups")
+        np.testing.assert_allclose(
+            rows[0], engine.embed_issue(None, "w11 w12"), rtol=1e-5, atol=1e-6)
+        np.testing.assert_allclose(
+            rows[2], engine.embed_issue("", ""), rtol=1e-5, atol=1e-6)
+
+    def test_spent_deadline_raises_before_anything_is_prepared(
+            self, engine, monkeypatch):
+        from code_intelligence_tpu.utils import resilience
+
+        touched = []
+        monkeypatch.setattr(engine, "numericalize",
+                            lambda text: touched.append("tokenise"))
+        monkeypatch.setattr(engine, "_embed_group_device",
+                            lambda seqs: touched.append("enqueue"))
+        with resilience.deadline_scope(resilience.Deadline(-1.0)):
+            with pytest.raises(resilience.DeadlineExceeded):
+                engine.embed_issues(worded_issues(self.LENGTHS),
+                                    scheduler="groups")
+        assert touched == []
+
+
 class TestServer:
     @pytest.fixture(scope="class")
     def server(self, request):

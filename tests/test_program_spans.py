@@ -1,7 +1,9 @@
 """The program's own names for its time (PR 24): the groups path of
 ``InferenceEngine.embed_issues`` records text-rule, tokenise, group and
 device-wait spans that tile a traced call and cost an untraced one no
-per-document clock read; ``LMTrainer.fit`` delivers every dispatch as a
+per-document clock read; since PR 25 a multi-group call prepares its
+documents shortest first and enqueues each group as soon as it exists,
+and two counts on those spans say whether that engaged; ``LMTrainer.fit`` delivers every dispatch as a
 trace of its own, however long the fit; and the compiled forward and
 train step carry ``jax.named_scope`` names for each of their parts."""
 
@@ -13,6 +15,7 @@ import jax
 import numpy as np
 import pytest
 
+from test_inference import worded_issues as worded
 from test_slot_scheduler import make_engine
 from test_training import repeating_corpus, tiny_model
 
@@ -33,6 +36,16 @@ def issues(lengths):
     the text rules add)."""
     return [{"title": f"w{i % 7}", "body": " ".join(
         f"w{(i + j) % 140}" for j in range(n))} for i, n in enumerate(lengths)]
+
+
+def sorted_slab_lane_steps(n_tokens):
+    """What the length-sorted slabs of ``B`` cost, by hand."""
+    n_tokens, lanes = sorted(n_tokens), 0
+    for i in range(0, len(n_tokens), B):
+        longest = n_tokens[i:i + B][-1]
+        bucket = next((b for b in BUCKETS if longest <= b), BUCKETS[-1])
+        lanes += B * bucket * max(1, -(-longest // bucket))
+    return lanes
 
 
 def traced_call(engine, docs):
@@ -122,10 +135,17 @@ class TestGroupsPathSpans:
         # none overlaps the next (rendered times are rounded to 1 us)
         for a, b in zip(phases, phases[1:]):
             assert b["lo"] >= a["hi"] - 5e-6, (a["name"], b["name"])
-        # groups and flushes lie inside the documents' group_embed interval
-        whole = spans["engine.group_embed"][0]
+        # groups and flushes lie inside the group_embed interval of the
+        # documents prepared first; every document's own preparation lies
+        # before its own interval, and (ten groups) inside other documents'
+        whole = min(spans["engine.group_embed"], key=lambda s: s["lo"])
         for s in spans["engine.group"] + spans["engine.finalize"]:
             assert whole["lo"] - 5e-6 <= s["lo"] and s["hi"] <= whole["hi"] + 5e-6
+        starts = {s["doc"]: s["lo"] for s in spans["engine.group_embed"]}
+        for s in spans["engine.text_rules"] + spans["engine.tokenize"]:
+            assert s["hi"] <= starts[s["doc"]] + 5e-6
+        assert sum(s["lo"] > whole["lo"] for s in spans["engine.tokenize"]) \
+            == len(starts) - (B + B // 4)
 
     def test_untraced_call_records_nothing_and_reads_no_clock_per_doc(
             self, engine, monkeypatch):
@@ -139,7 +159,8 @@ class TestGroupsPathSpans:
         rows = engine.embed_issues(docs, scheduler="groups")
         assert rows.shape == (41, engine.embed_dim)
         assert finished == []
-        # two reads a group and two a flush, none per document
+        # two reads a group and two a flush, none per document and none
+        # per preparation slab
         assert len(reads) == 2 * 11 + 2 < len(docs)
 
     def test_ambient_trace_gets_the_same_spans(self, engine):
@@ -150,6 +171,89 @@ class TestGroupsPathSpans:
         assert names.count("engine.text_rules") == 5
         assert names.count("engine.group") == 2
         assert names.count("engine.finalize") == 1
+
+
+class TestStreamedGroups:
+    """A call of more than ``B + B // 4`` documents is prepared shortest
+    first (by raw size) and its groups go out as they fill."""
+
+    LOOKAHEAD = B + B // 4
+    LENGTHS = [2, 30, 5, 9, 1, 14, 3, 40, 7, 4, 22, 11, 6, 17]  # 4 groups
+
+    def test_lane_steps_are_the_sorted_slabs_when_the_proxy_orders_well(
+            self, engine):
+        spans, _ = traced_call(engine, worded(self.LENGTHS))
+        n_tokens = [s["attrs"]["n_tokens"] for s in spans["engine.tokenize"]]
+        groups = sorted(spans["engine.group"], key=lambda s: s["lo"])
+        assert sum(g["attrs"]["lane_steps"] for g in groups) \
+            == sorted_slab_lane_steps(n_tokens)
+        assert [g["attrs"]["late_docs"] for g in groups] == [0, 0, 0, 0]
+        assert [g["attrs"]["rows"] for g in groups] == [4, 4, 4, 2]
+
+    def test_an_adversarial_proxy_costs_padding_within_a_bound(self, engine):
+        spans, _ = traced_call(engine, worded(self.LENGTHS, adversarial=True))
+        n_tokens = [s["attrs"]["n_tokens"] for s in spans["engine.tokenize"]]
+        groups = sorted(spans["engine.group"], key=lambda s: s["lo"])
+        lanes = sum(g["attrs"]["lane_steps"] for g in groups)
+        # the bound: every group padded as the call's longest document is
+        assert sorted_slab_lane_steps(n_tokens) < lanes <= len(groups) * \
+            sorted_slab_lane_steps([max(n_tokens)])
+        assert groups[0]["attrs"]["late_docs"] == 0
+        assert sum(g["attrs"]["late_docs"] for g in groups) > 0
+        assert sum(g["attrs"]["valid_tokens"] for g in groups) == sum(n_tokens)
+
+    def test_overlapped_tokens_count_from_the_first_enqueue(self, engine):
+        spans, _ = traced_call(engine, worded(self.LENGTHS))
+        first_group = min(s["lo"] for s in spans["engine.group"])
+        toks = sorted(spans["engine.tokenize"], key=lambda s: s["lo"])
+        for k, s in enumerate(toks):
+            a = s["attrs"]
+            before = k < self.LOOKAHEAD
+            assert (s["hi"] <= first_group + 5e-6) == before
+            assert a["n_tokens_overlapped"] == (0 if before else a["n_tokens"])
+        # shortest first: the raw size ordered the preparation
+        assert [s["attrs"]["n_tokens"] for s in toks] == sorted(
+            s["attrs"]["n_tokens"] for s in toks)
+
+    @pytest.mark.parametrize("n", [1, B, B + B // 4],
+                             ids=["one", "batch", "lookahead"])
+    def test_a_call_that_never_fills_the_buffer_is_prepared_whole(
+            self, engine, n):
+        spans, _ = traced_call(engine, worded(self.LENGTHS[:n]))
+        groups = spans["engine.group"]
+        assert len(spans["engine.text_rules"]) == n
+        assert len(spans["engine.tokenize"]) == n
+        assert len(spans["engine.group_embed"]) == n
+        assert len(groups) == -(-n // B)
+        assert len(spans["engine.finalize"]) == 1
+        # all of the call's host work before its first group, one
+        # group_embed interval for every document, as it always was
+        assert max(s["hi"] for s in spans["engine.tokenize"]) \
+            <= min(g["lo"] for g in groups) + 5e-6
+        embed_from = [s["lo"] for s in spans["engine.group_embed"]]
+        assert max(embed_from) - min(embed_from) < 5e-6
+        assert all(s["attrs"]["n_tokens_overlapped"] == 0
+                   for s in spans["engine.tokenize"])
+        assert all(g["attrs"]["late_docs"] == 0 for g in groups)
+        n_tokens = [s["attrs"]["n_tokens"] for s in spans["engine.tokenize"]]
+        assert sum(g["attrs"]["lane_steps"] for g in groups) \
+            == sorted_slab_lane_steps(n_tokens)
+
+    def test_ids_fed_in_true_length_order_are_never_late(self, engine):
+        rng = np.random.RandomState(5)
+        seqs = [rng.randint(20, 150, n).astype(np.int32)
+                for n in rng.randint(1, 60, 23)]
+        tracer = Tracer()
+        with tracer.span("request"):
+            engine.embed_ids_batch(
+                seqs, scheduler="groups",
+                ctxs=[tracing.current_context()] * len(seqs))
+        groups = [s["attrs"] for s in tracer.traces()[0]["spans"]
+                  if s["name"] == "engine.group"]
+        assert len(groups) == 6
+        assert sum(g["lane_steps"] for g in groups) \
+            == sorted_slab_lane_steps([len(s) for s in seqs])
+        assert all(g["late_docs"] == 0 for g in groups)
 
 
 class TestFitTraces:
