@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from code_intelligence_tpu.models import AWDLSTMConfig, AWDLSTMEncoder, init_lstm_states
+from code_intelligence_tpu.models import AWDLSTMConfig, build_encoder
 from code_intelligence_tpu.text import Tokenizer, Vocab, build_issue_text
 from code_intelligence_tpu.text.rules import TK_UNK
 from code_intelligence_tpu.utils import profiling, resilience, tracing
@@ -77,58 +77,31 @@ class InferenceEngine:
         mesh=None,
         precision: str = "f32",
     ):
-        # Serve-time kernel override: the weights-resident Pallas cell is
-        # numerically the same layer (parity-tested), so an encoder
-        # trained on the scan can still SERVE on the fused cell.
-        if lstm_pallas is not None:
-            config = dataclasses.replace(config, lstm_use_pallas=lstm_pallas)
-        # Off the TPU the kernel has no compiled lowering (interpret mode
-        # is for tests, orders of magnitude slower than the scan): a CPU
-        # host serves the parity-identical scan — loudly, whether the flag
-        # came from the caller or from an exported config (e.g. a distilled
-        # student trained with lstm_use_pallas=True). On the TPU a
-        # requested kernel is never swapped: what cannot run raises below.
-        if config.lstm_use_pallas and jax.default_backend() != "tpu":
-            logging.getLogger(__name__).warning(
-                "lstm_use_pallas requested but backend is %s, not tpu — "
-                "serving on the XLA scan instead", jax.default_backend())
-            config = dataclasses.replace(config, lstm_use_pallas=False)
-        # mesh-sharded serve step (RUNBOOK §26): a Mesh, or a --mesh spec
-        # string ("data,model" / "data=4,model=2") resolved against the
-        # visible devices. The slot/ragged schedulers this engine creates
-        # run their ONE compiled step under it; None = single-chip.
         if isinstance(mesh, str):
+            # mesh-sharded serve step (RUNBOOK §26): a --mesh spec string
+            # ("data,model" / "data=4,model=2") resolved against the
+            # visible devices; None = single-chip
             from code_intelligence_tpu.parallel.serve_shard import (
                 build_serve_mesh)
 
             mesh = build_serve_mesh(mesh)
-        if mesh is not None and config.lstm_use_pallas:
-            # a Mosaic call inside a GSPMD-partitioned program is opaque
-            # to the partitioner and the serve step has no shard_map
-            # around it (ROADMAP S9/D6). Only reachable on the TPU — off
-            # it the flag was already dropped above.
-            raise ValueError(
-                "lstm_pallas (and with it the int8-fused kernel) does not "
-                "compose with --mesh: the sharded serve step has no "
-                "shard_map around the Pallas call. Serve the mesh with "
-                "--no-lstm_pallas, or one chip with the kernel.")
-        # Serve-path weight precision (RUNBOOK §28): "int8" quantizes the
-        # encoder weights AT LOAD (ops/quantize.py) — int8 leaves + f32
-        # per-channel scales replace the f32 matmul weights, and the
-        # dequant is fused into the encoder's matmuls (in-register in the
-        # ragged Pallas tiles, XLA-fused on the reference path). Leaf
-        # dtypes change but leaf SHAPES don't, so every scheduler keeps
-        # exactly ONE compiled step shape. The engine owns this knob:
-        # exports stay f32 (no new export format).
         if precision not in ("f32", "int8"):
             raise ValueError(
                 f"precision must be 'f32' or 'int8', got {precision!r}")
-        config = dataclasses.replace(config, precision=precision)
+        if isinstance(config, AWDLSTMConfig):
+            config = self._awd_serve_config(config, lstm_pallas, mesh,
+                                            precision)
+        elif lstm_pallas or precision != "f32":
+            # the kernel override and quantize-at-load are the AWD
+            # encoder's; any other encoder computes in the type of the
+            # weights it is handed
+            raise ValueError(
+                f"lstm_pallas / precision={precision!r} apply to the "
+                f"AWD-LSTM encoder only, not to {type(config).__name__}")
         self.precision = precision
         self.mesh = mesh
         self.config = config
         self.vocab = vocab
-        self.encoder = AWDLSTMEncoder(config)
         # Accept encoder-only params ({"embedding": ..., "lstm_0_w_ih": ...})
         # or a full-LM params tree ({"encoder": {...}, "decoder_b": ...}).
         if "embedding" in params:
@@ -151,6 +124,9 @@ class InferenceEngine:
             enc = quantize_encoder_params(dict(enc), config)
         self.weight_bytes = tree_bytes(enc)
         self._enc_params = {"params": enc}
+        # either encoder, through the one contract (models/contract.py):
+        # init_states / encode / out_dim / state_bytes_per_row
+        self.encoder = build_encoder(config, enc)
         self.buckets = tuple(sorted(buckets))
         self.batch_size = batch_size
         # Window size for docs longer than the largest bucket; snapped to a
@@ -161,7 +137,7 @@ class InferenceEngine:
         # "auto" everywhere (engine, universal model, corpus builds): one
         # tokenization behavior at train and serve time by construction.
         self.tokenizer = Tokenizer(backend="auto")
-        self.embed_dim = 3 * config.emb_sz
+        self.embed_dim = 3 * self.encoder.out_dim
         self._fwd_cache: Dict[Tuple[int, int], object] = {}
         # default batching policy: "groups" = the reference-shaped
         # length-sorted lock-step path below; "slots" = continuous
@@ -182,6 +158,57 @@ class InferenceEngine:
         # version strings but different vocabs must never alias cache
         # entries, since the same token ids mean different documents
         self.vocab_hash = vocab.content_hash()
+
+    @staticmethod
+    def _awd_serve_config(config: AWDLSTMConfig, lstm_pallas, mesh,
+                          precision: str) -> AWDLSTMConfig:
+        """The AWD-LSTM encoder's own serve-time knobs."""
+        # Serve-time kernel override: the weights-resident Pallas cell is
+        # numerically the same layer (parity-tested), so an encoder
+        # trained on the scan can still SERVE on the fused cell.
+        if lstm_pallas is not None:
+            config = dataclasses.replace(config, lstm_use_pallas=lstm_pallas)
+        # Off the TPU the kernel has no compiled lowering (interpret mode
+        # is for tests, orders of magnitude slower than the scan): a CPU
+        # host serves the parity-identical scan — loudly, whether the flag
+        # came from the caller or from an exported config (e.g. a distilled
+        # student trained with lstm_use_pallas=True). On the TPU a
+        # requested kernel is never swapped: what cannot run raises below.
+        if config.lstm_use_pallas and jax.default_backend() != "tpu":
+            logging.getLogger(__name__).warning(
+                "lstm_use_pallas requested but backend is %s, not tpu — "
+                "serving on the XLA scan instead", jax.default_backend())
+            config = dataclasses.replace(config, lstm_use_pallas=False)
+        if mesh is not None and config.lstm_use_pallas:
+            # a Mosaic call inside a GSPMD-partitioned program is opaque
+            # to the partitioner and the serve step has no shard_map
+            # around it (ROADMAP S9/D6). Only reachable on the TPU — off
+            # it the flag was already dropped above.
+            raise ValueError(
+                "lstm_pallas (and with it the int8-fused kernel) does not "
+                "compose with --mesh: the sharded serve step has no "
+                "shard_map around the Pallas call. Serve the mesh with "
+                "--no-lstm_pallas, or one chip with the kernel.")
+        # Serve-path weight precision (RUNBOOK §28): "int8" quantizes the
+        # encoder weights AT LOAD (ops/quantize.py) — int8 leaves + f32
+        # per-channel scales replace the f32 matmul weights, and the
+        # dequant is fused into the encoder's matmuls (in-register in the
+        # ragged Pallas tiles, XLA-fused on the reference path). Leaf
+        # dtypes change but leaf SHAPES don't, so every scheduler keeps
+        # exactly ONE compiled step shape. The engine owns this knob:
+        # exports stay f32 (no new export format).
+        return dataclasses.replace(config, precision=precision)
+
+    def state_geometry(self) -> dict:
+        """What one row in flight on the ``groups`` path holds on the
+        device, from the encoder contract: the ledger's geometry note
+        (``utils/memtrack.py::capacity_report`` turns it into
+        ``rows_fit``, the largest ``batch_size`` the headroom takes)."""
+        return {
+            "out_dim": self.encoder.out_dim,
+            "kv_positions": self.encoder.cache_positions(),
+            "state_bytes_per_row": self.encoder.state_bytes_per_row(),
+        }
 
     def warmup(self, scheduler: Optional[str] = None) -> None:
         """Compile the serve path's step program(s) off the hot path —
@@ -214,25 +241,28 @@ class InferenceEngine:
 
         def fwd(params, tokens, lengths, h_states, pool_state):
             states = jax.tree.unflatten(self._state_treedef, h_states)
-            raw, _, new_states = self.encoder.apply(
-                params, tokens, states, deterministic=True
-            )
+            raw, new_states = self.encoder.encode(
+                params["params"], tokens, states)
             pool_state = self._accumulate_pool(raw, lengths, pool_state)
             return pool_state, jax.tree.leaves(new_states)
 
-        jitted = jax.jit(fwd)
+        # the carried state is donated: each chunk program writes its new
+        # state over the one it was handed. Programs are enqueued ahead of
+        # the device, and without this every enqueued chunk of a group
+        # holds a whole state of its own (the hybrid's is 93 MB a row)
+        jitted = jax.jit(fwd, donate_argnums=(3,))
         self._fwd_cache[(batch, length)] = jitted
         return jitted
 
     @property
     def _state_treedef(self):
         if not hasattr(self, "_cached_treedef"):
-            states = init_lstm_states(self.config, 1)
-            self._cached_treedef = jax.tree.structure(states)
+            self._cached_treedef = jax.tree.structure(
+                jax.eval_shape(lambda: self.encoder.init_states(1)))
         return self._cached_treedef
 
     def _init_pool_state(self, batch: int):
-        E = self.config.emb_sz
+        E = self.encoder.out_dim
         return (
             jnp.zeros((batch, E), jnp.float32),
             jnp.full((batch, E), -jnp.inf, jnp.float32),
@@ -293,13 +323,27 @@ class InferenceEngine:
     # computing while earlier groups are still unfetched) without holding
     # more than ~64 * 4 * (B, E) f32 pool arrays in HBM
     _FLUSH_GROUPS = 64
+    # carried state of enqueued groups the device has not finished: a
+    # group's state is allocated when it is enqueued, so a host that runs
+    # far ahead of the chip holds one state a group it is ahead by. Past
+    # this many bytes the host waits for the oldest group before it
+    # enqueues another. Never reached by the AWD encoders (13 MB a group
+    # of 200); the hybrid's 1.5 GB a group of 16 keeps two in flight
+    _STATE_BYTES_IN_FLIGHT = 3 << 30
 
-    @staticmethod
-    def _check_scheduler(scheduler: str) -> str:
+    def _check_scheduler(self, scheduler: str) -> str:
         if scheduler not in ("groups", "slots", "ragged"):
             raise ValueError(
                 f"scheduler must be 'groups', 'slots' or 'ragged', "
                 f"got {scheduler!r}")
+        if scheduler != "groups" and not isinstance(self.config,
+                                                    AWDLSTMConfig):
+            # the slot arenas hold the AWD encoder's per-layer (h, c)
+            # leaves and its step reaches into them (inference/slots.py);
+            # a second kind of state in one arena is ROADMAP M2
+            raise ValueError(
+                f"scheduler {scheduler!r} serves the AWD-LSTM encoder "
+                f"only; {type(self.encoder).__name__} runs on 'groups'")
         return scheduler
 
     def slot_scheduler(self, registry=None, chunk_len: Optional[int] = None,
@@ -313,6 +357,7 @@ class InferenceEngine:
         from code_intelligence_tpu.inference.slots import (
             RaggedSlotScheduler, SlotScheduler)
 
+        self._check_scheduler("ragged" if ragged else "slots")
         if ragged:
             if chunk_len is not None:
                 # the ragged step's geometry knob is page_len; silently
@@ -454,6 +499,7 @@ class InferenceEngine:
         embed_from = [0.0] * n if ctxs is not None else None
         buf: List[Tuple[int, int, np.ndarray]] = []  # (true length, doc, ids)
         pending = []
+        in_flight: List[Tuple[object, int]] = []  # (a pool leaf, state bytes)
         longest_sent = -1  # no group enqueued yet
 
         def flush():
@@ -503,6 +549,11 @@ class InferenceEngine:
                             length < longest_sent for length, _, _ in group))
                 longest_sent = max(longest_sent, group[-1][0])
                 pending.append((idx, pool))
+                in_flight.append((pool[3], counts["state_bytes"]))
+                while len(in_flight) > 1 and sum(
+                        b for _, b in in_flight) > self._STATE_BYTES_IN_FLIGHT:
+                    # not a pipeline flush: the newest groups stay queued
+                    in_flight.pop(0)[0].block_until_ready()  # graft: noqa[blocking-dispatch] — memory backpressure on the oldest group only; never reached below 3 GB of carried state in flight
                 if len(pending) >= self._FLUSH_GROUPS:
                     flush()
             del buf[:take]
@@ -529,18 +580,23 @@ class InferenceEngine:
         group's counts: what it holds (``rows``, ``valid_tokens``) and
         what the device is asked to run for it (``batch`` x ``bucket`` x
         ``chunks`` = ``lane_steps``) — the ``engine.group`` span's
-        attributes, counted here where the padding is made."""
+        attributes, counted here where the padding is made; with them
+        ``state_bytes`` (the state carried between the group's chunk
+        programs, all rows) and ``kv_positions`` (cache positions a row
+        is allocated; 0 for a fixed-size state), from the encoder."""
         B = self.batch_size  # fixed batch shape; pad the remainder
         max_len = max(len(s) for s in seqs)
         # Short groups run in one pass at the smallest fitting bucket; long
         # docs stream through chunk_len-sized windows with carried state.
         bucket = self._bucket_for(max_len) if max_len <= self.buckets[-1] else self.chunk_len
-        states = init_lstm_states(self.config, B)
-        h_leaves = jax.tree.leaves(states)
+        n_chunks = max(1, -(-max_len // bucket))
+        # the carried state, sized for the group's longest document (only
+        # a state that grows with the document reads the size)
+        positions = bucket * n_chunks
+        h_leaves = jax.tree.leaves(self.encoder.init_states(B, positions))
         pool = self._init_pool_state(B)
         pad_id = self.vocab.pad_id
 
-        n_chunks = max(1, -(-max_len // bucket))
         fwd = self._fwd(B, bucket)
         for ci in range(n_chunks):
             tokens = np.full((B, bucket), pad_id, np.int32)
@@ -557,6 +613,8 @@ class InferenceEngine:
             "chunks": n_chunks,
             "valid_tokens": sum(len(s) for s in seqs),
             "lane_steps": B * bucket * n_chunks,
+            "state_bytes": B * self.encoder.state_bytes_per_row(positions),
+            "kv_positions": self.encoder.cache_positions(positions),
         }
 
     def embed_text(self, text: str) -> np.ndarray:

@@ -4,5 +4,16 @@ from code_intelligence_tpu.models.awd_lstm import (
     AWDLSTMLM,
     init_lstm_states,
 )
+from code_intelligence_tpu.models.contract import (
+    ChunkEncoder,
+    build_encoder,
+    make_config,
+)
+from code_intelligence_tpu.models.granite_hybrid import (
+    GraniteHybridConfig,
+    GraniteHybridEncoder,
+)
 
-__all__ = ["AWDLSTMConfig", "AWDLSTMEncoder", "AWDLSTMLM", "init_lstm_states"]
+__all__ = ["AWDLSTMConfig", "AWDLSTMEncoder", "AWDLSTMLM", "init_lstm_states",
+           "ChunkEncoder", "build_encoder", "make_config",
+           "GraniteHybridConfig", "GraniteHybridEncoder"]

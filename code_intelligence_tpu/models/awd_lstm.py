@@ -21,7 +21,7 @@ Hidden state is functional: callers pass states in and get new states out
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Optional, Tuple
+from typing import Any, ClassVar, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -44,6 +44,8 @@ from code_intelligence_tpu.ops.quantize import SCALE_SUFFIX
 class AWDLSTMConfig:
     """Hyperparameters, mirroring the reference's config-dict mutation of
     fastai's ``awd_lstm_lm_config`` (`train.py:42-46,68-73`)."""
+
+    architecture: ClassVar[str] = "awd_lstm"  # models/contract.py
 
     vocab_size: int
     emb_sz: int = 800
@@ -132,6 +134,41 @@ class AWDLSTMEncoder(nn.Module):
     # mesh for seq_axis time-sharding (see AWDLSTMConfig.seq_axis); kept
     # out of the config so exported configs stay JSON-serializable
     mesh: Optional[Any] = None
+
+    # -- the encoder contract (models/contract.py): what the inference
+    # engine asks of a model. Not wrapped by Flax: they read the config
+    # alone and call ``apply`` from outside, on the unbound module.
+
+    @property
+    def out_dim(self) -> int:
+        return self.config.emb_sz
+
+    @nn.nowrap
+    def init_states(self, batch: int, positions=None):
+        """Fixed-size state: ``positions`` (documents' length) is not read."""
+        return init_lstm_states(self.config, batch)
+
+    @nn.nowrap
+    def cache_positions(self, positions=None) -> int:
+        return 0  # no part of the state grows with the document
+
+    @nn.nowrap
+    def encode(self, params, tokens, states):
+        raw, _, new_states = self.apply(
+            {"params": params}, tokens, states, deterministic=True)
+        return raw, new_states
+
+    @nn.nowrap
+    def state_bytes_per_row(self, max_len=None) -> int:
+        """``init_lstm_states``' two leaves a layer, by arithmetic (the
+        engine asks once a group, on its hot path)."""
+        cfg = self.config
+        units = 0
+        for li in range(cfg.n_layers):
+            in_dim = cfg.emb_sz if li == 0 else cfg.n_hid
+            units += cfg.layer_size(li) + (
+                in_dim if cfg.qrnn else cfg.layer_size(li))
+        return units * jnp.dtype(cfg.dtype).itemsize
 
     @nn.compact
     def __call__(

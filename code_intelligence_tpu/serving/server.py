@@ -230,6 +230,9 @@ class EmbeddingServer(ThreadingHTTPServer):
             self.ledger.register(
                 "engine.params",
                 lambda: getattr(self.engine, "_enc_params", None))
+        geometry = getattr(engine, "state_geometry", None)
+        if geometry is not None:  # test doubles have none
+            self.ledger.note_geometry(**geometry())
         super().__init__(addr, _Handler)  # bind first: a bind failure must
         if batch_window_ms is not None:  # not leak a running batcher thread
             from code_intelligence_tpu.serving.batcher import MicroBatcher

@@ -73,7 +73,12 @@ def export_encoder(out_dir, params: Any, config: AWDLSTMConfig, vocab=None) -> P
     enc = params["encoder"] if "encoder" in params else params
     save_params_npz(out / "encoder_params.npz", enc)
     cfg = dataclasses.asdict(config)
-    cfg["dtype"] = np.dtype(config.dtype).name if config.dtype is not None else "float32"
+    # which encoder reads it back (models/contract.py::make_config)
+    cfg["architecture"] = config.architecture
+    for key in ("dtype", "state_dtype"):  # dtype fields, by name
+        if key in cfg:
+            cfg[key] = np.dtype(cfg[key]).name if cfg[key] is not None \
+                else "float32"
     (out / CONFIG_NAME).write_text(json.dumps(cfg, indent=1))
     if vocab is not None:
         vocab.save(out / "vocab.json")
@@ -81,15 +86,19 @@ def export_encoder(out_dir, params: Any, config: AWDLSTMConfig, vocab=None) -> P
 
 
 def load_encoder(model_dir):
-    """Load ``(encoder_params, AWDLSTMConfig, vocab_path_or_None)``."""
-    import jax.numpy as jnp
-
+    """Load ``(encoder_params, config, vocab_path_or_None)``; the export's
+    ``architecture`` says which encoder's configuration it holds."""
+    from code_intelligence_tpu.models import make_config
     from code_intelligence_tpu.utils.params_io import load_params_npz
 
     model_dir = Path(model_dir)
+
     cfg_raw = json.loads((model_dir / CONFIG_NAME).read_text())
-    cfg_raw["dtype"] = jnp.dtype(cfg_raw.get("dtype", "float32"))
-    config = AWDLSTMConfig(**cfg_raw)
+    # exports older than the encoder contract hold the AWD-LSTM
+    architecture = cfg_raw.pop("architecture", AWDLSTMConfig.architecture)
+    if architecture == AWDLSTMConfig.architecture:
+        cfg_raw.setdefault("dtype", "float32")
+    config = make_config(architecture, cfg_raw)
     params = load_params_npz(model_dir / "encoder_params.npz")
     vocab_path = model_dir / "vocab.json"
     return params, config, (vocab_path if vocab_path.exists() else None)

@@ -392,6 +392,10 @@ class DeviceMemoryLedger:
         and per-tenant heads against the per-device budget, plus the
         paged-arena geometry when the scheduler noted one.
 
+        ``rows_fit`` is the headroom over the engine's
+        ``state_bytes_per_row`` note: how many rows of carried encoder
+        state fit, which bounds ``batch_size`` once the state is large.
+
         ``budget_bytes`` is PER DEVICE (default: what the device reports,
         ``budget_source: "device"``; the 16 GiB constant only where it
         reports nothing); headroom is measured on the fullest device (the
@@ -430,6 +434,10 @@ class DeviceMemoryLedger:
             "head_bytes": None if head_bytes is None else int(head_bytes),
             "heads_fit": None if not head_bytes
             else int(headroom // int(head_bytes)),
+            # rows of carried encoder state (the engine's geometry note,
+            # from the encoder contract): the batch the headroom takes
+            "rows_fit": None if not geometry.get("state_bytes_per_row")
+            else int(headroom // int(geometry["state_bytes_per_row"])),
             "geometry": geometry,
             "host": dict(snap["host"]),
         }

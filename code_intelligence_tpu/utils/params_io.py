@@ -37,5 +37,10 @@ def load_params_npz(path) -> dict:
         parts = key.split("/")
         for part in parts[:-1]:
             node = node.setdefault(part, {})
-        node[parts[-1]] = jnp.asarray(npz[key])
+        arr = npz[key]
+        if arr.dtype == np.dtype("V2"):
+            # numpy stores bfloat16 (an ml_dtypes extension type) as raw
+            # 2-byte records; nothing else here is 2 bytes of void
+            arr = arr.view(jnp.bfloat16)
+        node[parts[-1]] = jnp.asarray(arr)
     return params
