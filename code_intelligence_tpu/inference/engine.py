@@ -18,6 +18,14 @@ TPU-first redesign (SURVEY.md §7 stage 4):
   documents, `inference.py:60,70` — state never leaks across docs).
   Pooling (mean/max/last) accumulates across chunks and is exactly equal
   to full-sequence pooling.
+* **Rows that have finished leave a group**: a group's first chunk
+  program runs at ``batch_size``; before each later one the batch
+  narrows to the smallest of ``batch_size`` halved up to three times
+  that holds the documents still going (the groups are length-sorted,
+  so those are the batch's last rows), carrying that suffix of the state
+  and of the pool on the device. A group costs the lane-steps of the
+  rows each of its chunks ran, not ``batch_size`` times its longest
+  document; a batch size compiles at most four programs a bucket.
 * Padding is masked out of all three pools (the reference pools over raw
   padded activations only in its batch path — here padded and unpadded
   paths agree by construction).
@@ -29,7 +37,9 @@ The 2400→1600 truncation contract for downstream classifier heads
 
 from __future__ import annotations
 
+import bisect
 import dataclasses
+import functools
 import logging
 import time
 from typing import Dict, List, Optional, Sequence, Tuple, Union
@@ -254,12 +264,57 @@ class InferenceEngine:
         self._fwd_cache[(batch, length)] = jitted
         return jitted
 
-    @property
+    @functools.cached_property
     def _state_treedef(self):
-        if not hasattr(self, "_cached_treedef"):
-            self._cached_treedef = jax.tree.structure(
-                jax.eval_shape(lambda: self.encoder.init_states(1)))
-        return self._cached_treedef
+        return jax.tree.structure(
+            jax.eval_shape(lambda: self.encoder.init_states(1)))
+
+    @functools.cached_property
+    def _state_batch_axes(self) -> Tuple[Optional[int], ...]:
+        """Each state leaf's batch axis, from the contract alone: the one
+        axis on which the states of one row and of two differ (None for
+        a leaf every row shares, a position counter)."""
+        one, two = (jax.tree.leaves(jax.eval_shape(
+            lambda rows=rows: self.encoder.init_states(rows)))
+            for rows in (1, 2))
+        axes = []
+        for a, b in zip(one, two):
+            differ = [ax for ax, (p, q) in enumerate(zip(a.shape, b.shape))
+                      if p != q]
+            if len(differ) > 1 or len(a.shape) != len(b.shape):
+                raise ValueError(
+                    f"init_states gives a leaf {a.shape} for one row and "
+                    f"{b.shape} for two: no single batch axis")
+            axes.append(differ[0] if differ else None)
+        return tuple(axes)
+
+    @functools.cached_property
+    def _narrow(self):
+        """``narrow(h_states, pool_state, keep)``: the last ``keep`` rows
+        of a group's carried state and pool, and the pool of the rows
+        before them, which are finished. One small device program a
+        (batch, ``keep``) pair; nothing comes to the host."""
+        axes = self._state_batch_axes
+
+        def narrow(h_states, pool_state, keep):
+            def kept(x, axis=0):
+                n = x.shape[axis]
+                return jax.lax.slice_in_dim(x, n - keep, n, axis=axis)
+
+            return (tuple(p[: p.shape[0] - keep] for p in pool_state),
+                    tuple(kept(p) for p in pool_state),
+                    [x if ax is None else kept(x, ax)
+                     for x, ax in zip(h_states, axes)])
+
+        return jax.jit(narrow, static_argnums=(2,))
+
+    def _batch_for(self, rows: int) -> int:
+        """The batch a chunk program of ``rows`` live rows runs at: the
+        smallest of ``batch_size`` halved up to three times (200, 100,
+        50, 25) that holds them, so a batch size compiles at most four
+        programs a bucket."""
+        B = self.batch_size
+        return min(b for b in (-(-B // d) for d in (1, 2, 4, 8)) if b >= rows)
 
     def _init_pool_state(self, batch: int):
         E = self.encoder.out_dim
@@ -457,11 +512,17 @@ class InferenceEngine:
         look-ahead late, and costs padding, never a wrong row, when one
         does. A call of at most ``batch_size + batch_size // 4``
         documents never fills the buffer: it is prepared whole, sorted
-        exactly and sent as it always was.
+        exactly and sent as it always was. What a group then costs the
+        device is :meth:`_embed_group_device`'s: its first chunk program
+        at ``batch_size`` rows, every later one at the rows still alive
+        (rounded up to the halving grid), so a call's last group, the
+        only one of many chunks, no longer runs its longest document's
+        chunks at the whole batch.
 
         Spans, when ``ctxs`` carries per-doc SpanContexts: ONE
         ``engine.group`` per group (host assembly + enqueue, no device
-        sync; the group's padding counts, and ``late_docs`` = its
+        sync; the group's padding counts, ``row_chunks_dropped`` = how
+        far its chunk programs narrowed, and ``late_docs`` = its
         documents shorter than the longest document of a group this call
         had already enqueued: 0 when the feed was ordered well, the
         field signal that a corpus defeats the proxy) on the group's
@@ -507,8 +568,10 @@ class InferenceEngine:
                 return
             tf0 = time.perf_counter()
             with profiling.annotate("engine.finalize"):
-                for idx, pool in pending:
-                    out[idx] = self._finalize(pool)[: len(idx)]
+                for idx, pools in pending:
+                    # the group's documents are the last rows of its batch
+                    out[idx] = np.concatenate(
+                        [self._finalize(p) for p in pools])[-len(idx):]
             tracing.record_span("engine.finalize", tf0, time.perf_counter(),
                                 call_ctx, groups=len(pending))
             pending.clear()
@@ -538,7 +601,7 @@ class InferenceEngine:
                 # on a host round-trip every batch_size docs
                 tg0 = time.perf_counter()
                 with profiling.annotate("engine.group"):
-                    pool, counts = self._embed_group_device(
+                    pools, counts = self._embed_group_device(
                         [ids for _, _, ids in group])
                 tg1 = time.perf_counter()
                 if ctxs is not None:
@@ -548,8 +611,9 @@ class InferenceEngine:
                         late_docs=sum(
                             length < longest_sent for length, _, _ in group))
                 longest_sent = max(longest_sent, group[-1][0])
-                pending.append((idx, pool))
-                in_flight.append((pool[3], counts["state_bytes"]))
+                pending.append((idx, pools))
+                # a leaf the group's LAST program writes
+                in_flight.append((pools[-1][3], counts["state_bytes"]))
                 while len(in_flight) > 1 and sum(
                         b for _, b in in_flight) > self._STATE_BYTES_IN_FLIGHT:
                     # not a pipeline flush: the newest groups stay queued
@@ -577,15 +641,38 @@ class InferenceEngine:
     def _embed_group_device(self, seqs: List[np.ndarray]):  # graft: hot
         """Enqueue one group's forward passes; returns the DEVICE pool
         state (no host sync — ``_finalize`` materializes it) and the
-        group's counts: what it holds (``rows``, ``valid_tokens``) and
-        what the device is asked to run for it (``batch`` x ``bucket`` x
-        ``chunks`` = ``lane_steps``) — the ``engine.group`` span's
-        attributes, counted here where the padding is made; with them
-        ``state_bytes`` (the state carried between the group's chunk
-        programs, all rows) and ``kv_positions`` (cache positions a row
-        is allocated; 0 for a fixed-size state), from the encoder."""
-        B = self.batch_size  # fixed batch shape; pad the remainder
-        max_len = max(len(s) for s in seqs)
+        group's counts.
+
+        ``seqs`` come in ascending length (both feeders sort), and sit
+        in the LAST rows of the batch, padding rows first, so the rows
+        still alive at any chunk are a suffix of it. The first chunk
+        program runs at ``batch_size``. Before each later chunk the rows
+        whose documents have ended leave: the chunk runs at the smallest
+        batch of the halving grid (``_batch_for``) that holds the live
+        rows, on the suffix of the carried state and pool that
+        ``_narrow`` cuts on the device. The pool rows that left are
+        finished; the pool comes back as its pieces in row order (one
+        piece, as it always was, unless the group narrowed), the last
+        of them an output of the group's last program. A single-chunk
+        group, and a group whose rows all live to the end, run the
+        programs they always ran.
+
+        Counts, the ``engine.group`` span's attributes, counted here
+        where the padding is made: what the group holds (``rows``,
+        ``valid_tokens``), what it was enqueued as (``batch`` x
+        ``bucket`` x ``chunks`` = ``lane_steps``), what the device is
+        asked to run for it (``lane_steps_run`` = the rows each chunk
+        program ran x ``bucket``, summed; ``row_chunks_dropped`` =
+        ``batch`` less the rows run, summed over the chunks: 0 when
+        nothing narrowed), and from the encoder ``state_bytes`` (the
+        state carried out of the first chunk program, all ``batch``
+        rows) and ``kv_positions`` (cache positions a row is allocated;
+        0 for a fixed-size state)."""
+        B = self.batch_size  # the first chunk's shape; pad the remainder
+        lens = [len(s) for s in seqs]
+        if any(a > b for a, b in zip(lens, lens[1:])):
+            raise ValueError("a group's documents come in ascending length")
+        max_len = lens[-1]
         # Short groups run in one pass at the smallest fitting bucket; long
         # docs stream through chunk_len-sized windows with carried state.
         bucket = self._bucket_for(max_len) if max_len <= self.buckets[-1] else self.chunk_len
@@ -597,22 +684,35 @@ class InferenceEngine:
         pool = self._init_pool_state(B)
         pad_id = self.vocab.pad_id
 
-        fwd = self._fwd(B, bucket)
+        batch, rows_run, pools = B, 0, []
         for ci in range(n_chunks):
-            tokens = np.full((B, bucket), pad_id, np.int32)
-            lengths = np.zeros((B,), np.int32)
-            for r, s in enumerate(seqs):
+            if ci:
+                alive = len(seqs) - bisect.bisect_right(lens, ci * bucket)
+                keep = self._batch_for(alive)
+                if keep < batch:
+                    left, pool, h_leaves = self._narrow(
+                        tuple(h_leaves), pool, keep)
+                    pools.append(left)
+                    batch = keep
+            live = seqs[-batch:]
+            tokens = np.full((batch, bucket), pad_id, np.int32)
+            lengths = np.zeros((batch,), np.int32)
+            for r, s in enumerate(live, batch - len(live)):
                 chunk = s[ci * bucket : (ci + 1) * bucket]
                 tokens[r, : len(chunk)] = chunk
                 lengths[r] = len(chunk)
-            pool, h_leaves = fwd(
+            pool, h_leaves = self._fwd(batch, bucket)(
                 self._enc_params, jnp.asarray(tokens), jnp.asarray(lengths), tuple(h_leaves), pool
             )
-        return pool, {
+            rows_run += batch
+        pools.append(pool)
+        return pools, {
             "rows": len(seqs), "batch": B, "bucket": bucket,
             "chunks": n_chunks,
-            "valid_tokens": sum(len(s) for s in seqs),
+            "valid_tokens": sum(lens),
             "lane_steps": B * bucket * n_chunks,
+            "lane_steps_run": rows_run * bucket,
+            "row_chunks_dropped": B * n_chunks - rows_run,
             "state_bytes": B * self.encoder.state_bytes_per_row(positions),
             "kv_positions": self.encoder.cache_positions(positions),
         }

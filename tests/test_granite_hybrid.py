@@ -254,7 +254,7 @@ def test_embed_issues_equals_the_references_pooled_rows(params, engine):
 
 def test_group_span_carries_state_bytes_and_kv_positions(engine):
     _, counts = engine._embed_group_device(
-        [np.arange(20, 60, dtype=np.int32), np.arange(20, 25, dtype=np.int32)])
+        [np.arange(20, 25, dtype=np.int32), np.arange(20, 60, dtype=np.int32)])
     assert counts["chunks"] == 3 and counts["kv_positions"] == 128
     assert counts["state_bytes"] == 4 * engine.encoder.state_bytes_per_row(48)
     _, short = engine._embed_group_device([np.arange(20, 28, dtype=np.int32)])
@@ -313,8 +313,8 @@ def awd_engine(qrnn, vocab, **kw):
     params = AWDLSTMEncoder(cfg).init(
         {"params": jax.random.PRNGKey(0)}, np.zeros((1, 4), np.int32),
         init_lstm_states(cfg, 1))["params"]
-    return InferenceEngine(params, cfg, vocab, buckets=(8, 16, 32),
-                           batch_size=4, **kw)
+    return InferenceEngine(params, cfg, vocab, **{
+        "buckets": (8, 16, 32), "batch_size": 4, **kw})
 
 
 @pytest.mark.parametrize("qrnn", [False, True], ids=["lstm", "qrnn"])

@@ -124,7 +124,9 @@ class TestPooling:
         eng = InferenceEngine(params, cfg, vocab, buckets=(8, 16), batch_size=2, chunk_len=8)
         ids = np.arange(30, 70, dtype=np.int32)  # longer than biggest bucket
         emb = eng.embed_ids_batch([ids])[0]
-        assert set(eng._fwd_cache) == {(2, 8)}  # chunked at 8, not 16
+        # chunked at 8, not 16; the first chunk at the batch, the rest
+        # at the one live row
+        assert set(eng._fwd_cache) == {(2, 8), (1, 8)}
         # and numerically equal to the full forward
         states = init_lstm_states(cfg, 1)
         raw, _, _ = enc.apply({"params": params}, ids[None, :], states, deterministic=True)

@@ -15,6 +15,7 @@ import jax
 import numpy as np
 import pytest
 
+from test_group_narrowing import rows_run
 from test_inference import worded_issues as worded
 from test_slot_scheduler import make_engine
 from test_training import repeating_corpus, tiny_model
@@ -27,6 +28,7 @@ from code_intelligence_tpu.utils import tracing
 from code_intelligence_tpu.utils.tracing import Tracer
 
 B, BUCKETS = 4, (8, 16)
+GRID = (4, 2, 1)  # B, B/2, B/4 (and B/8 rounded up, 1 again)
 PHASES = ("engine.text_rules", "engine.tokenize", "engine.group",
           "engine.finalize")
 
@@ -38,14 +40,21 @@ def issues(lengths):
         f"w{(i + j) % 140}" for j in range(n))} for i, n in enumerate(lengths)]
 
 
-def sorted_slab_lane_steps(n_tokens):
-    """What the length-sorted slabs of ``B`` cost, by hand."""
+def sorted_slab_lane_steps(n_tokens, run=False):
+    """What the length-sorted slabs of ``B`` cost, by hand: as enqueued
+    (every chunk program at ``B`` rows: ``lane_steps``), or with ``run``
+    what the device is asked to run (``lane_steps_run``)."""
     n_tokens, lanes = sorted(n_tokens), 0
     for i in range(0, len(n_tokens), B):
-        longest = n_tokens[i:i + B][-1]
-        bucket = next((b for b in BUCKETS if longest <= b), BUCKETS[-1])
-        lanes += B * bucket * max(1, -(-longest // bucket))
+        slab = n_tokens[i:i + B]
+        bucket = next((b for b in BUCKETS if slab[-1] <= b), BUCKETS[-1])
+        ran = rows_run(slab, bucket, GRID)
+        lanes += (sum(ran) if run else B * len(ran)) * bucket
     return lanes
+
+
+def lane_steps_of(groups, key="lane_steps"):
+    return sum(g.get("attrs", g)[key] for g in groups)
 
 
 def traced_call(engine, docs):
@@ -113,6 +122,29 @@ class TestGroupsPathSpans:
         assert a["bucket"] in BUCKETS
         assert a["chunks"] == -(-max(n_tokens) // a["bucket"]) == chunks
         assert a["lane_steps"] == B * a["bucket"] * a["chunks"]
+        ran = rows_run(n_tokens, a["bucket"], GRID)
+        assert len(ran) == chunks
+        assert a["lane_steps_run"] == sum(ran) * a["bucket"]
+        assert a["row_chunks_dropped"] == B * chunks - sum(ran)
+        assert (a["row_chunks_dropped"] > 0) == (chunks > 1)
+
+    @pytest.mark.parametrize("lengths,ran", [
+        ([1, 2, 3, 4], [4]),                 # a single chunk
+        ([33, 34, 35, 36], [4, 4, 4]),       # every row alive to the end
+        ([5, 12, 20, 40], [4, 2, 1]),        # 4, 2, 1 alive
+        ([16, 16, 32, 48], [4, 2, 1]),       # ending ON the boundaries
+        ([9, 40], [4, 1, 1]),                # two documents in four rows
+    ], ids=["single", "all_alive", "4_2_1", "boundaries", "partial"])
+    def test_row_chunks_dropped_is_the_hand_count(self, engine, lengths,
+                                                  ran):
+        rng = np.random.RandomState(7)
+        _, a = engine._embed_group_device(
+            [rng.randint(20, 150, n).astype(np.int32) for n in lengths])
+        assert rows_run(lengths, a["bucket"], GRID) == ran
+        assert a["chunks"] == len(ran) and a["batch"] == B
+        assert a["lane_steps"] == B * a["bucket"] * len(ran)
+        assert a["lane_steps_run"] == sum(ran) * a["bucket"]
+        assert a["row_chunks_dropped"] == sum(B - r for r in ran)
 
     def test_groups_hold_the_length_sorted_documents(self, engine):
         spans, _ = traced_call(
@@ -185,8 +217,9 @@ class TestStreamedGroups:
         spans, _ = traced_call(engine, worded(self.LENGTHS))
         n_tokens = [s["attrs"]["n_tokens"] for s in spans["engine.tokenize"]]
         groups = sorted(spans["engine.group"], key=lambda s: s["lo"])
-        assert sum(g["attrs"]["lane_steps"] for g in groups) \
-            == sorted_slab_lane_steps(n_tokens)
+        assert lane_steps_of(groups) == sorted_slab_lane_steps(n_tokens)
+        assert lane_steps_of(groups, "lane_steps_run") \
+            == sorted_slab_lane_steps(n_tokens, run=True)
         assert [g["attrs"]["late_docs"] for g in groups] == [0, 0, 0, 0]
         assert [g["attrs"]["rows"] for g in groups] == [4, 4, 4, 2]
 
@@ -194,10 +227,16 @@ class TestStreamedGroups:
         spans, _ = traced_call(engine, worded(self.LENGTHS, adversarial=True))
         n_tokens = [s["attrs"]["n_tokens"] for s in spans["engine.tokenize"]]
         groups = sorted(spans["engine.group"], key=lambda s: s["lo"])
-        lanes = sum(g["attrs"]["lane_steps"] for g in groups)
+        lanes = lane_steps_of(groups)
         # the bound: every group padded as the call's longest document is
         assert sorted_slab_lane_steps(n_tokens) < lanes <= len(groups) * \
             sorted_slab_lane_steps([max(n_tokens)])
+        # rows that leave early take lane-steps off that, whatever the
+        # order was (an order that spreads the long documents over the
+        # groups can even run fewer than the sorted slabs do)
+        assert sum(n_tokens) < lane_steps_of(groups, "lane_steps_run") \
+            == lanes - BUCKETS[-1] * lane_steps_of(
+                groups, "row_chunks_dropped") < lanes
         assert groups[0]["attrs"]["late_docs"] == 0
         assert sum(g["attrs"]["late_docs"] for g in groups) > 0
         assert sum(g["attrs"]["valid_tokens"] for g in groups) == sum(n_tokens)
@@ -236,8 +275,9 @@ class TestStreamedGroups:
                    for s in spans["engine.tokenize"])
         assert all(g["attrs"]["late_docs"] == 0 for g in groups)
         n_tokens = [s["attrs"]["n_tokens"] for s in spans["engine.tokenize"]]
-        assert sum(g["attrs"]["lane_steps"] for g in groups) \
-            == sorted_slab_lane_steps(n_tokens)
+        assert lane_steps_of(groups) == sorted_slab_lane_steps(n_tokens)
+        assert lane_steps_of(groups, "lane_steps_run") \
+            == sorted_slab_lane_steps(n_tokens, run=True)
 
     def test_ids_fed_in_true_length_order_are_never_late(self, engine):
         rng = np.random.RandomState(5)
@@ -251,8 +291,11 @@ class TestStreamedGroups:
         groups = [s["attrs"] for s in tracer.traces()[0]["spans"]
                   if s["name"] == "engine.group"]
         assert len(groups) == 6
-        assert sum(g["lane_steps"] for g in groups) \
+        assert lane_steps_of(groups) \
             == sorted_slab_lane_steps([len(s) for s in seqs])
+        assert lane_steps_of(groups, "lane_steps_run") \
+            == sorted_slab_lane_steps([len(s) for s in seqs], run=True) \
+            < lane_steps_of(groups)
         assert all(g["late_docs"] == 0 for g in groups)
 
 
