@@ -59,8 +59,9 @@ from pathlib import Path
 @dataclasses.dataclass(frozen=True)
 class Widths:
     """One model + job size. The defaults are the flagship the repo
-    supports (bench.py ``_BENCH_MODEL``, reference ``train.py:42-46``);
-    tests/test_chip_smoke.py calls the legs at a tiny size on CPU."""
+    supports (``benchmark/configs/awd_lstm_flagship.json``, reference
+    ``train.py:42-46``); tests/test_chip_smoke.py calls the legs at a tiny
+    size on CPU."""
 
     vocab: int = 60000
     emb: int = 800

@@ -462,7 +462,8 @@ class InferenceEngine:
         """Embed already-numericalized docs; returns (N, 3*emb_sz) float32.
 
         Returning implies a full device sync: every group's result has
-        been materialized to host numpy (bench_serving relies on this).
+        been materialized to host numpy (the benchmark's window clock relies
+        on this).
 
         ``ctxs`` — optional per-doc tracing SpanContexts: the slots path
         attributes queue-wait/device/emit per document; the group path

@@ -22,13 +22,11 @@ only pays off on mixed lengths, so a regression (a geometry change, a
 step program growing per-step overhead, a parity break) would otherwise
 surface only in production metrics. RUNBOOK §23.
 
-This is deliberately a package-internal twin of the repo-root
-``bench_serving.bench_ragged_ab`` harness (runbook_ci must not import
-repo-root bench modules): both compute flops-per-token as the ONE step
-program's AOT flops × steps ÷ valid tokens off the same scheduler
-counters — lifetime totals here, per-run deltas there; identical ratios
-since every pass stages the same schedule. Keep their accounting in
-step when changing either.
+Flops-per-token is the ONE step program's AOT flops × steps ÷ valid
+tokens, off the schedulers' lifetime counters (every pass stages the same
+schedule). This gate is the only holder of these pins: it proves counts
+and parity on the CPU, never a rate; what the ragged path is worth in
+time is for a benchmark cell on the chip (ROADMAP Queue 2, part B).
 """
 
 from __future__ import annotations
@@ -44,9 +42,9 @@ FIXTURE = Path(__file__).resolve().parent / "fixtures" / "ragged_lengths.json"
 
 
 def _tiny_engine(batch_size: int = 8):
-    """Small randomly-initialized engine, sized like the bench smoke
-    engine (compute-dominated forward, chunk_len 64 / page_len 16 — the
-    production geometry ratio, not the unit-test toy one)."""
+    """Small randomly-initialized engine with a compute-dominated forward
+    (chunk_len 64 / page_len 16 — the production geometry ratio, not the
+    unit-test toy one)."""
     import jax
 
     from code_intelligence_tpu.inference import InferenceEngine

@@ -446,8 +446,7 @@ class SlotScheduler:
         """AOT ``{'flops', 'bytes_accessed'}`` of the ONE compiled step
         program: lowers the persistent step shape explicitly and reads
         XLA's ``cost_analysis`` — device-free, so the ragged-vs-dense
-        flops-per-token claim is provable on CPU
-        (`bench_serving.bench_ragged_ab`, ``runbook_ci
+        flops-per-token claim is provable on CPU (``runbook_ci
         --check_ragged``). Memoized: the lowering is a real compile and
         must never ride the serve hot path."""
         if self._step_cost is None:

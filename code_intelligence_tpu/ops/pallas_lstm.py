@@ -14,8 +14,9 @@ through torch (`Issue_Embeddings/train.py:88-92`; SURVEY.md §2.4 row 1 —
 item #2). Round 3's on-chip A/B overturned the round-2 assumption that
 the flagship H=2500 is out of reach: v5e's 128MB VMEM (~64MB Mosaic
 scope) holds the 50MB bf16 ``W_hh`` resident, and the fused forward
-measured 1.80x the XLA scan at H=2500 (4.68ms vs 8.44ms, B=104 T=67 —
-docs/RUNBOOK.md §11 / ``bench_pallas_lstm.py``).
+measured 1.80x the XLA scan at H=2500 (4.68ms vs 8.44ms, B=104 T=67; a
+toolchain that is gone, and no benchmark cell runs the kernel yet:
+ROADMAP S3/S7).
 
 Layout notes:
 
@@ -44,8 +45,7 @@ Layout notes:
 from __future__ import annotations
 
 import functools
-import os
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -70,11 +70,11 @@ _STREAM_TILE_BUDGET = int(4.5 * 1024 * 1024)
 _W_HH_BUDGET = 52 * 1024 * 1024
 # Per-kernel scoped-VMEM limit passed to Mosaic. Without it the kernel
 # inherits XLA's 16MB default *when embedded in a larger module* (e.g.
-# jit(train_step)), and the resident W_hh alone blows it: the round-3
-# bench challenger died at compile with "scoped allocation 54.80M,
+# jit(train_step)), and the resident W_hh alone blows it: inside a
+# train step the kernel died at compile with "scoped allocation 54.80M,
 # limit 16.00M" while the SAME kernel compiled standalone (whole-module
-# budget) in bench_pallas_lstm. _VMEM_BUDGET already keeps the real
-# usage under the ~64MB Mosaic ceiling; this just tells XLA so.
+# budget). _VMEM_BUDGET already keeps the real usage under the ~64MB
+# Mosaic ceiling; this just tells XLA so.
 _COMPILER_PARAMS = pltpu.CompilerParams(
     vmem_limit_bytes=_VMEM_BUDGET + 8 * 1024 * 1024)
 
@@ -110,8 +110,8 @@ def _sublane_snap(batch: int, itemsize: int) -> Tuple[int, int, list]:
 def feasible_tiles(batch: int, hidden: int, gate_dim: int, with_gates: bool,
                    itemsize: int) -> list:
     """All ``(batch_tile, time_chunk)`` candidates under both compile-time
-    ceilings (scoped VMEM + per-iteration stream budget) — the search
-    space `bench_pallas_lstm.py` times on chip (every invocation)."""
+    ceilings (scoped VMEM + per-iteration stream budget): what
+    `_pick_tiles` chooses from, and the space a chip sweep would time."""
     _, _, bts = _sublane_snap(batch, itemsize)
     w_bytes = gate_dim * hidden * itemsize
 
@@ -130,27 +130,6 @@ def feasible_tiles(batch: int, hidden: int, gate_dim: int, with_gates: bool,
         return est <= _VMEM_BUDGET
 
     return [(bt, tc) for bt in bts for tc in (4, 2, 1) if feasible(bt, tc)]
-
-
-def _env_tiles(var: str, cands: list, batch: int,
-               hidden: int) -> Optional[Tuple[int, int]]:
-    """Measured-tile override: ``var`` holds "B,H,bt,tc" (the tile-search
-    winner from `bench_pallas_lstm.py`, exported by the on-chip pipeline).
-    Applied ONLY when the embedded measurement shape matches this call's
-    (batch, hidden) AND the tile is in the feasible candidate set — a
-    winner measured at the flagship shape must not silently retune other
-    shapes (e.g. the distill student), and a stale value must never
-    produce a compile failure."""
-    raw = os.environ.get(var, "")
-    if not raw:
-        return None
-    try:
-        b, h, bt, tc = (int(p) for p in raw.split(","))
-    except ValueError:
-        return None
-    if (b, h) != (batch, hidden):
-        return None
-    return (bt, tc) if (bt, tc) in cands else None
 
 
 def _pick_tiles(batch: int, hidden: int, gate_dim: int, with_gates: bool,
@@ -174,21 +153,14 @@ def _pick_tiles(batch: int, hidden: int, gate_dim: int, with_gates: bool,
     bt112/tc2 at 6.2ms), the training forward bt-major (bt112/tc1 at
     5.96ms beat bt56/tc2 at 6.37ms — measured BEFORE the c_prev_seq
     residual stream was added; with it, bt112 no longer fits the stream
-    budget and the heuristic lands on bt56/tc1). Since round 5 the
-    on-chip bench runs a full STAGED SEARCH over `feasible_tiles` for
-    the training fwd and bwd at the flagship shape and hands the
-    measured winners back via the shape-validated
-    ``CI_TPU_LSTM_{FWD,BWD}_TILES`` env override (`_env_tiles`), so the
-    heuristic is the cold-start default, not the last word.
+    budget and the heuristic lands on bt56/tc1). The pick is a function
+    of the shapes alone: a chip sweep over `feasible_tiles` that finds a
+    better tile writes it here.
     """
     cands = feasible_tiles(batch, hidden, gate_dim, with_gates, itemsize)
     if not cands:
         _, _, bts = _sublane_snap(batch, itemsize)
         return bts[-1], 1
-    if with_gates:  # the variant the on-chip tile search measures
-        override = _env_tiles("CI_TPU_LSTM_FWD_TILES", cands, batch, hidden)
-        if override:
-            return override
     # MXU row utilization dominates while tiles are small (a bt=8 tile
     # wastes 15/16 of the array) with diminishing returns past ~56 rows,
     # then the time chunk's grid-overhead amortization takes over:
@@ -306,9 +278,8 @@ def fused_lstm_forward(
         gates ``(T, B, 4H)`` and the pre-step cell state ``c_prev_seq``
         ``(T, B, H)`` — for the fused backward; inference skips both
         HBM writes.
-      tiles: explicit ``(batch_tile, time_chunk)`` override for the
-        on-chip tile SEARCH (`bench_pallas_lstm.py` runs it every
-        invocation); product callers leave it None and get
+      tiles: explicit ``(batch_tile, time_chunk)`` for a chip sweep
+        over ``feasible_tiles``; product callers leave it None and get
         ``_pick_tiles``.
 
     Returns:
@@ -784,8 +755,8 @@ def _fwd(x, state, w_ih, w_hh, bias, interpret):
 
 def feasible_tiles_bwd(batch: int, hidden: int, gate_dim: int,
                        itemsize: int) -> list:
-    """Backward-kernel tile candidates (search space for the on-chip
-    bench). Streams per grid step: gates + dz (G each) and c_prev +
+    """Backward-kernel tile candidates (what `_pick_tiles_bwd` chooses
+    from). Streams per grid step: gates + dz (G each) and c_prev +
     d_out (H each) — heavier than the forward, so tiles come out smaller
     at the same budgets."""
     _, _, bts = _sublane_snap(batch, itemsize)
@@ -811,9 +782,6 @@ def _pick_tiles_bwd(batch: int, hidden: int, gate_dim: int,
     if not cands:
         _, _, bts = _sublane_snap(batch, itemsize)
         return bts[-1], 1
-    override = _env_tiles("CI_TPU_LSTM_BWD_TILES", cands, batch, hidden)
-    if override:
-        return override
     return max(cands, key=lambda c: (min(c[0], 56), c[1], c[0]))
 
 

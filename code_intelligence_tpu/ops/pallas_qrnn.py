@@ -420,8 +420,8 @@ def forget_mult_auto(z, f, h0=None, prefer_pallas: bool = False,
     ``AWDLSTMConfig(qrnn_use_pallas=True)``) — compiled on TPU, interpret
     mode elsewhere, the SAME routing as ``qrnn_layer``'s fused branch so
     the two selectors cannot diverge. Both paths are parity-tested
-    against each other, values and gradients (tests/test_pallas.py); the
-    on-chip bf16 A/B row lives in ``bench_pallas_lstm.py``.
+    against each other, values and gradients (tests/test_pallas.py); no
+    benchmark cell runs the kernel yet (ROADMAP S8).
     """
     from code_intelligence_tpu.ops.qrnn import _warn_interpret_once, forget_mult
 

@@ -26,9 +26,8 @@ engine over the committed ragged fixture lengths:
   capacity claim, measured on the SPMD-partitioned program,
 * ``mesh=None`` leaves today's single-chip path bitwise unchanged.
 
-This is deliberately a package-internal twin of
-``bench_serving --mesh_ab --smoke`` (runbook_ci must not import
-repo-root bench modules) — keep the pins in step when changing either.
+This gate is the only holder of these pins: counts and parity on a forced
+CPU mesh, never a rate (a four-chip cell is ROADMAP S9).
 """
 
 from __future__ import annotations

@@ -16,7 +16,7 @@ serving comparison; ROADMAP direction #1b):
   circuit breakers, one optional hedged retry, and fleet-wide canary
   verification (the same md5 split rule as serving/rollout.py).
 * :mod:`supervisor` — spawns/monitors N local replica processes for
-  tests, chaos drills, and ``bench_serving --fleet_ab``.
+  tests, chaos drills and the ``--check_*`` fleet gates.
 * :mod:`fleet_check` — the device-free ``runbook_ci --check_fleet``
   gate: a live 2-replica fake fleet proving deadline propagation,
   shed-before-proxy, and canary-split consistency.

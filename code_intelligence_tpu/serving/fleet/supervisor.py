@@ -1,7 +1,7 @@
 """Local fleet supervisor: spawn and monitor N replica processes.
 
 Production runs replicas under k8s (the reference's Deployment with a
-readiness probe); tests, chaos drills, and ``bench_serving --fleet_ab``
+readiness probe); tests, chaos drills and the ``--check_*`` fleet gates
 need the same topology on one host with real process boundaries — a
 SIGKILLed thread proves nothing, a SIGKILLed *process* proves the
 router's ejection path. The supervisor:

@@ -74,7 +74,8 @@ from typing import TYPE_CHECKING, Optional
 import numpy as np
 
 if TYPE_CHECKING:  # annotation-only: the HTTP layer itself is jax-free,
-    # so jax-less tooling (bench_serving --shed-check) can import it
+    # so jax-less tooling (the fake replicas of the fleet gates) can
+    # import it
     from code_intelligence_tpu.inference import InferenceEngine
 
 from code_intelligence_tpu.serving.slo import (

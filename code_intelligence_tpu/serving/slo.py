@@ -518,8 +518,7 @@ class ServeSLO:
 
     def stage_summary(self, qs: Sequence[float] = (0.5, 0.9, 0.99)
                       ) -> Dict[str, Dict[str, Any]]:
-        """Per-stage quantile table (ms) from the cumulative digests —
-        the live twin of ``bench_serving --trace``'s breakdown."""
+        """Per-stage quantile table (ms) from the cumulative digests."""
         with self._lock:
             items = sorted(self.stages.items())
             return {name: d.summary_ms(qs) for name, d in items}

@@ -27,8 +27,8 @@ event streams; ours must absorb the same shapes):
 
 Everything is deterministic given a seed and device-free: schedules
 are plain Python over ``random.Random``, and the clock is injectable
-so the autoscale gate replays a scenario in virtual time while
-``bench_serving --traffic`` replays the same arrivals in real time.
+so the autoscale gate replays a scenario in virtual time; no benchmark
+cell replays one in real time against a real engine yet (ROADMAP R2).
 """
 
 from __future__ import annotations

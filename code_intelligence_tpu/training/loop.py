@@ -67,8 +67,8 @@ class TrainConfig:
     # per-dispatch host latency. 1 = the classic step-per-dispatch loop.
     # Semantics are identical either way
     # (tests/test_training.py::TestTrainSteps). The default IS the product
-    # path — bench.py measures this same value; 20 has not been re-decided
-    # on a directly attached chip (ROADMAP S7/D11).
+    # path, and the value the `lstm_train_lm` cell runs; 20 has not been
+    # re-decided on a directly attached chip (ROADMAP S7/D11).
     steps_per_dispatch: int = 20
 
 

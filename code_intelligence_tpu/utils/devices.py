@@ -2,7 +2,7 @@
 measurement entry points, and the one persistent compile cache.
 
 Every entry point that compiles calls :func:`enable_compile_cache`
-before its first compile; the benches and ``chip_smoke.py`` call
+before its first compile; ``benchmark/run.py`` and ``chip_smoke.py`` call
 :func:`require_tpu` before anything else, because a time taken on the
 CPU backend or the Pallas interpreter says nothing about the chip.
 """

@@ -18,9 +18,9 @@ its p99 — the fleet average launders the straggler. This module gives
   own series. A regression names the stage AND the member — "fleet p99
   is up" is a page; "``127.0.0.1:8081``'s ``engine.group_embed`` is up
   3x while its siblings held" is a diagnosis.
-* ``bench_serving --fleet_ab`` lines carry ``member_latency_digests``
-  (keyed by the ``X-Fleet-Member`` response header), so a fleet bench
-  line is diffable per replica through the same gate.
+* A line carrying ``member_latency_digests`` (keyed by the
+  ``X-Fleet-Member`` response header) is diffable per replica through the
+  same gate; nothing in the tree writes one since PR 28 (ROADMAP D15).
 
 Honesty rules are inherited wholesale from perfwatch: provenance
 respected, low-count series skipped loudly, nothing-comparable exits 2,
@@ -103,7 +103,7 @@ def fleet_series_of(snap: dict) -> Tuple[Dict[str, dict],
                                          Dict[str, Dict[str, dict]]]:
     """``(fleet_series, member_series)`` — serialized digests — from any
     supported shape: a fleetwatch snapshot, a raw ``/fleet/slo`` body,
-    or a ``bench_serving --fleet_ab`` JSON line. ``fleet_series`` maps
+    or a fleet bench JSON line. ``fleet_series`` maps
     series name (``e2e`` + stages) -> digest; ``member_series`` maps
     member id -> the same, per member."""
     if snap.get("kind") == "fleetwatch_snapshot":
@@ -126,7 +126,7 @@ def fleet_series_of(snap: dict) -> Tuple[Dict[str, dict],
     if "member_latency_digests" in snap or (
             isinstance(snap.get("fleet"), dict)
             and "member_latency_digests" in snap["fleet"]):
-        # a bench_serving --fleet_ab line: the fleet side's per-member
+        # a fleet bench line: the fleet side's per-member
         # request digests, keyed by X-Fleet-Member
         side = snap if "member_latency_digests" in snap else snap["fleet"]
         fleet = {}

@@ -36,8 +36,8 @@ built on the same observer-not-dependency rules as utils/tracing.py:
   accounting path permanently falls back to the plain jitted callable.
 
 jax is imported lazily — the module must stay importable in jax-free
-processes (the embedding server's shed-check path imports the serving
-module, which imports this for ``/debug/flight``).
+processes (the fleet gates' fake replicas import the serving module,
+which imports this for ``/debug/flight``).
 """
 
 from __future__ import annotations

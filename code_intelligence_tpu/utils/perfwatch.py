@@ -451,7 +451,7 @@ def digests_of(snap: dict) -> Tuple[Optional[dict], Dict[str, dict]]:
     if "digests" in snap:  # a raw /debug/slo body
         dg = snap["digests"] or {}
         return dg.get("e2e"), dict(dg.get("stages") or {})
-    if "latency_digest" in snap:  # a bench_serving JSON line
+    if "latency_digest" in snap:  # a bench JSON line (no writer: D15)
         return snap["latency_digest"], {}
     return None, {}
 

@@ -636,41 +636,3 @@ def debug_traces_response(tracer: Optional[Tracer], query: str = ""):
     except Exception as e:  # the debug surface must not 500 the listener
         return 500, json.dumps({"error": str(e)[:200]}).encode(), \
             "application/json"
-
-
-# ---------------------------------------------------------------------
-# Aggregation (bench --trace breakdown)
-# ---------------------------------------------------------------------
-
-def stage_breakdown(traces: List[Dict[str, Any]]) -> Dict[str, Dict[str, float]]:
-    """Aggregate span durations by span name across traces: the per-stage
-    latency table ``bench_serving.py --trace`` prints."""
-    by_name: Dict[str, List[float]] = {}
-    for trace in traces:
-        for s in trace.get("spans", []):
-            by_name.setdefault(s["name"], []).append(s["duration_s"])
-    out: Dict[str, Dict[str, float]] = {}
-    for name, durs in sorted(by_name.items()):
-        durs.sort()
-        n = len(durs)
-        out[name] = {
-            "count": n,
-            "total_ms": round(sum(durs) * 1e3, 3),
-            "mean_ms": round(sum(durs) / n * 1e3, 3),
-            "p50_ms": round(durs[n // 2] * 1e3, 3),
-            "p95_ms": round(durs[min(n - 1, int(n * 0.95))] * 1e3, 3),
-        }
-    return out
-
-
-def format_breakdown(breakdown: Dict[str, Dict[str, float]]) -> str:
-    """Render the per-stage table (fixed-width text, one stage per row)."""
-    if not breakdown:
-        return "(no traced stages)"
-    header = f"{'stage':<24} {'count':>6} {'mean_ms':>9} {'p50_ms':>9} {'p95_ms':>9} {'total_ms':>10}"
-    lines = [header, "-" * len(header)]
-    for name, st in breakdown.items():
-        lines.append(
-            f"{name:<24} {st['count']:>6} {st['mean_ms']:>9.3f} "
-            f"{st['p50_ms']:>9.3f} {st['p95_ms']:>9.3f} {st['total_ms']:>10.3f}")
-    return "\n".join(lines)

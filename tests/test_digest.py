@@ -1,7 +1,7 @@
 """QuantileDigest (utils/digest.py): the SLO observatory's estimator.
 
 The contract every consumer leans on (serving/slo.py, perfwatch,
-bench_serving latency_digest lines, the metrics summary kind):
+the metrics summary kind):
 
 * relative-error bound vs exact sample percentiles — on uniform, Zipf,
   bimodal and adversarial streams,

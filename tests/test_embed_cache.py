@@ -1,11 +1,12 @@
 """Content-addressed embedding cache + single-flight coalescing
 (serving/embed_cache.py) and its serve-path wiring.
 
-Everything here is device-free: the cache is jax-free by design, and the
-engines are deterministic stubs with call counters — the two acceptance
-pins (cache stampede: N concurrent requests for a never-seen document
-cost exactly ONE device pass; hot-swap staleness: zero responses served
-from a retired version's entries) must be provable without a chip.
+Everything here but `TestRealEngine` is device-free: the cache is jax-free
+by design, and the engines are deterministic stubs with call counters —
+the two acceptance pins (cache stampede: N concurrent requests for a
+never-seen document cost exactly ONE device pass; hot-swap staleness: zero
+responses served from a retired version's entries) must be provable
+without a chip.
 """
 
 import threading
@@ -125,6 +126,50 @@ class TestVocabHash:
         eng = InferenceEngine(params, cfg, vocab, batch_size=2)
         assert eng.vocab_hash == vocab.content_hash()
         assert len(eng.vocab_hash) == 16
+
+
+class TestRealEngine:
+    def test_duplicates_cost_no_device_pass_and_rows_are_bitwise_equal(self):
+        """On a real engine and a seeded workload with duplicates (a few
+        hot issues, a tail seen once): the cached side runs the device for
+        EXACTLY the unique documents by token content, every cached row is
+        byte-identical to the uncached one, and the cache adds no host
+        sync and no recompile to the slot loop it wraps."""
+        from smoke_engine import make_smoke_engine
+
+        from code_intelligence_tpu.analysis import runtime as audit
+
+        engine = make_smoke_engine(batch_size=4)
+        rng = np.random.RandomState(0)
+        pool = [{"title": f"w{i} in w{i + 1}",
+                 "body": " ".join(f"w{j}" for j in rng.randint(5, 150, n))}
+                for i, n in enumerate(rng.choice([5, 20, 60], size=12))]
+        # two texts that tokenise alike are one document to the device
+        pool.append({"title": pool[0]["title"].upper(),
+                     "body": pool[0]["body"]})
+        issues = [pool[int((r - 1) % len(pool))]
+                  for r in np.random.RandomState(1).zipf(1.3, size=32)]
+        issues += pool  # every document at least once
+        passes = [0]
+
+        def embed_fn(eng, title, body):
+            passes[0] += 1
+            return eng.embed_issues([{"title": title, "body": body}],
+                                    scheduler="slots")[0]
+
+        uncached = [embed_fn(engine, d["title"], d["body"]) for d in issues]
+        n_unique = len({request_key(engine, d["title"], d["body"])
+                        for d in issues})
+        assert n_unique < len({(d["title"], d["body"]) for d in issues})
+        cache = EmbedCache()
+        passes[0] = 0
+        with audit.recompile_guard(fn="slots.step", budget=0), \
+                audit.no_implicit_transfers():
+            cached = [cached_embed(cache, engine, d["title"], d["body"],
+                                   embed_fn)[0] for d in issues]
+        assert passes[0] == n_unique == cache.stats()["misses"]
+        assert cache.stats()["hits"] == len(issues) - n_unique
+        assert all(np.array_equal(a, b) for a, b in zip(uncached, cached))
 
 
 # ---------------------------------------------------------------------------

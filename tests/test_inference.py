@@ -19,8 +19,7 @@ from code_intelligence_tpu.models import AWDLSTMConfig, AWDLSTMEncoder, init_lst
 from code_intelligence_tpu.text import SPECIALS, Vocab
 
 
-@pytest.fixture(scope="module")
-def engine():
+def make_engine(**kw):
     cfg = AWDLSTMConfig(vocab_size=200, emb_sz=8, n_hid=12, n_layers=2)
     enc = AWDLSTMEncoder(cfg)
     tokens = np.zeros((1, 4), np.int32)
@@ -29,7 +28,19 @@ def engine():
     )["params"]
     words = [f"w{i}" for i in range(150)]
     vocab = Vocab(SPECIALS + words)
-    return InferenceEngine(params, cfg, vocab, buckets=(8, 16), batch_size=4)
+    return InferenceEngine(params, cfg, vocab, **kw)
+
+
+@pytest.fixture(scope="module")
+def engine():
+    return make_engine(buckets=(8, 16), batch_size=4)
+
+
+def test_engine_lstm_pallas_override_is_tpu_gated():
+    eng = make_engine(buckets=(8,), batch_size=1, lstm_pallas=True)
+    # on the CPU backend the override must NOT enable the TPU-only kernel
+    assert eng.config.lstm_use_pallas == (jax.default_backend() == "tpu")
+    assert eng.embed_text("hello world").shape == (24,)
 
 
 class TestPooling:

@@ -1,7 +1,7 @@
 """Pallas fused LSTM cell: exact parity with the XLA-scan reference
 (`ops/lstm.py`) for forward outputs, carried state, and all gradients.
-Runs in interpret mode on the CPU mesh (the kernel itself is exercised on
-real hardware by bench_pallas_lstm.py)."""
+Runs in interpret mode on the CPU mesh (on real hardware the kernel is
+exercised by chip_smoke.py's `kernels` and `train_pallas` legs)."""
 
 import jax
 import jax.numpy as jnp
@@ -11,6 +11,11 @@ import pytest
 from code_intelligence_tpu.ops.lstm import lstm_layer
 from code_intelligence_tpu.ops.pallas_lstm import (
     MAX_RESIDENT_H,
+    _pick_tiles,
+    _pick_tiles_bwd,
+    _sublane_snap,
+    feasible_tiles,
+    feasible_tiles_bwd,
     fits_resident,
     fused_lstm_forward,
     fused_lstm_forward_ragged,
@@ -305,50 +310,47 @@ class TestResidencyGate:
         assert fits_resident(2500)  # flagship W_hh (50MB bf16) is resident
 
 
-class TestTileOverride:
-    """CI_TPU_LSTM_{FWD,BWD}_TILES: the on-chip tile-search handoff —
-    valid winners apply, anything stale/unparseable falls back to the
-    heuristic (a bad env value must never produce a compile failure)."""
+# the shapes the repo runs, bf16: train 104 x 2500, the bulk cells' 200 and
+# the 100 and 25 rows their last group narrows to, the product's serve
+# batch 32, and the tiny widths of the CPU tests
+_SHAPES = [(104, 2500), (200, 2500), (100, 2500), (25, 2500), (32, 2500),
+           (8, 96)]
+_PICKERS = {
+    "fwd_gates": (lambda b, h: _pick_tiles(b, h, 4 * h, True, 2),
+                  lambda b, h: feasible_tiles(b, h, 4 * h, True, 2)),
+    "fwd": (lambda b, h: _pick_tiles(b, h, 4 * h, False, 2),
+            lambda b, h: feasible_tiles(b, h, 4 * h, False, 2)),
+    "bwd": (lambda b, h: _pick_tiles_bwd(b, h, 4 * h, 2),
+            lambda b, h: feasible_tiles_bwd(b, h, 4 * h, 2)),
+}
 
-    def test_fwd_override_contract(self, monkeypatch):
-        from code_intelligence_tpu.ops.pallas_lstm import _pick_tiles
 
-        base = _pick_tiles(104, 2500, 10000, True, 2)
-        monkeypatch.setenv("CI_TPU_LSTM_FWD_TILES", "104,2500,16,4")
-        assert _pick_tiles(104, 2500, 10000, True, 2) == (16, 4)
-        monkeypatch.setenv("CI_TPU_LSTM_FWD_TILES", "104,2500,999,7")
-        assert _pick_tiles(104, 2500, 10000, True, 2) == base  # infeasible
-        monkeypatch.setenv("CI_TPU_LSTM_FWD_TILES", "junk")
-        assert _pick_tiles(104, 2500, 10000, True, 2) == base
+@pytest.mark.parametrize("picker", sorted(_PICKERS))
+@pytest.mark.parametrize("batch,hidden", _SHAPES)
+def test_tiles_follow_from_shapes(batch, hidden, picker, monkeypatch):
+    """A tile is a function of the shapes alone: it is feasible, it is the
+    same on a second call, and nothing in the environment moves it (the
+    variables below were a hand-off between processes until PR 28; a cell
+    runs with none set, so a tile that arrived that way could not be
+    measured)."""
+    pick, feasible = _PICKERS[picker]
+    monkeypatch.delenv("CI_TPU_LSTM_FWD_TILES", raising=False)
+    monkeypatch.delenv("CI_TPU_LSTM_BWD_TILES", raising=False)
+    base = pick(batch, hidden)
+    cands = feasible(batch, hidden)
+    assert len(cands) > 1 and base in cands
+    _, padded, _ = _sublane_snap(batch, 2)
+    assert padded % base[0] == 0  # an exact grid over the padded batch
+    assert pick(batch, hidden) == base
+    other = next(c for c in cands if c != base)
+    for var in ("CI_TPU_LSTM_FWD_TILES", "CI_TPU_LSTM_BWD_TILES"):
+        monkeypatch.setenv(var, f"{batch},{hidden},{other[0]},{other[1]}")
+    assert pick(batch, hidden) == base
 
-    def test_fwd_override_only_applies_to_measured_shape(self, monkeypatch):
-        from code_intelligence_tpu.ops.pallas_lstm import _pick_tiles
 
-        # a flagship-measured winner must not retune other shapes (the
-        # distill student, serving sizes): shape prefix mismatch -> ignore
-        monkeypatch.setenv("CI_TPU_LSTM_FWD_TILES", "104,2500,16,4")
-        other = _pick_tiles(104, 1024, 4096, True, 2)
-        monkeypatch.delenv("CI_TPU_LSTM_FWD_TILES")
-        assert _pick_tiles(104, 1024, 4096, True, 2) == other
-
-    def test_fwd_override_only_applies_to_training_variant(self, monkeypatch):
-        from code_intelligence_tpu.ops.pallas_lstm import _pick_tiles
-
-        inf_base = _pick_tiles(104, 2500, 10000, False, 2)
-        monkeypatch.setenv("CI_TPU_LSTM_FWD_TILES", "104,2500,16,4")
-        assert _pick_tiles(104, 2500, 10000, False, 2) == inf_base
-
-    def test_bwd_override_contract(self, monkeypatch):
-        from code_intelligence_tpu.ops.pallas_lstm import (
-            _pick_tiles_bwd,
-            feasible_tiles_bwd,
-        )
-
-        base = _pick_tiles_bwd(104, 2500, 10000, 2)
-        cands = feasible_tiles_bwd(104, 2500, 10000, 2)
-        alt = next(c for c in cands if c != base)
-        monkeypatch.setenv("CI_TPU_LSTM_BWD_TILES",
-                           f"104,2500,{alt[0]},{alt[1]}")
-        assert _pick_tiles_bwd(104, 2500, 10000, 2) == alt
-        monkeypatch.setenv("CI_TPU_LSTM_BWD_TILES", "104,2500,0,0")
-        assert _pick_tiles_bwd(104, 2500, 10000, 2) == base
+def test_no_feasible_tile_falls_back_to_the_smallest_batch_tile():
+    # float32 W_hh at H=2500 is 100 MB: nothing is feasible, and the
+    # documented fallback is the smallest batch tile, one timestep
+    assert feasible_tiles(104, 2500, 10000, True, 4) == []
+    assert _pick_tiles(104, 2500, 10000, True, 4) == (8, 1)
+    assert _pick_tiles_bwd(104, 2500, 10000, 4) == (8, 1)

@@ -1,0 +1,70 @@
+"""The tiles `_pick_tiles` chooses compile for the chip, at the shapes the
+repo runs.
+
+A tile is a function of the shapes alone (tests/test_pallas_lstm.py), so
+what the pickers return is what the chip is handed: here Mosaic, the TPU's
+own compiler, is asked to take it, for a v5e that is described and not
+attached (`/opt/skills/guides/on-chip-measurement` §2). Interpret mode
+cannot refuse a slice that is not aligned to the tiling or a kernel that
+asks for more VMEM than it may use; this can. Nothing runs and nothing is
+timed. One file, and the topology is described inside a fixture: only the
+worker that is given this file loads the TPU's library.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from code_intelligence_tpu.ops.pallas_lstm import (
+    fused_lstm_backward,
+    fused_lstm_forward,
+)
+
+H = 2500  # the flagship's hidden size: W_hh is 50 MB of bfloat16
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compiled_text(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+            for s in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+# (T, B): a bulk chunk program at the cells' 200 rows and at the 100 and 25
+# its last group narrows to, and the server's default batch
+@pytest.mark.parametrize("t,b", [(512, 200), (512, 100), (512, 25), (64, 32)])
+def test_the_inference_forward_compiles_at_the_picked_tile(one_chip, t, b):
+    text = _compiled_text(
+        lambda x, w, h, c: fused_lstm_forward(x, w, h, c, interpret=False),
+        one_chip, (t, b, 4 * H), (4 * H, H), (b, H), (b, H))
+    assert "tpu_custom_call" in text
+
+
+def test_the_training_forward_and_backward_compile_at_the_picked_tiles(
+        one_chip):
+    t, b = 67, 104  # the reference's bptt and batch
+    fwd = _compiled_text(
+        lambda x, w, h, c: fused_lstm_forward(x, w, h, c, with_gates=True,
+                                              interpret=False),
+        one_chip, (t, b, 4 * H), (4 * H, H), (b, H), (b, H))
+    assert "tpu_custom_call" in fwd
+    bwd = _compiled_text(
+        lambda g, cp, do, w, dh, dc: fused_lstm_backward(
+            g, cp, do, w, dh, dc, interpret=False),
+        one_chip, (t, b, 4 * H), (t, b, H), (t, b, H), (4 * H, H), (b, H),
+        (b, H))
+    assert "tpu_custom_call" in bwd

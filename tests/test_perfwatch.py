@@ -105,7 +105,7 @@ class TestCompare:
                                              "cache.lookup"}
 
     def test_bench_line_baseline_compares_e2e(self):
-        # a bench_serving JSON line carries latency_digest at top level
+        # a bench JSON line carries latency_digest at top level
         bench_line = {"metric": "embedding_serving_latency",
                       "provenance": "fresh",
                       "latency_digest": _digest(BASE)}
@@ -399,7 +399,7 @@ class TestDigestOverheadPin:
     def test_observe_cost_under_one_percent_of_smoke_latency(self):
         # ISSUE 8 acceptance: digest overhead per request < 1% of the
         # smoke-workload serve latency. The smoke single-doc p50 is
-        # ~10ms (bench_serving --smoke, latency_digest_ms); 1% = 100µs.
+        # ~10ms (a CPU reading from before the benchmark); 1% = 100µs.
         # One observe() = e2e digest add + 4 stage adds + window
         # bookkeeping + sentinel check — budget 100µs each.
         slo = ServeSLO(objective=SLOObjective(p99_ms=250.0))

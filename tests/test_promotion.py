@@ -699,12 +699,13 @@ class TestHotSwapPin:
     (PR 5 recompile_guard)."""
 
     def test_hot_swap_under_load_zero_drops_zero_recompiles(self):
-        import bench_serving
+        from smoke_engine import make_smoke_engine
+
         from code_intelligence_tpu.analysis import runtime as audit
         from code_intelligence_tpu.serving.server import make_server
 
-        incumbent = bench_serving.make_smoke_engine(batch_size=4)
-        candidate = bench_serving.make_smoke_engine(batch_size=4)
+        incumbent = make_smoke_engine(batch_size=4)
+        candidate = make_smoke_engine(batch_size=4)
         incumbent.version, candidate.version = "v1", "v2"
         # value-shaped sentinel only: the wall-clock latency band could
         # spuriously roll the canary back on a CI host stall, and this

@@ -111,6 +111,16 @@ class TestMeshSpec:
         serve_shard.ensure_multi_device(1, smoke=True)   # smoke forces
         serve_shard.ensure_multi_device(8, smoke=False)  # real mesh ok
 
+    def test_mesh_with_groups_scheduler_refused_at_cli(self):
+        # only the slot/ragged schedulers run the sharded step: the groups
+        # path would silently serve unsharded, so the server's CLI refuses
+        from code_intelligence_tpu.serving.server import main as server_main
+
+        with pytest.raises(SystemExit) as exc:
+            server_main(["--model_dir", "/x", "--mesh", "data,model",
+                         "--scheduler", "groups"])
+        assert exc.value.code == 2
+
 
 class TestPartitionRules:
     def test_match_partition_rules_by_path(self):

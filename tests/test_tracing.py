@@ -345,17 +345,30 @@ class TestExports:
         assert 'trace_span_seconds_bucket{span="slots.device_steps"' in out
         assert "# TYPE trace_span_seconds histogram" in out
 
-    def test_stage_breakdown_aggregates(self):
-        t = Tracer()
-        for _ in range(3):
-            with t.span("root"):
-                with t.span("stage_a"):
-                    pass
-        bd = tracing.stage_breakdown(t.traces())
-        assert bd["stage_a"]["count"] == 3
-        assert bd["root"]["count"] == 3
-        table = tracing.format_breakdown(bd)
-        assert "stage_a" in table and "p95_ms" in table
+    def test_every_document_of_a_slots_call_gets_each_stage(self):
+        # one trace a document, every root open at once while the slot
+        # scheduler has them all in flight (`ctxs=`): each document's
+        # trace carries each stage of the slot pipeline exactly once
+        from collections import Counter
+
+        from test_slot_scheduler import make_engine
+
+        engine = make_engine(batch_size=4, buckets=(8, 16))
+        issues = [{"title": f"w{i}", "body": "w4 w5 " * (1 + 3 * i)}
+                  for i in range(8)]
+        t = Tracer(max_traces=16, max_live=16)
+        roots = [t.start_span("request", doc=i) for i in range(len(issues))]
+        engine.embed_issues(issues, scheduler="slots",
+                            ctxs=[r.context for r in roots])
+        for r in roots:
+            r.end()
+        traces = t.traces()
+        assert len(traces) == len(issues)
+        for trace in traces:
+            names = Counter(s["name"] for s in trace["spans"])
+            for stage in ("engine.tokenize", "slots.queue_wait",
+                          "slots.device_steps", "slots.pool_emit"):
+                assert names[stage] == 1, (stage, names)
 
 
 class TestDebugEndpoints:

@@ -2,9 +2,9 @@
 
 Everything here is device-free and runs in compressed virtual time:
 the runner's clock and sleep are injected, so an 8-second scenario
-replays in milliseconds. The real-time replay against a live fleet is
-``bench_serving --traffic``; the virtual-time consumer is the
-autoscale gate (serving/fleet/autoscale_check.py).
+replays in milliseconds. The virtual-time consumer is the autoscale
+gate (serving/fleet/autoscale_check.py); nothing replays a scenario in
+real time against a real engine yet (ROADMAP R2).
 """
 
 import threading
@@ -97,10 +97,7 @@ class TestTrafficSchedule:
         with pytest.raises(ValueError, match="unknown traffic scenario"):
             TrafficSchedule("nope")
 
-    def test_cli_choices_match_scenarios(self):
-        # bench_serving --traffic hardcodes its choice list (the parser
-        # must stay importable without jax); pin the canonical set so
-        # the two cannot drift apart silently
+    def test_the_scenarios_are_the_documented_four(self):
         assert sorted(SCENARIOS) == ["diurnal", "flash_crowd",
                                      "retry_storm", "slow_drip"]
 
