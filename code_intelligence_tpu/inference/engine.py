@@ -50,6 +50,7 @@ import numpy as np
 
 from code_intelligence_tpu.models import AWDLSTMConfig, build_encoder
 from code_intelligence_tpu.text import Tokenizer, Vocab, build_issue_text
+from code_intelligence_tpu.text import rules as text_rules
 from code_intelligence_tpu.text.rules import TK_UNK
 from code_intelligence_tpu.utils import profiling, resilience, tracing
 
@@ -761,18 +762,28 @@ class InferenceEngine:
             def prepare(i, overlapped):
                 return self.numericalize(text_of(i))
         else:
+            # rule_passes / rule_passes_run: the regex scans the pre-rule
+            # chain could make for this document and the scans it made,
+            # counted outside the clock reads; ``engine.tokenize`` holds
+            # the chain's second application (``Tokenizer.tokenize``),
+            # the word split and ``numericalize``
             def prepare(i, overlapped):
-                tt0 = time.perf_counter()
-                text = text_of(i)
-                tracing.record_span("engine.text_rules", tt0,
-                                    time.perf_counter(), ctxs[i],
-                                    n_chars=len(text))
-                tt0 = time.perf_counter()
-                ids = self.numericalize(text)
+                with text_rules.counting_passes() as passes:
+                    tt0 = time.perf_counter()
+                    text = text_of(i)
+                    tt1 = time.perf_counter()
+                tracing.record_span("engine.text_rules", tt0, tt1, ctxs[i],
+                                    n_chars=len(text), rule_passes=passes[0],
+                                    rule_passes_run=passes[1])
+                with text_rules.counting_passes() as passes:
+                    tt0 = time.perf_counter()
+                    ids = self.numericalize(text)
+                    tt1 = time.perf_counter()
                 tracing.record_span(
-                    "engine.tokenize", tt0, time.perf_counter(), ctxs[i],
+                    "engine.tokenize", tt0, tt1, ctxs[i],
                     n_tokens=len(ids),
-                    n_tokens_overlapped=len(ids) if overlapped else 0)
+                    n_tokens_overlapped=len(ids) if overlapped else 0,
+                    rule_passes=passes[0], rule_passes_run=passes[1])
                 return ids
 
         if self._check_scheduler(scheduler or self.scheduler) == "groups":

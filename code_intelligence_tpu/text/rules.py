@@ -21,9 +21,12 @@ The title/body document contract of the reference
 
 from __future__ import annotations
 
+import contextlib
 import html
+import operator
 import re
-from typing import Callable, Iterable, List, Sequence
+import threading
+from typing import Callable, Iterable, Iterator, List, Sequence
 
 # ---------------------------------------------------------------------------
 # Special tokens
@@ -80,6 +83,76 @@ SPECIALS: List[str] = [
 Rule = Callable[[str], str]
 
 # ---------------------------------------------------------------------------
+# A pre-rule pays for a regex scan only where the text can match
+# ---------------------------------------------------------------------------
+#
+# Most issue text holds nothing most patterns need (no backtick, no ``<``,
+# no doubled space), and the chain runs twice over every document (once
+# a field in :func:`build_issue_text`, once more in ``Tokenizer.tokenize``).
+# So every rule hands each of its patterns to :func:`_scan` with a
+# NECESSARY condition of a match that runs at C speed (substring or
+# character membership): false means no match is possible and the text
+# comes back untouched, true means no more than "scan it". The result is
+# the unguarded ``pattern.sub`` for every input.
+
+
+class _PassTally(threading.local):
+    counts = None  # [scans the rules could make, scans made] while open
+
+
+_tally = _PassTally()
+
+
+@contextlib.contextmanager
+def counting_passes() -> Iterator[List[int]]:
+    """Count the pre-rules' regex scans on this thread while open:
+    yields ``[could, made]``, the scans the rules applied could make and
+    the scans their guards let through. For a traced caller
+    (``engine.embed_issues``); with none open the rules count nothing.
+    Not re-entrant."""
+    counts = _tally.counts = [0, 0]
+    try:
+        yield counts
+    finally:
+        _tally.counts = None
+
+
+def _scan(pattern: "re.Pattern[str]", repl, t: str, may_match: bool) -> str:
+    """``pattern.sub(repl, t)``, scanned only when ``may_match``: the
+    caller's necessary condition of a match in ``t``."""
+    counts = _tally.counts
+    if counts is not None:
+        counts[0] += 1
+        counts[1] += may_match
+    return pattern.sub(repl, t) if may_match else t
+
+
+def _has_digit(t: str) -> bool:
+    # ``\d`` also matches the other scripts' digits: outside ASCII, scan
+    return not t.isascii() or any(map(t.__contains__, "0123456789"))
+
+
+def _says_a_char_four_times(t: str) -> bool:
+    """Four equal characters in a row somewhere in an ASCII text, by
+    big-integer arithmetic: byte ``k`` of ``x ^ (x >> 8)`` is
+    ``t[k] ^ t[k - 1]``, so such a run is three zero bytes in a row
+    (a tenth of what ``_RE_REP``'s back-reference costs to find none).
+    Other text is scanned."""
+    if not t.isascii():
+        return True
+    b = t.encode("ascii")
+    x = int.from_bytes(b, "big")
+    return b"\0\0\0" in (x ^ (x >> 8)).to_bytes(len(b), "big")
+
+
+def _says_a_word_twice(t: str) -> bool:
+    """Some whitespace-separated word equals the next one, at an even
+    index: any three equal words in a row hold such a pair, and
+    ``_RE_WREP`` needs four (the last may be the head of a longer one)."""
+    words = t.split()
+    return any(map(operator.eq, words[::2], words[1::2]))
+
+# ---------------------------------------------------------------------------
 # Markdown pre-rules (mdparse-equivalent, string -> string)
 # ---------------------------------------------------------------------------
 
@@ -106,22 +179,24 @@ _RE_EMPHASIS = re.compile(r"(?<!\w)(\*{1,3}|_{1,3})(?=\S)(.+?)(?<=\S)\1(?!\w)")
 
 def md_code_blocks(t: str) -> str:
     """Replace fenced/indented code blocks with a single ``xxcdb`` marker."""
-    t = _RE_FENCED_CODE.sub(f" {TK_CODE_BLOCK} ", t)
-    return _RE_INDENT_CODE.sub(f"\n {TK_CODE_BLOCK} \n", t)
+    t = _scan(_RE_FENCED_CODE, f" {TK_CODE_BLOCK} ", t,
+              "```" in t or "~~~" in t)
+    return _scan(_RE_INDENT_CODE, f"\n {TK_CODE_BLOCK} \n", t,
+                 t.startswith(("    ", "\t")) or "\n    " in t or "\n\t" in t)
 
 
 def md_inline_code(t: str) -> str:
-    return _RE_INLINE_CODE.sub(f" {TK_CODE_INLINE} ", t)
+    return _scan(_RE_INLINE_CODE, f" {TK_CODE_INLINE} ", t, "`" in t)
 
 
 def md_images(t: str) -> str:
-    return _RE_IMAGE.sub(rf" {TK_IMAGE} \1 ", t)
+    return _scan(_RE_IMAGE, rf" {TK_IMAGE} \1 ", t, "![" in t)
 
 
 def md_links(t: str) -> str:
     """``[text](url)`` -> ``xxlnk text``; bare URLs -> ``xxlnk``."""
-    t = _RE_LINK.sub(rf" {TK_LINK} \1 ", t)
-    return _RE_AUTOLINK.sub(f" {TK_LINK} ", t)
+    t = _scan(_RE_LINK, rf" {TK_LINK} \1 ", t, "](" in t)
+    return _scan(_RE_AUTOLINK, f" {TK_LINK} ", t, "http" in t or "www." in t)
 
 
 _RE_BR = re.compile(r"<br\s*/?>", re.IGNORECASE)
@@ -130,17 +205,24 @@ _RE_BR = re.compile(r"<br\s*/?>", re.IGNORECASE)
 def md_html(t: str) -> str:
     # <br> carries line-break semantics — convert before the generic tag
     # replacement eats it.
-    t = _RE_BR.sub("\n", t)
-    return _RE_HTML_TAG.sub(f" {TK_HTML_BLOCK} ", t)
+    t = _scan(_RE_BR, "\n", t, "<" in t)
+    return _scan(_RE_HTML_TAG, f" {TK_HTML_BLOCK} ", t, "<" in t)
 
 
 def md_structure(t: str) -> str:
     """Headings, quotes, lists, horizontal rules, emphasis."""
-    t = _RE_HRULE.sub(f" {TK_HRULE} ", t)
-    t = _RE_HEADING.sub(f" {TK_HEADING} ", t)
-    t = _RE_QUOTE.sub(f" {TK_QUOTE} ", t)
-    t = _RE_LIST.sub(f" {TK_LIST_ITEM} ", t)
-    return _RE_EMPHASIS.sub(r"\2", t)
+    t = _scan(_RE_HRULE, f" {TK_HRULE} ", t,
+              "---" in t or "***" in t or "___" in t)
+    # a multiline ``^`` is the start of the text or follows a "\n"
+    t = _scan(_RE_HEADING, f" {TK_HEADING} ", t,
+              t.startswith("#") or "\n#" in t)
+    t = _scan(_RE_QUOTE, f" {TK_QUOTE} ", t, ">" in t)
+    t = _scan(_RE_LIST, f" {TK_LIST_ITEM} ", t,
+              "-" in t or "*" in t or "+" in t
+              or (("." in t or ")" in t) and _has_digit(t)))
+    # an opening and a closing run of the same character
+    return _scan(_RE_EMPHASIS, r"\2", t,
+                 t.count("*") > 1 or t.count("_") > 1)
 
 
 MARKDOWN_PRE_RULES: List[Rule] = [
@@ -158,6 +240,7 @@ MARKDOWN_PRE_RULES: List[Rule] = [
 
 _RE_REP = re.compile(r"(\S)(\1{3,})")
 _RE_WREP = re.compile(r"(?:^|\s)(\S+)((?:\s+\1){3,})\b")
+_RE_SPEC = re.compile(r"([/#@])")
 _RE_SPACE = re.compile(r" {2,}")
 
 
@@ -167,39 +250,39 @@ def fix_html(t: str) -> str:
     (``<br>`` tags are handled earlier by :func:`md_html`, which runs before
     the generic tag replacement in the default rule ordering.)
     """
-    t = t.replace("&nbsp;", " ")
-    t = html.unescape(t)
+    if "&" in t:  # every entity opens with one; no regex of ours here
+        t = html.unescape(t.replace("&nbsp;", " "))
     return t.replace(" ", " ").replace("\r", "\n")
+
+
+def _rep_marker(m: re.Match) -> str:
+    c, rep = m.groups()
+    return f" {TK_REP} {len(rep) + 1} {c} "
 
 
 def replace_rep(t: str) -> str:
     """``cccc`` -> ``xxrep 4 c`` (runs of 4+ of the same char)."""
+    return _scan(_RE_REP, _rep_marker, t, _says_a_char_four_times(t))
 
-    def _sub(m: re.Match) -> str:
-        c, rep = m.groups()
-        return f" {TK_REP} {len(rep) + 1} {c} "
 
-    return _RE_REP.sub(_sub, t)
+def _wrep_marker(m: re.Match) -> str:
+    w, rest = m.groups()
+    n = len(rest.split()) + 1
+    return f" {TK_WREP} {n} {w} "
 
 
 def replace_wrep(t: str) -> str:
     """``no no no no`` -> ``xxwrep 4 no`` (runs of 4+ of the same word)."""
-
-    def _sub(m: re.Match) -> str:
-        w, rest = m.groups()
-        n = len(rest.split()) + 1
-        return f" {TK_WREP} {n} {w} "
-
-    return _RE_WREP.sub(_sub, t)
+    return _scan(_RE_WREP, _wrep_marker, t, _says_a_word_twice(t))
 
 
 def spec_add_spaces(t: str) -> str:
     """Add spaces around ``/``, ``#``, ``@`` so paths/labels/mentions split."""
-    return re.sub(r"([/#@])", r" \1 ", t)
+    return _scan(_RE_SPEC, r" \1 ", t, "/" in t or "#" in t or "@" in t)
 
 
 def rm_useless_spaces(t: str) -> str:
-    return _RE_SPACE.sub(" ", t)
+    return _scan(_RE_SPACE, " ", t, "  " in t)
 
 
 TEXT_PRE_RULES: List[Rule] = [

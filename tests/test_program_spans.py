@@ -179,6 +179,39 @@ class TestGroupsPathSpans:
         assert sum(s["lo"] > whole["lo"] for s in spans["engine.tokenize"]) \
             == len(starts) - (B + B // 4)
 
+    MARKDOWN = {"title": "Crash in `parse()` with **nested** lists",
+                "body": "# Steps\n\n- run `make all`\n- see "
+                        "[the log](https://example.com/log)\n\n```\n"
+                        "Traceback: boom\n```\n\n> it failed &amp; "
+                        "hung!!!!! <br> cc @dev kind/bug"}
+    PLAIN = {"title": "Crash when saving a file",
+             "body": "The editor stops responding when I save a large file "
+                     "twice in a row and nothing is written to the log"}
+    PASSES = 17  # the regex scans one application of the chain could make
+
+    @pytest.mark.parametrize("doc,text_run,tokenize_run", [
+        # the second application meets "@", "/" and the doubled spaces
+        # that spacing them leaves
+        (MARKDOWN, 14, 2),
+        (PLAIN, 0, 0),
+    ], ids=["markdown", "plain"])
+    def test_rule_passes_ride_on_both_tokenise_spans(self, engine, doc,
+                                                     text_run, tokenize_run):
+        """``rule_passes``: the scans the chain could make (title and body
+        on ``engine.text_rules``, the document once more on
+        ``engine.tokenize``: the chain is applied twice);
+        ``rule_passes_run``: the scans the guards let through."""
+        spans, _ = traced_call(engine, [doc, doc])
+        for name, could, run in (
+                ("engine.text_rules", 2 * self.PASSES, text_run),
+                ("engine.tokenize", self.PASSES, tokenize_run)):
+            assert len(spans[name]) == 2
+            for s in spans[name]:
+                a = s["attrs"]
+                assert a["rule_passes"] == could
+                assert a["rule_passes_run"] == run, (name, a)
+        assert engine_mod.text_rules._tally.counts is None  # closed again
+
     def test_untraced_call_records_nothing_and_reads_no_clock_per_doc(
             self, engine, monkeypatch):
         reads, finished = [], []
@@ -186,6 +219,9 @@ class TestGroupsPathSpans:
             perf_counter=lambda: reads.append(1) or time.perf_counter()))
         monkeypatch.setattr(Tracer, "_finish_span",
                             lambda self, span: finished.append(span.name))
+        # nor does it count the pre-rules' scans
+        monkeypatch.setattr(engine_mod.text_rules, "counting_passes",
+                            lambda: reads.append("counter"))
         assert tracing.current_context() is None
         docs = issues([3] * 41)  # 11 groups, one flush
         rows = engine.embed_issues(docs, scheduler="groups")
