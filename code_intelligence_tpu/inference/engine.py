@@ -40,6 +40,7 @@ from __future__ import annotations
 import bisect
 import dataclasses
 import functools
+import inspect
 import logging
 import time
 from typing import Dict, List, Optional, Sequence, Tuple, Union
@@ -250,10 +251,15 @@ class InferenceEngine:
         if cached is not None:
             return cached
 
+        takes_lengths = self._encode_takes_lengths
+
         def fwd(params, tokens, lengths, h_states, pool_state):
             states = jax.tree.unflatten(self._state_treedef, h_states)
+            # each row's valid tokens, to every encoder that names them
+            # (models/contract.py)
+            extra = {"lengths": lengths} if takes_lengths else {}
             raw, new_states = self.encoder.encode(
-                params["params"], tokens, states)
+                params["params"], tokens, states, **extra)
             pool_state = self._accumulate_pool(raw, lengths, pool_state)
             return pool_state, jax.tree.leaves(new_states)
 
@@ -264,6 +270,11 @@ class InferenceEngine:
         jitted = jax.jit(fwd, donate_argnums=(3,))
         self._fwd_cache[(batch, length)] = jitted
         return jitted
+
+    @functools.cached_property
+    def _encode_takes_lengths(self) -> bool:
+        return "lengths" in inspect.signature(
+            self.encoder.encode).parameters
 
     @functools.cached_property
     def _state_treedef(self):
@@ -565,6 +576,11 @@ class InferenceEngine:
         in_flight: List[Tuple[object, int]] = []  # (a pool leaf, state bytes)
         longest_sent = -1  # no group enqueued yet
 
+        # traced calls: what the encoder counted on the device for each
+        # group (models/contract.py::state_counters), fetched after the
+        # group's pooled rows and outside the span's clock reads
+        counted = [] if call_ctx is not None else None
+
         def flush():
             if not pending:
                 return
@@ -574,8 +590,13 @@ class InferenceEngine:
                     # the group's documents are the last rows of its batch
                     out[idx] = np.concatenate(
                         [self._finalize(p) for p in pools])[-len(idx):]
-            tracing.record_span("engine.finalize", tf0, time.perf_counter(),
-                                call_ctx, groups=len(pending))
+            tf1 = time.perf_counter()
+            attrs = {}
+            if counted:
+                attrs = self.encoder.counter_attrs(jax.device_get(counted))
+                counted.clear()
+            tracing.record_span("engine.finalize", tf0, tf1, call_ctx,
+                                groups=len(pending), **attrs)
             pending.clear()
 
         feed, feeding = iter(order), True
@@ -604,7 +625,7 @@ class InferenceEngine:
                 tg0 = time.perf_counter()
                 with profiling.annotate("engine.group"):
                     pools, counts = self._embed_group_device(
-                        [ids for _, _, ids in group])
+                        [ids for _, _, ids in group], counted)
                 tg1 = time.perf_counter()
                 if ctxs is not None:
                     tracing.record_span(
@@ -640,7 +661,8 @@ class InferenceEngine:
     def _bucket_for(self, length: int) -> int:
         return self._bucket_for_static(length, self.buckets)
 
-    def _embed_group_device(self, seqs: List[np.ndarray]):  # graft: hot
+    def _embed_group_device(self, seqs: List[np.ndarray],  # graft: hot
+                            counted: Optional[list] = None):
         """Enqueue one group's forward passes; returns the DEVICE pool
         state (no host sync — ``_finalize`` materializes it) and the
         group's counts.
@@ -666,10 +688,15 @@ class InferenceEngine:
         asked to run for it (``lane_steps_run`` = the rows each chunk
         program ran x ``bucket``, summed; ``row_chunks_dropped`` =
         ``batch`` less the rows run, summed over the chunks: 0 when
-        nothing narrowed), and from the encoder ``state_bytes`` (the
+        nothing narrowed; ``cache_steps_run`` = the rows each chunk
+        program ran x the positions they had reached by its end, summed:
+        what an encoder that attends to a cache is asked to meet), and
+        from the encoder ``state_bytes`` (the
         state carried out of the first chunk program, all ``batch``
         rows) and ``kv_positions`` (cache positions a row is allocated;
-        0 for a fixed-size state)."""
+        0 for a fixed-size state). ``counted``, where a list is given,
+        gains what the encoder counted in the group's carried state
+        (still on the device)."""
         B = self.batch_size  # the first chunk's shape; pad the remainder
         lens = [len(s) for s in seqs]
         if any(a > b for a, b in zip(lens, lens[1:])):
@@ -686,7 +713,7 @@ class InferenceEngine:
         pool = self._init_pool_state(B)
         pad_id = self.vocab.pad_id
 
-        batch, rows_run, pools = B, 0, []
+        batch, rows_run, cache_steps, pools = B, 0, 0, []
         for ci in range(n_chunks):
             if ci:
                 alive = len(seqs) - bisect.bisect_right(lens, ci * bucket)
@@ -707,7 +734,13 @@ class InferenceEngine:
                 self._enc_params, jnp.asarray(tokens), jnp.asarray(lengths), tuple(h_leaves), pool
             )
             rows_run += batch
+            cache_steps += batch * bucket * (ci + 1)
         pools.append(pool)
+        if counted is not None:
+            counts = self.encoder.state_counters(
+                jax.tree.unflatten(self._state_treedef, h_leaves))
+            if counts is not None:
+                counted.append(counts)
         return pools, {
             "rows": len(seqs), "batch": B, "bucket": bucket,
             "chunks": n_chunks,
@@ -715,6 +748,7 @@ class InferenceEngine:
             "lane_steps": B * bucket * n_chunks,
             "lane_steps_run": rows_run * bucket,
             "row_chunks_dropped": B * n_chunks - rows_run,
+            "cache_steps_run": cache_steps,
             "state_bytes": B * self.encoder.state_bytes_per_row(positions),
             "kv_positions": self.encoder.cache_positions(positions),
         }

@@ -9,6 +9,10 @@ from code_intelligence_tpu.models.contract import (
     build_encoder,
     make_config,
 )
+from code_intelligence_tpu.models.deepseek_v3 import (
+    DeepseekV3Config,
+    DeepseekV3Encoder,
+)
 from code_intelligence_tpu.models.granite_hybrid import (
     GraniteHybridConfig,
     GraniteHybridEncoder,
@@ -16,4 +20,5 @@ from code_intelligence_tpu.models.granite_hybrid import (
 
 __all__ = ["AWDLSTMConfig", "AWDLSTMEncoder", "AWDLSTMLM", "init_lstm_states",
            "ChunkEncoder", "build_encoder", "make_config",
+           "DeepseekV3Config", "DeepseekV3Encoder",
            "GraniteHybridConfig", "GraniteHybridEncoder"]

@@ -153,10 +153,19 @@ class AWDLSTMEncoder(nn.Module):
         return 0  # no part of the state grows with the document
 
     @nn.nowrap
-    def encode(self, params, tokens, states):
+    def encode(self, params, tokens, states, lengths=None):
+        """``lengths`` are not read: a recurrence runs every lane."""
         raw, _, new_states = self.apply(
             {"params": params}, tokens, states, deterministic=True)
         return raw, new_states
+
+    @nn.nowrap
+    def state_counters(self, states):
+        return None  # the state holds no counts
+
+    @nn.nowrap
+    def counter_attrs(self, counted) -> dict:
+        return {}
 
     @nn.nowrap
     def state_bytes_per_row(self, max_len=None) -> int:

@@ -2,7 +2,7 @@
 
 The engine streams a document through fixed-size chunk programs and
 pools the hidden states it gets back; it never looks inside the model.
-Anything that offers these five names can be served on the ``groups``
+Anything that offers these seven names can be served on the ``groups``
 path (``embed_issues``, ``embed_ids_batch``, ``embed_text``, the server
 with ``--scheduler groups``):
 
@@ -14,10 +14,14 @@ with ``--scheduler groups``):
     Only an encoder whose state GROWS with the document (a key/value
     cache) reads ``positions``; it raises ``ValueError`` for a document
     it cannot hold.
-``encode(params, tokens, states) -> (hidden (B, T, out_dim), new_states)``
+``encode(params, tokens, states, lengths=None) -> (hidden (B, T, out_dim), new_states)``
     one chunk, evaluation semantics, ``new_states`` of the structure
-    and shapes of ``states``. (The ISSUE called it ``apply``; Flax owns
-    that name on ``AWDLSTMEncoder``.)
+    and shapes of ``states``. ``lengths`` ``(B,)`` are each row's valid
+    tokens in this chunk (the lanes after them are padding, a padding
+    row's are all padding): the engine hands them to every encoder that
+    names the parameter; an encoder whose work does not depend on them
+    ignores them. (The ISSUE called it ``apply``; Flax owns that name on
+    ``AWDLSTMEncoder``.)
 ``state_bytes_per_row(max_len=None)``
     bytes of carried state one row holds for a document of ``max_len``
     tokens: what sets the batch once the state is large.
@@ -25,6 +29,13 @@ with ``--scheduler groups``):
     the part of the state that grows with the document: positions of
     key/value cache a row is allocated for documents of ``positions``
     tokens; 0 where the whole state is of fixed size.
+``state_counters(states)`` and ``counter_attrs(counted)``
+    counts the encoder keeps ON THE DEVICE in its carried state (rows
+    routed to experts): the first picks them out of a group's last
+    ``new_states`` (a device array, ``None`` where the encoder counts
+    nothing), the second turns the fetched ones of a flush's groups into
+    attributes of its ``engine.finalize`` span. Traced calls only; the
+    fetch rides the pooled rows'.
 
 ``slots`` and ``ragged`` reach into the AWD encoder's layers
 (``inference/slots.py``) and take no other encoder.
@@ -37,6 +48,8 @@ from typing import Any, Mapping, Optional, Protocol, Tuple, runtime_checkable
 import jax.numpy as jnp
 
 from code_intelligence_tpu.models.awd_lstm import AWDLSTMConfig, AWDLSTMEncoder
+from code_intelligence_tpu.models.deepseek_v3 import (
+    DeepseekV3Config, DeepseekV3Encoder)
 from code_intelligence_tpu.models.granite_hybrid import (
     GraniteHybridConfig, GraniteHybridEncoder)
 
@@ -47,36 +60,65 @@ class ChunkEncoder(Protocol):
 
     def init_states(self, batch: int, positions: Optional[int] = None): ...
 
-    def encode(self, params, tokens, states) -> Tuple[Any, Any]: ...
+    def encode(self, params, tokens, states,
+               lengths=None) -> Tuple[Any, Any]: ...
 
     def state_bytes_per_row(self, max_len: Optional[int] = None) -> int: ...
 
     def cache_positions(self, positions: Optional[int] = None) -> int: ...
 
+    def state_counters(self, states): ...
+
+    def counter_attrs(self, counted) -> Mapping[str, Any]: ...
+
+
+def _awd_config(model: Mapping, **extra) -> AWDLSTMConfig:
+    kw = dict(model, **extra)
+    if "dtype" in kw:
+        kw["dtype"] = jnp.dtype(kw["dtype"])
+    return AWDLSTMConfig(**kw)
+
+
+def _in_weights_dtype(encoder_cls):
+    """An encoder that computes in the type of the weights it will be
+    handed (``params``' embedding)."""
+    def build(config, params=None):
+        if params is None:
+            return encoder_cls(config)
+        return encoder_cls(config, dtype=params["embedding"].dtype)
+    return build
+
+
+# architecture -> (configuration class, configuration from a mapping,
+# encoder from a configuration and the weights)
+ENCODERS = {
+    AWDLSTMConfig.architecture: (
+        AWDLSTMConfig, _awd_config, lambda config, params=None:
+        AWDLSTMEncoder(config)),
+    GraniteHybridConfig.architecture: (
+        GraniteHybridConfig, GraniteHybridConfig.from_dict,
+        _in_weights_dtype(GraniteHybridEncoder)),
+    DeepseekV3Config.architecture: (
+        DeepseekV3Config, DeepseekV3Config.from_dict,
+        _in_weights_dtype(DeepseekV3Encoder)),
+}
+
 
 def make_config(architecture: str, model: Mapping, **extra):
     """The configuration of ``architecture`` from a ``model`` mapping
     (a benchmark configuration's block, an export's ``config.json``)."""
-    if architecture == AWDLSTMConfig.architecture:
-        kw = dict(model, **extra)
-        if "dtype" in kw:
-            kw["dtype"] = jnp.dtype(kw["dtype"])
-        return AWDLSTMConfig(**kw)
-    if architecture == GraniteHybridConfig.architecture:
-        return GraniteHybridConfig.from_dict(model, **extra)
-    raise ValueError(
-        f"unknown architecture {architecture!r}: "
-        f"{AWDLSTMConfig.architecture!r} or "
-        f"{GraniteHybridConfig.architecture!r}")
+    if architecture not in ENCODERS:
+        raise ValueError(
+            f"unknown architecture {architecture!r}: one of "
+            f"{sorted(ENCODERS)}")
+    return ENCODERS[architecture][1](model, **extra)
 
 
 def build_encoder(config, params=None) -> ChunkEncoder:
-    """The encoder ``config`` describes. The hybrid computes in the type
-    of the weights it will be handed (``params``' embedding)."""
-    if isinstance(config, AWDLSTMConfig):
-        return AWDLSTMEncoder(config)
-    if isinstance(config, GraniteHybridConfig):
-        if params is None:
-            return GraniteHybridEncoder(config)
-        return GraniteHybridEncoder(config, dtype=params["embedding"].dtype)
-    raise ValueError(f"no encoder for a {type(config).__name__}")
+    """The encoder ``config`` describes."""
+    entry = ENCODERS.get(getattr(type(config), "architecture", None))
+    if entry is None or not isinstance(config, entry[0]):
+        raise ValueError(
+            f"no encoder for a {type(config).__name__}: the configurations "
+            f"of {sorted(ENCODERS)}")
+    return entry[2](config, params)

@@ -201,9 +201,16 @@ class GraniteHybridEncoder:
             * 2 * cfg.num_key_value_heads * cfg.head_dim * item
         return fixed + grows
 
-    def encode(self, params, tokens, states):
+    def state_counters(self, states):
+        return None  # the state holds no counts
+
+    def counter_attrs(self, counted) -> dict:
+        return {}
+
+    def encode(self, params, tokens, states, lengths=None):
         """One chunk: ``tokens`` ``(B, T)`` with the carried ``states``
-        in, ``(hidden (B, T, out_dim) float32, new states)`` out."""
+        in, ``(hidden (B, T, out_dim) float32, new states)`` out.
+        ``lengths`` are not read: scan and attention run every lane."""
         cfg = self.config
         dtype = params["embedding"].dtype
         res = cfg.residual_multiplier

@@ -1,0 +1,152 @@
+"""A routed expert layer that is told which experts it holds.
+
+Expert parallelism gives a chip ``count`` of a layer's ``n_experts``
+experts. The router here keeps its published width: every token is
+scored against all ``n_experts``, picks its ``top_k`` among them and
+normalises over all it picked, exactly as if every expert were here.
+The chip then computes the part of the sum its own experts give,
+``sum over chosen i in [first, first + count)  w_i * E_i(h)``, and
+nothing for the rest: what the absent experts would add is another
+chip's to compute and no code here stands in for it.
+
+``route`` is DeepSeek-V3's router (``topk_method: noaux_tc``): sigmoid
+scores in float32; the choice is made on ``score + bias`` (the bias
+balances load and moves nothing else): experts lie in ``n_group`` groups,
+a group's score is the sum of its two best, the best ``topk_group``
+groups are kept, and the ``top_k`` best experts among them are chosen;
+the weights are the UNBIASED scores of the chosen, normalised to sum 1
+and multiplied by ``scaling``.
+
+``routed_experts`` runs the held experts as ONE grouped matmul over the
+rows routed to them (``jax.lax.ragged_dot``: on the TPU a kernel whose
+grid follows the group sizes, so device time follows the rows routed and
+not a worst-case bound). No token is ever dropped and there is no
+capacity factor: assignments are sorted by expert and taken ``N`` (the
+tokens' number) at a time for as many rounds as they need (one, unless
+more than ``N`` assignments land here: ``top_k * count / n_experts`` of
+a token's choices do on average).
+"""
+
+from __future__ import annotations
+
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+
+def route(
+    h: jnp.ndarray,         # (N, E) float32
+    w_router: jnp.ndarray,  # (E, n_experts)
+    bias: jnp.ndarray,      # (n_experts,) float32: e_score_correction_bias
+    n_group: int,
+    topk_group: int,
+    top_k: int,
+    scaling: float,
+    norm_topk_prob: bool = True,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``(experts (N, top_k) int32, weights (N, top_k) float32)``. The
+    published router multiplies in float32: on the TPU that takes
+    ``Precision.HIGHEST`` (the default rounds the inputs to bfloat16)."""
+    logits = jnp.dot(
+        h.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+    scores = jax.nn.sigmoid(logits)
+    choice = scores + bias.astype(jnp.float32)
+    N, n_experts = choice.shape
+    if n_group > 1:
+        per = choice.reshape(N, n_group, n_experts // n_group)
+        group_score = lax.top_k(per, 2)[0].sum(-1)
+        _, kept = lax.top_k(group_score, topk_group)
+        keep = jnp.zeros((N, n_group), bool).at[
+            jnp.arange(N)[:, None], kept].set(True)
+        choice = jnp.where(keep[:, :, None], per, -jnp.inf).reshape(
+            N, n_experts)
+    _, experts = lax.top_k(choice, top_k)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    if norm_topk_prob and top_k > 1:
+        weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+    return experts.astype(jnp.int32), weights * scaling
+
+
+def swiglu(x, w_in, w_out, dtype):
+    """``(silu(g) * u) @ W_out`` with ``[g | u] = x @ W_in``; float32
+    out."""
+    g, u = jnp.split(jnp.dot(x.astype(w_in.dtype), w_in,
+                             preferred_element_type=dtype), 2, axis=-1)
+    act = jax.nn.silu(g.astype(jnp.float32)) * u.astype(jnp.float32)
+    return jnp.dot(act.astype(w_out.dtype), w_out,
+                   preferred_element_type=jnp.float32)
+
+
+def assign(experts: jnp.ndarray, first: int, count: int,
+           valid: Optional[jnp.ndarray] = None):
+    """Assignments of tokens to the held experts ``[first, first +
+    count)``, sorted by expert: ``(order, rows)`` with ``order`` ``(N *
+    top_k,)`` the flat assignment indices (token ``i // top_k``, choice
+    ``i % top_k``), held ones first and expert by expert, tokens in
+    order within an expert; ``rows`` ``(count,)`` the rows each held
+    expert gets. ``valid`` ``(N,)`` leaves tokens out (padding lanes)."""
+    local = experts - first
+    held = (local >= 0) & (local < count)
+    if valid is not None:
+        held = held & valid[:, None]
+    key = jnp.where(held, local, count).reshape(-1)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    rows = jnp.sum(key[:, None] == jnp.arange(count)[None, :], axis=0,
+                   dtype=jnp.int32)
+    return order, rows
+
+
+def routed_experts(
+    x: jnp.ndarray,        # (N, E)
+    experts: jnp.ndarray,  # (N, top_k) int32, over all n_experts
+    weights: jnp.ndarray,  # (N, top_k) float32
+    w_in: jnp.ndarray,     # (count, E, 2 * F): [gate | up] of held experts
+    w_out: jnp.ndarray,    # (count, F, E)
+    first: int,
+    valid: Optional[jnp.ndarray] = None,  # (N,) bool
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``(y (N, E) float32, rows (count,) int32)``: the held experts'
+    part of every token's weighted sum, and the rows each held expert
+    ran. Named scopes: ``dispatch`` (the sort, each round's gather),
+    ``experts`` (the grouped matmuls), ``combine`` (weigh, add back)."""
+    N, E = x.shape
+    top_k = experts.shape[1]
+    count = w_in.shape[0]
+    dtype = w_in.dtype
+    with jax.named_scope("dispatch"):
+        order, per_expert = assign(experts, first, count, valid)
+        ends = jnp.cumsum(per_expert)
+        total = ends[-1]
+        # padded so that every round slices N whole entries
+        order = jnp.concatenate([order, jnp.zeros((N,), jnp.int32)])
+        flat_w = weights.reshape(-1)
+
+    def one_round(r, y):
+        start = r * N  # N rows a round
+        with jax.named_scope("dispatch"):
+            picked = lax.dynamic_slice_in_dim(order, start, N)
+            token = picked // top_k
+            live = start + jnp.arange(N) < total
+            # this round's share of each expert's rows
+            sizes = jnp.diff(jnp.clip(ends - start, 0, N), prepend=0)
+            xs = jnp.take(x, token, axis=0).astype(dtype)
+        with jax.named_scope("experts"):
+            g, u = jnp.split(lax.ragged_dot(
+                xs, w_in, sizes, preferred_element_type=dtype), 2, axis=-1)
+            act = jax.nn.silu(g.astype(jnp.float32)) * u.astype(jnp.float32)
+            out = lax.ragged_dot(act.astype(dtype), w_out, sizes,
+                                 preferred_element_type=jnp.float32)
+        with jax.named_scope("combine"):
+            # rows past the round's last assignment hold whatever the
+            # grouped matmul left there: selected away, never multiplied
+            out = jnp.where(live[:, None],
+                            out * jnp.take(flat_w, picked)[:, None], 0.0)
+            return y.at[token].add(out)
+
+    y = lax.fori_loop(0, (total + N - 1) // N, one_round,
+                      jnp.zeros((N, E), jnp.float32))
+    return y, per_expert
