@@ -11,10 +11,14 @@ output), with random weights:
 
 * **train** — ``training.cli.main`` on a seeded Zipf corpus, reference
   defaults (``--bs 104 --bptt 67 --bf16``), two dispatches of
-  ``steps_per_dispatch`` windows and one validation pass; once on the XLA
-  scan and once with ``--lstm_pallas``. Loss finite at every step and
-  lower at the end, one compile per step program, checkpoint and
-  ``encoder_export/`` written, the two runs agree inside ``TRAIN_BAND``.
+  ``steps_per_dispatch`` windows and one validation pass, on the cell
+  the train step chooses for itself (``training/loop.py::
+  train_cell_is_resident``: on one chip in bf16 the weights-resident
+  Pallas cell in all four layers, and the lowered step is checked for its
+  Mosaic call). Loss finite at every step and lower at the end, one
+  compile per step program, checkpoint and ``encoder_export/`` written.
+  (The XLA scan trains under **multichip**, whose loss has to agree with
+  this one inside ``TRAIN_BAND``.)
 * **serve** — the real HTTP server (``serving.server.build_server`` +
   ``serve_forever``) from the export the train leg wrote, CLI defaults;
   mixed-length GitHub-shaped documents over ``POST /text``. Every row is
@@ -91,7 +95,8 @@ SERVE_BAND = {"bf16": 0.03, "int8": 0.05}
 # 6 to 8 in absolute terms — apart (PR 21 chip runs). A property of the
 # bf16 carry on every path, not of the scheduler (ROADMAP S12).
 STATE_BAND = 0.05
-# scan vs Pallas LM loss after the same 40 bf16 steps from the same seed
+# one chip (resident cell) vs a mesh (XLA scan): LM loss after the same 40
+# bf16 steps from the same seed
 TRAIN_BAND = 0.05
 # kernels vs their f32 "highest" XLA reference, as a fraction of the
 # reference's max magnitude (bf16 inputs, f32 accumulation in-kernel)
@@ -262,7 +267,7 @@ def leg_train(work: Path, w: Widths, corpus_dir: Path, max_tokens: int,
     assert any((model_dir / "ckpt").iterdir()), "no orbax checkpoint"
     if expect_mosaic:
         assert _lowered_train_step_has_mosaic(w), \
-            f"{name}: --lstm_pallas train step has no Mosaic custom call"
+            f"{name}: the one-chip bf16 train step has no Mosaic custom call"
     out = {"name": name, "first_loss": round(first, 4),
            "last_loss": round(last, 4),
            "val_loss": round(summary["val_loss"], 4),
@@ -279,10 +284,11 @@ def leg_train(work: Path, w: Widths, corpus_dir: Path, max_tokens: int,
 
 
 def _lowered_train_step_has_mosaic(w: Widths) -> bool:
-    """Lower (not compile) the ``--lstm_pallas`` bf16 train step at these
-    widths and look for the Mosaic custom call: ``models/awd_lstm.py``
-    takes the scan for any layer ``fits_resident`` refuses, so a leg that
-    asks for the kernel checks that the kernel is what it got."""
+    """Lower (not compile) the one-chip bf16 train step at these widths
+    and look for the Mosaic custom call: the step chooses its own cell
+    (``training/loop.py::train_cell_is_resident``) and takes the scan for
+    any layer ``fits_resident`` refuses, so the leg checks that the
+    kernel is what it got."""
     import jax
     import jax.numpy as jnp
 
@@ -291,8 +297,7 @@ def _lowered_train_step_has_mosaic(w: Widths) -> bool:
     from code_intelligence_tpu.training import LMTrainer, TrainConfig
 
     cfg = AWDLSTMConfig(vocab_size=w.vocab, emb_sz=w.emb, n_hid=w.hid,
-                        n_layers=w.layers, dtype=jnp.bfloat16,
-                        lstm_use_pallas=True)
+                        n_layers=w.layers, dtype=jnp.bfloat16)
     trainer = LMTrainer(
         cfg, TrainConfig(batch_size=w.bs, bptt=w.bptt),
         mesh=make_mesh({"data": 1}, devices=jax.devices()[:1]))
@@ -794,7 +799,7 @@ def leg_multichip(work: Path, w: Widths, corpus_dir: Path, max_tokens: int,
     """Two train layouts and one serve mesh over all ``n_devices`` chips
     of the host, each with per-device evidence: live bytes on every
     device while it runs, and the partitioned program's per-device flops
-    against ``one_chip`` (the ``train_scan`` leg's result)."""
+    against ``one_chip`` (the ``train`` leg's result)."""
     single_chip_flops = one_chip["train_steps_flops"]
     single_chip_loss = one_chip["last_loss"]
     out: dict = {"devices_used": n_devices}
@@ -835,11 +840,11 @@ def leg_multichip(work: Path, w: Widths, corpus_dir: Path, max_tokens: int,
 
 
 def run(w: Widths = FLAGSHIP, legs: tuple = (
-        "train_scan", "serve_slots", "kernels", "train_pallas",
-        "serve_ragged", "serve_int8", "multichip"),
+        "train", "serve_slots", "kernels", "serve_ragged", "serve_int8",
+        "multichip"),
         expect_mosaic: bool = True, work: "Path | None" = None) -> dict:
-    """Run the named legs in order. ``serve_*``, ``train_pallas`` and
-    ``multichip`` need ``train_scan`` (its export, its loss, its flops)."""
+    """Run the named legs in order. ``serve_*`` and ``multichip`` need
+    ``train`` (its export, its loss, its flops)."""
     import jax
 
     results: dict = {}
@@ -855,20 +860,11 @@ def run(w: Widths = FLAGSHIP, legs: tuple = (
         reference = None
         export = None
         for leg in legs:
-            if leg == "train_scan":
+            if leg == "train":
                 results[leg] = leg_train(work, w, corpus_dir, max_tokens,
-                                         "scan")
+                                         "train", expect_mosaic=expect_mosaic)
                 export = Path(results[leg]["export"])
-                shutil.rmtree(work / "scan" / "ckpt")  # ~2.3 GB at flagship
-            elif leg == "train_pallas":
-                results[leg] = leg_train(
-                    work, w, corpus_dir, max_tokens, "pallas",
-                    ("--lstm_pallas",), expect_mosaic=expect_mosaic)
-                shutil.rmtree(work / "pallas", ignore_errors=True)
-                gap = abs(results[leg]["last_loss"]
-                          - results["train_scan"]["last_loss"])
-                assert gap <= TRAIN_BAND, \
-                    f"scan vs Pallas loss differ by {gap} (> {TRAIN_BAND})"
+                shutil.rmtree(work / "train" / "ckpt")  # ~2.3 GB at flagship
             elif leg == "serve_slots":
                 reference = leg_serve(export, w, issues, "slots",
                                       reference=reference)
@@ -890,7 +886,7 @@ def run(w: Widths = FLAGSHIP, legs: tuple = (
                 if n >= 4:
                     results[leg] = leg_multichip(
                         work, w, corpus_dir, max_tokens, issues, n,
-                        one_chip=results["train_scan"])
+                        one_chip=results["train"])
                 log(f"multichip: {n} device(s) visible, "
                     f"{n if n >= 4 else 0} used")
             else:

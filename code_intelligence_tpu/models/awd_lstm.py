@@ -64,8 +64,13 @@ class AWDLSTMConfig:
     qrnn_use_pallas: bool = False  # Pallas forget-mult kernel (ops/pallas_qrnn.py)
     # Pallas weights-resident fused LSTM cell for layers whose W_hh fits
     # VMEM — on v5e that includes the flagship H=2500 in bf16
-    # (ops.pallas_lstm.fits_resident, measured 1.80x the scan on chip);
-    # layers past the residency boundary keep the XLA scan.
+    # (ops.pallas_lstm.fits_resident); layers past the residency boundary
+    # keep the XLA scan. A TRAIN step does not read what a caller puts
+    # here: it sets the field itself from backend, dtype, fits_resident
+    # and its mesh (training/loop.py::train_cell_config; on the chip the
+    # cell's step fell from 91.87 to 74.9 ms with it, PR 31). The serve
+    # side (InferenceEngine) still reads it, from the export or its own
+    # keyword; no cell has shown the kernel to win there (ROADMAP D4).
     lstm_use_pallas: bool = False
     # QRNN only: shard the recurrence's TIME axis over this mesh axis
     # (true sequence/context parallelism — parallel/seq_parallel.py). The

@@ -11,12 +11,21 @@ time steps of each batch tile.
 Replaces (role-wise) the cuDNN fused LSTM cell the reference reaches
 through torch (`Issue_Embeddings/train.py:88-92`; SURVEY.md §2.4 row 1 —
 "Pallas ... fused LSTM cell as stage 2 optimization"; round-1 VERDICT
-item #2). Round 3's on-chip A/B overturned the round-2 assumption that
-the flagship H=2500 is out of reach: v5e's 128MB VMEM (~64MB Mosaic
-scope) holds the 50MB bf16 ``W_hh`` resident, and the fused forward
-measured 1.80x the XLA scan at H=2500 (4.68ms vs 8.44ms, B=104 T=67; a
-toolchain that is gone, and no benchmark cell runs the kernel yet:
-ROADMAP S3/S7).
+item #2). v5e's 128MB VMEM (~64MB Mosaic scope) holds the flagship's
+50MB bf16 ``W_hh`` resident. Where it runs and what it reads (v5e, jax
+0.9.0, PR 31, `PERF.md` §5-6): the TRAIN step of a one-chip bf16 run
+(`training/loop.py::train_cell_is_resident` chooses it; the benchmark
+cell `lstm_train_lm`) runs the forward with residuals and the adjoint
+below in all four layers. Inside that step at B=104 T=67 H=2500 the
+forward kernel takes 2.0-2.1 ms a layer a window (30-31 us a timestep,
+its matmul alone is 28 us at the MXU's peak) and the adjoint 2.3-2.4 ms,
+where the XLA scan's forward took 2.9 ms and its backward, with the
+recurrent weight gradients accumulated in the loop, 9-15 ms a layer; the
+step went from 91.87 to 74.9 ms. The inference
+kernels (dense without residuals, ragged, int8-ragged) are in no cell:
+alone, at 104 rows, the scan's forward (2.96 ms a layer) is level with
+the dense kernel's (2.61-2.96 ms by tile), so the serve side has no case
+for them yet (ROADMAP D4).
 
 Layout notes:
 
@@ -64,6 +73,14 @@ _VMEM_BUDGET = 63 * 1024 * 1024
 # Streamed-tile ceiling from Mosaic's ~16MB per-iteration stack budget
 # (see _pick_tiles docstring for the on-chip boundary mapping).
 _STREAM_TILE_BUDGET = int(4.5 * 1024 * 1024)
+# The same ceiling for the two TRAIN kernels (forward with residuals, and
+# the adjoint), mapped on today's toolchain by compiling every candidate
+# for a described v5e (PR 31, B104 T67 H=2500): whole-batch tiles
+# bt112/tc1 stream 5.04 MB (forward) and 5.6 MB (adjoint) a grid step and
+# compile; the forward at 10 MB (bt112/tc2, bt56/tc4) runs out of VMEM.
+# The inference kernels keep the budget above, so the serve programs'
+# tiles are the ones they were.
+_TRAIN_STREAM_TILE_BUDGET = 6 * 1024 * 1024
 # W_hh residency gate: the flagship H=2500 (50MB bf16) fits with room
 # for minimum streaming tiles; H≈2610 bf16 is the practical edge
 # (4·2610²·2 = 51.9MB).
@@ -77,6 +94,18 @@ _W_HH_BUDGET = 52 * 1024 * 1024
 # Mosaic ceiling; this just tells XLA so.
 _COMPILER_PARAMS = pltpu.CompilerParams(
     vmem_limit_bytes=_VMEM_BUDGET + 8 * 1024 * 1024)
+# The ADJOINT at a whole-batch tile sits at that limit's edge: inside
+# `train_steps` bt112/tc1 compiled, but under a plain `jax.grad` of one
+# layer it did not ("ran out of memory in memory space vmem": there XLA
+# keeps the kernel's small operands in VMEM itself, and Mosaic's own f32
+# temporaries of a (112, 10000) tile are 4.5 MB each). v5e has 128 MiB;
+# at 80 MiB every batch tried compiled (32-200 rows at H=2500, AOT for
+# v5e, PR 31). (95 MiB on both train kernels read 0.9 % fewer tokens/s
+# in the cell than 71 / 80; the kernels' own times were the same, so
+# that is the spread between two compiles, not a reason.) The forward
+# kernels keep the limit above: the serve programs are what they were.
+_BWD_COMPILER_PARAMS = pltpu.CompilerParams(
+    vmem_limit_bytes=_VMEM_BUDGET + 17 * 1024 * 1024)
 
 
 def fits_resident(hidden_size: int, itemsize: int = 2) -> bool:
@@ -120,7 +149,8 @@ def feasible_tiles(batch: int, hidden: int, gate_dim: int, with_gates: bool,
         c_tile = tc * bt * hidden * itemsize
         # training fwd streams x_proj in + gates and c_prev out
         streamed = x_tile + (x_tile + c_tile if with_gates else 0)
-        if streamed > _STREAM_TILE_BUDGET:
+        if streamed > (_TRAIN_STREAM_TILE_BUDGET if with_gates
+                       else _STREAM_TILE_BUDGET):
             return False
         tile = 2 * x_tile
         out = 2 * c_tile
@@ -134,41 +164,41 @@ def feasible_tiles(batch: int, hidden: int, gate_dim: int, with_gates: bool,
 
 def _pick_tiles(batch: int, hidden: int, gate_dim: int, with_gates: bool,
                 itemsize: int) -> Tuple[int, int]:
-    """Choose (batch_tile, time_chunk) for the fused kernel.
+    """Choose (batch_tile, time_chunk) for the fused kernel: a function
+    of the shapes alone, written from ONE sweep of `feasible_tiles` on
+    the chip (v5e, jax 0.9.0, PR 31; B104 x T67 bf16, ms a window, the
+    wrapper's pad and slices included; the full table is in PERF.md §6).
 
-    Measured on v5e (RUNBOOK §11): the MXU wants a LARGE batch tile (an
-    8-row tile wastes 15/16 of the systolic array — the round-2 default
-    bt=8 is why the kernel initially lost to the scan), and a moderate
-    time chunk amortizes grid overhead. Two compile-time ceilings bound
-    the choice, both mapped empirically on chip at H=2500:
+    Training forward (``with_gates``), H=2500: bt112/tc1 **3.16**,
+    bt56/tc1 3.45, bt56/tc2 3.50, bt16 5.15-5.23, bt8 9.1-9.3; H=800:
+    bt112/tc1 **0.607**, bt112/tc2 0.623, bt56 0.626-0.643, bt16 0.87,
+    bt8 1.38. The batch tile decides (each grid step pays ~9 us whatever
+    its rows, then ~0.34 us a row: at 112 rows the kernel alone is 30-31
+    us a timestep inside `train_steps`, against 28 us for its matmul at
+    the MXU's peak), the time chunk moves nothing (under 2 %), so: the
+    LARGEST batch tile, then the SMALLEST time chunk.
 
-    * the ~64MB scoped-VMEM budget (resident W_hh + all blocks), and
-    * a ~16MB per-iteration stack budget that caps the STREAMED tile
-      bytes — x tile plus (when emitted) gates tile — at ~4.5MB
-      (bt72/tc4 no-gates at 5.8MB streamed died with a 17.5M-stack
-      compile error; every ≤4.5MB config compiled).
+    Inference (no gates): the rule measured on the toolchain before this
+    one stands, because the serve programs are not this sweep's to
+    change; for whoever decides that (ROADMAP D4) the same sweep read
+    H=2500 bt112/tc1 2.61, bt112/tc2 2.66, bt56/tc1 2.92 and this rule's
+    bt56/tc4 2.96; H=800 bt112/tc2 0.451, bt56/tc1 0.452, this rule's
+    bt112/tc4 0.476.
 
-    Within the feasible set the measured winners differ by variant:
-    inference (no gates) was fastest tc-major (bt56/tc4 at 4.68ms beat
-    bt112/tc2 at 6.2ms), the training forward bt-major (bt112/tc1 at
-    5.96ms beat bt56/tc2 at 6.37ms — measured BEFORE the c_prev_seq
-    residual stream was added; with it, bt112 no longer fits the stream
-    budget and the heuristic lands on bt56/tc1). The pick is a function
-    of the shapes alone: a chip sweep over `feasible_tiles` that finds a
-    better tile writes it here.
+    Two compile-time ceilings bound the choice (`feasible_tiles`): the
+    scoped-VMEM budget (resident W_hh + all blocks) and the streamed
+    bytes of one grid step. Forward bt112/tc2 and bt56/tc4 at H=2500
+    with gates (10 MB streamed) fail to compile: "ran out of memory in
+    memory space vmem".
     """
     cands = feasible_tiles(batch, hidden, gate_dim, with_gates, itemsize)
     if not cands:
         _, _, bts = _sublane_snap(batch, itemsize)
         return bts[-1], 1
-    # MXU row utilization dominates while tiles are small (a bt=8 tile
-    # wastes 15/16 of the array) with diminishing returns past ~56 rows,
-    # then the time chunk's grid-overhead amortization takes over:
-    # maximize (min(bt, 56), tc, bt) — an empirical fit to the on-chip
-    # measurements that reproduces every solid winner ((56,4) no-gates
-    # at H=2500 over (112,2) at 4.68 vs 6.2ms; (112,4) at the serve
-    # sizes) and avoids the tc-major trap of returning bt=8 when only
-    # small tiles fit tc=4.
+    if with_gates:
+        return max(cands, key=lambda c: (c[0], -c[1]))
+    # no gates: maximize (min(bt, 56), tc, bt), the fit to the earlier
+    # toolchain's measurements ((56,4) at H=2500, (112,4) at serve sizes)
     return max(cands, key=lambda c: (min(c[0], 56), c[1], c[0]))
 
 
@@ -250,8 +280,8 @@ def _pad_axis(x: jnp.ndarray, axis: int, multiple: int) -> jnp.ndarray:
     return jnp.pad(x, widths)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("with_gates", "interpret", "tiles"))
+@functools.partial(jax.jit, static_argnames=("with_gates", "interpret",
+                                              "tiles", "t_real"))
 def fused_lstm_forward(
     x_proj: jnp.ndarray,
     w_hh: jnp.ndarray,
@@ -260,6 +290,7 @@ def fused_lstm_forward(
     with_gates: bool = False,
     interpret: bool = False,
     tiles: "Tuple[int, int] | None" = None,
+    t_real: "int | None" = None,
 ):
     """Run the fused cell over a window.
 
@@ -281,6 +312,10 @@ def fused_lstm_forward(
       tiles: explicit ``(batch_tile, time_chunk)`` for a chip sweep
         over ``feasible_tiles``; product callers leave it None and get
         ``_pick_tiles``.
+      t_real: the live timesteps of a window the CALLER already padded in
+        time (the train path hands in arrays padded to the kernel's own
+        grid, so the pads and slices below are all no-ops; the carry
+        freezes past ``t_real``). Default: all ``T``.
 
     Returns:
       ``(outputs (T, B, H), (gates, c_prev_seq)-or-None, (h_T, c_T))``.
@@ -289,6 +324,7 @@ def fused_lstm_forward(
     H = G // 4
     dtype = x_proj.dtype
     bt, tc = tiles or _pick_tiles(B, H, G, with_gates, dtype.itemsize)
+    t_live = T if t_real is None else t_real
     # Batch pads to the sublane-snapped dim (bf16: mult of 16) — see
     # _sublane_snap; bt divides it, so no second batch padding happens.
     sub, _, _ = _sublane_snap(B, dtype.itemsize)
@@ -311,7 +347,7 @@ def fused_lstm_forward(
     scratch = [pltpu.VMEM((bt, H), dtype), pltpu.VMEM((bt, H), dtype)]
 
     if with_gates:
-        kernel = functools.partial(_kernel_with_gates, T)
+        kernel = functools.partial(_kernel_with_gates, t_live)
         out_specs = [
             out_block_seq,
             pl.BlockSpec((tc, bt, G), lambda b, t: (t, b, 0), memory_space=pltpu.VMEM),
@@ -326,7 +362,7 @@ def fused_lstm_forward(
             jax.ShapeDtypeStruct((Bp, H), dtype),
         ]
     else:
-        kernel = functools.partial(_kernel_no_gates, T)
+        kernel = functools.partial(_kernel_no_gates, t_live)
         out_specs = [out_block_seq, out_block_state, out_block_state]
         out_shape = [
             jax.ShapeDtypeStruct((Tp, Bp, H), dtype),
@@ -718,20 +754,16 @@ def lstm_layer_fused_ragged_int8(x, state, w_ih_q, w_ih_scale, w_hh_q,
 
 
 # ---------------------------------------------------------------------------
-# Training wrapper: pallas forward + XLA adjoint backward over saved gates
+# Training wrapper: Pallas forward with residuals + Pallas adjoint; the
+# weight / input gradients are XLA einsums over the adjoint's dz
 # ---------------------------------------------------------------------------
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
 def lstm_layer_fused(x, state, w_ih, w_hh, bias, interpret=False):
     """Drop-in for `ops.lstm.lstm_layer` (same signature minus the mask —
-    callers apply DropConnect to ``w_hh`` before the call)."""
-    out_tm, _, new_state = _fwd_impl(x, state, w_ih, w_hh, bias, interpret,
-                                     with_gates=False)
-    return out_tm.swapaxes(0, 1), new_state
-
-
-def _fwd_impl(x, state, w_ih, w_hh, bias, interpret, with_gates):
+    callers apply DropConnect to ``w_hh`` before the call). Called without
+    a gradient (validation) it runs the forward without residuals."""
     # CPU (tests, multichip dryrun) has no Mosaic backend: interpret mode
     # keeps the exact same numerics there.
     interpret = interpret or jax.default_backend() != "tpu"
@@ -739,18 +771,42 @@ def _fwd_impl(x, state, w_ih, w_hh, bias, interpret, with_gates):
     # layout, not an extra transpose pass.
     x_proj = jnp.einsum("bti,gi->tbg", x, w_ih) + bias
     h0, c0 = state
-    out_tm, gates_tm, (h_t, c_t) = fused_lstm_forward(
-        x_proj, w_hh, h0, c0, with_gates=with_gates, interpret=interpret
-    )
-    return out_tm, gates_tm, (h_t, c_t)
+    out_tm, _, new_state = fused_lstm_forward(
+        x_proj, w_hh, h0, c0, interpret=interpret)
+    return out_tm.swapaxes(0, 1), new_state
+
+
+def _train_grid(batch: int, steps: int, hidden: int,
+                itemsize: int) -> Tuple[int, int]:
+    """``(padded batch, padded steps)`` that BOTH train kernels' grids
+    divide: what `_fwd` pads the layer's input to, once, so that neither
+    kernel's wrapper pads or slices anything."""
+    _, tc_f = _pick_tiles(batch, hidden, 4 * hidden, True, itemsize)
+    _, tc_b = _pick_tiles_bwd(batch, hidden, 4 * hidden, itemsize)
+    _, bp, _ = _sublane_snap(batch, itemsize)  # every batch tile divides it
+    tc = max(tc_f, tc_b)  # time chunks are 1, 2 or 4
+    return bp, -(-steps // tc) * tc
 
 
 def _fwd(x, state, w_ih, w_hh, bias, interpret):
-    out_tm, (gates_tm, c_prev_tm), new_state = _fwd_impl(
-        x, state, w_ih, w_hh, bias, interpret, with_gates=True)
+    """Forward for the adjoint. The layer's INPUT is padded to the
+    kernels' grid (104 rows to 112: 35 MB) and the projection emits
+    ``x_proj`` padded; the residuals stay padded until `_bwd`. Padding
+    ``x_proj`` itself, and slicing the residuals only for `_bwd` to pad
+    them again, were 4.0 ms of the 78.4 ms train step (v5e, PR 31)."""
+    interpret = interpret or jax.default_backend() != "tpu"
+    B, T, _ = x.shape
+    H = w_hh.shape[1]
+    bp, tp = _train_grid(B, T, H, x.dtype.itemsize)
+    x_p = jnp.pad(x, ((0, bp - B), (0, tp - T), (0, 0)))
+    x_proj = jnp.einsum("bti,gi->tbg", x_p, w_ih) + bias
     h0, c0 = state
-    res = (x, h0, c0, w_ih, w_hh, bias, out_tm, gates_tm, c_prev_tm)
-    return (out_tm.swapaxes(0, 1), new_state), res
+    pad_b = ((0, bp - B), (0, 0))
+    out_p, (gates_p, c_prev_p), (h_t, c_t) = fused_lstm_forward(
+        x_proj, w_hh, jnp.pad(h0, pad_b), jnp.pad(c0, pad_b),
+        with_gates=True, interpret=interpret, t_real=T)
+    res = (x, h0, c0, w_ih, w_hh, bias, out_p, gates_p, c_prev_p)
+    return (out_p[:T, :B].swapaxes(0, 1), (h_t[:B], c_t[:B])), res
 
 
 def feasible_tiles_bwd(batch: int, hidden: int, gate_dim: int,
@@ -766,7 +822,7 @@ def feasible_tiles_bwd(batch: int, hidden: int, gate_dim: int,
         g_tile = tc * bt * gate_dim * itemsize
         c_tile = tc * bt * hidden * itemsize
         streamed = g_tile + c_tile + c_tile  # gates, c_prev, d_out in
-        if streamed + g_tile > _STREAM_TILE_BUDGET:  # + dz out
+        if streamed + g_tile > _TRAIN_STREAM_TILE_BUDGET:  # + dz out
             return False
         est = (w_bytes + 2 * (2 * g_tile + 2 * c_tile)  # dbl-buffered
                + 4 * bt * hidden * itemsize             # state blocks
@@ -778,11 +834,19 @@ def feasible_tiles_bwd(batch: int, hidden: int, gate_dim: int,
 
 def _pick_tiles_bwd(batch: int, hidden: int, gate_dim: int,
                     itemsize: int) -> Tuple[int, int]:
+    """The adjoint's tile, from the same sweep as `_pick_tiles` (v5e,
+    PR 31, B104 x T67 bf16, ms a window): H=2500 bt112/tc1 **3.60**,
+    bt56/tc1 3.94, bt56/tc2 3.98, bt16 5.64-5.71, bt8 9.7; H=800
+    bt112/tc1 = bt112/tc2 **0.690**, bt56 0.755-0.764, bt16 1.02, bt8
+    1.6. Past the stream budget bt112/tc2 and bt112/tc4 compile and read
+    3.65 / 3.66: nothing to gain there. Largest batch tile, then the
+    smallest time chunk (inside `train_steps` the kernel alone is 34-36 us
+    a timestep at H=2500)."""
     cands = feasible_tiles_bwd(batch, hidden, gate_dim, itemsize)
     if not cands:
         _, _, bts = _sublane_snap(batch, itemsize)
         return bts[-1], 1
-    return max(cands, key=lambda c: (min(c[0], 56), c[1], c[0]))
+    return max(cands, key=lambda c: (c[0], -c[1]))
 
 
 def _bwd_kernel(t_real, gates_ref, c_prev_ref, d_out_ref, w_hh_ref,
@@ -839,7 +903,8 @@ def _bwd_kernel(t_real, gates_ref, c_prev_ref, d_out_ref, w_hh_ref,
     dc0_ref[:] = dc_scr[:].astype(dc0_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "tiles"))
+@functools.partial(jax.jit,
+                   static_argnames=("interpret", "tiles", "t_real"))
 def fused_lstm_backward(
     gates: jnp.ndarray,
     c_prev_seq: jnp.ndarray,
@@ -849,6 +914,7 @@ def fused_lstm_backward(
     d_c_t: jnp.ndarray,
     interpret: bool = False,
     tiles: "Tuple[int, int] | None" = None,
+    t_real: "int | None" = None,
 ):
     """Weights-resident adjoint over a window (time-major).
 
@@ -859,6 +925,8 @@ def fused_lstm_backward(
       w_hh: ``(4H, H)`` recurrent weights (the same DropConnect-masked
         tensor the forward ran with).
       d_h_t, d_c_t: ``(B, H)`` final-state cotangents.
+      t_real: as in :func:`fused_lstm_forward` (steps past it emit zero
+        ``dz`` and leave the carry alone).
 
     Returns:
       ``(dz (T, B, 4H) pre-activation grads, dh0, dc0)``.
@@ -906,13 +974,13 @@ def fused_lstm_backward(
                pltpu.VMEM((bt, H), jnp.float32)]
 
     dz, dh0, dc0 = pl.pallas_call(
-        functools.partial(_bwd_kernel, T),
+        functools.partial(_bwd_kernel, T if t_real is None else t_real),
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
-        compiler_params=_COMPILER_PARAMS,
+        compiler_params=_BWD_COMPILER_PARAMS,
         interpret=interpret,
     )(gates_p, c_prev_p, d_out_p, w_hh.astype(dtype), dht_p, dct_p)
     return dz[:T, :B], dh0[:B], dc0[:B]
@@ -923,20 +991,33 @@ def _bwd(interpret, res, cts):
     weights-resident Pallas kernel (interpret mode off-TPU), emitting the
     pre-activation grads ``dz``; the weight/bias/input gradients are the
     big batched einsums XLA already does at high MFU."""
-    x, h0, c0, w_ih, w_hh, bias, out_tm, gates_tm, c_prev_tm = res
+    x, h0, c0, w_ih, w_hh, bias, out_p, gates_p, c_prev_p = res
     d_out, (d_h_t, d_c_t) = cts
     f32 = jnp.float32
+    B, T, _ = x.shape
+    tp, bp, _ = gates_p.shape
 
     interpret = interpret or jax.default_backend() != "tpu"
-    dz, dh0, dc0 = fused_lstm_backward(
-        gates_tm, c_prev_tm, d_out.swapaxes(0, 1), w_hh,
-        d_h_t, d_c_t, interpret=interpret,
+    # the cotangents (H wide, 35 MB) are padded; the residuals (4H wide)
+    # arrive padded. Padded rows and steps carry zero cotangent, so their
+    # dz is zero and they are sliced off before the einsums below.
+    pad_b = ((0, bp - B), (0, 0))
+    dz_p, dh0, dc0 = fused_lstm_backward(
+        gates_p, c_prev_p,
+        jnp.pad(d_out.swapaxes(0, 1), ((0, tp - T), (0, bp - B), (0, 0))),
+        w_hh, jnp.pad(d_h_t, pad_b), jnp.pad(d_c_t, pad_b),
+        interpret=interpret, t_real=T,
     )
-    dz = dz.astype(f32)
+    dz = dz_p[:T, :B].astype(f32)
+    dh0, dc0 = dh0[:B], dc0[:B]
+    out_tm = out_p[:T, :B]
     h_prev = jnp.concatenate(
         [h0.astype(f32)[None], out_tm.astype(f32)[:-1]], axis=0)
 
-    # weight/bias/input grads: big batched matmuls (MXU work)
+    # weight/bias/input grads: big batched matmuls (MXU work). The f32
+    # upcasts cost nothing on the chip: XLA folds them into the matmuls'
+    # operands (PR 31 ran these einsums on the bf16 operands instead and
+    # every one of them read the same to 0.01 ms).
     d_w_hh = jnp.einsum("tbg,tbh->gh", dz, h_prev)
     d_bias = dz.sum(axis=(0, 1))
     d_w_ih = jnp.einsum("tbg,bti->gi", dz, x.astype(f32))

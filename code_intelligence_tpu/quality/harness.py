@@ -501,8 +501,10 @@ def stage_distill(cfg: QualityConfig) -> dict:
         batch_size=cfg.distill_batch_size,
         max_len=cfg.distill_max_len,
         seed=cfg.seed,
-        # smoke teachers are tiny f32 models; the residency *requirement*
-        # only makes sense at serving scale
+        # what the student's EXPORT carries for the serve side (smoke
+        # students are tiny: the residency promise only makes sense at
+        # serving scale). The distillation step's cell is the train-side
+        # rule's, not this (training/loop.py::train_cell_config).
         lstm_use_pallas=cfg.distill_n_hid >= 128,
     )
     distiller = EmbeddingDistiller(teacher_params, teacher_cfg, dcfg)

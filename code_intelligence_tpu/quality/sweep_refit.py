@@ -87,7 +87,9 @@ def refit_argv(best_params: dict, corpus_dir: Path, model_dir: Path,
         argv += [f"--{flag}", str(base * drop)]
     if not bool(best_params.get("one_cycle", True)):
         argv.append("--no_one_cycle")
-    for flag in ("qrnn", "qrnn_pallas", "lstm_pallas"):
+    # the LSTM's cell is the train step's own choice (training/loop.py),
+    # so an older best.json's "lstm_pallas" is not passed on
+    for flag in ("qrnn", "qrnn_pallas"):
         if (arch or {}).get(flag):
             argv.append(f"--{flag}")
     if bf16:

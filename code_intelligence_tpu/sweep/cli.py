@@ -61,9 +61,6 @@ def main(argv=None):
                    help="sweep the QRNN variant instead of the LSTM")
     p.add_argument("--qrnn_pallas", action="store_true",
                    help="Pallas forget-mult kernel (implies --qrnn)")
-    p.add_argument("--lstm_pallas", action="store_true",
-                   help="Pallas weights-resident fused LSTM cell for "
-                        "H<=1024 layers (exactly the sweep's size range)")
     p.add_argument("--wandb_project", default=None, metavar="PROJECT",
                    help="also stream each trial as a tracker run (requires "
                         "the wandb client; results.jsonl is always written)")
@@ -123,7 +120,6 @@ def main(argv=None):
             **{k: v * drop for k, v in BASE_DROPOUTS.items()},
             qrnn=args.qrnn or args.qrnn_pallas,
             qrnn_use_pallas=args.qrnn_pallas,
-            lstm_use_pallas=args.lstm_pallas,
         )
         bptt = int(params.get("bptt", fb["bptt"]))
         # the reference sweeps bs/wd/one_cycle too (sweep.yaml:24-33);
@@ -199,7 +195,6 @@ def main(argv=None):
         "arch": {
             "qrnn": bool(args.qrnn or args.qrnn_pallas),
             "qrnn_pallas": bool(args.qrnn_pallas),
-            "lstm_pallas": bool(args.lstm_pallas),
         },
     }
     (out_dir / "best.json").write_text(json.dumps(summary, indent=1))

@@ -46,11 +46,6 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--qrnn", action="store_true")
     p.add_argument("--qrnn_pallas", action="store_true",
                    help="Pallas forget-mult kernel for the QRNN recurrence")
-    p.add_argument("--lstm_pallas", action="store_true",
-                   help="Pallas weights-resident fused LSTM cell for layers "
-                        "whose W_hh fits VMEM (ops.pallas_lstm.fits_resident: "
-                        "the flagship H=2500 in bf16, not in f32); larger "
-                        "layers keep the XLA scan")
     p.add_argument("--seq_parallel", type=int, default=1, metavar="N",
                    help="shard the QRNN recurrence's TIME axis over N "
                         "devices (context parallelism; requires --qrnn and "
@@ -187,7 +182,6 @@ def main(argv=None) -> dict:
         weight_p=args.weight_p,
         qrnn=args.qrnn,
         qrnn_use_pallas=args.qrnn_pallas,
-        lstm_use_pallas=args.lstm_pallas,
         seq_axis="seq" if sp > 1 else None,
         dtype=jnp.bfloat16 if args.bf16 else jnp.float32,
     )

@@ -38,6 +38,7 @@ import optax
 
 from code_intelligence_tpu.models import AWDLSTMConfig, AWDLSTMEncoder, init_lstm_states
 from code_intelligence_tpu.models.classifier import masked_concat_pool
+from code_intelligence_tpu.training.loop import train_cell_config
 
 log = logging.getLogger(__name__)
 
@@ -58,7 +59,10 @@ class DistillConfig:
     # otherwise dominate the 1500-step full-scale run
     steps_per_dispatch: int = 10
     seed: int = 0
-    lstm_use_pallas: bool = True  # exported student config enables the kernel
+    # what the EXPORTED student config carries, for the serve side to read
+    # (inference/engine.py). The distillation step's own cell is chosen by
+    # the train-side rule (training/loop.py::train_cell_config).
+    lstm_use_pallas: bool = True
     # dtype written into the exported config — the one the SERVING path
     # runs. bf16 halves serve-time HBM traffic and W_hh residency cost
     # (under the round-3 v5e budget, bf16 is resident to H~2600 vs ~1800
@@ -100,7 +104,9 @@ class EmbeddingDistiller:
             dtype=jnp.float32,
         )
         self.teacher_enc = AWDLSTMEncoder(self.teacher_cfg)
-        self.student_enc = AWDLSTMEncoder(self.student_cfg)
+        # one device, float32: the same rule as the LM trainer's step
+        self.student_enc = AWDLSTMEncoder(
+            train_cell_config(self.student_cfg, mesh_size=1)[0])
         self.optimizer = optax.adamw(dcfg.lr, weight_decay=0.01)
         self.params = None
         self.opt_state = None

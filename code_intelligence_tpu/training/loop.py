@@ -33,6 +33,7 @@ from flax import struct
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from code_intelligence_tpu.models import AWDLSTMConfig, AWDLSTMLM, init_lstm_states
+from code_intelligence_tpu.ops.pallas_lstm import fits_resident
 from code_intelligence_tpu.parallel import (
     batch_sharding,
     make_mesh,
@@ -72,6 +73,39 @@ class TrainConfig:
     steps_per_dispatch: int = 20
 
 
+def train_cell_is_resident(backend: str, itemsize: int, hidden: int,
+                           mesh_size: int) -> bool:
+    """Resident cell or scan, for ONE LSTM layer of a train step: the
+    rule, from what the program can observe and nothing a user sets.
+
+    The weights-resident Pallas cell (`ops/pallas_lstm.py`, forward with
+    residuals and the adjoint) runs where it exists and where it fits:
+    on the TPU (off it the kernel is the interpreter, a test device);
+    when the layer's ``W_hh`` at the compute dtype fits VMEM
+    (``fits_resident``: H=2500 and H=800 in bfloat16, not H=2500 in
+    float32, 100 MB); and on a one-device mesh (a GSPMD-partitioned step
+    cannot hold a Mosaic call: JAX refuses it at the first dispatch, 4x
+    v5e, PR 21). Everywhere else the layer runs the XLA scan."""
+    return (backend == "tpu" and mesh_size == 1
+            and fits_resident(hidden, itemsize))
+
+
+def train_cell_config(config: AWDLSTMConfig,
+                      mesh_size: int) -> Tuple[AWDLSTMConfig, int]:
+    """``config`` as a train step builds its model, and how many of its
+    LSTM layers then run the resident cell. `models/awd_lstm.py` asks
+    ``fits_resident`` per layer under ``lstm_use_pallas``, so the field
+    is set iff some layer qualifies; what the caller's config said is
+    not read (the serve side reads its own: ``InferenceEngine``)."""
+    backend = jax.default_backend()
+    itemsize = jnp.dtype(config.dtype).itemsize
+    resident = 0 if config.qrnn else sum(
+        train_cell_is_resident(backend, itemsize, config.layer_size(li),
+                               mesh_size)
+        for li in range(config.n_layers))
+    return dataclasses.replace(config, lstm_use_pallas=resident > 0), resident
+
+
 class TrainState(struct.PyTreeNode):
     step: jnp.ndarray
     params: Any
@@ -95,25 +129,29 @@ class LMTrainer:
         self.tcfg = train_config
         self.mesh = mesh if mesh is not None else make_mesh()
         if (self.mesh.size > 1 and jax.default_backend() == "tpu"
-                and (model_config.lstm_use_pallas
-                     or model_config.qrnn_use_pallas)):
+                and model_config.qrnn_use_pallas):
             # the train step is one GSPMD-partitioned jit with no
             # shard_map around the kernel; on the chip JAX refuses that
             # at the first dispatch (4x v5e, PR 21). Refuse it here, by
             # name. (Off the TPU interpret mode lowers to plain HLO,
             # which partitions — the CPU mesh tests keep running it.)
             raise ValueError(
-                "--lstm_pallas / --qrnn_pallas do not compose with a "
+                "--qrnn_pallas does not compose with a "
                 f"multi-device mesh {dict(self.mesh.shape)} on TPU: JAX "
                 "refuses the partitioned step (\"Mosaic kernels cannot be "
                 "automatically partitioned. Please wrap the call in a "
                 "shard_map.\"). Train one chip with the kernel, or the "
                 "mesh on the XLA scan.")
+        # the LSTM recurrence's cell (resident Pallas cell or XLA scan) is
+        # this step's own choice, per layer, from backend, dtype,
+        # fits_resident and the mesh: train_cell_is_resident
+        step_config, self.resident_lstm_layers = train_cell_config(
+            model_config, self.mesh.size)
         # seq_axis: the model's QRNN layers time-shard their recurrence over
         # this mesh (parallel/seq_parallel.py); without it mesh stays out of
         # the module so jit caching keys only on config
         self.model = AWDLSTMLM(
-            model_config,
+            step_config,
             mesh=self.mesh if model_config.seq_axis else None,
         )
         total = (steps_per_epoch or 1000) * train_config.cycle_len
@@ -502,7 +540,9 @@ class LMTrainer:
         # never pays more than a few dict ops per DISPATCH (k steps), and
         # never raises.
         tracer = tracing.get_tracer()
-        fit_span = tracer.start_span("train.fit", epochs=epochs)
+        resident = self.resident_lstm_layers
+        fit_span = tracer.start_span("train.fit", epochs=epochs,
+                                     resident_lstm_layers=resident)
         fit_id = fit_span.trace_id
         ep_span = None
         with self.mesh:
@@ -546,7 +586,8 @@ class LMTrainer:
                         compiled = self._train_step is not None
                         timer.start()
                         with tracer.span("train.step", fit_id=fit_id,
-                                         epoch=_epoch, compile=not compiled):
+                                         epoch=_epoch, compile=not compiled,
+                                         resident_lstm_layers=resident):
                             state, metrics = self.train_step(state, x, y)
                         dt = timer.stop()
                         if not compiled:
@@ -574,7 +615,8 @@ class LMTrainer:
                         # timeline, on the device trace's own clock
                         with tracer.span("train.dispatch", fit_id=fit_id,
                                          epoch=_epoch, windows=n,
-                                         compile=not compiled), \
+                                         compile=not compiled,
+                                         resident_lstm_layers=resident), \
                                 profiling.annotate("train.dispatch"):
                             state, ms = self.train_steps(state, xs, ys)
                             # ONE transfer for the whole chunk — per-element

@@ -38,12 +38,12 @@ def test_every_leg_runs_at_tiny_widths(tmp_path):
     w = chip_smoke.Widths(vocab=3000, emb=16, hid=24, layers=3, bs=8,
                           bptt=10, steps_per_dispatch=3, serve_batch=8,
                           n_docs=10)
-    legs = ("train_scan", "serve_slots", "kernels", "train_pallas",
-            "serve_ragged", "serve_int8", "multichip")
+    legs = ("train", "serve_slots", "kernels", "serve_ragged",
+            "serve_int8", "multichip")
     results = chip_smoke.run(w, legs=legs, expect_mosaic=False,
                              work=tmp_path)
-    assert results["train_scan"]["compiles"] == {"train.steps": 1,
-                                                 "eval.steps": 1}
+    assert results["train"]["compiles"] == {"train.steps": 1,
+                                            "eval.steps": 1}
     assert len(results["kernels"]) == 11  # every pallas_call in the repo
     # the conftest's 8 virtual devices: the multi-device leg ran on all
     n = len(jax.devices())

@@ -90,11 +90,27 @@ class TestTrainingCLI:
                         "--qrnn", "--seq_parallel", "16", "--bptt", "16",
                         "--bs", "8"])
 
+    @pytest.mark.parametrize("cli", ["training", "sweep"])
+    def test_no_flag_steers_the_lstm_cell(self, cli, capsys):
+        # the train step picks its own cell (training/loop.py::
+        # train_cell_is_resident); the flag that used to is refused
+        import importlib
+
+        main = importlib.import_module(
+            f"code_intelligence_tpu.{cli}.cli").main
+        required = {"training": ["--corpus_dir", "c", "--model_dir", "m"],
+                    "sweep": ["--corpus_dir", "c", "--out_dir", "o"]}[cli]
+        with pytest.raises(SystemExit) as exc:
+            main([*required, "--lstm_pallas"])
+        assert exc.value.code == 2
+        assert "unrecognized arguments: --lstm_pallas" in capsys.readouterr().err
+
     @pytest.mark.slow  # full CLI training (~18s): kernel numerics are
     # pinned in test_pallas_lstm/test_pallas; this checks flag plumbing
     def test_pallas_kernel_flags_train_end_to_end(self, tmp_path):
-        # --lstm_pallas / --qrnn_pallas reach real train runs (interpret
-        # mode on CPU; the same flags select the Mosaic kernels on chip)
+        # --qrnn_pallas reaches a real train run (interpret mode on CPU;
+        # the same flag selects the Mosaic kernel on chip); the LSTM run
+        # beside it takes the cell its step chooses, here the scan
         from code_intelligence_tpu.training.cli import main as train_main
 
         corpus = self._tiny_corpus(tmp_path)
@@ -102,7 +118,6 @@ class TestTrainingCLI:
             "--corpus_dir", corpus, "--model_dir", str(tmp_path / "mp"),
             "--bs", "8", "--bptt", "8", "--emb_sz", "8", "--n_hid", "16",
             "--n_layers", "2", "--cycle_len", "1", "--data_parallel", "1",
-            "--lstm_pallas",
         ])
         assert np.isfinite(lstm["val_loss"])
         qrnn = train_main([

@@ -54,6 +54,10 @@ class TestDistill:
         dcfg = DistillConfig(n_hid=8, n_layers=2, max_len=24, batch_size=8,
                              steps=10, lstm_use_pallas=True)
         d = EmbeddingDistiller(params, cfg, dcfg)
+        # the flag is what the export carries; the distillation step's own
+        # cell is the train-side rule's, which off the TPU is the scan
+        assert d.student_cfg.lstm_use_pallas
+        assert not d.student_enc.config.lstm_use_pallas
         d.init()
         d.fit(_docs(16, np.random.RandomState(1)), log_every=10)
         vocab = Vocab(SPECIALS + [f"w{i}" for i in range(60 - len(SPECIALS))])
@@ -66,6 +70,24 @@ class TestDistill:
         emb = engine.embed_issue("w1 w2", "w3 w4")
         assert emb.shape == (3 * cfg.emb_sz,)
         assert np.isfinite(emb).all()
+
+    @pytest.mark.parametrize("backend,flag,resident", [
+        ("tpu", True, True), ("tpu", False, True),
+        ("cpu", True, False), ("cpu", False, False)])
+    def test_the_distillation_step_takes_the_train_side_rule(
+            self, teacher, monkeypatch, backend, flag, resident):
+        # float32 on one device: resident on the TPU wherever W_hh fits,
+        # whatever the export is told to carry
+        from code_intelligence_tpu.training import loop
+
+        real = loop.train_cell_is_resident
+        monkeypatch.setattr(loop, "train_cell_is_resident",
+                            lambda _b, *rest: real(backend, *rest))
+        params, cfg = teacher
+        d = EmbeddingDistiller(params, cfg, DistillConfig(
+            n_hid=8, n_layers=2, lstm_use_pallas=flag))
+        assert d.student_enc.config.lstm_use_pallas is resident
+        assert d.student_cfg.lstm_use_pallas is flag
 
     def test_student_cannot_exceed_teacher_width(self, teacher):
         params, cfg = teacher

@@ -1,7 +1,8 @@
 """Pallas fused LSTM cell: exact parity with the XLA-scan reference
 (`ops/lstm.py`) for forward outputs, carried state, and all gradients.
 Runs in interpret mode on the CPU mesh (on real hardware the kernel is
-exercised by chip_smoke.py's `kernels` and `train_pallas` legs)."""
+exercised by chip_smoke.py's `kernels` and `train` legs, and by the
+benchmark's `lstm_train_lm` cell)."""
 
 import jax
 import jax.numpy as jnp
@@ -14,9 +15,11 @@ from code_intelligence_tpu.ops.pallas_lstm import (
     _pick_tiles,
     _pick_tiles_bwd,
     _sublane_snap,
+    _train_grid,
     feasible_tiles,
     feasible_tiles_bwd,
     fits_resident,
+    fused_lstm_backward,
     fused_lstm_forward,
     fused_lstm_forward_ragged,
     lstm_layer_fused,
@@ -259,6 +262,59 @@ class TestGradientParity:
         np.testing.assert_allclose(g_fus, g_ref, rtol=2e-4, atol=2e-5)
 
 
+class TestCallerPaddedWindow:
+    """The train path pads the layer's input once, to both kernels'
+    grids, and hands the kernels windows that are already padded: the
+    live steps are ``t_real``, and the padded ones change nothing."""
+
+    def _window(self, seed=13):
+        x, (h0, c0), w_ih, w_hh, bias = make_inputs(seed=seed)
+        x_proj = jnp.einsum("bti,gi->tbg", x, w_ih) + bias
+        return x_proj, w_hh, h0, c0
+
+    def test_forward_freezes_the_carry_past_t_real(self):
+        x_proj, w_hh, h0, c0 = self._window()
+        want = fused_lstm_forward(x_proj, w_hh, h0, c0, with_gates=True,
+                                  interpret=True, tiles=(8, 1))
+        padded = jnp.pad(x_proj, ((0, 3), (0, 0), (0, 0)))  # 21 -> 24 steps
+        got = fused_lstm_forward(padded, w_hh, h0, c0, with_gates=True,
+                                 interpret=True, tiles=(8, 4), t_real=T)
+        np.testing.assert_allclose(got[0][:T], want[0], rtol=1e-6, atol=1e-6)
+        for g, w in zip(got[1], want[1]):  # gates, c_prev
+            np.testing.assert_allclose(g[:T], w, rtol=1e-6, atol=1e-6)
+        for g, w in zip(got[2], want[2]):  # the state after T live steps
+            np.testing.assert_allclose(g, w, rtol=1e-6, atol=1e-6)
+
+    def test_adjoint_emits_nothing_past_t_real(self):
+        x_proj, w_hh, h0, c0 = self._window(seed=14)
+        _, (gates, c_prev), _ = fused_lstm_forward(
+            x_proj, w_hh, h0, c0, with_gates=True, interpret=True)
+        rng = np.random.RandomState(3)
+        d_out = jnp.asarray(rng.randn(T, B, H) * 0.1, jnp.float32)
+        dh = jnp.asarray(rng.randn(B, H) * 0.1, jnp.float32)
+        dc = jnp.asarray(rng.randn(B, H) * 0.1, jnp.float32)
+        want = fused_lstm_backward(gates, c_prev, d_out, w_hh, dh, dc,
+                                   interpret=True, tiles=(8, 1))
+        pad = ((0, 3), (0, 0), (0, 0))
+        got = fused_lstm_backward(
+            jnp.pad(gates, pad, constant_values=0.5), jnp.pad(c_prev, pad),
+            jnp.pad(d_out, pad), w_hh, dh, dc, interpret=True,
+            tiles=(8, 4), t_real=T)
+        np.testing.assert_allclose(got[0][:T], want[0], rtol=1e-6, atol=1e-6)
+        assert not np.asarray(got[0][T:]).any()
+        for g, w in zip(got[1:], want[1:]):  # dh0, dc0
+            np.testing.assert_allclose(g, w, rtol=1e-6, atol=1e-6)
+
+    @pytest.mark.parametrize("batch,hidden", [
+        (104, 2500), (104, 800), (128, 2500), (200, 2500), (4, 16)])
+    def test_one_grid_serves_both_train_kernels(self, batch, hidden):
+        bp, tp = _train_grid(batch, 67, hidden, 2)
+        for bt, tc in (_pick_tiles(batch, hidden, 4 * hidden, True, 2),
+                       _pick_tiles_bwd(batch, hidden, 4 * hidden, 2)):
+            assert bp % bt == 0 and tp % tc == 0
+        assert bp >= batch and tp >= 67 and bp - batch < 16
+
+
 class TestModelIntegration:
     def test_awd_encoder_parity_with_flag(self):
         # the full AWD-LSTM encoder produces identical outputs with the
@@ -346,6 +402,34 @@ def test_tiles_follow_from_shapes(batch, hidden, picker, monkeypatch):
     for var in ("CI_TPU_LSTM_FWD_TILES", "CI_TPU_LSTM_BWD_TILES"):
         monkeypatch.setenv(var, f"{batch},{hidden},{other[0]},{other[1]}")
     assert pick(batch, hidden) == base
+
+
+# One sweep on the chip (v5e, PR 31, B104 x T67 bf16; PERF.md §6 has the
+# table): the train kernels take the largest batch tile and the smallest
+# time chunk; the inference kernel's pick is the one it had.
+@pytest.mark.parametrize("hidden,picker,winner", [
+    (2500, "fwd_gates", (112, 1)), (2500, "bwd", (112, 1)),
+    (800, "fwd_gates", (112, 1)), (800, "bwd", (112, 1)),
+    (2500, "fwd", (56, 4)), (800, "fwd", (112, 4))])
+def test_the_pickers_return_the_sweeps_winners(hidden, picker, winner):
+    assert _PICKERS[picker][0](104, hidden) == winner
+
+
+@pytest.mark.parametrize("picker", sorted(_PICKERS))
+@pytest.mark.parametrize("hidden", [2500, 800])
+@pytest.mark.parametrize("batch", [32, 200])
+def test_the_serve_batches_get_a_feasible_tile(batch, hidden, picker):
+    pick, feasible = _PICKERS[picker]
+    assert pick(batch, hidden) in feasible(batch, hidden)
+
+
+@pytest.mark.parametrize("batch,hidden,tile", [
+    (32, 2500, (32, 4)), (200, 2500, (104, 2)), (100, 2500, (56, 4)),
+    (25, 2500, (32, 4)), (32, 800, (32, 4)), (200, 800, (104, 4))])
+def test_the_inference_tiles_are_the_ones_they_were(batch, hidden, tile):
+    # the wider stream budget and the sweep's rule are the train kernels'
+    # alone: what a serve program compiles did not move with this PR
+    assert _pick_tiles(batch, hidden, 4 * hidden, False, 2) == tile
 
 
 def test_no_feasible_tile_falls_back_to_the_smallest_batch_tile():

@@ -217,6 +217,22 @@ class TestSweepRefit:
         assert "--lstm_pallas" not in s
         build_parser().parse_args(argv)  # argparse accepts the whole argv
 
+    @pytest.mark.parametrize("arch", [
+        {"lstm_pallas": True},  # a best.json from before PR 31
+        {"qrnn": False, "qrnn_pallas": False, "lstm_pallas": True},
+        {}, None])
+    def test_refit_leaves_the_lstm_cell_to_the_train_step(self, tmp_path,
+                                                           arch):
+        # no flag steers it (training/loop.py::train_cell_is_resident):
+        # what an older sweep recorded under "lstm_pallas" is not passed on
+        from code_intelligence_tpu.quality.sweep_refit import refit_argv
+        from code_intelligence_tpu.training.cli import build_parser
+
+        argv = refit_argv({"lr": 2e-3}, tmp_path / "c", tmp_path / "m",
+                          cycle_len=1, arch=arch)
+        assert not any("pallas" in a for a in argv)
+        build_parser().parse_args(argv)
+
     def test_refit_fallbacks_match_sweep_trial_not_flagship(self, tmp_path):
         # ADVICE r3 (medium): a sweep yaml that omits a model dim must refit
         # at the TRIAL's fallback (sweep/cli.py: emb_sz=400, n_hid=1152,

@@ -278,15 +278,19 @@ class TestMeshExecution:
             state, m = trainer.train_step(state, x, y)
         assert np.isfinite(float(m["loss"]))
 
-    def test_pallas_lstm_composes_with_dp8(self):
-        # The fused-kernel flag under a multi-device data mesh (interpret
-        # kernels on the CPU backend — the same standard of multichip
-        # evidence as the rest of this class): the batch-sharded train
-        # step must compile and run, and the dispatch-batched scan too.
+    def test_a_mesh_trains_on_the_scan_where_one_chip_would_be_resident(
+            self, monkeypatch):
+        # what was a refusal by name (a Mosaic call inside the
+        # GSPMD-partitioned step: JAX refuses it on the chip at the first
+        # dispatch, 4x v5e, PR 21): the rule sees the mesh and the step
+        # runs the scan, single window and scanned dispatch, no raise
+        _rule_sees_backend(monkeypatch, "tpu")
         mesh = make_mesh({"data": 8})
         trainer = LMTrainer(
-            tiny_model(lstm_use_pallas=True),
+            tiny_model(lstm_use_pallas=True),  # what a caller set: not read
             TrainConfig(batch_size=16, bptt=6), mesh=mesh)
+        assert trainer.resident_lstm_layers == 0
+        assert not trainer.model.config.lstm_use_pallas
         dl = LMStreamLoader(repeating_corpus(), 16, 6)
         state = trainer.init_state(jax.random.PRNGKey(0))
         it = dl.epoch(0)
@@ -298,24 +302,19 @@ class TestMeshExecution:
             state, ms = trainer.train_steps(state, np.stack(xs), np.stack(ys))
         assert np.isfinite(np.asarray(jax.device_get(ms["loss"]))).all()
 
-    def test_pallas_under_a_mesh_is_refused_by_name_on_tpu(
+    def test_qrnn_pallas_under_a_mesh_is_refused_by_name_on_tpu(
             self, monkeypatch):
-        # On the chip JAX refuses a Mosaic call inside the partitioned
-        # step ("cannot be automatically partitioned", seen on 4x v5e at
-        # the first dispatch, PR 21): the trainer refuses the combination
-        # at construction instead, naming the flags. One chip keeps it.
+        # the QRNN kernel is still a flag, so its refusal stays; the LSTM
+        # cell is the step's own choice and has nothing left to refuse
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        with pytest.raises(ValueError, match="lstm_pallas.*shard_map"):
-            LMTrainer(tiny_model(lstm_use_pallas=True),
-                      TrainConfig(batch_size=16, bptt=6),
-                      mesh=make_mesh({"data": 8}))
-        with pytest.raises(ValueError, match="qrnn_pallas"):
+        with pytest.raises(ValueError, match="qrnn_pallas.*shard_map"):
             LMTrainer(tiny_model(qrnn=True, qrnn_use_pallas=True),
                       TrainConfig(batch_size=16, bptt=6),
                       mesh=make_mesh({"data": 4, "model": 2}))
-        LMTrainer(tiny_model(lstm_use_pallas=True),
-                  TrainConfig(batch_size=16, bptt=6),
-                  mesh=make_mesh({"data": 1}, devices=jax.devices()[:1]))
+        for mesh in (make_mesh({"data": 8}),
+                     make_mesh({"data": 1}, devices=jax.devices()[:1])):
+            LMTrainer(tiny_model(lstm_use_pallas=True),
+                      TrainConfig(batch_size=16, bptt=6), mesh=mesh)
 
     def test_mp_dispatches_reuse_one_program(self):
         # GSPMD hands the carried states back split over 'model' unless
@@ -432,3 +431,128 @@ class TestCheckpoint:
             np.asarray(params["embedding"]),
             np.asarray(state.params["encoder"]["embedding"]),
         )
+
+
+# -- the train step's cell: resident Pallas cell or XLA scan ----------------
+
+def _rule_sees_backend(monkeypatch, backend):
+    """The rule's first input, steered from the test (the trainer asks
+    ``jax.default_backend()``, which is "cpu" here); its other inputs
+    come from the config and the mesh as they do on the chip."""
+    from code_intelligence_tpu.training import loop
+
+    real = loop.train_cell_is_resident
+    monkeypatch.setattr(
+        loop, "train_cell_is_resident",
+        lambda _backend, *rest: real(backend, *rest))
+
+
+def _one_chip():
+    return make_mesh({"data": 1}, devices=jax.devices()[:1])
+
+
+# W_hh bytes at (itemsize, H) against the 52 MiB residency budget:
+# bf16 800 -> 5 MB, 2500 -> 50 MB, 2610 -> 54.5 MB (the edge, inside);
+# f32 800 -> 10 MB, 2500 -> 100 MB, 2610 -> 109 MB
+_FITS = {(2, 800): True, (2, 2500): True, (2, 2610): True,
+         (4, 800): True, (4, 2500): False, (4, 2610): False}
+
+
+@pytest.mark.parametrize("mesh_size", [1, 4])
+@pytest.mark.parametrize("hidden", [800, 2500, 2610])
+@pytest.mark.parametrize("itemsize", [2, 4])
+@pytest.mark.parametrize("backend", ["tpu", "cpu", "gpu"])
+def test_train_cell_rule_table(backend, itemsize, hidden, mesh_size):
+    from code_intelligence_tpu.training.loop import train_cell_is_resident
+
+    want = backend == "tpu" and mesh_size == 1 and _FITS[itemsize, hidden]
+    assert train_cell_is_resident(
+        backend, itemsize, hidden, mesh_size) is want
+
+
+class TestTrainCell:
+    def test_off_the_tpu_the_step_is_the_scan_and_says_so(self, monkeypatch):
+        from code_intelligence_tpu.utils import tracing
+
+        tracer = tracing.Tracer()
+        monkeypatch.setattr(tracing, "_default", tracer)
+        trainer = LMTrainer(
+            tiny_model(), TrainConfig(batch_size=8, bptt=6,
+                                      steps_per_dispatch=3),
+            mesh=_one_chip(), steps_per_epoch=40)
+        assert trainer.resident_lstm_layers == 0
+        assert not trainer.model.config.lstm_use_pallas
+        got = []
+        tracer.on_trace(got.append)
+        dl = LMStreamLoader(repeating_corpus(), 8, 6)
+        trainer.fit(_FirstWindows(dl, 3), None, epochs=1)
+        by_root = {t["root"]: t for t in got}
+        for root in ("train.dispatch", "train.fit"):
+            attrs = by_root[root]["spans"][0]["attrs"]
+            assert attrs["resident_lstm_layers"] == 0, (root, attrs)
+
+    def test_each_layer_decides_for_itself(self, monkeypatch):
+        # float32 at H=2500 is 100 MB and stays on the scan; the last
+        # layer (emb_sz wide) of the same model fits and is resident
+        _rule_sees_backend(monkeypatch, "tpu")
+        for dtype, want in ((jnp.float32, 1), (jnp.bfloat16, 4)):
+            trainer = LMTrainer(
+                AWDLSTMConfig(vocab_size=50, dtype=dtype),  # the defaults
+                TrainConfig(batch_size=8, bptt=6), mesh=_one_chip())
+            assert trainer.resident_lstm_layers == want
+            assert trainer.model.config.lstm_use_pallas
+        assert LMTrainer(
+            tiny_model(qrnn=True), TrainConfig(batch_size=8, bptt=6),
+            mesh=_one_chip()).resident_lstm_layers == 0
+
+    def test_resident_steps_equal_the_scans(self, monkeypatch):
+        # the rule's answer forced to "resident" (the kernels in interpret
+        # mode, tiny widths): the first three steps' loss and gradient
+        # norm are the scan's, in TestTrainSteps' band, and so are the
+        # dropout draws (the recurrent mask is drawn before the branch)
+        tcfg = TrainConfig(batch_size=8, bptt=6, lr=5e-3,
+                           steps_per_dispatch=3)
+        scan = LMTrainer(tiny_model(), tcfg, mesh=_one_chip(),
+                         steps_per_epoch=40)
+        _rule_sees_backend(monkeypatch, "tpu")
+        resident = LMTrainer(tiny_model(), tcfg, mesh=_one_chip(),
+                             steps_per_epoch=40)
+        assert (scan.resident_lstm_layers,
+                resident.resident_lstm_layers) == (0, 2)
+        dl = LMStreamLoader(repeating_corpus(), 8, 6, shuffle_offsets=False)
+        it = dl.epoch(0)
+        xs, ys = map(np.stack, zip(*(next(it) for _ in range(3))))
+        out = {}
+        for name, trainer in (("scan", scan), ("resident", resident)):
+            state = trainer.init_state(jax.random.PRNGKey(0))
+            drawn = trainer.model.apply(
+                {"params": state.params}, xs[0], state.lstm_states,
+                deterministic=False,
+                rngs={"dropout": jax.random.PRNGKey(7)})[2]
+            with trainer.mesh:
+                _, ms = trainer.train_steps(state, xs, ys)
+            out[name] = (jax.device_get(ms), np.asarray(drawn))
+        for key in ("loss", "grad_norm"):
+            np.testing.assert_allclose(
+                out["resident"][0][key], out["scan"][0][key],
+                rtol=1e-5, atol=1e-6)
+        # same masks: a different draw would move whole units to zero
+        np.testing.assert_allclose(out["resident"][1], out["scan"][1],
+                                   rtol=1e-5, atol=1e-6)
+        assert ((out["resident"][1] == 0) == (out["scan"][1] == 0)).all()
+
+
+class _FirstWindows:
+    """The first ``n`` windows of a loader, as a loader."""
+
+    def __init__(self, loader, n):
+        self.loader, self.n = loader, n
+        self.local_bs = loader.local_bs
+        self.tokens_per_epoch = n * loader.local_bs * loader.bptt
+
+    def epoch(self, epoch):
+        for i, xy in enumerate(self.loader.epoch(epoch)):
+            if i >= self.n:
+                return
+            yield xy
+
