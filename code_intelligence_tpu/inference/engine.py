@@ -219,6 +219,7 @@ class InferenceEngine:
         return {
             "out_dim": self.encoder.out_dim,
             "kv_positions": self.encoder.cache_positions(),
+            "kv_positions_window": self.encoder.window_positions(),
             "state_bytes_per_row": self.encoder.state_bytes_per_row(),
         }
 
@@ -690,11 +691,16 @@ class InferenceEngine:
         ``batch`` less the rows run, summed over the chunks: 0 when
         nothing narrowed; ``cache_steps_run`` = the rows each chunk
         program ran x the positions they had reached by its end, summed:
-        what an encoder that attends to a cache is asked to meet), and
+        what an encoder that attends to a growing cache is asked to
+        meet; ``window_steps_run`` = the same sum with the positions
+        reached capped at what a ring holds: what its layers under a
+        sliding window are asked to meet, 0 where it has none), and
         from the encoder ``state_bytes`` (the
         state carried out of the first chunk program, all ``batch``
-        rows) and ``kv_positions`` (cache positions a row is allocated;
-        0 for a fixed-size state). ``counted``, where a list is given,
+        rows), ``kv_positions`` and ``kv_positions_window`` (positions a
+        row is allocated in the cache that grows with the document and
+        in the ring; 0 for an encoder without that kind of state).
+        ``counted``, where a list is given,
         gains what the encoder counted in the group's carried state
         (still on the device)."""
         B = self.batch_size  # the first chunk's shape; pad the remainder
@@ -710,10 +716,11 @@ class InferenceEngine:
         # a state that grows with the document reads the size)
         positions = bucket * n_chunks
         h_leaves = jax.tree.leaves(self.encoder.init_states(B, positions))
+        ring = self.encoder.window_positions(positions)
         pool = self._init_pool_state(B)
         pad_id = self.vocab.pad_id
 
-        batch, rows_run, cache_steps, pools = B, 0, 0, []
+        batch, rows_run, cache_steps, window_steps, pools = B, 0, 0, 0, []
         for ci in range(n_chunks):
             if ci:
                 alive = len(seqs) - bisect.bisect_right(lens, ci * bucket)
@@ -735,6 +742,7 @@ class InferenceEngine:
             )
             rows_run += batch
             cache_steps += batch * bucket * (ci + 1)
+            window_steps += batch * min(bucket * (ci + 1), ring)
         pools.append(pool)
         if counted is not None:
             counts = self.encoder.state_counters(
@@ -749,8 +757,10 @@ class InferenceEngine:
             "lane_steps_run": rows_run * bucket,
             "row_chunks_dropped": B * n_chunks - rows_run,
             "cache_steps_run": cache_steps,
+            "window_steps_run": window_steps,
             "state_bytes": B * self.encoder.state_bytes_per_row(positions),
             "kv_positions": self.encoder.cache_positions(positions),
+            "kv_positions_window": ring,
         }
 
     def embed_text(self, text: str) -> np.ndarray:

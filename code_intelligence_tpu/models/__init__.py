@@ -1,3 +1,4 @@
+from code_intelligence_tpu.models.afmoe import AfmoeConfig, AfmoeEncoder
 from code_intelligence_tpu.models.awd_lstm import (
     AWDLSTMConfig,
     AWDLSTMEncoder,
@@ -18,7 +19,7 @@ from code_intelligence_tpu.models.granite_hybrid import (
     GraniteHybridEncoder,
 )
 
-__all__ = ["AWDLSTMConfig", "AWDLSTMEncoder", "AWDLSTMLM", "init_lstm_states",
+__all__ = ["AfmoeConfig", "AfmoeEncoder", "AWDLSTMConfig", "AWDLSTMEncoder", "AWDLSTMLM", "init_lstm_states",
            "ChunkEncoder", "build_encoder", "make_config",
            "DeepseekV3Config", "DeepseekV3Encoder",
            "GraniteHybridConfig", "GraniteHybridEncoder"]

@@ -1,0 +1,347 @@
+"""AFMoE style encoder (Arcee Trinity): grouped-query attention whose
+layers are of two kinds, sliding-window and global, with per-head QK
+norms and a sigmoid output gate; four norms a layer; a dense SwiGLU MLP
+in the leading layers and, after them, sigmoid-routed experts with a
+shared one, of which this chip holds a SHARE.
+
+Published as ``model_type: afmoe``; the field names of
+:class:`AfmoeConfig` are those of the model's ``config.json``. Equations
+(``x`` the float32 residual, ``eps`` = ``rms_norm_eps``, no biases but
+the router's; ``ops/attention.py`` and ``ops/moe.py`` hold the two
+mechanisms, and a model with DeepSeek-V3's router shares the second):
+
+    x = E[ids] * sqrt(hidden_size)                     (mup_enabled)
+    every layer:  x += RMSNorm(Attn(RMSNorm(x)));  x += RMSNorm(FFN(RMSNorm(x)))
+    out = RMSNorm(x)                                   # pooled; no LM head
+
+    Attn(a): q = RMSNorm_d(a W_q), k = RMSNorm_d(a W_k), v = a W_v  (a head)
+      a sliding layer: rotary on q and k (all ``head_dim`` dims, plain
+        inverse frequencies, ``rotate_half`` pairs), and a query at t
+        sees the keys t - sliding_window < j <= t; a full layer: no
+        rotary, every key j <= t
+      o = softmax(q.k / sqrt(head_dim)) v;  o = o * sigmoid(a W_gate)
+      Attn = o W_o
+    FFN, layers < num_dense_layers: SwiGLU of intermediate_size
+    FFN, the others: sum_i w_i E_i(m) + E_shared(m), the experts SwiGLU
+      of moe_intermediate_size, (i, w_i) from the router (ops/moe.route
+      with one group: sigmoid scores, the bias moves the choice only,
+      the chosen scores normalised and times route_scale)
+
+**The share.** ``experts_held = (first, count)`` says which of the
+``num_experts`` experts this chip holds, as ``models/deepseek_v3.py``
+has it: the router keeps all its outputs and ``num_experts_per_tok``;
+the sum runs over the chosen experts that are held, plus the shared one.
+
+A plain class, not a Flax module: it owns no parameters. The tree it
+reads (``benchmark/reference/afmoe.py::init_params`` makes one from a
+seed), matrices as ``(in, out)``, a dict of leaves a layer:
+
+    embedding (V, E), final_norm (E,)
+    layers/layer_<i>, every layer: input_norm, post_attn_norm,
+      pre_mlp_norm, post_mlp_norm (E,); qkv (E, (Hq + 2 Hkv) d):
+      [q | k | v]; q_norm, k_norm (d,); gate (E, Hq d); o (Hq d, E)
+    a dense layer besides: w_in (E, 2 F), w_out (F, E)  ([gate | up])
+    an expert layer besides: router (E, num_experts), bias
+      (num_experts,) float32, shared_in (E, 2 Fs), shared_out (Fs, E),
+      experts_in (count, E, 2 Fe), experts_out (count, Fe, E): the HELD
+      experts alone
+
+The compute type is the type of the weights; RMSNorm statistics, rotary,
+softmax, the gate's sigmoid and the router are float32 always.
+
+**State carried between chunk programs, of two kinds** (``init_states``):
+a full layer's keys and values GROW with the document and are allocated
+for all of it (``cache_positions``); a sliding layer's live in a RING of
+``sliding_window`` (in whole chunks) + ``chunk_positions`` slots that
+later chunks overwrite (``window_positions``), so a row's state for such
+a layer stops growing at the window. Beside them one position counter and the expert layers'
+counts (``state_counters``).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any, ClassVar, Mapping, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from code_intelligence_tpu.models.deepseek_v3 import share_of
+from code_intelligence_tpu.models.granite_hybrid import _matmul, _rms_norm
+from code_intelligence_tpu.ops import mla, moe
+from code_intelligence_tpu.ops.attention import gqa_cached
+
+SLIDING, FULL = "sliding_attention", "full_attention"
+
+
+@dataclasses.dataclass(frozen=True)
+class AfmoeConfig:
+    architecture: ClassVar[str] = "afmoe"
+
+    vocab_size: int
+    hidden_size: int = 3072
+    intermediate_size: int = 12288
+    moe_intermediate_size: int = 3072
+    num_hidden_layers: int = 60
+    num_dense_layers: int = 6
+    layer_types: Tuple[str, ...] = ()
+    num_attention_heads: int = 48
+    num_key_value_heads: int = 8
+    head_dim: int = 128
+    sliding_window: int = 4096
+    num_experts: int = 256             # the router's outputs
+    num_shared_experts: int = 1
+    num_experts_per_tok: int = 4
+    n_group: int = 1
+    topk_group: int = 1
+    route_norm: bool = True
+    route_scale: float = 2.448
+    score_func: str = "sigmoid"
+    mup_enabled: bool = True
+    rms_norm_eps: float = 1e-5
+    rope_theta: float = 10000.0
+    rope_scaling: Any = None
+    # the share: (first expert held, how many), None = all of them
+    experts_held: Optional[Tuple[int, int]] = None
+    # serving: positions one document's growing cache can hold, and the
+    # longest chunk a program runs (a ring holds the window and one chunk)
+    kv_positions: int = 16384
+    chunk_positions: int = 512
+    state_dtype: Any = jnp.bfloat16    # the caches' type
+
+    def __post_init__(self):
+        held = self.experts_held or (0, self.num_experts)
+        object.__setattr__(self, "experts_held", tuple(int(v) for v in held))
+        object.__setattr__(self, "state_dtype", jnp.dtype(self.state_dtype))
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        if len(self.layer_types) != self.num_hidden_layers or set(
+                self.layer_types) - {SLIDING, FULL}:
+            raise ValueError(
+                f"layer_types must name {self.num_hidden_layers} layers, "
+                f"each {SLIDING!r} or {FULL!r}: {self.layer_types}")
+        if self.score_func != "sigmoid" or self.rope_scaling is not None:
+            raise ValueError(
+                "only score_func 'sigmoid' and plain rotary (rope_scaling "
+                f"null) are implemented, not {self.score_func!r} / "
+                f"{self.rope_scaling!r}")
+        first, count = self.experts_held
+        if not (0 <= first and 0 < count
+                and first + count <= self.num_experts):
+            raise ValueError(
+                f"experts_held {self.experts_held} lies outside the "
+                f"router's {self.num_experts} experts")
+        if self.num_experts % self.n_group:
+            raise ValueError("n_group must divide num_experts")
+        if not 0 <= self.num_dense_layers <= self.num_hidden_layers:
+            raise ValueError("num_dense_layers exceeds the layers")
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError(
+                "num_key_value_heads must divide num_attention_heads")
+
+    @classmethod
+    def from_dict(cls, model: Mapping, **extra) -> "AfmoeConfig":
+        """From a published ``config.json``'s keys; keys that do not
+        shape the encoder are passed over. A configuration of a share
+        carries ``experts_held: {"first", "count", "of"}``: its
+        ``num_experts`` then counts the experts HELD, and ``of`` is the
+        router's width."""
+        names = {f.name for f in dataclasses.fields(cls)}
+        kw = {k: v for k, v in model.items() if k in names}
+        return cls(**{**kw, **share_of(model, "num_experts"), **extra})
+
+    @property
+    def n_moe_layers(self) -> int:
+        return self.num_hidden_layers - self.num_dense_layers
+
+    @property
+    def ring_positions(self) -> int:
+        """Slots of a sliding layer's ring: the whole chunks that hold
+        what a chunk's queries can see of the chunks before, and the
+        chunk itself."""
+        chunk = self.chunk_positions
+        return chunk * (-(-self.sliding_window // chunk) + 1)
+
+    def count(self, kind: str) -> int:
+        return sum(t == kind for t in self.layer_types)
+
+
+class AfmoeEncoder:
+    """The encoder contract (`models/contract.py`) over AFMoE."""
+
+    def __init__(self, config: AfmoeConfig, dtype=jnp.bfloat16):
+        self.config = config
+        self.dtype = jnp.dtype(dtype)  # of the weights it will be handed
+        self._inv_freq = mla.yarn_inv_freq(config.head_dim, config.rope_theta)
+        self._scale = config.head_dim ** -0.5
+
+    # -- contract --------------------------------------------------------
+
+    @property
+    def out_dim(self) -> int:
+        return self.config.hidden_size
+
+    def cache_positions(self, positions=None) -> int:
+        """Positions a full layer's cache is allocated at for documents
+        of up to ``positions`` tokens: their own length where one chunk
+        holds them, else the smallest of ``kv_positions`` halved that
+        does, so that the groups of a call compile a few cache sizes and
+        not one a length."""
+        cfg = self.config
+        if positions is None:
+            return cfg.kv_positions
+        if positions > cfg.kv_positions:
+            raise ValueError(
+                f"a document of {positions} positions does not fit the "
+                f"key/value cache of kv_positions={cfg.kv_positions}")
+        if positions <= cfg.chunk_positions:
+            return positions
+        size = cfg.kv_positions
+        while size % 2 == 0 and size // 2 >= positions:
+            size //= 2
+        return size
+
+    def window_positions(self, positions=None) -> int:
+        """Slots a sliding layer's ring is allocated: the full layers'
+        allocation until that passes ``sliding_window`` + one chunk, the
+        ring's length from there on."""
+        if not self.config.count(SLIDING):
+            return 0
+        return min(self.cache_positions(positions),
+                   self.config.ring_positions)
+
+    def _slots(self, positions):
+        """Each layer's cache length."""
+        full, ring = (self.cache_positions(positions),
+                      self.window_positions(positions))
+        return [ring if kind == SLIDING else full
+                for kind in self.config.layer_types]
+
+    def init_states(self, batch: int, positions=None):
+        cfg = self.config
+
+        def caches():
+            return tuple(jnp.zeros(
+                (batch, slots, cfg.num_key_value_heads, cfg.head_dim),
+                cfg.state_dtype) for slots in self._slots(positions))
+
+        return {"k": caches(), "v": caches(),
+                "pos": jnp.zeros((), jnp.int32),
+                "counts": jnp.zeros((len(moe.COUNTERS),), jnp.int32)}
+
+    def state_bytes_per_row(self, max_len=None) -> int:
+        """Bytes of keys and values one row holds for a document of
+        ``max_len`` tokens: the full layers' part grows with it, the
+        sliding layers' stops at the ring."""
+        cfg = self.config
+        return sum(self._slots(max_len)) * 2 * cfg.num_key_value_heads \
+            * cfg.head_dim * cfg.state_dtype.itemsize
+
+    def state_counters(self, states):
+        """The counts the expert layers have kept since ``init_states``
+        (a device array; ``counter_attrs`` names them)."""
+        return states["counts"]
+
+    def counter_attrs(self, counted) -> dict:
+        """Span attributes from the fetched ``state_counters`` of a
+        flush's groups (``ops/moe.py::counter_attrs``)."""
+        return moe.counter_attrs(counted, self.config.n_moe_layers,
+                                 self.config.experts_held[1])
+
+    def encode(self, params, tokens, states, lengths=None):
+        """One chunk: ``tokens`` ``(B, T)`` with the carried ``states``
+        in, ``(hidden (B, T, out_dim) float32, new states)`` out. Every
+        chunk of a document must be ``T`` long once it is longer than a
+        ring (``ops/attention.py``). ``lengths`` ``(B,)``, where the
+        caller knows them, are each row's valid tokens in this chunk:
+        the lanes after them are padding, which attention never lets
+        reach a valid token (causal) and which is then not routed to any
+        expert."""
+        cfg = self.config
+        dtype = params["embedding"].dtype
+        eps = cfg.rms_norm_eps
+        B, T = tokens.shape
+        with jax.named_scope("embedding"):
+            h = jnp.take(params["embedding"], tokens, axis=0).astype(
+                jnp.float32)
+            if cfg.mup_enabled:
+                h = h * math.sqrt(cfg.hidden_size)
+        pos = states["pos"]
+        valid = None
+        if lengths is not None:
+            valid = (jnp.arange(T)[None, :] < lengths[:, None]).reshape(-1)
+        k_caches, v_caches = [], []
+        rows = busiest = jnp.zeros((), jnp.int32)
+        for i, kind in enumerate(cfg.layer_types):
+            p = params["layers"][f"layer_{i}"]
+            with jax.named_scope(f"attention_{i}"):
+                out, kc, vc = self._attention(
+                    p, h, states["k"][i], states["v"][i], pos, dtype,
+                    sliding=kind == SLIDING)
+                h = h + _rms_norm(out, p["post_attn_norm"], eps)
+            k_caches.append(kc)
+            v_caches.append(vc)
+            if i < cfg.num_dense_layers:
+                with jax.named_scope(f"mlp_{i}"):
+                    m = _rms_norm(h, p["pre_mlp_norm"], eps)
+                    f = moe.swiglu(m, p["w_in"], p["w_out"], dtype)
+                    h = h + _rms_norm(f, p["post_mlp_norm"], eps)
+            else:
+                with jax.named_scope(f"moe_{i}"):
+                    m = _rms_norm(h, p["pre_mlp_norm"], eps)
+                    f, per_expert = moe.expert_layer(
+                        p, m.reshape(B * T, -1), valid, dtype,
+                        n_group=cfg.n_group, topk_group=cfg.topk_group,
+                        top_k=cfg.num_experts_per_tok,
+                        scaling=cfg.route_scale,
+                        norm_topk_prob=cfg.route_norm,
+                        first=cfg.experts_held[0],
+                        shared=bool(cfg.num_shared_experts))
+                    h = h + _rms_norm(f.reshape(B, T, -1),
+                                      p["post_mlp_norm"], eps)
+                rows = rows + per_expert.sum()
+                busiest = busiest + per_expert.max()
+        with jax.named_scope("final_norm"):
+            out = _rms_norm(h, params["final_norm"], eps)
+        ran = jnp.int32(1 if cfg.n_moe_layers else 0)
+        new_states = {
+            "k": tuple(k_caches), "v": tuple(v_caches), "pos": pos + T,
+            "counts": states["counts"] + jnp.stack([rows, busiest, ran]),
+        }
+        return out, new_states
+
+    # -- layers ----------------------------------------------------------
+
+    def _attention(self, p, h, k_cache, v_cache, pos, dtype, sliding: bool):
+        """``Attn`` of the module's docstring, before its post-norm."""
+        cfg = self.config
+        b, T, _ = h.shape
+        Hq, Hkv, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                      cfg.head_dim)
+        eps = cfg.rms_norm_eps
+        a = _rms_norm(h, p["input_norm"], eps).astype(dtype)
+        with jax.named_scope("qkv_proj"):
+            qkv = _matmul(a, p["qkv"], dtype)
+            q = qkv[..., :Hq * d].reshape(b, T, Hq, d)
+            k = qkv[..., Hq * d:(Hq + Hkv) * d].reshape(b, T, Hkv, d)
+            v = qkv[..., (Hq + Hkv) * d:].reshape(b, T, Hkv, d)
+        with jax.named_scope("qk_norm"):
+            q = _rms_norm(q, p["q_norm"], eps)
+            k = _rms_norm(k, p["k_norm"], eps)
+        if sliding:
+            with jax.named_scope("rope"):
+                positions = pos + jnp.arange(T)
+                q = mla.apply_rope(q, positions, self._inv_freq,
+                                   interleaved=False)
+                k = mla.apply_rope(k, positions, self._inv_freq,
+                                   interleaved=False)
+        with jax.named_scope("window_core" if sliding else "global_core"):
+            out, k_cache, v_cache = gqa_cached(
+                q, k, v, k_cache, v_cache, pos, self._scale, mxu_dtype=dtype,
+                window=cfg.sliding_window if sliding else None)
+        with jax.named_scope("gate"):
+            out = out.reshape(b, T, Hq * d) * jax.nn.sigmoid(
+                _matmul(a, p["gate"]))
+        with jax.named_scope("o_proj"):
+            out = _matmul(out, p["o"])
+        return out, k_cache, v_cache

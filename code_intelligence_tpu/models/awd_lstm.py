@@ -158,6 +158,10 @@ class AWDLSTMEncoder(nn.Module):
         return 0  # no part of the state grows with the document
 
     @nn.nowrap
+    def window_positions(self, positions=None) -> int:
+        return 0  # nor is any of it a ring
+
+    @nn.nowrap
     def encode(self, params, tokens, states, lengths=None):
         """``lengths`` are not read: a recurrence runs every lane."""
         raw, _, new_states = self.apply(

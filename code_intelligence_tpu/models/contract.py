@@ -2,7 +2,7 @@
 
 The engine streams a document through fixed-size chunk programs and
 pools the hidden states it gets back; it never looks inside the model.
-Anything that offers these seven names can be served on the ``groups``
+Anything that offers these eight names can be served on the ``groups``
 path (``embed_issues``, ``embed_ids_batch``, ``embed_text``, the server
 with ``--scheduler groups``):
 
@@ -25,10 +25,18 @@ with ``--scheduler groups``):
 ``state_bytes_per_row(max_len=None)``
     bytes of carried state one row holds for a document of ``max_len``
     tokens: what sets the batch once the state is large.
-``cache_positions(positions=None)``
-    the part of the state that grows with the document: positions of
-    key/value cache a row is allocated for documents of ``positions``
-    tokens; 0 where the whole state is of fixed size.
+``cache_positions(positions=None)`` and ``window_positions(positions=None)``
+    the two kinds of state that depend on the document's length, each as
+    the positions a row is allocated for documents of ``positions``
+    tokens. The first is the cache that GROWS with the document (keys
+    and values of layers that attend to all of it, a latent cache); the
+    second the RING of layers that attend under a sliding window, which
+    grows like the first until it holds the window and one chunk and
+    then stops: a chunk program's cores meet ``min(positions reached,
+    allocated)`` of each. 0 where an encoder has no state of that kind
+    (both, where the whole state is of fixed size). One row may hold
+    both, layer by layer; the engine counts each and looks inside
+    neither.
 ``state_counters(states)`` and ``counter_attrs(counted)``
     counts the encoder keeps ON THE DEVICE in its carried state (rows
     routed to experts): the first picks them out of a group's last
@@ -47,6 +55,7 @@ from typing import Any, Mapping, Optional, Protocol, Tuple, runtime_checkable
 
 import jax.numpy as jnp
 
+from code_intelligence_tpu.models.afmoe import AfmoeConfig, AfmoeEncoder
 from code_intelligence_tpu.models.awd_lstm import AWDLSTMConfig, AWDLSTMEncoder
 from code_intelligence_tpu.models.deepseek_v3 import (
     DeepseekV3Config, DeepseekV3Encoder)
@@ -66,6 +75,8 @@ class ChunkEncoder(Protocol):
     def state_bytes_per_row(self, max_len: Optional[int] = None) -> int: ...
 
     def cache_positions(self, positions: Optional[int] = None) -> int: ...
+
+    def window_positions(self, positions: Optional[int] = None) -> int: ...
 
     def state_counters(self, states): ...
 
@@ -101,6 +112,8 @@ ENCODERS = {
     DeepseekV3Config.architecture: (
         DeepseekV3Config, DeepseekV3Config.from_dict,
         _in_weights_dtype(DeepseekV3Encoder)),
+    AfmoeConfig.architecture: (
+        AfmoeConfig, AfmoeConfig.from_dict, _in_weights_dtype(AfmoeEncoder)),
 }
 
 

@@ -66,10 +66,22 @@ import jax.numpy as jnp
 from code_intelligence_tpu.models.granite_hybrid import _matmul, _rms_norm
 from code_intelligence_tpu.ops import mla, moe
 
-# what the expert layers count on the device, summed since init_states:
-# rows routed to held experts; the busiest held expert's rows of each
-# expert layer of each program; programs
-COUNTERS = ("routed_rows", "busiest_rows", "moe_programs")
+
+def share_of(model: Mapping, count_key: str) -> dict:
+    """What a configuration of a SHARE says to its dataclass: the file's
+    ``experts_held: {"first", "count", "of"}`` beside a ``count_key``
+    that counts the experts held becomes the router's width under
+    ``count_key`` and ``experts_held = (first, count)``; nothing for a
+    mapping without the block."""
+    held = model.get("experts_held")
+    if not isinstance(held, Mapping):
+        return {}
+    if model.get(count_key, held["count"]) != held["count"]:
+        raise ValueError(
+            f"{count_key} {model[count_key]} is not the count of "
+            f"experts_held {dict(held)}")
+    return {count_key: held["of"],
+            "experts_held": (held["first"], held["count"])}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -138,15 +150,7 @@ class DeepseekV3Config:
         the router's width."""
         names = {f.name for f in dataclasses.fields(cls)}
         kw = {k: v for k, v in model.items() if k in names}
-        held = model.get("experts_held")
-        if isinstance(held, Mapping):
-            if model.get("n_routed_experts", held["count"]) != held["count"]:
-                raise ValueError(
-                    f"n_routed_experts {model['n_routed_experts']} is not "
-                    f"the count of experts_held {dict(held)}")
-            kw["n_routed_experts"] = held["of"]
-            kw["experts_held"] = (held["first"], held["count"])
-        return cls(**{**kw, **extra})
+        return cls(**{**kw, **share_of(model, "n_routed_experts"), **extra})
 
     @property
     def rope(self) -> Optional[dict]:
@@ -199,6 +203,9 @@ class DeepseekV3Encoder:
         return positions if positions <= cfg.kv_positions // 4 \
             else cfg.kv_positions
 
+    def window_positions(self, positions=None) -> int:
+        return 0  # no layer attends under a window: no ring
+
     def init_states(self, batch: int, positions=None):
         cfg = self.config
         S = self.cache_positions(positions)
@@ -207,7 +214,7 @@ class DeepseekV3Encoder:
                 jnp.zeros((batch, S, cfg.latent_dim), cfg.state_dtype)
                 for _ in range(cfg.num_hidden_layers)),
             "pos": jnp.zeros((), jnp.int32),
-            "counts": jnp.zeros((len(COUNTERS),), jnp.int32),
+            "counts": jnp.zeros((len(moe.COUNTERS),), jnp.int32),
         }
 
     def state_bytes_per_row(self, max_len=None) -> int:
@@ -224,25 +231,9 @@ class DeepseekV3Encoder:
 
     def counter_attrs(self, counted) -> dict:
         """Span attributes from the fetched ``state_counters`` of a
-        flush's groups: ``routed_rows`` (assignments to held experts
-        that ran: of valid tokens alone when the engine hands the
-        lengths over, as it does), ``moe_programs``, and per held expert
-        a layer a program ``expert_rows_mean`` and ``expert_rows_max``:
-        the rows the MEAN and the BUSIEST held expert of an expert layer
-        ran in one program, each averaged over layers and programs.
-        Their ratio is each program's max / mean weighted by its rows:
-        how far routing is from even WITHIN a program, whatever the
-        programs' sizes."""
-        cfg = self.config
-        rows, busiest, programs = (
-            sum(int(c[i]) for c in counted) for i in range(len(COUNTERS)))
-        layer_programs = programs * cfg.n_moe_layers
-        if not layer_programs:
-            return {}
-        return {"routed_rows": rows, "moe_programs": programs,
-                "expert_rows_max": busiest / layer_programs,
-                "expert_rows_mean":
-                    rows / (layer_programs * cfg.experts_held[1])}
+        flush's groups (``ops/moe.py::counter_attrs``)."""
+        return moe.counter_attrs(counted, self.config.n_moe_layers,
+                                 self.config.experts_held[1])
 
     def encode(self, params, tokens, states, lengths=None):
         """One chunk: ``tokens`` ``(B, T)`` with the carried ``states``
@@ -276,8 +267,14 @@ class DeepseekV3Encoder:
                     h = h + moe.swiglu(u, p["w_in"], p["w_out"], dtype)
             else:
                 with jax.named_scope(f"moe_{i}"):
-                    out, per_expert = self._moe(
-                        p, u.reshape(B * T, -1), valid, dtype)
+                    out, per_expert = moe.expert_layer(
+                        p, u.reshape(B * T, -1), valid, dtype,
+                        n_group=cfg.n_group, topk_group=cfg.topk_group,
+                        top_k=cfg.num_experts_per_tok,
+                        scaling=cfg.routed_scaling_factor,
+                        norm_topk_prob=cfg.norm_topk_prob,
+                        first=cfg.experts_held[0],
+                        shared=bool(cfg.n_shared_experts))
                 h = h + out.reshape(B, T, -1)
                 rows = rows + per_expert.sum()
                 busiest = busiest + per_expert.max()
@@ -320,22 +317,3 @@ class DeepseekV3Encoder:
         with jax.named_scope("o_proj"):
             out = _matmul(out.reshape(b, T, H * cfg.v_head_dim), p["o"])
         return out, cache
-
-    def _moe(self, p, u, valid, dtype):
-        """One expert layer (its leaves ``p``) over the flat tokens ``u``
-        ``(N, E)`` float32: the held experts' share and the shared
-        expert."""
-        cfg = self.config
-        first, _ = cfg.experts_held
-        with jax.named_scope("router"):
-            experts, weights = moe.route(
-                u, p["router"], p["bias"], cfg.n_group, cfg.topk_group,
-                cfg.num_experts_per_tok, cfg.routed_scaling_factor,
-                cfg.norm_topk_prob)
-        y, per_expert = moe.routed_experts(
-            u, experts, weights, p["experts_in"], p["experts_out"], first,
-            valid)
-        if cfg.n_shared_experts:
-            with jax.named_scope("shared_expert"):
-                y = y + moe.swiglu(u, p["shared_in"], p["shared_out"], dtype)
-        return y, per_expert

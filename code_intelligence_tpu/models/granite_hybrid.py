@@ -170,6 +170,9 @@ class GraniteHybridEncoder:
         return positions if positions <= cfg.kv_positions // 4 \
             else cfg.kv_positions
 
+    def window_positions(self, positions=None) -> int:
+        return 0  # no layer attends under a window: no ring
+
     def init_states(self, batch: int, positions=None):
         cfg, dtype = self.config, self.dtype
         S = self.cache_positions(positions)

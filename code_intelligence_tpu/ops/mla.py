@@ -104,16 +104,22 @@ def softmax_scale(q_head_dim: int, scaling: Optional[Mapping]) -> float:
 
 
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, inv_freq,
-               factor: float = 1.0) -> jnp.ndarray:
+               factor: float = 1.0, interleaved: bool = True) -> jnp.ndarray:
     """``x`` ``(b, T, ..., d)`` rotated at ``positions`` ``(T,)``;
-    float32 out, de-interleaved (see the module's docstring)."""
+    float32 out. ``interleaved``: the pair turned by frequency ``i`` is
+    ``(x[2i], x[2i+1])``, written de-interleaved (the module's
+    docstring); otherwise it is ``(x[i], x[i + d/2])``, the
+    ``rotate_half`` of the models that publish plain rotary."""
     ang = positions.astype(jnp.float32)[:, None] \
         * jnp.asarray(inv_freq, jnp.float32)[None, :]
     shape = (1, x.shape[1]) + (1,) * (x.ndim - 3) + (ang.shape[-1],)
     cos = (jnp.cos(ang) * factor).reshape(shape)
     sin = (jnp.sin(ang) * factor).reshape(shape)
     xf = x.astype(jnp.float32)
-    a, b = xf[..., 0::2], xf[..., 1::2]
+    if interleaved:
+        a, b = xf[..., 0::2], xf[..., 1::2]
+    else:
+        a, b = jnp.split(xf, 2, axis=-1)
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
 
 

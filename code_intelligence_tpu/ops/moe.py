@@ -25,11 +25,17 @@ capacity factor: assignments are sorted by expert and taken ``N`` (the
 tokens' number) at a time for as many rounds as they need (one, unless
 more than ``N`` assignments land here: ``top_k * count / n_experts`` of
 a token's choices do on average).
+
+``expert_layer`` is the whole block as an encoder calls it (router, the
+held experts' part, the shared expert), and ``COUNTERS`` /
+``counter_attrs`` what such encoders count on the device and how the
+counts become span attributes: one copy for every model with routed
+experts.
 """
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -150,3 +156,59 @@ def routed_experts(
     y = lax.fori_loop(0, (total + N - 1) // N, one_round,
                       jnp.zeros((N, E), jnp.float32))
     return y, per_expert
+
+
+def expert_layer(
+    p,                     # a layer's leaves: router, bias, experts_in/out, shared_in/out
+    u: jnp.ndarray,        # (N, E) float32
+    valid: Optional[jnp.ndarray],
+    dtype,
+    *,
+    n_group: int,
+    topk_group: int,
+    top_k: int,
+    scaling: float,
+    norm_topk_prob: bool,
+    first: int,
+    shared: bool,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """One expert layer over the flat tokens ``u``: ``(the held experts'
+    share + the shared expert (N, E) float32, rows each held expert
+    ran)``. Named scopes ``router``, ``routed_experts``'s three, and
+    ``shared_expert``."""
+    with jax.named_scope("router"):
+        experts, weights = route(
+            u, p["router"], p["bias"], n_group, topk_group, top_k, scaling,
+            norm_topk_prob)
+    y, per_expert = routed_experts(
+        u, experts, weights, p["experts_in"], p["experts_out"], first, valid)
+    if shared:
+        with jax.named_scope("shared_expert"):
+            y = y + swiglu(u, p["shared_in"], p["shared_out"], dtype)
+    return y, per_expert
+
+
+# what the expert layers count on the device, summed since init_states:
+# rows routed to held experts; the busiest held expert's rows of each
+# expert layer of each program; programs
+COUNTERS = ("routed_rows", "busiest_rows", "moe_programs")
+
+
+def counter_attrs(counted: Sequence, n_moe_layers: int, held: int) -> dict:
+    """Span attributes from the fetched ``COUNTERS`` of a flush's groups:
+    ``routed_rows`` (assignments to held experts that ran: of valid
+    tokens alone when the engine hands the lengths over, as it does),
+    ``moe_programs``, and per held expert a layer a program
+    ``expert_rows_mean`` and ``expert_rows_max``: the rows the MEAN and
+    the BUSIEST held expert of an expert layer ran in one program, each
+    averaged over layers and programs. Their ratio is each program's
+    max / mean weighted by its rows: how far routing is from even WITHIN
+    a program, whatever the programs' sizes."""
+    rows, busiest, programs = (
+        sum(int(c[i]) for c in counted) for i in range(len(COUNTERS)))
+    layer_programs = programs * n_moe_layers
+    if not layer_programs:
+        return {}
+    return {"routed_rows": rows, "moe_programs": programs,
+            "expert_rows_max": busiest / layer_programs,
+            "expert_rows_mean": rows / (layer_programs * held)}
