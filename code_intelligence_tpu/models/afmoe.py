@@ -69,8 +69,7 @@ import jax.numpy as jnp
 
 from code_intelligence_tpu.models.deepseek_v3 import share_of
 from code_intelligence_tpu.models.granite_hybrid import _matmul, _rms_norm
-from code_intelligence_tpu.ops import mla, moe
-from code_intelligence_tpu.ops.attention import gqa_cached
+from code_intelligence_tpu.ops import attention, mla, moe
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 
@@ -221,13 +220,14 @@ class AfmoeEncoder:
         cfg = self.config
 
         def caches():
+            # head-major, as ``ops/attention.py`` reads them
             return tuple(jnp.zeros(
-                (batch, slots, cfg.num_key_value_heads, cfg.head_dim),
+                (batch, cfg.num_key_value_heads, slots, cfg.head_dim),
                 cfg.state_dtype) for slots in self._slots(positions))
 
         return {"k": caches(), "v": caches(),
                 "pos": jnp.zeros((), jnp.int32),
-                "counts": jnp.zeros((len(moe.COUNTERS),), jnp.int32)}
+                "counts": jnp.zeros((len(moe.COUNTERS) + 1,), jnp.int32)}
 
     def state_bytes_per_row(self, max_len=None) -> int:
         """Bytes of keys and values one row holds for a document of
@@ -239,14 +239,24 @@ class AfmoeEncoder:
 
     def state_counters(self, states):
         """The counts the expert layers have kept since ``init_states``
-        (a device array; ``counter_attrs`` names them)."""
+        (``ops/moe.py::COUNTERS``) and, last, the attention layers whose
+        core the group's programs ran on the Pallas kernel (a device
+        array; ``counter_attrs`` names them)."""
         return states["counts"]
 
     def counter_attrs(self, counted) -> dict:
         """Span attributes from the fetched ``state_counters`` of a
-        flush's groups (``ops/moe.py::counter_attrs``)."""
-        return moe.counter_attrs(counted, self.config.n_moe_layers,
-                                 self.config.experts_held[1])
+        flush's groups: ``ops/moe.py::counter_attrs`` and
+        ``attention_kernel_layers``, the attention layers on the Pallas
+        core in a group's programs (``ops/attention.py::core_is_kernel``:
+        all programs of a group run one chunk length against one cache
+        size, so one answer a group), averaged over the groups."""
+        attrs = moe.counter_attrs(counted, self.config.n_moe_layers,
+                                  self.config.experts_held[1])
+        if counted:
+            attrs["attention_kernel_layers"] = \
+                sum(int(c[-1]) for c in counted) / len(counted)
+        return attrs
 
     def encode(self, params, tokens, states, lengths=None):
         """One chunk: ``tokens`` ``(B, T)`` with the carried ``states``
@@ -304,9 +314,15 @@ class AfmoeEncoder:
         with jax.named_scope("final_norm"):
             out = _rms_norm(h, params["final_norm"], eps)
         ran = jnp.int32(1 if cfg.n_moe_layers else 0)
+        on_kernel = sum(attention.core_is_kernel(
+            jax.default_backend(), dtype, T, kc.shape[2],
+            cfg.num_attention_heads // cfg.num_key_value_heads,
+            cfg.head_dim) for kc in k_caches)
         new_states = {
             "k": tuple(k_caches), "v": tuple(v_caches), "pos": pos + T,
-            "counts": states["counts"] + jnp.stack([rows, busiest, ran]),
+            # sums since init_states, then what this program's rule said
+            "counts": states["counts"].at[:-1].add(
+                jnp.stack([rows, busiest, ran])).at[-1].set(on_kernel),
         }
         return out, new_states
 
@@ -336,7 +352,7 @@ class AfmoeEncoder:
                 k = mla.apply_rope(k, positions, self._inv_freq,
                                    interleaved=False)
         with jax.named_scope("window_core" if sliding else "global_core"):
-            out, k_cache, v_cache = gqa_cached(
+            out, k_cache, v_cache = attention.gqa_cached(
                 q, k, v, k_cache, v_cache, pos, self._scale, mxu_dtype=dtype,
                 window=cfg.sliding_window if sliding else None)
         with jax.named_scope("gate"):

@@ -176,7 +176,8 @@ class GraniteHybridEncoder:
     def init_states(self, batch: int, positions=None):
         cfg, dtype = self.config, self.dtype
         S = self.cache_positions(positions)
-        kv = (cfg.count("attention"), batch, S, cfg.num_key_value_heads,
+        # head-major, as ``ops/attention.py`` reads them
+        kv = (cfg.count("attention"), batch, cfg.num_key_value_heads, S,
               cfg.head_dim)
         runs = [n for kind, _, n in cfg.runs() if kind == "mamba"]
         return {

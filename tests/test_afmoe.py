@@ -29,6 +29,7 @@ from code_intelligence_tpu.models import (
     AfmoeConfig, AfmoeEncoder, ChunkEncoder, build_encoder, make_config)
 from code_intelligence_tpu.models import contract
 from code_intelligence_tpu.ops import mla, moe
+from code_intelligence_tpu.ops import attention
 from code_intelligence_tpu.ops.attention import gqa_cached
 from code_intelligence_tpu.text import SPECIALS, Vocab
 from code_intelligence_tpu.utils import tracing
@@ -123,14 +124,14 @@ def _qkv(total, seed=0, b=2, Hq=4, Hkv=2, d=8):
             jax.random.normal(k[2], (b, total, Hkv, d)))
 
 
-def _through_the_cache(q, k, v, S, T, **kw):
+def _through_the_cache(q, k, v, S, T, dtype=jnp.float32, **kw):
     b, total, _, d = q.shape
-    kc = vc = jnp.zeros((b, S, k.shape[2], d))
+    kc = vc = jnp.zeros((b, k.shape[2], S, d), dtype)     # head-major
     outs = []
     for lo in range(0, total, T):
         out, kc, vc = gqa_cached(
             q[:, lo:lo + T], k[:, lo:lo + T], v[:, lo:lo + T], kc, vc,
-            jnp.int32(lo), 0.3, mxu_dtype=jnp.float32, **kw)
+            jnp.int32(lo), 0.3, mxu_dtype=dtype, **kw)
         outs.append(out)
     return jnp.concatenate(outs, 1)
 
@@ -153,6 +154,78 @@ def test_the_core_equals_a_dense_masked_softmax(window, S, T, key_block,
                              key_block=key_block, q_block=q_block)
     np.testing.assert_allclose(got, _dense(q, k, v, 0.3, window),
                                rtol=2e-5, atol=2e-5)
+
+
+def _the_rule_says_kernel(monkeypatch, tiles):
+    """The rule's answer steered from the test (it sees the CPU and
+    float32 here), and tiles that divide the tiny shapes; the kernel
+    itself asks the real backend and is interpreted."""
+    monkeypatch.setattr(attention, "core_is_kernel", lambda *a: True)
+    monkeypatch.setattr(attention, "_kernel_tiles", lambda *a: tiles)
+
+
+# (window, S, T, (q_block, key_block), heads (Hq, Hkv, d), dtype): the
+# table above through the kernel, then the two cells' head counts in
+# bfloat16, and a cache whose first query blocks stop short of its last
+# key block; every chunk after the first comes at a ``pos`` that is not 0
+@pytest.mark.parametrize("window,S,T,tiles,heads,dtype", [
+    (8, 12, 4, (4, 12), (4, 2, 8), jnp.float32),
+    (8, 12, 4, (4, 4), (4, 2, 8), jnp.float32),
+    (8, 16, 8, (4, 4), (4, 2, 8), jnp.float32),
+    (24, 32, 8, (4, 8), (4, 2, 8), jnp.float32),
+    (None, 64, 8, (4, 16), (4, 2, 8), jnp.float32),
+    (16, 32, 16, (16, 16), (12, 2, 128), jnp.bfloat16),
+    (None, 64, 16, (8, 32), (8, 2, 64), jnp.bfloat16),
+    (8, 16, 8, (8, 8), (4, 2, 8), jnp.float32),
+    (None, 64, 8, (4, 8), (4, 2, 8), jnp.float32),
+], ids=["ring", "ring_blocked", "ring_q_blocks", "ring_fills", "global",
+        "rep6_d128_bf16", "rep4_d64_bf16", "one_q_block", "live_stops_short"])
+def test_the_kernel_equals_a_dense_masked_softmax(monkeypatch, window, S, T,
+                                                  tiles, heads, dtype):
+    """``gqa_cached`` with its core on the Pallas kernel, interpreted."""
+    _the_rule_says_kernel(monkeypatch, tiles)
+    Hq, Hkv, d = heads
+    q, k, v = _qkv(48, Hq=Hq, Hkv=Hkv, d=d)
+    got = _through_the_cache(q, k, v, S, T, dtype=dtype, window=window)
+    if dtype == jnp.bfloat16:     # the products' operands are rounded
+        q, k, v = (x.astype(dtype).astype(jnp.float32) for x in (q, k, v))
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(got, _dense(q, k, v, 0.3, window),
+                               rtol=tol, atol=tol)
+
+
+def test_the_kernel_fetches_only_the_key_blocks_reached(monkeypatch):
+    """The index map of the keys, read off the ``BlockSpec``: a 64-slot
+    cache in key blocks of 8 with 16 positions cached and 8 arriving:
+    the first query block of 4 reaches blocks 0..2 and is handed block 2
+    again for the five steps after (no new fetch, and ``pl.when`` skips
+    the step); a wrapped ring is handed all of its blocks."""
+    from jax.experimental import pallas as pl
+
+    maps = []
+    real = pl.BlockSpec
+
+    def recording(shape, index_map):
+        maps.append(index_map)
+        return real(shape, index_map)
+
+    monkeypatch.setattr(pl, "BlockSpec", recording)
+    q, k, v = _qkv(8)
+    cache = jnp.zeros((2, 2, 64, 8))
+    attention._kernel_core(q, cache, cache, jnp.int32(16), 0.3, None,
+                           jnp.float32, (4, 8))
+    keys = maps[1]
+    pos = np.asarray([16], np.int32)
+    assert [int(keys(0, 0, 0, j, pos)[2]) for j in range(8)] \
+        == [0, 1, 2, 2, 2, 2, 2, 2]
+    assert [int(keys(0, 0, 1, j, pos)[2]) for j in range(8)] \
+        == [0, 1, 2, 2, 2, 2, 2, 2]     # queries 20..23: still block 2
+    del maps[:]
+    ring = jnp.zeros((2, 2, 16, 8))
+    attention._kernel_core(q, ring, ring, jnp.int32(24), 0.3, 8,
+                           jnp.float32, (4, 8))
+    assert [int(maps[1](0, 0, 0, j, np.asarray([24], np.int32))[2])
+            for j in range(2)] == [0, 1]
 
 
 def test_the_core_meets_only_the_key_blocks_reached(monkeypatch):
@@ -207,7 +280,7 @@ def test_encoder_equals_the_reference(params, tokens, want):
 def test_streamed_through_rings_that_wrap_equals_the_whole_document(
         params, encoder, tokens, want):
     got, states = streamed(encoder, params, tokens)
-    assert [c.shape[1] for c in states["k"]] == [12, 12, 12, 64, 12]
+    assert [c.shape[2] for c in states["k"]] == [12, 12, 12, 64, 12]
     assert T_DOC // 12 >= 3      # every ring wrapped three times
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
     assert int(states["counts"][2]) == T_DOC // 4
@@ -236,7 +309,7 @@ def test_a_ring_one_chunk_too_short_is_seen(monkeypatch, params, tokens,
     monkeypatch.setattr(AfmoeConfig, "ring_positions", 8)
     enc = build_encoder(config(), params)
     got, states = streamed(enc, params, tokens)
-    assert states["k"][0].shape[1] == 8
+    assert states["k"][0].shape[2] == 8
     assert _differs(got, want) > 0.05
 
 
@@ -420,6 +493,102 @@ def test_counts_ride_the_spans(params, engine):
     assert g["window_steps_run"] < g["cache_steps_run"]
 
 
+def test_the_kernel_count_rides_the_finalize_span(params, engine):
+    """``attention_kernel_layers``: 0 here (the rule sees the CPU)."""
+    log = []
+    tracer = tracing.Tracer(max_traces=4, max_live=16)
+    tracer.on_trace(log.append)
+    root = tracer.start_span("doc")
+    engine.embed_ids_batch([np.arange(20, 32, dtype=np.int32)],
+                           ctxs=[root.context])
+    root.end()
+    (fin,) = [s for t in log for s in t["spans"]
+              if s["name"] == "engine.finalize"]
+    assert fin["attrs"]["attention_kernel_layers"] == 0
+    assert fin["attrs"]["moe_programs"] == 3
+
+
+def test_the_encoder_on_the_kernel_equals_the_reference(
+        monkeypatch, params, tokens, want):
+    """Every layer's core through the Pallas kernel (interpreted), the
+    rings wrapping three times, and the count says five layers."""
+    _the_rule_says_kernel(monkeypatch, (4, 4))
+    enc = build_encoder(config(), params)
+    got, states = streamed(enc, params, tokens)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    assert enc.counter_attrs([np.asarray(states["counts"])])[
+        "attention_kernel_layers"] == 5
+
+
+# -- which core: the rule ------------------------------------------------------
+
+BF16, F32 = jnp.bfloat16, jnp.float32
+
+
+@pytest.mark.parametrize("backend,dtype,T,S,rep,d,kernel", [
+    ("tpu", BF16, 512, 4096, 6, 128, True),    # the cell's short group
+    ("tpu", BF16, 512, 4608, 6, 128, True),    # its rings
+    ("tpu", BF16, 512, 16384, 6, 128, True),   # its global layer
+    ("cpu", BF16, 512, 4608, 6, 128, False),
+    ("tpu", F32, 512, 4608, 6, 128, False),    # the parity tests' type
+    ("tpu", BF16, 512, 512, 6, 128, False),    # one key block
+    ("tpu", BF16, 64, 64, 6, 128, False),      # a single-chunk document
+    ("tpu", BF16, 500, 4000, 6, 128, False),   # no tile divides it
+    ("tpu", BF16, 512, 2048, 4, 64, True),     # granite: PERF.md §6, PR 33
+], ids=["short_group", "ring", "global", "cpu", "float32", "one_key_block",
+        "single_chunk", "no_tile", "granite_d64"])
+def test_the_rule_reads_observables_alone(backend, dtype, T, S, rep, d,
+                                          kernel):
+    assert attention.core_is_kernel(backend, dtype, T, S, rep, d) is kernel
+
+
+def test_the_kernels_tiles_are_a_function_of_the_shapes():
+    """Aligned to bfloat16's (16, 128) tiles and dividing chunk and
+    cache, at every shape the cell runs."""
+    for S in (4096, 4608, 16384):
+        qb, kb = attention._kernel_tiles(512, S, 6)
+        assert 512 % qb == 0 and qb % 16 == 0 and qb & (qb - 1) == 0
+        assert S % kb == 0 and kb % 128 == 0 and S > kb
+    assert attention._kernel_tiles(500, 4000, 6) is None
+    assert [attention._kernel_tiles(512, S, 6) for S in (4096, 4608, 16384)] \
+        == [(512, 1024), (512, 1536), (512, 1024)]
+    assert attention._kernel_tiles(512, 2048, 4) == (512, 1024)   # granite
+
+
+def test_no_name_selects_a_core():
+    """The core is the code's choice: nothing a caller, a configuration
+    or a command line can say names one."""
+    import inspect
+    import json
+    import re
+    from pathlib import Path
+
+    from code_intelligence_tpu.models.granite_hybrid import (
+        GraniteHybridConfig)
+
+    assert list(inspect.signature(gqa_cached).parameters) == [
+        "q", "k", "v", "k_cache", "v_cache", "pos", "scale", "q_block",
+        "mxu_dtype", "window", "key_block"]
+    words = {"pallas", "kernel", "core", "xla", "interpret", "tile", "tiles"}
+    for cls in (AfmoeConfig, GraniteHybridConfig):
+        assert not [f.name for f in dataclasses.fields(cls)
+                    if words & set(f.name.split("_"))]
+    root = Path(attention.__file__).resolve().parents[2]
+    for name in ("trinity_large_ep8_share", "granite_4_0_h_micro"):
+        serve = json.loads((root / "benchmark" / "configs" /
+                            f"{name}.json").read_text())["serve"]
+        assert sorted(serve) == ["batch_size", "buckets", "kv_positions",
+                                 "scheduler"]
+    options = re.compile(r'add_argument\(\s*"--([a-z_0-9-]+)"')
+    for cli in ("training/cli.py", "sweep/cli.py", "serving/server.py"):
+        names = options.findall(
+            (root / "code_intelligence_tpu" / cli).read_text())
+        assert names and not [n for n in names
+                              if re.search("attention|gqa|core", n)]
+    source = Path(attention.__file__).read_text()
+    assert "environ" not in source and "getenv" not in source
+
+
 def test_a_document_past_the_cache_is_refused(engine):
     with pytest.raises(ValueError, match="kv_positions=64"):
         engine.embed_ids_batch([np.full(70, 25, np.int32)])
@@ -454,7 +623,7 @@ def test_it_satisfies_the_contract_and_counts_its_state(encoder):
         == (4 * 12 + 64) * per_slot
     states = encoder.init_states(2, 40)
     got = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(states))
-    assert got - 4 - 3 * 4 == 2 * encoder.state_bytes_per_row(40)
+    assert got - 4 - 4 * 4 == 2 * encoder.state_bytes_per_row(40)
     with pytest.raises(ValueError, match="kv_positions=64"):
         encoder.cache_positions(65)
     # no sliding layer: no ring
@@ -485,7 +654,8 @@ def test_published_widths_carry_142_6_megabytes_a_row():
         == (4096, 4096)
     assert enc.state_bytes_per_row(3072) == 5 * 4096 * 4096 == 83886080
     shapes = jax.eval_shape(lambda: enc.init_states(16, 16384))
-    assert [k.shape[1] for k in shapes["k"]] == [4608] * 3 + [16384, 4608]
+    assert [k.shape[1:3] for k in shapes["k"]] == \
+        [(8, 4608)] * 3 + [(8, 16384), (8, 4608)]      # head-major
     assert shapes["k"][0].dtype == jnp.bfloat16
 
 
@@ -514,7 +684,7 @@ def test_the_table_has_a_fourth_row():
     assert contract.ENCODERS["afmoe"][0] is AfmoeConfig
     enc = build_encoder(config())
     assert isinstance(enc, AfmoeEncoder) and isinstance(enc, ChunkEncoder)
-    assert enc.state_counters(enc.init_states(1)).shape == (3,)
+    assert enc.state_counters(enc.init_states(1)).shape == (4,)
     assert enc.counter_attrs([]) == {}
 
 

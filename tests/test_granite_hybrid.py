@@ -131,7 +131,7 @@ def test_cached_attention_equals_dense_causal(T):
     s = jnp.where(jnp.tril(jnp.ones((2 * T, 2 * T), bool)), s, -jnp.inf)
     want = jnp.einsum("bhts,bshd->bthd", jax.nn.softmax(s, -1),
                       jnp.repeat(v, 2, axis=2))
-    kc = vc = jnp.zeros((b, S, Hkv, d))
+    kc = vc = jnp.zeros((b, Hkv, S, d))     # head-major
     pos = jnp.zeros((), jnp.int32)
     outs = []
     for lo in (0, T):  # two chunks through the cache
@@ -154,17 +154,31 @@ def test_encoder_equals_the_reference(params, encoder):
     assert got.shape == (3, 44, encoder.out_dim)
 
 
-@pytest.mark.parametrize("cuts", [(19,), (13, 30), (7, 18, 33)],
-                         ids=["2_programs", "3_programs", "4_programs"])
-def test_one_program_equals_chunk_programs(params, encoder, cuts):
+@pytest.mark.parametrize("cuts,kernel", [
+    ((19,), False), ((13, 30), False), ((7, 18, 33), False),
+    ((16, 32), True)],
+    ids=["2_programs", "3_programs", "4_programs", "3_on_the_kernel"])
+def test_one_program_equals_chunk_programs(monkeypatch, params, encoder,
+                                           cuts, kernel):
     """Boundaries that are no multiple of the scan's chunk (8): the conv
-    tail, the SSM state and the key/value cache all cross them."""
+    tail, the SSM state and the key/value cache all cross them. Once
+    with the chunk programs' attention on the Pallas core (interpreted
+    here; the rule's answer and tiles that divide 16, 12 and the 128
+    slots come from the test): the whole document stays the XLA core's."""
     tokens = jax.random.randint(jax.random.PRNGKey(2), (2, 44), 0, 300)
     whole, whole_states = jax.jit(encoder.encode)(
         params, tokens, encoder.init_states(2, 44))
     np.testing.assert_allclose(whole, reference_hidden(params, tokens),
                                rtol=1e-4, atol=2e-5)
     states = encoder.init_states(2)        # the whole cache
+    if kernel:
+        from code_intelligence_tpu.ops import attention
+        monkeypatch.setattr(attention, "core_is_kernel", lambda *a: True)
+        monkeypatch.setattr(attention, "_kernel_tiles", lambda *a: (4, 32))
+        real, traced = attention._kernel_core, []
+        monkeypatch.setattr(
+            attention, "_kernel_core",
+            lambda *a, **kw: traced.append(a[0].shape[1]) or real(*a, **kw))
     parts = []
     for lo, hi in zip((0,) + cuts, cuts + (44,)):
         out, states = jax.jit(encoder.encode)(params, tokens[:, lo:hi],
@@ -172,13 +186,15 @@ def test_one_program_equals_chunk_programs(params, encoder, cuts):
         parts.append(out)
     np.testing.assert_allclose(jnp.concatenate(parts, 1), whole,
                                rtol=1e-4, atol=2e-5)
+    if kernel:      # the one attention layer of each program
+        assert traced == [16, 16, 12]
     assert int(states["pos"]) == 44
     for a, b in zip(states["ssm"], whole_states["ssm"]):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=2e-5)
     for a, b in zip(states["conv"], whole_states["conv"]):
         np.testing.assert_allclose(a, b, atol=1e-6)
-    np.testing.assert_allclose(states["k"][:, :, :44],
-                               whole_states["k"][:, :, :44], atol=1e-5)
+    np.testing.assert_allclose(states["k"][:, :, :, :44],
+                               whole_states["k"][:, :, :, :44], atol=1e-5)
 
 
 def test_a_dropped_carry_is_seen(params, encoder):

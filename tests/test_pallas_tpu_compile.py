@@ -97,3 +97,53 @@ def test_a_layers_gradient_compiles_outside_the_train_step(
     text = jax.jit(jax.grad(loss, argnums=(0, 3, 4, 5))).lower(
         *args).compile().as_text()
     assert text.count("tpu_custom_call") >= 2  # forward and adjoint
+
+
+# -- ops/attention.py::gqa_cached at the attention cells' shapes ------------
+
+def _gqa_text(monkeypatch, one_chip, rows, T, S, window, Hq, Hkv, d):
+    """The compiled text of one ``gqa_cached`` call as the encoders make
+    it; the rule asks the backend, so the test answers for it."""
+    from code_intelligence_tpu.ops.attention import gqa_cached
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def core(q, k, v, k_cache, v_cache, pos):
+        return gqa_cached(q, k, v, k_cache, v_cache, pos, d ** -0.5,
+                          window=window)
+
+    args = [jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+            for s in ((rows, T, Hq, d), (rows, T, Hkv, d), (rows, T, Hkv, d),
+                      (rows, Hkv, S, d), (rows, Hkv, S, d))]
+    pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    return jax.jit(core).lower(*args, pos).compile().as_text()
+
+
+# `trinity_bulk_long_tail`: the short group's caches, the long group's
+# rings and its global cache, at every batch the group narrows to
+@pytest.mark.parametrize("rows", [16, 8, 4, 2])
+@pytest.mark.parametrize("S,window", [(4096, 4096), (4096, None),
+                                      (4608, 4096), (16384, None)])
+def test_the_attention_kernel_compiles_at_trinitys_shapes(
+        one_chip, monkeypatch, rows, S, window):
+    text = _gqa_text(monkeypatch, one_chip, rows, 512, S, window, 48, 8, 128)
+    assert "tpu_custom_call" in text
+
+
+# `granite_bulk_mixed`: its multi-chunk group (16 and 2 rows against the
+# 2048-position cache) takes the kernel at head_dim 64
+@pytest.mark.parametrize("rows", [16, 2])
+def test_the_attention_kernel_compiles_at_granites_shape(
+        one_chip, monkeypatch, rows):
+    text = _gqa_text(monkeypatch, one_chip, rows, 512, 2048, None, 32, 8, 64)
+    assert "tpu_custom_call" in text
+
+
+# where the rule says XLA no Mosaic call appears: a single-chunk group
+# (one key block) of either model
+@pytest.mark.parametrize("T,Hq,d", [(512, 48, 128), (64, 48, 128),
+                                    (256, 32, 64)])
+def test_a_cache_of_one_key_block_stays_on_the_xla_core(
+        one_chip, monkeypatch, T, Hq, d):
+    text = _gqa_text(monkeypatch, one_chip, 16, T, T, None, Hq, 8, d)
+    assert "tpu_custom_call" not in text
