@@ -264,6 +264,10 @@ class InferenceEngine:
             pool_state = self._accumulate_pool(raw, lengths, pool_state)
             return pool_state, jax.tree.leaves(new_states)
 
+        # a device trace names a module after the function it was traced
+        # from: ``jit_fwd_b16_l512``, so a capture's device time splits by
+        # program shape (and joins the ``engine.program`` spans)
+        fwd.__name__ = fwd.__qualname__ = f"fwd_b{batch}_l{length}"
         # the carried state is donated: each chunk program writes its new
         # state over the one it was handed. Programs are enqueued ahead of
         # the device, and without this every enqueued chunk of a group
@@ -623,15 +627,16 @@ class InferenceEngine:
                 # enqueue the group's device programs; defer the host
                 # fetch so the device pipelines groups instead of idling
                 # on a host round-trip every batch_size docs
+                group_ctx = _first_traced(ctxs[i] for i in idx) \
+                    if ctxs is not None else None
                 tg0 = time.perf_counter()
                 with profiling.annotate("engine.group"):
                     pools, counts = self._embed_group_device(
-                        [ids for _, _, ids in group], counted)
+                        [ids for _, _, ids in group], counted, group_ctx)
                 tg1 = time.perf_counter()
-                if ctxs is not None:
+                if group_ctx is not None:
                     tracing.record_span(
-                        "engine.group", tg0, tg1,
-                        _first_traced(ctxs[i] for i in idx), **counts,
+                        "engine.group", tg0, tg1, group_ctx, **counts,
                         late_docs=sum(
                             length < longest_sent for length, _, _ in group))
                 longest_sent = max(longest_sent, group[-1][0])
@@ -663,7 +668,8 @@ class InferenceEngine:
         return self._bucket_for_static(length, self.buckets)
 
     def _embed_group_device(self, seqs: List[np.ndarray],  # graft: hot
-                            counted: Optional[list] = None):
+                            counted: Optional[list] = None,
+                            trace_ctx=None):
         """Enqueue one group's forward passes; returns the DEVICE pool
         state (no host sync — ``_finalize`` materializes it) and the
         group's counts.
@@ -702,7 +708,21 @@ class InferenceEngine:
         in the ring; 0 for an encoder without that kind of state).
         ``counted``, where a list is given,
         gains what the encoder counted in the group's carried state
-        (still on the device)."""
+        (still on the device).
+
+        ``trace_ctx``, where a SpanContext is given (traced calls only:
+        without one no clock is read here), gains ONE ``engine.program``
+        span a chunk program, from just before the chunk's blocks go to
+        the device to the return of the jitted call (the enqueue; no
+        device sync), so that the group's span has children and block
+        filling, ``init_states`` and ``_narrow`` are its self time. Its
+        attributes: ``rows`` (the batch this program runs at), ``batch``
+        (the group's first-chunk batch), ``bucket``, ``valid_tokens``
+        (the chunk's own) and ``lane_steps`` (``rows`` x ``bucket``); with
+        (``rows``, ``bucket``) a capture's module ``jit_fwd_b<rows>_l<bucket>``
+        is laid against them. Over a group's programs ``lane_steps`` and
+        ``valid_tokens`` sum to the group's ``lane_steps_run`` and
+        ``valid_tokens``."""
         B = self.batch_size  # the first chunk's shape; pad the remainder
         lens = [len(s) for s in seqs]
         if any(a > b for a, b in zip(lens, lens[1:])):
@@ -737,9 +757,17 @@ class InferenceEngine:
                 chunk = s[ci * bucket : (ci + 1) * bucket]
                 tokens[r, : len(chunk)] = chunk
                 lengths[r] = len(chunk)
+            if trace_ctx is not None:
+                tp0 = time.perf_counter()
             pool, h_leaves = self._fwd(batch, bucket)(
                 self._enc_params, jnp.asarray(tokens), jnp.asarray(lengths), tuple(h_leaves), pool
             )
+            if trace_ctx is not None:
+                tracing.record_span(
+                    "engine.program", tp0, time.perf_counter(), trace_ctx,
+                    rows=batch, batch=B, bucket=bucket,
+                    valid_tokens=int(lengths.sum()),
+                    lane_steps=batch * bucket)
             rows_run += batch
             cache_steps += batch * bucket * (ci + 1)
             window_steps += batch * min(bucket * (ci + 1), ring)
@@ -809,8 +837,8 @@ class InferenceEngine:
             # rule_passes / rule_passes_run: the regex scans the pre-rule
             # chain could make for this document and the scans it made,
             # counted outside the clock reads; ``engine.tokenize`` holds
-            # the chain's second application (``Tokenizer.tokenize``),
-            # the word split and ``numericalize``
+            # the chain's second application (``Tokenizer.tokenize``,
+            # not counted), the word split and ``numericalize``
             def prepare(i, overlapped):
                 with text_rules.counting_passes() as passes:
                     tt0 = time.perf_counter()
@@ -819,15 +847,13 @@ class InferenceEngine:
                 tracing.record_span("engine.text_rules", tt0, tt1, ctxs[i],
                                     n_chars=len(text), rule_passes=passes[0],
                                     rule_passes_run=passes[1])
-                with text_rules.counting_passes() as passes:
-                    tt0 = time.perf_counter()
-                    ids = self.numericalize(text)
-                    tt1 = time.perf_counter()
+                tt0 = time.perf_counter()
+                ids = self.numericalize(text)
+                tt1 = time.perf_counter()
                 tracing.record_span(
                     "engine.tokenize", tt0, tt1, ctxs[i],
                     n_tokens=len(ids),
-                    n_tokens_overlapped=len(ids) if overlapped else 0,
-                    rule_passes=passes[0], rule_passes_run=passes[1])
+                    n_tokens_overlapped=len(ids) if overlapped else 0)
                 return ids
 
         if self._check_scheduler(scheduler or self.scheduler) == "groups":

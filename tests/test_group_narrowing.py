@@ -27,6 +27,7 @@ from test_granite_hybrid import MODEL, awd_engine
 from code_intelligence_tpu.inference import InferenceEngine
 from code_intelligence_tpu.models import make_config
 from code_intelligence_tpu.text import SPECIALS, Vocab
+from code_intelligence_tpu.utils.tracing import Tracer
 
 B, BUCKETS, CHUNK = 8, (8, 16), 16
 GRID = (8, 4, 2, 1)  # B, B/2, B/4, B/8
@@ -133,6 +134,37 @@ def test_counts_say_what_the_device_ran(engines, case):
     for key in ("rows", "batch", "bucket", "chunks", "valid_tokens",
                 "lane_steps", "state_bytes", "kv_positions"):
         assert counts[key] == as_wide[key], key
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_one_program_span_a_chunk_program(engines, case):
+    """PR 34: under a traced document each chunk program records one
+    ``engine.program`` with the rows it ran at, and the group's sums are
+    the sums over them; the same group with no context records none and
+    counts the same."""
+    narrowing, _ = engines
+    lengths, ran = CASES[case]
+    seqs = documents(lengths)
+    tracer = Tracer()
+    root = tracer.start_span("bench.doc")
+    _, counts = narrowing._embed_group_device(seqs, None, root.context)
+    root.end()
+    programs = [s["attrs"] for s in tracer.traces()[0]["spans"]
+                if s["name"] == "engine.program"]
+    assert [a["rows"] for a in programs] == ran
+    assert all(a["batch"] == B and a["bucket"] == counts["bucket"]
+               for a in programs)
+    # the k-th program holds each document's k-th chunk
+    bucket = counts["bucket"]
+    assert [a["valid_tokens"] for a in programs] == [
+        sum(min(max(n - k * bucket, 0), bucket) for n in lengths)
+        for k in range(len(ran))]
+    assert sum(a["lane_steps"] for a in programs) == counts["lane_steps_run"]
+    assert sum(a["valid_tokens"] for a in programs) == counts["valid_tokens"]
+    assert sum(a["lane_steps"] * (k + 1) for k, a in enumerate(programs)) \
+        == counts["cache_steps_run"]
+    _, untraced = narrowing._embed_group_device(seqs)
+    assert untraced == counts
 
 
 def test_unsorted_group_is_refused(engines):

@@ -189,27 +189,27 @@ class TestGroupsPathSpans:
                      "twice in a row and nothing is written to the log"}
     PASSES = 17  # the regex scans one application of the chain could make
 
-    @pytest.mark.parametrize("doc,text_run,tokenize_run", [
-        # the second application meets "@", "/" and the doubled spaces
-        # that spacing them leaves
-        (MARKDOWN, 14, 2),
-        (PLAIN, 0, 0),
+    @pytest.mark.parametrize("doc,text_run", [
+        (MARKDOWN, 14),
+        (PLAIN, 0),
     ], ids=["markdown", "plain"])
     def test_rule_passes_ride_on_both_tokenise_spans(self, engine, doc,
-                                                     text_run, tokenize_run):
-        """``rule_passes``: the scans the chain could make (title and body
-        on ``engine.text_rules``, the document once more on
-        ``engine.tokenize``: the chain is applied twice);
-        ``rule_passes_run``: the scans the guards let through."""
+                                                     text_run):
+        """``rule_passes``: the scans the chain could make over title and
+        body; ``rule_passes_run``: the scans the guards let through. The
+        pair rides on ``engine.text_rules``, which
+        ``pre_rule_passes_run_pct`` reads; the chain's second application
+        under ``engine.tokenize`` is not counted (since PR 34: no metric
+        read it)."""
         spans, _ = traced_call(engine, [doc, doc])
-        for name, could, run in (
-                ("engine.text_rules", 2 * self.PASSES, text_run),
-                ("engine.tokenize", self.PASSES, tokenize_run)):
-            assert len(spans[name]) == 2
-            for s in spans[name]:
-                a = s["attrs"]
-                assert a["rule_passes"] == could
-                assert a["rule_passes_run"] == run, (name, a)
+        assert len(spans["engine.text_rules"]) == 2
+        for s in spans["engine.text_rules"]:
+            a = s["attrs"]
+            assert a["rule_passes"] == 2 * self.PASSES
+            assert a["rule_passes_run"] == text_run, a
+        assert len(spans["engine.tokenize"]) == 2
+        for s in spans["engine.tokenize"]:
+            assert set(s["attrs"]) == {"n_tokens", "n_tokens_overlapped"}
         assert engine_mod.text_rules._tally.counts is None  # closed again
 
     def test_untraced_call_records_nothing_and_reads_no_clock_per_doc(
@@ -223,12 +223,19 @@ class TestGroupsPathSpans:
         monkeypatch.setattr(engine_mod.text_rules, "counting_passes",
                             lambda: reads.append("counter"))
         assert tracing.current_context() is None
-        docs = issues([3] * 41)  # 11 groups, one flush
+        # 11 groups, one flush; the last group is one document of 105
+        # tokens, streamed through seven chunk programs of 16
+        docs = issues([3] * 40 + [50])
+        programs = []
+        fwd = engine._fwd
+        monkeypatch.setattr(engine, "_fwd", lambda b, l: programs.append(
+            (b, l)) or fwd(b, l))
         rows = engine.embed_issues(docs, scheduler="groups")
         assert rows.shape == (41, engine.embed_dim)
         assert finished == []
-        # two reads a group and two a flush, none per document and none
-        # per preparation slab
+        assert len(programs) == 10 + 7
+        # two reads a group and two a flush, none per document, none per
+        # preparation slab and none per chunk program
         assert len(reads) == 2 * 11 + 2 < len(docs)
 
     def test_ambient_trace_gets_the_same_spans(self, engine):
@@ -240,6 +247,103 @@ class TestGroupsPathSpans:
         assert names.count("engine.group") == 2
         assert names.count("engine.finalize") == 1
 
+
+def group_under_a_trace(engine, lengths):
+    """One group straight into ``_embed_group_device`` under a root span
+    of its own, the way ``_embed_groups`` hands it the group's first
+    traced document: the group's counts and its ``engine.program`` spans
+    in the order they were recorded."""
+    tracer = Tracer()
+    root = tracer.start_span("bench.doc")
+    rng = np.random.RandomState(7)
+    _, counts = engine._embed_group_device(
+        [rng.randint(20, 150, n).astype(np.int32) for n in lengths],
+        None, root.context)
+    root.end()
+    (trace,) = tracer.traces()
+    return counts, [s for s in trace["spans"]
+                    if s["name"] == "engine.program"]
+
+
+class TestProgramSpans:
+    """One ``engine.program`` a chunk program (PR 34), a child of its
+    group's ``engine.group``: the shape the program ran at and what it
+    held, so that a capture's ``jit_fwd_b<rows>_l<bucket>`` modules can be
+    laid against them."""
+
+    @pytest.mark.parametrize("lengths,programs", [
+        # (rows, bucket, valid_tokens) of each chunk program, by hand
+        ([5, 12, 20, 40], [(4, 16, 5 + 12 + 16 + 16), (2, 16, 4 + 16),
+                           (1, 16, 8)]),
+        ([1, 2, 3, 4], [(4, 8, 10)]),
+        ([33, 34, 35, 36], [(4, 16, 64), (4, 16, 64), (4, 16, 1 + 2 + 3 + 4)]),
+        ([9, 40], [(4, 16, 9 + 16), (1, 16, 16), (1, 16, 8)]),
+    ], ids=["narrowed", "single_chunk", "all_alive", "partial"])
+    def test_one_span_a_chunk_program_with_the_hand_counts(
+            self, engine, lengths, programs):
+        counts, spans = group_under_a_trace(engine, lengths)
+        got = [s["attrs"] for s in spans]
+        assert [(a["rows"], a["bucket"], a["valid_tokens"]) for a in got] \
+            == programs
+        for a in got:
+            assert set(a) == {"rows", "batch", "bucket", "valid_tokens",
+                              "lane_steps"}
+            assert a["batch"] == B
+            assert a["lane_steps"] == a["rows"] * a["bucket"]
+        # the group's sums are the sums over its programs; the k-th
+        # program's rows have reached (k + 1) buckets of positions
+        assert counts["chunks"] == len(got)
+        assert counts["lane_steps_run"] == sum(a["lane_steps"] for a in got)
+        assert counts["valid_tokens"] == sum(a["valid_tokens"] for a in got)
+        assert counts["cache_steps_run"] == sum(
+            a["lane_steps"] * (k + 1) for k, a in enumerate(got))
+        assert counts["row_chunks_dropped"] == sum(
+            a["batch"] - a["rows"] for a in got)
+
+    def test_a_program_lies_inside_its_groups_span_on_its_groups_trace(
+            self, engine):
+        spans, _ = traced_call(
+            engine, issues([2, 30, 5, 9, 1, 14, 3, 40, 7, 4, 50, 22]))
+        groups = spans["engine.group"]
+        assert len(groups) == 3
+        assert len(spans["engine.program"]) == sum(
+            g["attrs"]["chunks"] for g in groups) > len(groups)
+        for g in groups:
+            mine = sorted((p for p in spans["engine.program"]
+                           if p["doc"] == g["doc"]), key=lambda p: p["lo"])
+            assert len(mine) == g["attrs"]["chunks"]
+            assert mine[0]["attrs"]["rows"] == B
+            # rendered times are rounded to 1 us
+            assert g["lo"] - 5e-6 <= mine[0]["lo"]
+            assert mine[-1]["hi"] <= g["hi"] + 5e-6
+            for a, b in zip(mine, mine[1:]):
+                assert b["lo"] >= a["hi"] - 5e-6
+                assert b["attrs"]["rows"] <= a["attrs"]["rows"]
+            # the group has self time: the blocks are filled outside them
+            assert sum(p["hi"] - p["lo"] for p in mine) < g["hi"] - g["lo"]
+            assert g["attrs"]["lane_steps_run"] == sum(
+                p["attrs"]["lane_steps"] for p in mine)
+            assert g["attrs"]["valid_tokens"] == sum(
+                p["attrs"]["valid_tokens"] for p in mine)
+
+    def test_a_group_without_a_traced_document_records_no_program(
+            self, engine):
+        _, counts = engine._embed_group_device(
+            [np.arange(20, 60, dtype=np.int32)], [], None)
+        assert counts["chunks"] == 3  # and nothing raised without a context
+
+    @pytest.mark.parametrize("shape", [(4, 8), (2, 16)], ids=str)
+    def test_a_compiled_forward_is_named_by_its_shape(self, engine, shape):
+        b, l = shape
+        lowered = engine._fwd(b, l).lower(
+            engine._enc_params, np.zeros((b, l), np.int32),
+            np.zeros((b,), np.int32),
+            tuple(jax.tree.leaves(engine.encoder.init_states(b, l))),
+            engine._init_pool_state(b))
+        names = re.findall(r"module @(\w+)", lowered.as_text())
+        assert names == [f"jit_fwd_b{b}_l{l}"]
+        # every reader of the benchmark finds the forwards by "fwd"
+        assert "fwd" in names[0] and "narrow" not in names[0]
 
 class TestStreamedGroups:
     """A call of more than ``B + B // 4`` documents is prepared shortest
