@@ -51,7 +51,8 @@ softmax and the router are float32 always.
 
 State carried between chunk programs (``init_states``): per layer the
 latent cache ``(rows, positions, kv_rank + rope)`` in the weights' type,
-one position counter, and three counts the expert layers keep
+one position counter, three counts the expert layers keep and, last,
+the attention layers whose core the program ran on the Pallas kernel
 (``state_counters``).
 """
 
@@ -214,7 +215,7 @@ class DeepseekV3Encoder:
                 jnp.zeros((batch, S, cfg.latent_dim), cfg.state_dtype)
                 for _ in range(cfg.num_hidden_layers)),
             "pos": jnp.zeros((), jnp.int32),
-            "counts": jnp.zeros((len(moe.COUNTERS),), jnp.int32),
+            "counts": jnp.zeros((len(moe.COUNTERS) + 1,), jnp.int32),
         }
 
     def state_bytes_per_row(self, max_len=None) -> int:
@@ -226,14 +227,24 @@ class DeepseekV3Encoder:
 
     def state_counters(self, states):
         """The counts the expert layers have kept since ``init_states``
-        (a device array; ``counter_attrs`` names them)."""
+        (``ops/moe.py::COUNTERS``) and, last, the attention layers whose
+        core the group's programs ran on the Pallas kernel (a device
+        array; ``counter_attrs`` names them)."""
         return states["counts"]
 
     def counter_attrs(self, counted) -> dict:
         """Span attributes from the fetched ``state_counters`` of a
-        flush's groups (``ops/moe.py::counter_attrs``)."""
-        return moe.counter_attrs(counted, self.config.n_moe_layers,
-                                 self.config.experts_held[1])
+        flush's groups: ``ops/moe.py::counter_attrs`` and
+        ``attention_kernel_layers``, the attention layers on the Pallas
+        core in a group's programs (``ops/mla.py::core_is_kernel``: all
+        programs of a group run one chunk length against one cache
+        size, so one answer a group), averaged over the groups."""
+        attrs = moe.counter_attrs(counted, self.config.n_moe_layers,
+                                  self.config.experts_held[1])
+        if counted:
+            attrs["attention_kernel_layers"] = \
+                sum(int(c[-1]) for c in counted) / len(counted)
+        return attrs
 
     def encode(self, params, tokens, states, lengths=None):
         """One chunk: ``tokens`` ``(B, T)`` with the carried ``states``
@@ -281,10 +292,16 @@ class DeepseekV3Encoder:
         with jax.named_scope("final_norm"):
             out = _rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
         ran = jnp.int32(1 if cfg.n_moe_layers else 0)
+        on_kernel = sum(mla.core_is_kernel(
+            jax.default_backend(), dtype, T, cache.shape[1],
+            cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.v_head_dim,
+            cfg.kv_lora_rank) for cache in latents)
         new_states = {
             "latent": tuple(latents),
             "pos": pos + T,
-            "counts": states["counts"] + jnp.stack([rows, busiest, ran]),
+            # sums since init_states, then what this program's rule said
+            "counts": states["counts"].at[:-1].add(
+                jnp.stack([rows, busiest, ran])).at[-1].set(on_kernel),
         }
         return out, new_states
 

@@ -197,6 +197,213 @@ def test_expanded_is_the_cheaper_form_for_every_shape_the_engine_runs():
     assert absorbed(128, 2048) < expanded(128, 2048)
 
 
+# -- ops: the core as one Pallas kernel, and the rule that picks it -----------
+
+def _the_rule_says_kernel(monkeypatch, tiles):
+    """The rule's answer steered from the test (it sees the CPU and
+    float32 here), and tiles that divide the tiny shapes; the kernel
+    itself asks the real backend and is interpreted."""
+    monkeypatch.setattr(mla, "core_is_kernel", lambda *a: True)
+    monkeypatch.setattr(mla, "_kernel_tiles", lambda *a: tiles)
+
+
+def _dense_core(a, cache, pos, scale, dtype):
+    """One softmax over every cached position up to ``pos + T``, the
+    operands of the three products rounded to ``dtype`` as the cores
+    round them (the expanded keys and values and ``p`` among them)."""
+    r = lambda x: x.astype(dtype).astype(jnp.float32)  # noqa: E731
+    b, T, H, nope = a["q_nope"].shape
+    rank = cache.shape[-1] - a["q_pe"].shape[-1]
+    n = pos + T
+    lat = r(cache[:, :n])
+    kv = r(jnp.einsum("bsc,cn->bsn", lat[..., :rank], r(a["w_kvb"]),
+                      precision="highest")).reshape(b, n, H, -1)
+    s = jnp.einsum("bthd,bshd->bhts", r(a["q_nope"]), kv[..., :nope],
+                   precision="highest") \
+        + jnp.einsum("bthr,bsr->bhts", r(a["q_pe"]), lat[..., rank:],
+                     precision="highest")
+    seen = jnp.arange(n)[None, :] <= (pos + jnp.arange(T))[:, None]
+    p = jax.nn.softmax(jnp.where(seen, s * scale, -jnp.inf), axis=-1)
+    return jnp.einsum("bhts,bshd->bthd", r(p), kv[..., nope:],
+                      precision="highest")
+
+
+# (pos, T, S, (q_block, key_block, heads), sizes, dtype): from an empty
+# and from a filled cache; query blocks that stop short of the last key
+# block allocated; one key block (a single-chunk document); one row;
+# every head in one step; the published head sizes in bfloat16, where
+# the expanded keys are rounded before they meet the queries
+TINY = dict(H=4, rank=16, nope=8, rope=4, v=8)
+WIDE = dict(H=2, rank=128, nope=128, rope=64, v=128)
+
+
+@pytest.mark.parametrize("pos,T,S,tiles,sizes,dtype", [
+    (0, 16, 32, (16, 8, 2), dict(TINY, b=2), jnp.float32),
+    (8, 16, 32, (16, 8, 2), dict(TINY, b=2), jnp.float32),
+    (16, 16, 32, (8, 16, 1), dict(TINY, b=2), jnp.float32),
+    (8, 8, 64, (4, 8, 2), dict(TINY, b=2), jnp.float32),
+    (0, 16, 16, (16, 16, 2), dict(TINY, b=2), jnp.float32),
+    (16, 16, 32, (16, 32, 4), dict(TINY, b=1), jnp.float32),
+    (0, 16, 32, (16, 16, 2), dict(WIDE, b=2), jnp.bfloat16),
+    (16, 16, 32, (16, 16, 1), dict(WIDE, b=2), jnp.bfloat16),
+], ids=["empty_cache", "filled", "q_blocks", "live_stops_short",
+        "one_key_block", "one_row_all_heads", "bf16_first_chunk",
+        "bf16_filled"])
+def test_the_kernel_equals_the_xla_core_and_a_dense_softmax(
+        pos, T, S, tiles, sizes, dtype):
+    """``_kernel_core`` interpreted, on the cache the chunk is written
+    into, against the XLA core at the same operand type and against one
+    dense softmax."""
+    a = _core_inputs(T=T, S=S, **sizes)
+    cache = jax.lax.dynamic_update_slice_in_dim(
+        a["cache"], a["latent"], pos, axis=1).astype(dtype)
+    args = (a["q_nope"], a["q_pe"], cache, jnp.int32(pos), a["w_kvb"], 0.3,
+            sizes["v"], dtype)
+    got = mla._kernel_core(*args, tiles)
+    assert got.shape == (sizes["b"], T, sizes["H"], sizes["v"])
+    assert got.dtype == jnp.float32
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(got, mla._xla_core(*args, 2, 8, 8),
+                               rtol=tol, atol=tol)
+    np.testing.assert_allclose(got, _dense_core(a, cache, pos, 0.3, dtype),
+                               rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("pos", [0, 8, 16])
+def test_mla_cached_on_the_kernel_writes_the_cache_and_attends(
+        monkeypatch, pos):
+    """``mla_cached`` itself with the rule steered: the same cache write,
+    the same result as with the rule left alone (the XLA core)."""
+    a = _core_inputs()
+    call = lambda: jax.jit(lambda a: mla.mla_cached(  # noqa: E731
+        **a, pos=jnp.int32(pos), scale=0.3, v_dim=8, head_block=2,
+        q_block=8, key_block=8, mxu_dtype=jnp.float32))(a)
+    want, want_cache = call()
+    _the_rule_says_kernel(monkeypatch, (8, 8, 2))
+    got, cache = call()
+    np.testing.assert_array_equal(cache, want_cache)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+def test_the_kernel_fetches_only_the_key_blocks_reached(monkeypatch):
+    """The index maps, read off the ``BlockSpec``s: a 64-position cache
+    in key blocks of 8 with 16 positions cached and 8 arriving: the
+    first query block of 4 reaches blocks 0..2 and is handed block 2
+    again for the five steps after (no new fetch, and ``pl.when`` skips
+    the step); the latent and the rotary key move together; a group of
+    heads is a block of the transposed queries' heads, of the output's
+    and of ``W_kvb``'s key half's columns, and of the rows of its
+    transposed value half."""
+    from jax.experimental import pallas as pl
+
+    maps = []
+    real = pl.BlockSpec
+
+    def recording(shape, index_map):
+        maps.append((shape, index_map))
+        return real(shape, index_map)
+
+    monkeypatch.setattr(pl, "BlockSpec", recording)
+    a = _core_inputs(T=8, S=64)
+    mla._kernel_core(a["q_nope"], a["q_pe"], a["cache"], jnp.int32(16),
+                     a["w_kvb"], 0.3, 8, jnp.float32, (4, 8, 2))
+    (q, latent, pe, w_k, w_v, out) = maps
+    pos = np.asarray([16], np.int32)
+    for _, keys in (latent, pe):
+        assert [tuple(int(v) for v in keys(1, 0, 0, j, pos))
+                for j in range(8)] == [(1, min(j, 2), 0) for j in range(8)]
+        assert [int(keys(0, 1, 1, j, pos)[1]) for j in range(8)] \
+            == [0, 1, 2, 2, 2, 2, 2, 2]     # queries 20..23: still block 2
+        assert [int(keys(0, 0, 0, j, np.asarray([40], np.int32))[1])
+                for j in range(8)] == [0, 1, 2, 3, 4, 5, 5, 5]
+    assert latent[0] == (None, 8, 16)       # c_kv: the first 16 columns
+    assert pe[0] == (None, 8, 4 + 116)      # k_pe, padded as the queries
+    # the queries lie (rows, heads, [nope | rope | 0], T): queries across
+    assert q[0] == (None, 2, 128, 4) and out[0] == (None, 4, 2 * 8)
+    assert tuple(int(v) for v in q[1](1, 1, 1, 5, pos)) == (1, 1, 0, 1)
+    assert tuple(int(v) for v in out[1](1, 1, 1, 5, pos)) == (1, 1, 1)
+    # W_kvb's key half by columns, its value half transposed by rows
+    assert w_k[0] == (16, 2 * 8) and w_v[0] == (2 * 8, 16)
+    assert tuple(int(v) for v in w_k[1](1, 1, 1, 5, pos)) == (0, 1)
+    assert tuple(int(v) for v in w_v[1](1, 1, 1, 5, pos)) == (1, 0)
+
+
+BF16, F32 = jnp.bfloat16, jnp.float32
+PUBLISHED = (128, 128, 128, 512)  # heads, nope, v, rank
+
+
+# the cell's eleven programs a call: the multi-chunk group's four (512
+# queries against the 2048-position cache) and the single-chunk groups of
+# buckets 512 and 256 on the kernel, buckets 128 and 64 on the XLA core
+@pytest.mark.parametrize("backend,dtype,T,S,sizes,kernel", [
+    ("tpu", BF16, 512, 2048, PUBLISHED, True),
+    ("tpu", BF16, 512, 512, PUBLISHED, True),
+    ("tpu", BF16, 256, 256, PUBLISHED, True),
+    ("tpu", BF16, 128, 128, PUBLISHED, False),
+    ("tpu", BF16, 64, 64, PUBLISHED, False),
+    ("cpu", BF16, 512, 2048, PUBLISHED, False),
+    ("tpu", F32, 512, 2048, PUBLISHED, False),    # the parity tests' type
+    ("tpu", BF16, 500, 2000, PUBLISHED, False),   # no tile divides it
+    ("tpu", BF16, 512, 1280, PUBLISHED, False),   # nor this cache
+    ("tpu", BF16, 512, 2048, (4, 8, 8, 16), False),  # sizes under a lane
+], ids=["multi_chunk", "bucket_512", "bucket_256", "bucket_128", "bucket_64",
+        "cpu", "float32", "no_tile", "no_key_block", "tiny_heads"])
+def test_the_rule_reads_observables_alone(backend, dtype, T, S, sizes,
+                                          kernel):
+    assert mla.core_is_kernel(backend, dtype, T, S, *sizes) is kernel
+
+
+def test_the_kernels_tiles_are_a_function_of_the_shapes():
+    """The whole chunk's queries, key blocks of 512 positions (the
+    chunks' length: no block of a first chunk lies unwritten) or a
+    single-chunk cache whole, eight heads a step; aligned to bfloat16's
+    (16, 128) tiles and dividing chunk, cache and heads."""
+    assert [mla._kernel_tiles(T, S, 128) for T, S in (
+        (512, 2048), (512, 512), (256, 256), (256, 1024))] \
+        == [(512, 512, 8), (512, 512, 8), (256, 256, 8), (256, 512, 8)]
+    for T, S in ((512, 2048), (512, 512), (256, 256)):
+        qb, kb, hs = mla._kernel_tiles(T, S, 128)
+        assert qb == T and qb % 128 == 0 and S % kb == 0 and kb % 16 == 0
+        assert 128 % hs == 0
+    assert mla._kernel_tiles(512, 2048, 4) == (512, 512, 4)
+    assert mla._kernel_tiles(512, 2048, 12) == (512, 512, 6)
+    for T, S in ((128, 128), (64, 64), (500, 2000), (1024, 2048),
+                 (512, 1280), (384, 384)):
+        assert mla._kernel_tiles(T, S, 128) is None
+
+
+def test_no_name_selects_a_core():
+    """The core is the code's choice: nothing a caller, a configuration
+    or a command line can say names one."""
+    import inspect
+    import json
+    import re
+    from pathlib import Path
+
+    assert list(inspect.signature(mla.mla_cached).parameters) == [
+        "q_nope", "q_pe", "latent", "cache", "pos", "w_kvb", "scale",
+        "v_dim", "head_block", "q_block", "key_block", "mxu_dtype"]
+    words = {"pallas", "kernel", "core", "xla", "interpret", "tile", "tiles"}
+    assert not [f.name for f in dataclasses.fields(DeepseekV3Config)
+                if words & set(f.name.split("_"))]
+    root = Path(mla.__file__).resolve().parents[2]
+    serve = json.loads((root / "benchmark" / "configs" /
+                        "deepseek_v3_ep16_share.json").read_text())["serve"]
+    assert sorted(serve) == ["batch_size", "buckets", "kv_positions",
+                             "scheduler"]
+    options = re.compile(r'add_argument\(\s*"--([a-z_0-9-]+)"')
+    for cli in ("training/cli.py", "sweep/cli.py", "serving/server.py"):
+        names = options.findall(
+            (root / "code_intelligence_tpu" / cli).read_text())
+        assert names and not [n for n in names
+                              if re.search("attention|mla|latent|core", n)]
+    source = Path(mla.__file__).read_text()
+    assert "environ" not in source and "getenv" not in source
+    model = Path(contract.ENCODERS["deepseek_v3"][1].__module__.replace(
+        ".", "/") + ".py")
+    assert "core_is_kernel" in (root / model).read_text()
+
+
 # -- ops: the router and the share -------------------------------------------
 
 def _route(scores, bias, **kw):
@@ -341,7 +548,28 @@ def test_one_program_equals_chunk_programs(params, encoder, tokens, cuts):
     np.testing.assert_allclose(jnp.concatenate(outs, 1), want, rtol=2e-5,
                                atol=2e-5)
     assert int(states["pos"]) == 24
-    assert int(states["counts"][3]) == len(cuts) + 1
+    assert int(states["counts"][2]) == len(cuts) + 1
+
+
+def test_the_encoder_on_the_kernel_equals_the_reference(
+        monkeypatch, params, tokens):
+    """Every layer's core through the Pallas kernel (interpreted), three
+    chunk programs of 8 against the 64-position cache, and the count
+    says three layers."""
+    _the_rule_says_kernel(monkeypatch, (4, 16, 2))
+    enc = build_encoder(config(), params)
+    want, _ = reference(params, tokens)
+    states = enc.init_states(3, 64)
+    outs = []
+    for lo in (0, 8, 16):
+        out, states = jax.jit(enc.encode)(params, tokens[:, lo:lo + 8],
+                                          states)
+        outs.append(out)
+    np.testing.assert_allclose(jnp.concatenate(outs, 1), want, rtol=2e-5,
+                               atol=2e-5)
+    assert enc.counter_attrs([np.asarray(states["counts"])])[
+        "attention_kernel_layers"] == 3
+    assert int(states["counts"][2]) == 3    # programs: summed, not set
 
 
 def test_a_dropped_cache_is_seen(params, encoder, tokens):
@@ -499,6 +727,23 @@ def test_counts_ride_the_finalize_span(params, engine):
     assert engine.encoder.counter_attrs([]) == {}
 
 
+def test_the_kernel_count_rides_the_finalize_span(params, engine):
+    """``attention_kernel_layers``: 0 here (the rule sees the CPU)."""
+    log = []
+    tracer = tracing.Tracer(max_traces=4, max_live=16)
+    tracer.on_trace(log.append)
+    root = tracer.start_span("doc")
+    engine.embed_ids_batch([np.arange(20, 32, dtype=np.int32)],
+                           ctxs=[root.context])
+    root.end()
+    (fin,) = [s for t in log for s in t["spans"]
+              if s["name"] == "engine.finalize"]
+    assert fin["attrs"]["attention_kernel_layers"] == 0
+    assert fin["attrs"]["moe_programs"] == 2
+    enc = engine.encoder
+    assert enc.state_counters(enc.init_states(1)).shape == (4,)
+
+
 def test_a_document_past_the_cache_is_refused(engine):
     with pytest.raises(ValueError, match="kv_positions=64"):
         engine.embed_ids_batch([np.full(70, 25, np.int32)])
@@ -525,7 +770,7 @@ def test_it_satisfies_the_contract_and_counts_its_state(encoder):
     assert encoder.cache_positions(17) == encoder.cache_positions() == 64
     states = encoder.init_states(2, 16)
     got = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(states))
-    assert got - 4 - 3 * 4 == 2 * encoder.state_bytes_per_row(16)
+    assert got - 4 - 4 * 4 == 2 * encoder.state_bytes_per_row(16)
     with pytest.raises(ValueError, match="kv_positions=64"):
         encoder.cache_positions(65)
 

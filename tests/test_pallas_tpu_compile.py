@@ -147,3 +147,50 @@ def test_a_cache_of_one_key_block_stays_on_the_xla_core(
         one_chip, monkeypatch, T, Hq, d):
     text = _gqa_text(monkeypatch, one_chip, 16, T, T, None, Hq, 8, d)
     assert "tpu_custom_call" not in text
+
+
+# -- ops/mla.py::mla_cached at the latent-attention cell's shapes ------------
+
+def _mla_text(monkeypatch, one_chip, rows, T, S, dtype=jnp.bfloat16):
+    """The compiled text of one ``mla_cached`` call as the encoder makes
+    it at the published sizes (128 heads of 128 + 64 | 128, rank 512);
+    the rule asks the backend, so the test answers for it."""
+    from code_intelligence_tpu.ops.mla import mla_cached
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    H, nope, rope, v, rank = 128, 128, 64, 128, 512
+
+    def core(q_nope, q_pe, latent, cache, w_kvb, pos):
+        return mla_cached(q_nope, q_pe, latent, cache, pos, w_kvb, 0.1352, v,
+                          mxu_dtype=dtype)
+
+    shapes = [((rows, T, H, nope), dtype), ((rows, T, H, rope), jnp.float32),
+              ((rows, T, rank + rope), jnp.float32),
+              ((rows, S, rank + rope), dtype), ((rank, H * (nope + v)), dtype),
+              ((), jnp.int32)]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    return jax.jit(core).lower(*args).compile().as_text()
+
+
+# `deepseek_v3_bulk_mixed`: the multi-chunk group's programs (16 and 2
+# rows against the 2048-position cache) and the single-chunk groups whose
+# bucket the rule sends to the kernel
+@pytest.mark.parametrize("rows,T,S", [(16, 512, 2048), (2, 512, 2048),
+                                      (16, 512, 512), (16, 256, 256)])
+def test_the_latent_kernel_compiles_at_deepseeks_shapes(
+        one_chip, monkeypatch, rows, T, S):
+    text = _mla_text(monkeypatch, one_chip, rows, T, S)
+    assert "tpu_custom_call" in text and "mla_cached_core" in text
+    # one body a shape: no static prefixes to switch over
+    assert "conditional" not in text
+
+
+# where the rule says XLA no Mosaic call appears: the single-chunk groups
+# of buckets 128 and under, and float32 operands
+@pytest.mark.parametrize("T,S,dtype", [(128, 128, jnp.bfloat16),
+                                       (64, 64, jnp.bfloat16),
+                                       (256, 256, jnp.float32)])
+def test_the_latent_core_stays_on_xla_where_the_rule_says_so(
+        one_chip, monkeypatch, T, S, dtype):
+    text = _mla_text(monkeypatch, one_chip, 16, T, S, dtype)
+    assert "tpu_custom_call" not in text
