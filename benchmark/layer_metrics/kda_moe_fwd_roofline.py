@@ -1,0 +1,33 @@
+"""Roofline share of the delta-rule / latent-attention expert model's
+forward programs: the least time the chip could take for the VALID
+tokens of the traced calls over the programs' device time, in %.
+Operations (``harness/flops_bailing.py``): the matmuls every token meets
+and the recurrence's own (``engine.group`` spans' valid tokens), the
+grouped matmuls of the rows routed (``engine.finalize`` spans'
+``routed_rows``), latent attention over whole documents
+(``engine.tokenize`` spans' lengths); bytes: one read of the held bf16
+matrices per execution. Prints which bounds it."""
+from benchmark.harness import flops, flops_bailing
+
+
+def read(ctx, spec):
+    durs = ctx.module_durations(spec["module"])
+    by_name = ctx.traced_spans.by_name()
+    groups = by_name.get("engine.group")
+    docs = by_name.get("engine.tokenize")
+    flushes = [s for s in by_name.get("engine.finalize", [])
+               if "routed_rows" in s.attrs]
+    if not durs or not groups or not docs or not flushes:
+        return None
+    model = ctx.config
+    routed = sum(float(s.attrs["routed_rows"]) for s in flushes)
+    need = flops_bailing.encoder_flops(
+        model, sum(int(g.attrs["valid_tokens"]) for g in groups), routed,
+        [int(d.attrs["n_tokens"]) for d in docs])
+    moved = len(durs) * flops_bailing.weight_bytes(model)
+    least, bound = flops.roofline_seconds(need, moved, ctx.peaks)
+    print(f"[bench] {spec['name']}: {len(docs)} documents in {len(groups)} "
+          f"groups, {routed:.0f} routed rows, {need:.4g} operations, "
+          f"{moved:.4g} bytes, least {least:.6f} s ({bound}-bound) over "
+          f"{sum(durs):.6f} s in {len(durs)} executions", flush=True)
+    return 100.0 * least / sum(durs)

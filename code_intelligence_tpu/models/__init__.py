@@ -5,6 +5,10 @@ from code_intelligence_tpu.models.awd_lstm import (
     AWDLSTMLM,
     init_lstm_states,
 )
+from code_intelligence_tpu.models.bailing_hybrid import (
+    BailingHybridConfig,
+    BailingHybridEncoder,
+)
 from code_intelligence_tpu.models.contract import (
     ChunkEncoder,
     build_encoder,
@@ -20,6 +24,7 @@ from code_intelligence_tpu.models.granite_hybrid import (
 )
 
 __all__ = ["AfmoeConfig", "AfmoeEncoder", "AWDLSTMConfig", "AWDLSTMEncoder", "AWDLSTMLM", "init_lstm_states",
+           "BailingHybridConfig", "BailingHybridEncoder",
            "ChunkEncoder", "build_encoder", "make_config",
            "DeepseekV3Config", "DeepseekV3Encoder",
            "GraniteHybridConfig", "GraniteHybridEncoder"]

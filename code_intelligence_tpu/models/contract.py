@@ -57,6 +57,8 @@ import jax.numpy as jnp
 
 from code_intelligence_tpu.models.afmoe import AfmoeConfig, AfmoeEncoder
 from code_intelligence_tpu.models.awd_lstm import AWDLSTMConfig, AWDLSTMEncoder
+from code_intelligence_tpu.models.bailing_hybrid import (
+    BailingHybridConfig, BailingHybridEncoder)
 from code_intelligence_tpu.models.deepseek_v3 import (
     DeepseekV3Config, DeepseekV3Encoder)
 from code_intelligence_tpu.models.granite_hybrid import (
@@ -114,6 +116,9 @@ ENCODERS = {
         _in_weights_dtype(DeepseekV3Encoder)),
     AfmoeConfig.architecture: (
         AfmoeConfig, AfmoeConfig.from_dict, _in_weights_dtype(AfmoeEncoder)),
+    BailingHybridConfig.architecture: (
+        BailingHybridConfig, BailingHybridConfig.from_dict,
+        _in_weights_dtype(BailingHybridEncoder)),
 }
 
 

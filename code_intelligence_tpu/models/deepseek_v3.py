@@ -85,6 +85,51 @@ def share_of(model: Mapping, count_key: str) -> dict:
             "experts_held": (held["first"], held["count"])}
 
 
+def latent_block(p, h, cache, pos, dtype, *, heads: int, nope: int,
+                 rope: int, v_dim: int, rank: int, eps: float, inv_freq,
+                 rope_factor: float, scale: float, q_low_rank: bool = True,
+                 head_gate: bool = False):
+    """``MLA(RMSNorm(h))`` of the module's docstring over one chunk,
+    through the latent ``cache`` at ``pos``: ``(out (b, T, E) float32,
+    cache)``. One copy for every model with latent attention; what such
+    models differ in are two placements: a query made in two steps
+    through a normed low-rank ``c_q`` (leaves ``q_a``, ``q_norm``,
+    ``q_b``) or by one matrix ``q`` (``q_low_rank=False``), and
+    ``head_gate``: each head's output times ``sigmoid(u w_h)`` (leaf
+    ``gate`` ``(E, heads)``) before ``o``. Named scopes ``q_proj``,
+    ``kv_latent``, ``rope``, ``mla_core``, ``gate`` (where gated),
+    ``o_proj``."""
+    b, T, _ = h.shape
+    u = _rms_norm(h, p["norm"], eps).astype(dtype)
+    with jax.named_scope("q_proj"):
+        if q_low_rank:
+            c_q = _rms_norm(_matmul(u, p["q_a"]), p["q_norm"], eps)
+            q = _matmul(c_q, p["q_b"], dtype)
+        else:
+            q = _matmul(u, p["q"], dtype)
+        q = q.reshape(b, T, heads, nope + rope)
+    with jax.named_scope("kv_latent"):
+        kv = _matmul(u, p["kv_a"])
+        c_kv = _rms_norm(kv[..., :rank], p["kv_norm"], eps)
+    with jax.named_scope("rope"):
+        positions = pos + jnp.arange(T)
+        q_pe = mla.apply_rope(q[..., nope:], positions, inv_freq,
+                              rope_factor)
+        k_pe = mla.apply_rope(kv[..., rank:], positions, inv_freq,
+                              rope_factor)
+    latent = jnp.concatenate([c_kv, k_pe], axis=-1)
+    with jax.named_scope("mla_core"):
+        out, cache = mla.mla_cached(
+            q[..., :nope], q_pe, latent, cache, pos, p["kv_b"], scale,
+            v_dim, mxu_dtype=dtype)
+    if head_gate:
+        with jax.named_scope("gate"):
+            out = out * jax.nn.sigmoid(_matmul(u, p["gate"]))[..., None]
+    with jax.named_scope("o_proj"):
+        out = _matmul(out.reshape(b, T, heads * v_dim), p["o"])
+    return out, cache
+
+
 @dataclasses.dataclass(frozen=True)
 class DeepseekV3Config:
     architecture: ClassVar[str] = "deepseek_v3"
@@ -309,28 +354,9 @@ class DeepseekV3Encoder:
 
     def _attention(self, p, h, cache, pos, dtype):
         cfg = self.config
-        b, T, _ = h.shape
-        H, nope, rope = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
-                         cfg.qk_rope_head_dim)
-        eps = cfg.rms_norm_eps
-        u = _rms_norm(h, p["norm"], eps).astype(dtype)
-        with jax.named_scope("q_proj"):
-            c_q = _rms_norm(_matmul(u, p["q_a"]), p["q_norm"], eps)
-            q = _matmul(c_q, p["q_b"], dtype).reshape(b, T, H, nope + rope)
-        with jax.named_scope("kv_latent"):
-            kv = _matmul(u, p["kv_a"])
-            c_kv = _rms_norm(kv[..., :cfg.kv_lora_rank], p["kv_norm"], eps)
-        with jax.named_scope("rope"):
-            positions = pos + jnp.arange(T)
-            q_pe = mla.apply_rope(q[..., nope:], positions, self._inv_freq,
-                                  self._rope_factor)
-            k_pe = mla.apply_rope(kv[..., cfg.kv_lora_rank:], positions,
-                                  self._inv_freq, self._rope_factor)
-        latent = jnp.concatenate([c_kv, k_pe], axis=-1)
-        with jax.named_scope("mla_core"):
-            out, cache = mla.mla_cached(
-                q[..., :nope], q_pe, latent, cache, pos, p["kv_b"],
-                self._scale, cfg.v_head_dim, mxu_dtype=dtype)
-        with jax.named_scope("o_proj"):
-            out = _matmul(out.reshape(b, T, H * cfg.v_head_dim), p["o"])
-        return out, cache
+        return latent_block(
+            p, h, cache, pos, dtype, heads=cfg.num_attention_heads,
+            nope=cfg.qk_nope_head_dim, rope=cfg.qk_rope_head_dim,
+            v_dim=cfg.v_head_dim, rank=cfg.kv_lora_rank,
+            eps=cfg.rms_norm_eps, inv_freq=self._inv_freq,
+            rope_factor=self._rope_factor, scale=self._scale)

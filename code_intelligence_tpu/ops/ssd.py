@@ -23,6 +23,7 @@ from __future__ import annotations
 
 from typing import Tuple
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -111,11 +112,14 @@ def ssd_recurrence(x, dt, A, B, C, D, state):
 
 
 def causal_conv1d(x: jnp.ndarray, w: jnp.ndarray, bias: jnp.ndarray,
-                  tail: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                  tail: jnp.ndarray, lengths=None
+                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Depthwise causal convolution over time. ``x`` ``(b, T, C)``, ``w``
     ``(C, K)`` (``w[:, K-1]`` meets the current token), ``tail``
     ``(b, K-1, C)``: the inputs just before ``x``. Returns the float32
-    output ``(b, T, C)`` and the new tail (``x``'s dtype)."""
+    output ``(b, T, C)`` and the new tail (``x``'s dtype): the last
+    ``K - 1`` inputs, or with ``lengths`` ``(b,)`` the ``K - 1`` before
+    each row's valid end (a row of padding alone keeps its tail)."""
     K = w.shape[1]
     T = x.shape[1]
     xp = jnp.concatenate([tail.astype(x.dtype), x], axis=1)  # (b, T+K-1, C)
@@ -123,4 +127,7 @@ def causal_conv1d(x: jnp.ndarray, w: jnp.ndarray, bias: jnp.ndarray,
     out = bias.astype(jnp.float32)
     for k in range(K):
         out = out + xp[:, k:k + T].astype(jnp.float32) * wf[:, k]
-    return out, xp[:, T:]
+    if lengths is None:
+        return out, xp[:, T:]
+    return out, jax.vmap(
+        lambda row, n: lax.dynamic_slice_in_dim(row, n, K - 1))(xp, lengths)

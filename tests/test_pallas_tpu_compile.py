@@ -151,14 +151,14 @@ def test_a_cache_of_one_key_block_stays_on_the_xla_core(
 
 # -- ops/mla.py::mla_cached at the latent-attention cell's shapes ------------
 
-def _mla_text(monkeypatch, one_chip, rows, T, S, dtype=jnp.bfloat16):
+def _mla_text(monkeypatch, one_chip, rows, T, S, dtype=jnp.bfloat16, H=128):
     """The compiled text of one ``mla_cached`` call as the encoder makes
-    it at the published sizes (128 heads of 128 + 64 | 128, rank 512);
+    it at the published sizes (``H`` heads of 128 + 64 | 128, rank 512);
     the rule asks the backend, so the test answers for it."""
     from code_intelligence_tpu.ops.mla import mla_cached
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    H, nope, rope, v, rank = 128, 128, 64, 128, 512
+    nope, rope, v, rank = 128, 64, 128, 512
 
     def core(q_nope, q_pe, latent, cache, w_kvb, pos):
         return mla_cached(q_nope, q_pe, latent, cache, pos, w_kvb, 0.1352, v,
@@ -183,6 +183,34 @@ def test_the_latent_kernel_compiles_at_deepseeks_shapes(
     assert "tpu_custom_call" in text and "mla_cached_core" in text
     # one body a shape: no static prefixes to switch over
     assert "conditional" not in text
+
+
+# `ling_bulk_long_tail`: 32 heads against the long group's cache of 16,384
+# positions (32 key blocks of 512) and the short group's of 4,096, at the
+# rows its programs narrow to
+@pytest.mark.parametrize("rows,S", [(16, 16384), (2, 16384), (16, 4096)])
+def test_the_latent_kernel_compiles_at_the_long_caches(
+        one_chip, monkeypatch, rows, S):
+    text = _mla_text(monkeypatch, one_chip, rows, 512, S, H=32)
+    assert "tpu_custom_call" in text and "mla_cached_core" in text
+    assert "conditional" not in text
+
+
+# ops/kda.py::kda_scan at the same cell's shapes: plain XLA (no Mosaic
+# call), and the chip's compiler takes a full program's temporaries
+@pytest.mark.parametrize("rows", [16, 2])
+def test_the_delta_rule_recurrence_compiles_at_the_cells_shapes(
+        one_chip, rows):
+    from code_intelligence_tpu.ops.kda import kda_scan
+
+    T, H, d = 512, 32, 128
+    shapes = [(rows, T, H, d)] * 4 + [(rows, T, H), (rows, H, d, d)]
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+            for s in shapes]
+    compiled = jax.jit(lambda *a: kda_scan(
+        *a, chunk=64, mxu_dtype=jnp.bfloat16)).lower(*args).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 1024 ** 3
 
 
 # where the rule says XLA no Mosaic call appears: the single-chunk groups
