@@ -101,6 +101,7 @@ _SWIGLU_LIMITS = ("expert_swiglu_limit_list", "share_expert_swiglu_limit_list")
 # the decays of a gate bounded at -5 a token at any chunk; 64 is the
 # published kernels' chunk
 _KDA_CHUNK = 64
+_KDA_SUB = 16
 
 
 @dataclasses.dataclass(frozen=True)
@@ -299,10 +300,11 @@ class BailingHybridEncoder:
     def counter_attrs(self, counted) -> dict:
         """Span attributes from the fetched ``state_counters`` of a
         flush's groups: ``ops/moe.py::counter_attrs``, ``kda_layers``
-        (the configuration's), ``kda_kernel_layers`` (0: the recurrence
-        has one core, XLA's) and ``attention_kernel_layers`` (the latent
-        layers on ``ops/mla.py``'s kernel in a group's programs), the
-        last two averaged over the groups."""
+        (the configuration's), ``kda_kernel_layers`` and
+        ``attention_kernel_layers`` (the KDA layers on ``ops/kda.py``'s
+        kernel and the latent layers on ``ops/mla.py``'s in a group's
+        programs, as each op's ``core_is_kernel`` said), the last two
+        averaged over the groups."""
         attrs = moe.counter_attrs(counted, self.config.n_moe_layers,
                                   self.config.experts_held[1])
         if counted:
@@ -374,19 +376,22 @@ class BailingHybridEncoder:
         with jax.named_scope("final_norm"):
             out = _rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
         ran = jnp.int32(1 if cfg.n_moe_layers else 0)
+        backend = jax.default_backend()
         on_kernel = sum(mla.core_is_kernel(
-            jax.default_backend(), dtype, T, cache.shape[1],
-            cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.v_head_dim,
-            cfg.kv_lora_rank) for cache in latents)
+            backend, dtype, T, cache.shape[1], cfg.num_attention_heads,
+            cfg.qk_nope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank)
+            for cache in latents)
+        kda_on_kernel = len(kda_states) * kda.core_is_kernel(
+            backend, dtype, T, cfg.num_attention_heads, cfg.head_dim,
+            cfg.head_dim, _KDA_CHUNK, _KDA_SUB)
         new_states = {
             "kda": tuple(kda_states), "conv": tuple(tails),
             "latent": tuple(latents), "pos": pos + T,
             # sums since init_states, then what this program's rules
-            # said: no KDA layer on a kernel (there is none), the latent
-            # layers on theirs
+            # said: the KDA and the latent layers on their kernels
             "counts": states["counts"].at[:-2].add(
                 jnp.stack([rows, busiest, ran])).at[-2:].set(
-                jnp.array([0, on_kernel], jnp.int32)),
+                jnp.array([kda_on_kernel, on_kernel], jnp.int32)),
         }
         return out, new_states
 
@@ -423,7 +428,8 @@ class BailingHybridEncoder:
             beta = jnp.where(valid[..., None], beta, 0.0)
         with jax.named_scope("kda_core"):
             o, S_new = kda.kda_scan(
-                q, k, v, g, beta, S, _KDA_CHUNK, mxu_dtype=dtype)
+                q, k, v, g, beta, S, _KDA_CHUNK, mxu_dtype=dtype,
+                sub=_KDA_SUB)
         with jax.named_scope("gated_norm"):
             o = _rms_norm(o, p["o_norm"], cfg.rms_norm_eps) \
                 * jax.nn.sigmoid(fgb[..., D:2 * D]).reshape(b, T, H, d)

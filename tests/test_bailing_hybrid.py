@@ -416,9 +416,9 @@ def test_chunked_through_both_kinds_of_state_with_narrowing(
         6 * 4 * 16 * 16 * 4 + 6 * 3 * 192 * 4 + 256 * 40 * 4)
 
 
-def test_counts_ride_the_spans(params, engine):
-    rng = np.random.default_rng(11)
-    seqs = [rng.integers(20, 300, n).astype(np.int32) for n in (5, 30, 40)]
+def _traced_finalize(engine, seqs):
+    """The spans of one traced ``embed_ids_batch`` call, and its
+    ``engine.finalize`` among them."""
     log = []
     tracer = tracing.Tracer(max_traces=4, max_live=16)
     tracer.on_trace(log.append)
@@ -428,13 +428,20 @@ def test_counts_ride_the_spans(params, engine):
         r.end()
     spans = [s for t in log for s in t["spans"]]
     (fin,) = [s for s in spans if s["name"] == "engine.finalize"]
+    return spans, fin["attrs"]
+
+
+def test_counts_ride_the_spans(params, engine):
+    rng = np.random.default_rng(11)
+    seqs = [rng.integers(20, 300, n).astype(np.int32) for n in (5, 30, 40)]
+    spans, a = _traced_finalize(engine, seqs)
     want = 0
     for s in seqs:
         _, chosen = reference(params, jnp.asarray(s)[None])
         want += sum(int(((c >= 4) & (c < 12)).sum()) for c in chosen)
-    a = fin["attrs"]
     assert a["routed_rows"] == want > 0
     assert a["moe_programs"] == 3       # chunks of 16: rows 4, 2, 2
+    # what the two rules say here: the CPU, float32, sizes under a lane
     assert (a["kda_layers"], a["kda_kernel_layers"],
             a["attention_kernel_layers"]) == (6, 0, 0)
     (group,) = [s for s in spans if s["name"] == "engine.group"]
@@ -442,6 +449,33 @@ def test_counts_ride_the_spans(params, engine):
     assert (g["chunks"], g["kv_positions"]) == (3, 64)
     programs = [s for s in spans if s["name"] == "engine.program"]
     assert len(programs) == 3
+
+
+def test_kda_kernel_layers_is_what_the_rule_says(params, vocab, monkeypatch):
+    """The rule patched true, tiles for the tiny shapes and chunk
+    programs of 64 tokens (one whole chunk of the recurrence): every KDA
+    layer of every program runs the interpreted kernel, the count says
+    6, and the rows are the XLA scan's."""
+    seqs = [np.random.default_rng(12).integers(20, 300, n).astype(np.int32)
+            for n in (50, 64)]
+
+    def build():
+        return InferenceEngine(params, config(), vocab, buckets=(64,),
+                               batch_size=2)
+
+    want = build().embed_ids_batch(seqs)
+    monkeypatch.setattr(kda, "core_is_kernel", lambda *a: True)
+    monkeypatch.setattr(kda, "_kernel_tiles", lambda *a: 2)
+    ran = []
+    real = kda._kernel_scan
+    monkeypatch.setattr(kda, "_kernel_scan",
+                        lambda *a: ran.append(a[0].shape) or real(*a))
+    engine = build()
+    _, a = _traced_finalize(engine, seqs)
+    assert (a["kda_layers"], a["kda_kernel_layers"]) == (6, 6)
+    assert ran and len(ran) % 6 == 0
+    np.testing.assert_allclose(engine.embed_ids_batch(seqs), want,
+                               rtol=2e-4, atol=2e-5)
 
 
 def test_a_document_past_the_cache_is_refused(engine):

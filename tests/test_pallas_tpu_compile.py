@@ -196,21 +196,48 @@ def test_the_latent_kernel_compiles_at_the_long_caches(
     assert "conditional" not in text
 
 
-# ops/kda.py::kda_scan at the same cell's shapes: plain XLA (no Mosaic
-# call), and the chip's compiler takes a full program's temporaries
-@pytest.mark.parametrize("rows", [16, 2])
-def test_the_delta_rule_recurrence_compiles_at_the_cells_shapes(
-        one_chip, rows):
+# ops/kda.py::kda_scan at the same cell's shapes (32 heads of 128 | 128,
+# chunks of 64 in sub-blocks of 16, float32 operands as the encoder holds
+# them, bfloat16 in-chunk products), at the rows its programs narrow to
+def _kda_compiled(one_chip, rows, mxu_dtype=jnp.bfloat16):
     from code_intelligence_tpu.ops.kda import kda_scan
 
     T, H, d = 512, 32, 128
     shapes = [(rows, T, H, d)] * 4 + [(rows, T, H), (rows, H, d, d)]
     args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
             for s in shapes]
-    compiled = jax.jit(lambda *a: kda_scan(
-        *a, chunk=64, mxu_dtype=jnp.bfloat16)).lower(*args).compile()
+    return jax.jit(lambda *a: kda_scan(
+        *a, chunk=64, mxu_dtype=mxu_dtype)).lower(*args).compile()
+
+
+# the kernel: Mosaic takes the strided loads of a head's chunk, the rolls,
+# the float32 products and the blocks' VMEM; nothing is copied around it
+# (the `(b, T * H, d)` view of the operands is the same bytes)
+@pytest.mark.parametrize("rows", [16, 2])
+def test_the_delta_rule_kernel_compiles_at_the_cells_shapes(
+        one_chip, monkeypatch, rows):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = _kda_compiled(one_chip, rows)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "kda_scan_core" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1024 ** 2
+
+
+# the XLA scan, what the rule picks off the TPU (here) and for float32:
+# no Mosaic call, and the chip's compiler takes a full program's
+# temporaries
+@pytest.mark.parametrize("rows", [16, 2])
+def test_the_delta_rule_recurrence_compiles_at_the_cells_shapes(
+        one_chip, rows):
+    compiled = _kda_compiled(one_chip, rows)
     assert "tpu_custom_call" not in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 1024 ** 3
+
+
+def test_the_delta_rule_stays_on_xla_in_float32(one_chip, monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = _kda_compiled(one_chip, 2, jnp.float32)
+    assert "tpu_custom_call" not in compiled.as_text()
 
 
 # where the rule says XLA no Mosaic call appears: the single-chunk groups
