@@ -22,9 +22,14 @@ from code_intelligence_tpu.models.granite_hybrid import (
     GraniteHybridConfig,
     GraniteHybridEncoder,
 )
+from code_intelligence_tpu.models.smallthinker import (
+    SmallThinkerConfig,
+    SmallThinkerEncoder,
+)
 
 __all__ = ["AfmoeConfig", "AfmoeEncoder", "AWDLSTMConfig", "AWDLSTMEncoder", "AWDLSTMLM", "init_lstm_states",
            "BailingHybridConfig", "BailingHybridEncoder",
            "ChunkEncoder", "build_encoder", "make_config",
            "DeepseekV3Config", "DeepseekV3Encoder",
-           "GraniteHybridConfig", "GraniteHybridEncoder"]
+           "GraniteHybridConfig", "GraniteHybridEncoder",
+           "SmallThinkerConfig", "SmallThinkerEncoder"]

@@ -69,6 +69,8 @@ import jax.numpy as jnp
 
 from code_intelligence_tpu.models.deepseek_v3 import share_of
 from code_intelligence_tpu.models.granite_hybrid import _matmul, _rms_norm
+from code_intelligence_tpu.models.windowed_caches import (
+    WindowedCaches, ring_positions)
 from code_intelligence_tpu.ops import attention, mla, moe
 
 SLIDING, FULL = "sliding_attention", "full_attention"
@@ -155,18 +157,25 @@ class AfmoeConfig:
 
     @property
     def ring_positions(self) -> int:
-        """Slots of a sliding layer's ring: the whole chunks that hold
-        what a chunk's queries can see of the chunks before, and the
-        chunk itself."""
-        chunk = self.chunk_positions
-        return chunk * (-(-self.sliding_window // chunk) + 1)
+        """Slots of a sliding layer's ring."""
+        return ring_positions(self.sliding_window, self.chunk_positions)
+
+    @property
+    def sliding_layers(self) -> Tuple[bool, ...]:
+        return tuple(kind == SLIDING for kind in self.layer_types)
 
     def count(self, kind: str) -> int:
         return sum(t == kind for t in self.layer_types)
 
 
-class AfmoeEncoder:
-    """The encoder contract (`models/contract.py`) over AFMoE."""
+class AfmoeEncoder(WindowedCaches):
+    """The encoder contract (`models/contract.py`) over AFMoE; its two
+    kinds of caches and their arithmetic (``cache_positions``,
+    ``window_positions``, ``init_states``, ``state_bytes_per_row``) are
+    `models/windowed_caches.py`'s."""
+
+    # ``ops/moe.py::COUNTERS`` and the attention layers on the Pallas core
+    n_counts = len(moe.COUNTERS) + 1
 
     def __init__(self, config: AfmoeConfig, dtype=jnp.bfloat16):
         self.config = config
@@ -179,63 +188,6 @@ class AfmoeEncoder:
     @property
     def out_dim(self) -> int:
         return self.config.hidden_size
-
-    def cache_positions(self, positions=None) -> int:
-        """Positions a full layer's cache is allocated at for documents
-        of up to ``positions`` tokens: their own length where one chunk
-        holds them, else the smallest of ``kv_positions`` halved that
-        does, so that the groups of a call compile a few cache sizes and
-        not one a length."""
-        cfg = self.config
-        if positions is None:
-            return cfg.kv_positions
-        if positions > cfg.kv_positions:
-            raise ValueError(
-                f"a document of {positions} positions does not fit the "
-                f"key/value cache of kv_positions={cfg.kv_positions}")
-        if positions <= cfg.chunk_positions:
-            return positions
-        size = cfg.kv_positions
-        while size % 2 == 0 and size // 2 >= positions:
-            size //= 2
-        return size
-
-    def window_positions(self, positions=None) -> int:
-        """Slots a sliding layer's ring is allocated: the full layers'
-        allocation until that passes ``sliding_window`` + one chunk, the
-        ring's length from there on."""
-        if not self.config.count(SLIDING):
-            return 0
-        return min(self.cache_positions(positions),
-                   self.config.ring_positions)
-
-    def _slots(self, positions):
-        """Each layer's cache length."""
-        full, ring = (self.cache_positions(positions),
-                      self.window_positions(positions))
-        return [ring if kind == SLIDING else full
-                for kind in self.config.layer_types]
-
-    def init_states(self, batch: int, positions=None):
-        cfg = self.config
-
-        def caches():
-            # head-major, as ``ops/attention.py`` reads them
-            return tuple(jnp.zeros(
-                (batch, cfg.num_key_value_heads, slots, cfg.head_dim),
-                cfg.state_dtype) for slots in self._slots(positions))
-
-        return {"k": caches(), "v": caches(),
-                "pos": jnp.zeros((), jnp.int32),
-                "counts": jnp.zeros((len(moe.COUNTERS) + 1,), jnp.int32)}
-
-    def state_bytes_per_row(self, max_len=None) -> int:
-        """Bytes of keys and values one row holds for a document of
-        ``max_len`` tokens: the full layers' part grows with it, the
-        sliding layers' stops at the ring."""
-        cfg = self.config
-        return sum(self._slots(max_len)) * 2 * cfg.num_key_value_heads \
-            * cfg.head_dim * cfg.state_dtype.itemsize
 
     def state_counters(self, states):
         """The counts the expert layers have kept since ``init_states``

@@ -63,6 +63,8 @@ from code_intelligence_tpu.models.deepseek_v3 import (
     DeepseekV3Config, DeepseekV3Encoder)
 from code_intelligence_tpu.models.granite_hybrid import (
     GraniteHybridConfig, GraniteHybridEncoder)
+from code_intelligence_tpu.models.smallthinker import (
+    SmallThinkerConfig, SmallThinkerEncoder)
 
 
 @runtime_checkable
@@ -119,6 +121,9 @@ ENCODERS = {
     BailingHybridConfig.architecture: (
         BailingHybridConfig, BailingHybridConfig.from_dict,
         _in_weights_dtype(BailingHybridEncoder)),
+    SmallThinkerConfig.architecture: (
+        SmallThinkerConfig, SmallThinkerConfig.from_dict,
+        _in_weights_dtype(SmallThinkerEncoder)),
 }
 
 

@@ -15,7 +15,16 @@ balances load and moves nothing else): experts lie in ``n_group`` groups,
 a group's score is the sum of its two best, the best ``topk_group``
 groups are kept, and the ``top_k`` best experts among them are chosen;
 the weights are the UNBIASED scores of the chosen, normalised to sum 1
-and multiplied by ``scaling``.
+and multiplied by ``scaling``. With ``score_func="softmax"`` it is the
+plain softmax router instead: the choice is made on the logits and the
+weights are the softmax over the chosen logits.
+
+**Routing and applying are two calls.** ``routed_experts`` takes a
+router's choice and the tensor the experts READ, so a model whose router
+reads another tensor than its experts (the layer's input, before
+attention) calls ``route`` there; it may make ``assign``'s sort there
+too and hand it in (``assigned``). ``expert_layer`` is the two on one
+tensor.
 
 ``routed_experts`` runs the held experts as ONE grouped matmul over the
 rows routed to them (``jax.lax.ragged_dot``: on the TPU a kernel whose
@@ -26,7 +35,7 @@ tokens' number) at a time for as many rounds as they need (one, unless
 more than ``N`` assignments land here: ``top_k * count / n_experts`` of
 a token's choices do on average).
 
-``expert_layer`` is the whole block as an encoder calls it (router, the
+``expert_layer`` is the whole block as most encoders call it (router, the
 held experts' part, the shared expert), and ``COUNTERS`` /
 ``counter_attrs`` what such encoders count on the device and how the
 counts become span attributes: one copy for every model with routed
@@ -45,22 +54,30 @@ from jax import lax
 def route(
     h: jnp.ndarray,         # (N, E) float32
     w_router: jnp.ndarray,  # (E, n_experts)
-    bias: jnp.ndarray,      # (n_experts,) float32: e_score_correction_bias
+    bias: Optional[jnp.ndarray],  # (n_experts,) float32, or None
     n_group: int,
     topk_group: int,
     top_k: int,
     scaling: float,
     norm_topk_prob: bool = True,
+    score_func: str = "sigmoid",
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """``(experts (N, top_k) int32, weights (N, top_k) float32)``. The
     published router multiplies in float32: on the TPU that takes
-    ``Precision.HIGHEST`` (the default rounds the inputs to bfloat16)."""
+    ``Precision.HIGHEST`` (the default rounds the inputs to bfloat16).
+    ``score_func`` ``"sigmoid"``: the module docstring's router;
+    ``"softmax"``: the choice is made on the logits (+ ``bias``, where
+    there is one) and the weights are the softmax over the CHOSEN logits
+    (= the softmax over all, taken at the chosen and normalised:
+    ``norm_topk_prob`` holds by construction)."""
+    if score_func not in ("sigmoid", "softmax"):
+        raise ValueError(f"score_func {score_func!r}: 'sigmoid' or 'softmax'")
     logits = jnp.dot(
         h.astype(jnp.float32), w_router.astype(jnp.float32),
         precision=lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
-    scores = jax.nn.sigmoid(logits)
-    choice = scores + bias.astype(jnp.float32)
+    scores = jax.nn.sigmoid(logits) if score_func == "sigmoid" else logits
+    choice = scores if bias is None else scores + bias.astype(jnp.float32)
     N, n_experts = choice.shape
     if n_group > 1:
         per = choice.reshape(N, n_group, n_experts // n_group)
@@ -72,7 +89,9 @@ def route(
             N, n_experts)
     _, experts = lax.top_k(choice, top_k)
     weights = jnp.take_along_axis(scores, experts, axis=-1)
-    if norm_topk_prob and top_k > 1:
+    if score_func == "softmax":
+        weights = jax.nn.softmax(weights, axis=-1)
+    elif norm_topk_prob and top_k > 1:
         weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
     return experts.astype(jnp.int32), weights * scaling
 
@@ -106,25 +125,36 @@ def assign(experts: jnp.ndarray, first: int, count: int,
     return order, rows
 
 
+# the gate's activation of the routed experts: SwiGLU's and ReGLU's
+_GATE_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
 def routed_experts(
-    x: jnp.ndarray,        # (N, E)
+    x: jnp.ndarray,        # (N, E): what the experts read
     experts: jnp.ndarray,  # (N, top_k) int32, over all n_experts
     weights: jnp.ndarray,  # (N, top_k) float32
     w_in: jnp.ndarray,     # (count, E, 2 * F): [gate | up] of held experts
     w_out: jnp.ndarray,    # (count, F, E)
     first: int,
     valid: Optional[jnp.ndarray] = None,  # (N,) bool
+    act: str = "silu",
+    assigned: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """``(y (N, E) float32, rows (count,) int32)``: the held experts'
     part of every token's weighted sum, and the rows each held expert
-    ran. Named scopes: ``dispatch`` (the sort, each round's gather),
-    ``experts`` (the grouped matmuls), ``combine`` (weigh, add back)."""
+    ran. ``act`` is the gate's activation (``"silu"`` SwiGLU, ``"relu"``
+    ReGLU); ``assigned`` is ``assign(experts, first, count, valid)``
+    where the caller made it already (beside its router, ahead of what
+    ``x`` waits for). Named scopes: ``dispatch`` (the sort, each round's
+    gather), ``experts`` (the grouped matmuls), ``combine`` (weigh, add
+    back)."""
     N, E = x.shape
     top_k = experts.shape[1]
     count = w_in.shape[0]
     dtype = w_in.dtype
     with jax.named_scope("dispatch"):
-        order, per_expert = assign(experts, first, count, valid)
+        order, per_expert = assigned if assigned is not None else assign(
+            experts, first, count, valid)
         ends = jnp.cumsum(per_expert)
         total = ends[-1]
         # padded so that every round slices N whole entries
@@ -143,8 +173,9 @@ def routed_experts(
         with jax.named_scope("experts"):
             g, u = jnp.split(lax.ragged_dot(
                 xs, w_in, sizes, preferred_element_type=dtype), 2, axis=-1)
-            act = jax.nn.silu(g.astype(jnp.float32)) * u.astype(jnp.float32)
-            out = lax.ragged_dot(act.astype(dtype), w_out, sizes,
+            gated = _GATE_ACTS[act](g.astype(jnp.float32)) \
+                * u.astype(jnp.float32)
+            out = lax.ragged_dot(gated.astype(dtype), w_out, sizes,
                                  preferred_element_type=jnp.float32)
         with jax.named_scope("combine"):
             # rows past the round's last assignment hold whatever the
@@ -174,7 +205,8 @@ def expert_layer(
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """One expert layer over the flat tokens ``u``: ``(the held experts'
     share + the shared expert (N, E) float32, rows each held expert
-    ran)``. Named scopes ``router``, ``routed_experts``'s three, and
+    ran)``: ``route`` and ``routed_experts`` on one tensor. Named
+    scopes ``router``, ``routed_experts``'s three, and
     ``shared_expert``."""
     with jax.named_scope("router"):
         experts, weights = route(
