@@ -15,6 +15,7 @@ its spans; the contract's numbers at the published widths.
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +34,7 @@ from code_intelligence_tpu.ops import attention
 from code_intelligence_tpu.ops.attention import gqa_cached
 from code_intelligence_tpu.text import SPECIALS, Vocab
 from code_intelligence_tpu.utils import tracing
+from encoder_programs import compiled, seeded
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 MODEL = {
@@ -56,7 +58,7 @@ T_DOC = 40   # five windows: a ring of 8 + 4 slots wraps three times
 
 @pytest.fixture(scope="module")
 def params():
-    return ref.init_params(jax.random.PRNGKey(32), MODEL, TAILS)
+    return seeded(ref, 32, MODEL, TAILS)
 
 
 def config(**extra):
@@ -93,7 +95,7 @@ def want(params, tokens):
 def streamed(enc, params, tokens, chunk=4, between=None):
     """``tokens`` through ``enc`` in chunk programs of ``chunk``."""
     states = enc.init_states(tokens.shape[0], tokens.shape[1])
-    step = jax.jit(enc.encode)
+    step = compiled(enc)
     outs = []
     for lo in range(0, tokens.shape[1], chunk):
         out, states = step(params, tokens[:, lo:lo + chunk], states)
@@ -117,21 +119,34 @@ def _dense(q, k, v, scale, window=None):
                       jnp.repeat(v, rep, axis=2))
 
 
+@functools.partial(jax.jit, static_argnames=("total", "seed", "b", "Hq",
+                                              "Hkv", "d"))
 def _qkv(total, seed=0, b=2, Hq=4, Hkv=2, d=8):
+    """One compiled program a shape: drawn op by op, every ``normal`` of
+    a new shape is a compilation of its own."""
     k = jax.random.split(jax.random.PRNGKey(seed + total), 3)
     return (jax.random.normal(k[0], (b, total, Hq, d)),
             jax.random.normal(k[1], (b, total, Hkv, d)),
             jax.random.normal(k[2], (b, total, Hkv, d)))
 
 
-def _through_the_cache(q, k, v, S, T, dtype=jnp.float32, **kw):
+def _through_the_cache(q, k, v, S, T, dtype=jnp.float32, jitted=True,
+                       **kw):
+    """ONE compiled program for the chunks (``pos`` is traced, as the
+    encoders hand it over); ``jitted=False`` for the test that reads
+    the loops' concrete bounds."""
     b, total, _, d = q.shape
     kc = vc = jnp.zeros((b, k.shape[2], S, d), dtype)     # head-major
+
+    def step(q, k, v, kc, vc, pos):
+        return gqa_cached(q, k, v, kc, vc, pos, 0.3, mxu_dtype=dtype, **kw)
+
+    if jitted:
+        step = jax.jit(step)
     outs = []
     for lo in range(0, total, T):
-        out, kc, vc = gqa_cached(
-            q[:, lo:lo + T], k[:, lo:lo + T], v[:, lo:lo + T], kc, vc,
-            jnp.int32(lo), 0.3, mxu_dtype=dtype, **kw)
+        out, kc, vc = step(q[:, lo:lo + T], k[:, lo:lo + T], v[:, lo:lo + T],
+                           kc, vc, jnp.int32(lo))
         outs.append(out)
     return jnp.concatenate(outs, 1)
 
@@ -242,10 +257,12 @@ def test_the_core_meets_only_the_key_blocks_reached(monkeypatch):
 
     monkeypatch.setattr(lax, "fori_loop", counting)
     q, k, v = _qkv(32)
-    _through_the_cache(q, k, v, 64, 8, key_block=8, q_block=128)
+    _through_the_cache(q, k, v, 64, 8, jitted=False, key_block=8,
+                       q_block=128)
     assert trips == [1, 2, 3, 4]
     del trips[:]
-    _through_the_cache(q, k, v, 16, 8, window=8, key_block=8, q_block=128)
+    _through_the_cache(q, k, v, 16, 8, jitted=False, window=8,
+                       key_block=8, q_block=128)
     assert trips == [1, 2, 2, 2]
 
 
@@ -271,8 +288,7 @@ def test_encoder_equals_the_reference(params, tokens, want):
     """The whole document as ONE chunk: every sliding layer masks five
     windows' worth of keys."""
     enc = build_encoder(config(chunk_positions=T_DOC), params)
-    got, states = jax.jit(enc.encode)(params, tokens,
-                                      enc.init_states(3, T_DOC))
+    got, states = compiled(enc)(params, tokens, enc.init_states(3, T_DOC))
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
     assert int(states["pos"]) == T_DOC
 
@@ -327,14 +343,13 @@ def test_rotary_on_the_global_layer_is_seen(params, tokens):
     both a window no document reaches). The two references differ, and
     the program is equal to the published one."""
     wide = dict(MODEL, sliding_window=1 << 20)
-    with jax.default_matmul_precision("highest"):
-        plain = ref.encode(params, tokens, wide)[0]
-        rotated = ref.encode(params, tokens,
-                             dict(wide, layer_types=[SLIDING] * 5))[0]
+    plain = reference(params, tokens, wide)[0]
+    rotated = reference(params, tokens,
+                        dict(wide, layer_types=[SLIDING] * 5))[0]
     assert float(jnp.abs(rotated - plain).max()) > 0.05
     enc = build_encoder(config(sliding_window=1 << 20,
                                chunk_positions=T_DOC), params)
-    got, _ = jax.jit(enc.encode)(params, tokens, enc.init_states(3, T_DOC))
+    got, _ = compiled(enc)(params, tokens, enc.init_states(3, T_DOC))
     np.testing.assert_allclose(got, plain, rtol=2e-5, atol=2e-5)
 
 
@@ -345,7 +360,7 @@ def test_the_gate_the_norms_and_the_multiplier_are_in_the_comparison(
     enc = build_encoder(config(chunk_positions=T_DOC), params)
 
     def run(p):
-        return jax.jit(enc.encode)(p, tokens, enc.init_states(3, T_DOC))[0]
+        return compiled(enc)(p, tokens, enc.init_states(3, T_DOC))[0]
 
     def with_layer(i, **leaves):
         layers = dict(params["layers"])
@@ -361,7 +376,7 @@ def test_the_gate_the_norms_and_the_multiplier_are_in_the_comparison(
         assert float(jnp.abs(run(changed) - want).max()) > 0.01
     flat = build_encoder(config(chunk_positions=T_DOC, mup_enabled=False),
                          params)
-    got = jax.jit(flat.encode)(params, tokens, flat.init_states(3, T_DOC))[0]
+    got = compiled(flat)(params, tokens, flat.init_states(3, T_DOC))[0]
     assert float(jnp.abs(got - want).max()) > 0.01
 
 
@@ -371,22 +386,23 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
     """One expert layer, 16 experts: the routed parts of the eight
     shares of 2 (and of the two of 8) summed, plus the shared expert
     ONCE, equal the uncut reference's whole layer."""
-    whole = ref.init_params(jax.random.PRNGKey(4), UNCUT,
-                            TAILS)["layers"]["layer_1"]
+    whole = seeded(ref, 4, UNCUT, TAILS, layer="layer_1")
     x = jax.random.normal(jax.random.PRNGKey(5), (40, 64))
     with jax.default_matmul_precision("highest"):
-        want, chosen = ref.moe_layer(whole, x, UNCUT)
+        want, chosen = jax.jit(lambda p, x: ref.moe_layer(p, x, UNCUT))(
+            whole, x)
         shared = ref.swiglu(x, whole["shared_in"], whole["shared_out"])
+    # ``first`` is traced: one program a share's size, not one a share
+    share = jax.jit(lambda held, first: moe.expert_layer(
+        held, x, None, jnp.float32, n_group=1, topk_group=1, top_k=4,
+        scaling=2.448, norm_topk_prob=True, first=first, shared=False))
     for count in (2, 8):
         total, rows = shared, 0
         for first in range(0, 16, count):
             held = dict(whole, experts_in=whole["experts_in"][
                 first:first + count], experts_out=whole["experts_out"][
                 first:first + count])
-            part, per_expert = moe.expert_layer(
-                held, x, None, jnp.float32, n_group=1, topk_group=1,
-                top_k=4, scaling=2.448, norm_topk_prob=True, first=first,
-                shared=False)
+            part, per_expert = share(held, jnp.int32(first))
             total = total + part
             rows += int(per_expert.sum())
         assert rows == 40 * 4          # every choice lands on one share
@@ -692,8 +708,7 @@ def test_export_round_trip_in_bfloat16(tmp_path, vocab):
     from code_intelligence_tpu.training.checkpoint import export_encoder
 
     cfg = make_config("afmoe", MODEL, kv_positions=64, chunk_positions=8)
-    weights = ref.init_params(jax.random.PRNGKey(1), MODEL,
-                              dtype=jnp.bfloat16)
+    weights = seeded(ref, 1, MODEL, dtype=jnp.bfloat16)
     export_encoder(tmp_path, weights, cfg, vocab)
     eng = InferenceEngine.from_export(tmp_path, buckets=(8,), batch_size=2)
     assert eng.config == cfg and eng.encoder.dtype == jnp.bfloat16

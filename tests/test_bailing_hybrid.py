@@ -16,6 +16,7 @@ published widths.
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -35,6 +36,7 @@ from code_intelligence_tpu.ops import kda, mla, moe
 from code_intelligence_tpu.ops.ssd import causal_conv1d
 from code_intelligence_tpu.text import SPECIALS, Vocab
 from code_intelligence_tpu.utils import tracing
+from encoder_programs import compiled, seeded
 
 MODEL = {
     "vocab_size": 300, "hidden_size": 64, "intermediate_size": 96,
@@ -62,7 +64,7 @@ T_DOC = 200   # four chunks of the recurrence (64), the last one short
 
 @pytest.fixture(scope="module")
 def params():
-    return ref.init_params(jax.random.PRNGKey(36), MODEL, TAILS)
+    return seeded(ref, 36, MODEL, TAILS)
 
 
 def config(**extra):
@@ -107,8 +109,8 @@ def streamed(enc, params, tokens, programs, between=None):
         n = chunk.shape[1]
         chunk = jnp.pad(chunk, ((0, 0), (0, size - n)))
         with jax.default_matmul_precision("highest"):
-            out, states = enc.encode(params, chunk, states,
-                                     lengths=jnp.full((b,), n, jnp.int32))
+            out, states = compiled(enc)(
+                params, chunk, states, lengths=jnp.full((b,), n, jnp.int32))
         if between is not None:
             states = between(states)
         outs.append(out[:, :n])
@@ -117,7 +119,12 @@ def streamed(enc, params, tokens, programs, between=None):
 
 # -- the recurrence ---------------------------------------------------------------
 
+@functools.partial(jax.jit, static_argnames=("b", "T", "H", "dk", "dv",
+                                              "at_bound"))
 def kda_inputs(seed, b, T, H=3, dk=16, dv=8, at_bound=False):
+    """One compiled program a shape (`tests/test_kda_kernel.py` draws
+    from it too): drawn op by op, every ``normal`` of a new shape is a
+    compilation of its own."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
     q = jax.random.normal(ks[0], (b, T, H, dk))
     k = jax.random.normal(ks[1], (b, T, H, dk))
@@ -131,6 +138,9 @@ def kda_inputs(seed, b, T, H=3, dk=16, dv=8, at_bound=False):
     return q, k, v, g, beta, jax.random.normal(ks[5], (b, H, dk, dv))
 
 
+recurrence = jax.jit(kda.kda_recurrence)
+
+
 @pytest.mark.parametrize("T,chunk,sub", [
     (128, 64, 16),    # chunks divide T
     (100, 64, 16),    # the last chunk is padded
@@ -139,8 +149,9 @@ def kda_inputs(seed, b, T, H=3, dk=16, dv=8, at_bound=False):
 ])
 def test_chunked_equals_token_by_token_with_state_in(T, chunk, sub):
     inputs = kda_inputs(T, 2, T)
-    o, S = kda.kda_scan(*inputs, chunk=chunk, mxu_dtype=jnp.float32, sub=sub)
-    o_want, S_want = kda.kda_recurrence(*inputs)
+    o, S = jax.jit(functools.partial(
+        kda.kda_scan, chunk=chunk, mxu_dtype=jnp.float32, sub=sub))(*inputs)
+    o_want, S_want = recurrence(*inputs)
     # float32 sums in another order; outputs are O(0.3), states O(1)
     np.testing.assert_allclose(o, o_want, atol=5e-6)
     np.testing.assert_allclose(S, S_want, atol=5e-6)
@@ -153,9 +164,9 @@ def test_gates_at_the_lower_bound_over_a_whole_chunk_stay_finite(dtype, atol):
     a chunk, ``e^{320}`` is not a float32; the sub-blocks keep every
     ``exp`` within ``e^{+-40}``."""
     inputs = kda_inputs(7, 1, 128, at_bound=True)
-    o, S = kda.kda_scan(*inputs, mxu_dtype=dtype)
+    o, S = jax.jit(functools.partial(kda.kda_scan, mxu_dtype=dtype))(*inputs)
     assert bool(jnp.isfinite(o).all()) and bool(jnp.isfinite(S).all())
-    o_want, S_want = kda.kda_recurrence(*inputs)
+    o_want, S_want = recurrence(*inputs)
     np.testing.assert_allclose(o, o_want, atol=atol)
     np.testing.assert_allclose(S, S_want, atol=atol)
 
@@ -183,9 +194,9 @@ def test_repeated_keys_do_not_cancel_in_the_solve():
     product loses float32 there); forward substitution is exact."""
     q, k, v, g, beta, S = kda_inputs(9, 1, 128)
     k = jnp.broadcast_to(k[:, :1], k.shape)
-    o, S1 = kda.kda_scan(q, k, v, jnp.zeros_like(g), jnp.ones_like(beta), S,
-                         mxu_dtype=jnp.float32)
-    o_want, S_want = kda.kda_recurrence(
+    o, S1 = jax.jit(functools.partial(kda.kda_scan, mxu_dtype=jnp.float32))(
+        q, k, v, jnp.zeros_like(g), jnp.ones_like(beta), S)
+    o_want, S_want = recurrence(
         q, k, v, jnp.zeros_like(g), jnp.ones_like(beta), S)
     np.testing.assert_allclose(o, o_want, atol=5e-6)
     np.testing.assert_allclose(S1, S_want, atol=5e-6)
@@ -220,8 +231,8 @@ def test_encoder_equals_the_reference(params, encoder, tokens, want):
     against token by token, a cache against a dense softmax, a grouped
     matmul against a masked loop); values are O(5)."""
     with jax.default_matmul_precision("highest"):
-        got, states = encoder.encode(params, tokens,
-                                     encoder.init_states(2, T_DOC))
+        got, states = compiled(encoder)(params, tokens,
+                                        encoder.init_states(2, T_DOC))
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=5e-5)
     assert int(states["pos"]) == T_DOC
 
@@ -241,14 +252,14 @@ def test_padding_lanes_leave_state_and_tails_as_they_were(
     """A program of padding alone (``lengths`` 0) after a real one: the
     matrix states and the conv tails come back bit for bit; a row with 3
     valid tokens of 8 ends where the same 3 tokens alone end."""
+    step = compiled(encoder)
     with jax.default_matmul_precision("highest"):
-        _, before = encoder.encode(params, tokens[:, :64],
-                                   encoder.init_states(2, 256))
-        _, after = encoder.encode(params, tokens[:, 64:72], before,
-                                  lengths=jnp.zeros((2,), jnp.int32))
-        _, part = encoder.encode(params, tokens[:, 64:72], before,
-                                 lengths=jnp.full((2,), 3, jnp.int32))
-        _, alone = encoder.encode(params, tokens[:, 64:67], before)
+        _, before = step(params, tokens[:, :64], encoder.init_states(2, 256))
+        _, after = step(params, tokens[:, 64:72], before,
+                        lengths=jnp.zeros((2,), jnp.int32))
+        _, part = step(params, tokens[:, 64:72], before,
+                       lengths=jnp.full((2,), 3, jnp.int32))
+        _, alone = step(params, tokens[:, 64:67], before)
     for kind in ("kda", "conv"):
         for a, b in zip(before[kind], after[kind]):
             np.testing.assert_array_equal(a, b)
@@ -282,8 +293,7 @@ def test_bfloat16_program_against_the_float32_reference(tokens):
     branches and four hand-overs of the state. Relative RMS error, as
     the benchmark's check reads it: measured 1.8 %."""
     dense = dict(MODEL, first_k_dense_replace=7)
-    weights = ref.init_params(jax.random.PRNGKey(36), dense, TAILS,
-                              dtype=jnp.bfloat16)
+    weights = seeded(ref, 36, dense, TAILS, jnp.bfloat16)
     enc = build_encoder(make_config(
         "bailing_hybrid", dense, kv_positions=256), weights)
     assert enc.dtype == enc.config.state_dtype == jnp.bfloat16
@@ -300,26 +310,25 @@ def test_the_latent_block_without_a_low_rank_query_is_a_whole_softmax(params):
     p = {k: v for k, v in params["layers"]["layer_5"].items()}
     h = jax.random.normal(jax.random.PRNGKey(2), (2, 48, 64))
     eps = MODEL["rms_norm_eps"]
+    # one program for the three chunks (``pos`` is traced), one ungated
+    block = jax.jit(lambda h, cache, pos, head_gate: latent_block(
+        p, h, cache, pos, jnp.float32, heads=4, nope=16, rope=8, v_dim=16,
+        rank=32, eps=eps, inv_freq=mla.yarn_inv_freq(8, 6000000.0),
+        rope_factor=1.0, scale=24 ** -0.5, q_low_rank=False,
+        head_gate=head_gate), static_argnums=3)
     with jax.default_matmul_precision("highest"):
-        want = ref.latent_attention(
-            p, ref.rms_norm(h, p["norm"], eps), MODEL)
+        want = jax.jit(lambda h: ref.latent_attention(
+            p, ref.rms_norm(h, p["norm"], eps), MODEL))(h)
         cache = jnp.zeros((2, 64, 40))
         outs = []
         for a in range(0, 48, 16):
-            out, cache = latent_block(
-                p, h[:, a:a + 16], cache, jnp.int32(a), jnp.float32,
-                heads=4, nope=16, rope=8, v_dim=16, rank=32, eps=eps,
-                inv_freq=mla.yarn_inv_freq(8, 6000000.0), rope_factor=1.0,
-                scale=24 ** -0.5, q_low_rank=False, head_gate=True)
+            out, cache = block(h[:, a:a + 16], cache, jnp.int32(a), True)
             outs.append(out)
     np.testing.assert_allclose(jnp.concatenate(outs, 1), want, atol=2e-5)
     # the gate is in the comparison
     with jax.default_matmul_precision("highest"):
-        ungated, _ = latent_block(
-            p, h[:, :16], jnp.zeros((2, 64, 40)), jnp.int32(0), jnp.float32,
-            heads=4, nope=16, rope=8, v_dim=16, rank=32, eps=eps,
-            inv_freq=mla.yarn_inv_freq(8, 6000000.0), rope_factor=1.0,
-            scale=24 ** -0.5, q_low_rank=False, head_gate=False)
+        ungated, _ = block(h[:, :16], jnp.zeros((2, 64, 40)), jnp.int32(0),
+                           False)
     assert float(jnp.abs(ungated - want[:, :16]).max()) > 1e-2
 
 
@@ -338,8 +347,9 @@ def test_the_mechanisms_are_in_the_comparison(monkeypatch, params, tokens,
     for broken in (no_decay, no_norm):
         monkeypatch.setattr(kda, "kda_scan", broken)
         enc = build_encoder(config(), params)
-        with jax.default_matmul_precision("highest"):
-            got, _ = enc.encode(params, tokens, enc.init_states(2, T_DOC))
+        with jax.default_matmul_precision("highest"):   # traced patched
+            got, _ = compiled(enc)(params, tokens,
+                                   enc.init_states(2, T_DOC))
         monkeypatch.undo()
         assert _differs(got, want, 8) > 1e-2, broken.__name__
 
@@ -351,22 +361,23 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
     four shares of 4 (one whole routing group each; and of the two of 8)
     summed, plus the shared expert ONCE, equal the uncut reference's
     whole layer."""
-    whole = ref.init_params(jax.random.PRNGKey(4), UNCUT,
-                            TAILS)["layers"]["layer_1"]
+    whole = seeded(ref, 4, UNCUT, TAILS, layer="layer_1")
     x = jax.random.normal(jax.random.PRNGKey(5), (40, 64))
     with jax.default_matmul_precision("highest"):
-        want, chosen = ref.moe_layer(whole, x, UNCUT)
+        want, chosen = jax.jit(lambda p, x: ref.moe_layer(p, x, UNCUT))(
+            whole, x)
         shared = ref.swiglu(x, whole["shared_in"], whole["shared_out"])
+    # ``first`` is traced: one program a share's size, not one a share
+    share = jax.jit(lambda held, first: moe.expert_layer(
+        held, x, None, jnp.float32, n_group=4, topk_group=2, top_k=4,
+        scaling=2.5, norm_topk_prob=True, first=first, shared=False))
     for count in (4, 8):
         total, rows = shared, 0
         for first in range(0, 16, count):
             held = dict(whole, experts_in=whole["experts_in"][
                 first:first + count], experts_out=whole["experts_out"][
                 first:first + count])
-            part, per_expert = moe.expert_layer(
-                held, x, None, jnp.float32, n_group=4, topk_group=2,
-                top_k=4, scaling=2.5, norm_topk_prob=True, first=first,
-                shared=False)
+            part, per_expert = share(held, jnp.int32(first))
             total = total + part
             rows += int(per_expert.sum())
         assert rows == 40 * 4          # every choice lands on one share
@@ -606,8 +617,7 @@ def test_export_round_trip_in_bfloat16(tmp_path, vocab):
     from code_intelligence_tpu.training.checkpoint import export_encoder
 
     cfg = make_config("bailing_hybrid", MODEL, kv_positions=64)
-    weights = ref.init_params(jax.random.PRNGKey(1), MODEL,
-                              dtype=jnp.bfloat16)
+    weights = seeded(ref, 1, MODEL, dtype=jnp.bfloat16)
     export_encoder(tmp_path, weights, cfg, vocab)
     eng = InferenceEngine.from_export(tmp_path, buckets=(8,), batch_size=2)
     assert eng.config == cfg and eng.encoder.dtype == jnp.bfloat16

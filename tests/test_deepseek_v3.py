@@ -14,6 +14,7 @@ reference's and the bfloat16 flips counted; the contract's numbers.
 """
 
 import dataclasses
+import functools
 import math
 
 import jax
@@ -32,6 +33,7 @@ from code_intelligence_tpu.models import contract
 from code_intelligence_tpu.ops import mla, moe
 from code_intelligence_tpu.text import SPECIALS, Vocab
 from code_intelligence_tpu.utils import tracing
+from encoder_programs import compiled, seeded
 
 YARN = {"beta_fast": 32, "beta_slow": 1, "factor": 40, "mscale": 1,
         "mscale_all_dim": 1, "original_max_position_embeddings": 4096,
@@ -55,7 +57,7 @@ TAILS = {"dist": "student_t", "df": 4}
 
 @pytest.fixture(scope="module")
 def params():
-    return ref.init_params(jax.random.PRNGKey(30), MODEL, TAILS)
+    return seeded(ref, 30, MODEL, TAILS)
 
 
 def config(**extra):
@@ -126,7 +128,11 @@ def test_rope_follows_the_published_pairing():
                                rtol=1e-5, atol=1e-6)
 
 
+@functools.partial(jax.jit, static_argnames=(
+    "b", "T", "S", "H", "rank", "nope", "rope", "v"))
 def _core_inputs(b=2, T=16, S=32, H=4, rank=16, nope=8, rope=4, v=8):
+    """One compiled program a shape: drawn op by op, every ``normal`` of
+    a new shape is a compilation of its own."""
     k = iter(jax.random.split(jax.random.PRNGKey(T * 7 + S), 8))
     return dict(
         q_nope=jax.random.normal(next(k), (b, T, H, nope)),
@@ -207,6 +213,12 @@ def _the_rule_says_kernel(monkeypatch, tiles):
     monkeypatch.setattr(mla, "_kernel_tiles", lambda *a: tiles)
 
 
+# the kernel's two neighbours as compiled programs (the kernel itself is
+# interpreted, and what it costs here is its run)
+_xla_core = jax.jit(mla._xla_core, static_argnums=tuple(range(5, 11)))
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4))
 def _dense_core(a, cache, pos, scale, dtype):
     """One softmax over every cached position up to ``pos + T``, the
     operands of the three products rounded to ``dtype`` as the cores
@@ -263,7 +275,7 @@ def test_the_kernel_equals_the_xla_core_and_a_dense_softmax(
     assert got.shape == (sizes["b"], T, sizes["H"], sizes["v"])
     assert got.dtype == jnp.float32
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
-    np.testing.assert_allclose(got, mla._xla_core(*args, 2, 8, 8),
+    np.testing.assert_allclose(got, _xla_core(*args, 2, 8, 8),
                                rtol=tol, atol=tol)
     np.testing.assert_allclose(got, _dense_core(a, cache, pos, 0.3, dtype),
                                rtol=tol, atol=tol)
@@ -473,22 +485,24 @@ def test_the_shares_add_up_to_the_uncut_layer():
     """One expert layer, 16 experts: the routed parts of the two shares
     of 8 (and of the four of 4) summed, plus the shared expert ONCE,
     equal the uncut reference's whole layer."""
-    whole = ref.init_params(jax.random.PRNGKey(4), UNCUT,
-                            TAILS)["layers"]["layer_1"]
+    whole = seeded(ref, 4, UNCUT, TAILS, layer="layer_1")
     x = jax.random.normal(jax.random.PRNGKey(5), (40, 64))
     with jax.default_matmul_precision("highest"):
-        want, chosen = ref.moe_layer(whole, x, UNCUT)
+        want, chosen = jax.jit(lambda p, x: ref.moe_layer(p, x, UNCUT))(
+            whole, x)
         shared = ref.swiglu(x, whole["shared_in"], whole["shared_out"])
     experts, weights = moe.route(
         x, whole["router"], whole["bias"], 4, 2, 4, 2.5)
     np.testing.assert_array_equal(np.sort(experts, -1), np.sort(chosen, -1))
+    # ``first`` is traced: one program a share's size, not one a share
+    share = jax.jit(lambda w_in, w_out, first: moe.routed_experts(
+        x, experts, weights, w_in, w_out, first))
     for count in (8, 4):
         total, rows = shared, 0
         for first in range(0, 16, count):
-            part, per_expert = moe.routed_experts(
-                x, experts, weights,
+            part, per_expert = share(
                 whole["experts_in"][first:first + count],
-                whole["experts_out"][first:first + count], first)
+                whole["experts_out"][first:first + count], jnp.int32(first))
             total = total + part
             rows += int(per_expert.sum())
         assert rows == 40 * 4          # every choice lands on one share
@@ -503,13 +517,17 @@ def test_no_token_is_dropped_when_every_choice_lands_here():
     with seven padding lanes left out, 4 x 17 through three rounds that
     cut an expert's rows in two; equal to the reference's dense loop."""
     model = dict(MODEL, experts_held={"first": 4, "count": 4, "of": 16})
-    p = ref.init_params(jax.random.PRNGKey(6), model,
-                        TAILS)["layers"]["layer_1"]
+    p = seeded(ref, 6, model, TAILS, layer="layer_1")
     bias = jnp.full((16,), -1.0).at[4:8].set(1.0)
     x = jax.random.normal(jax.random.PRNGKey(7), (24, 64))
-    with jax.default_matmul_precision("highest"):
+
+    @jax.jit
+    def the_references(p, x):
         r_experts, r_weights, _ = ref.route(x, p["router"], bias, model)
-        want = ref.routed_part(p, x, r_experts, r_weights, 4)
+        return ref.routed_part(p, x, r_experts, r_weights, 4)
+
+    with jax.default_matmul_precision("highest"):
+        want = the_references(p, x)
     experts, weights = moe.route(x, p["router"], bias, 4, 2, 4, 2.5)
     assert sorted(set(np.asarray(experts).ravel())) == [4, 5, 6, 7]
     got, per_expert = jax.jit(lambda x, e, w: moe.routed_experts(
@@ -517,9 +535,9 @@ def test_no_token_is_dropped_when_every_choice_lands_here():
     assert per_expert.tolist() == [24, 24, 24, 24]
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
     valid = jnp.arange(24) < 17
-    got, per_expert = moe.routed_experts(
-        x, experts, weights, p["experts_in"], p["experts_out"], 4,
-        valid=valid)
+    got, per_expert = jax.jit(lambda x, e, w, valid: moe.routed_experts(
+        x, e, w, p["experts_in"], p["experts_out"], 4, valid=valid))(
+            x, experts, weights, valid)
     assert per_expert.tolist() == [17, 17, 17, 17]
     np.testing.assert_allclose(got[:17], want[:17], rtol=2e-5, atol=2e-5)
     assert float(jnp.abs(got[17:]).max()) == 0.0
@@ -541,8 +559,7 @@ def test_one_program_equals_chunk_programs(params, encoder, tokens, cuts):
     states = encoder.init_states(3, 64)
     outs, lo = [], 0
     for hi in cuts + (24,):
-        out, states = jax.jit(encoder.encode)(params, tokens[:, lo:hi],
-                                              states)
+        out, states = compiled(encoder)(params, tokens[:, lo:hi], states)
         outs.append(out)
         lo = hi
     np.testing.assert_allclose(jnp.concatenate(outs, 1), want, rtol=2e-5,
@@ -562,8 +579,7 @@ def test_the_encoder_on_the_kernel_equals_the_reference(
     states = enc.init_states(3, 64)
     outs = []
     for lo in (0, 8, 16):
-        out, states = jax.jit(enc.encode)(params, tokens[:, lo:lo + 8],
-                                          states)
+        out, states = compiled(enc)(params, tokens[:, lo:lo + 8], states)
         outs.append(out)
     np.testing.assert_allclose(jnp.concatenate(outs, 1), want, rtol=2e-5,
                                atol=2e-5)
@@ -574,11 +590,11 @@ def test_the_encoder_on_the_kernel_equals_the_reference(
 
 def test_a_dropped_cache_is_seen(params, encoder, tokens):
     want, _ = reference(params, tokens)
-    _, states = encoder.encode(params, tokens[:, :16],
-                               encoder.init_states(3, 64))
+    step = compiled(encoder)
+    _, states = step(params, tokens[:, :16], encoder.init_states(3, 64))
     fresh = dict(encoder.init_states(3, 64), pos=states["pos"])
-    dropped, _ = encoder.encode(params, tokens[:, 16:], fresh)
-    kept, _ = encoder.encode(params, tokens[:, 16:], states)
+    dropped, _ = step(params, tokens[:, 16:], fresh)
+    kept, _ = step(params, tokens[:, 16:], states)
     np.testing.assert_allclose(kept, want[:, 16:], rtol=2e-5, atol=2e-5)
     assert float(jnp.abs(dropped - want[:, 16:]).max()) > 0.05
 
@@ -840,8 +856,7 @@ def test_export_round_trip_in_bfloat16(tmp_path, vocab):
     from code_intelligence_tpu.training.checkpoint import export_encoder
 
     cfg = make_config("deepseek_v3", MODEL, kv_positions=64)
-    weights = ref.init_params(jax.random.PRNGKey(1), MODEL,
-                              dtype=jnp.bfloat16)
+    weights = seeded(ref, 1, MODEL, dtype=jnp.bfloat16)
     export_encoder(tmp_path, weights, cfg, vocab)
     eng = InferenceEngine.from_export(tmp_path, buckets=(8, 16),
                                       batch_size=2)

@@ -13,6 +13,7 @@ the capacity planner do with an encoder outside theirs.
 """
 
 import dataclasses
+import functools
 import json
 from pathlib import Path
 
@@ -32,6 +33,7 @@ from code_intelligence_tpu.ops.attention import gqa_cached
 from code_intelligence_tpu.ops.ssd import (
     causal_conv1d, ssd_recurrence, ssd_scan)
 from code_intelligence_tpu.text import SPECIALS, Vocab
+from encoder_programs import compiled, seeded
 
 MODEL = {
     "vocab_size": 300, "hidden_size": 64, "num_hidden_layers": 4,
@@ -48,8 +50,7 @@ FIXTURE = (Path(__file__).resolve().parents[1] / "code_intelligence_tpu"
 
 @pytest.fixture(scope="module")
 def params():
-    return ref.init_params(jax.random.PRNGKey(26), MODEL,
-                           {"dist": "student_t", "df": 4})
+    return seeded(ref, 26, MODEL, {"dist": "student_t", "df": 4})
 
 
 @pytest.fixture(scope="module")
@@ -78,26 +79,32 @@ def test_chunked_scan_equals_the_recurrence(T, chunk):
     """From a NON-ZERO state, any length (a ragged last chunk is padded
     with dt = 0): outputs and the state handed back."""
     b, H, P, N = 2, 3, 4, 5
-    k = iter(jax.random.split(jax.random.PRNGKey(T * 31 + chunk), 8))
-    x = jax.random.normal(next(k), (b, T, H, P))
-    dt = jax.nn.softplus(jax.random.normal(next(k), (b, T, H)) - 2.0)
-    A = -jax.random.uniform(next(k), (H,), minval=1.0, maxval=16.0)
-    B = jax.random.normal(next(k), (b, T, N))
-    C = jax.random.normal(next(k), (b, T, N))
-    D = jax.random.normal(next(k), (H,))
-    S0 = jax.random.normal(next(k), (b, H, P, N))
-    want_y, want_S = ssd_recurrence(x, dt, A, B, C, D, S0)
-    got_y, got_S = ssd_scan(x, dt, A, B, C, D, S0, chunk,
-                            mxu_dtype=jnp.float32)
+
+    @jax.jit       # the draws as one program, the scan one a length
+    def inputs(key):
+        k = iter(jax.random.split(key, 8))
+        return (jax.random.normal(next(k), (b, T, H, P)),
+                jax.nn.softplus(jax.random.normal(next(k), (b, T, H)) - 2.0),
+                -jax.random.uniform(next(k), (H,), minval=1.0, maxval=16.0),
+                jax.random.normal(next(k), (b, T, N)),
+                jax.random.normal(next(k), (b, T, N)),
+                jax.random.normal(next(k), (H,)),
+                jax.random.normal(next(k), (b, H, P, N)))
+
+    x, dt, A, B, C, D, S0 = inputs(jax.random.PRNGKey(T * 31 + chunk))
+    scan = jax.jit(functools.partial(ssd_scan, mxu_dtype=jnp.float32),
+                   static_argnums=7)
+    want_y, want_S = jax.jit(ssd_recurrence)(x, dt, A, B, C, D, S0)
+    got_y, got_S = scan(x, dt, A, B, C, D, S0, chunk)
     np.testing.assert_allclose(got_y, want_y, rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(got_S, want_S, rtol=2e-5, atol=2e-5)
     # and in two calls, the state carried between them
     if T > 3:
         cut = T // 2 + 1  # not a multiple of the chunk
-        y1, S1 = ssd_scan(x[:, :cut], dt[:, :cut], A, B[:, :cut],
-                          C[:, :cut], D, S0, chunk, mxu_dtype=jnp.float32)
-        y2, S2 = ssd_scan(x[:, cut:], dt[:, cut:], A, B[:, cut:],
-                          C[:, cut:], D, S1, chunk, mxu_dtype=jnp.float32)
+        y1, S1 = scan(x[:, :cut], dt[:, :cut], A, B[:, :cut], C[:, :cut],
+                      D, S0, chunk)
+        y2, S2 = scan(x[:, cut:], dt[:, cut:], A, B[:, cut:], C[:, cut:],
+                      D, S1, chunk)
         np.testing.assert_allclose(jnp.concatenate([y1, y2], 1), want_y,
                                    rtol=2e-5, atol=2e-5)
         np.testing.assert_allclose(S2, want_S, rtol=2e-5, atol=2e-5)
@@ -133,11 +140,13 @@ def test_cached_attention_equals_dense_causal(T):
                       jnp.repeat(v, 2, axis=2))
     kc = vc = jnp.zeros((b, Hkv, S, d))     # head-major
     pos = jnp.zeros((), jnp.int32)
+    step = jax.jit(functools.partial(       # ``pos`` traced: one program
+        gqa_cached, scale=0.3, q_block=128, mxu_dtype=jnp.float32))
     outs = []
     for lo in (0, T):  # two chunks through the cache
-        out, kc, vc = gqa_cached(
+        out, kc, vc = step(
             q[:, lo:lo + T], kk[:, lo:lo + T], v[:, lo:lo + T], kc, vc,
-            pos + lo, 0.3, q_block=128, mxu_dtype=jnp.float32)
+            pos + lo)
         outs.append(out)
     np.testing.assert_allclose(jnp.concatenate(outs, 1), want,
                                rtol=2e-5, atol=2e-5)
@@ -148,8 +157,7 @@ def test_cached_attention_equals_dense_causal(T):
 def test_encoder_equals_the_reference(params, encoder):
     tokens = jax.random.randint(jax.random.PRNGKey(1), (3, 44), 0, 300)
     want = reference_hidden(params, tokens)
-    got, _ = jax.jit(encoder.encode)(
-        params, tokens, encoder.init_states(3, 44))
+    got, _ = compiled(encoder)(params, tokens, encoder.init_states(3, 44))
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=2e-5)
     assert got.shape == (3, 44, encoder.out_dim)
 
@@ -166,11 +174,12 @@ def test_one_program_equals_chunk_programs(monkeypatch, params, encoder,
     here; the rule's answer and tiles that divide 16, 12 and the 128
     slots come from the test): the whole document stays the XLA core's."""
     tokens = jax.random.randint(jax.random.PRNGKey(2), (2, 44), 0, 300)
-    whole, whole_states = jax.jit(encoder.encode)(
+    whole, whole_states = compiled(encoder)(
         params, tokens, encoder.init_states(2, 44))
     np.testing.assert_allclose(whole, reference_hidden(params, tokens),
                                rtol=1e-4, atol=2e-5)
     states = encoder.init_states(2)        # the whole cache
+    step = compiled(encoder)
     if kernel:
         from code_intelligence_tpu.ops import attention
         monkeypatch.setattr(attention, "core_is_kernel", lambda *a: True)
@@ -179,10 +188,14 @@ def test_one_program_equals_chunk_programs(monkeypatch, params, encoder,
         monkeypatch.setattr(
             attention, "_kernel_core",
             lambda *a, **kw: traced.append(a[0].shape[1]) or real(*a, **kw))
+        # an encoder and a jit of its own a program, built after the
+        # patch: every trace sees it, and ``traced`` reads one attention
+        # layer a program
+        step = lambda *a: jax.jit(  # noqa: E731
+            build_encoder(encoder.config, params).encode)(*a)
     parts = []
     for lo, hi in zip((0,) + cuts, cuts + (44,)):
-        out, states = jax.jit(encoder.encode)(params, tokens[:, lo:hi],
-                                              states)
+        out, states = step(params, tokens[:, lo:hi], states)
         parts.append(out)
     np.testing.assert_allclose(jnp.concatenate(parts, 1), whole,
                                rtol=1e-4, atol=2e-5)
@@ -201,9 +214,9 @@ def test_a_dropped_carry_is_seen(params, encoder):
     """The seeded scan parameters make the state matter: the second
     chunk from a zero state is far from the second half of the whole."""
     tokens = jax.random.randint(jax.random.PRNGKey(4), (2, 32), 0, 300)
-    whole, _ = encoder.encode(params, tokens, encoder.init_states(2, 32))
-    fresh, _ = encoder.encode(params, tokens[:, 16:],
-                              encoder.init_states(2, 16))
+    step = compiled(encoder)
+    whole, _ = step(params, tokens, encoder.init_states(2, 32))
+    fresh, _ = step(params, tokens[:, 16:], encoder.init_states(2, 16))
     err = np.sqrt(np.mean((np.asarray(fresh - whole[:, 16:])) ** 2))
     assert err > 0.05 * np.sqrt(np.mean(np.asarray(whole) ** 2))
 
@@ -211,8 +224,9 @@ def test_a_dropped_carry_is_seen(params, encoder):
 def test_reference_states_match_the_programs(params, encoder):
     tokens = jax.random.randint(jax.random.PRNGKey(5), (2, 20), 0, 300)
     with jax.default_matmul_precision("highest"):
-        _, want = ref.encode(params, tokens, MODEL)
-    _, got = encoder.encode(params, tokens, encoder.init_states(2, 20))
+        _, want = jax.jit(lambda p, t: ref.encode(p, t, MODEL))(
+            params, tokens)
+    _, got = compiled(encoder)(params, tokens, encoder.init_states(2, 20))
     flat = [got["ssm"][r][i] for r in range(len(got["ssm"]))
             for i in range(got["ssm"][r].shape[0])]
     assert len(flat) == len(want) == 3
@@ -326,7 +340,7 @@ def test_serve_dtype_follows_the_weights(params, vocab):
 def awd_engine(qrnn, vocab, **kw):
     cfg = AWDLSTMConfig(vocab_size=300, emb_sz=8, n_hid=12, n_layers=3,
                         qrnn=qrnn, pad_id=vocab.pad_id)
-    params = AWDLSTMEncoder(cfg).init(
+    params = jax.jit(AWDLSTMEncoder(cfg).init)(
         {"params": jax.random.PRNGKey(0)}, np.zeros((1, 4), np.int32),
         init_lstm_states(cfg, 1))["params"]
     return InferenceEngine(params, cfg, vocab, **{
@@ -420,8 +434,7 @@ def test_export_round_trip_rebuilds_the_hybrid(tmp_path, vocab):
     from code_intelligence_tpu.training.checkpoint import export_encoder
 
     cfg = make_config("granite_hybrid", MODEL, kv_positions=64)
-    weights = ref.init_params(jax.random.PRNGKey(1), MODEL,
-                              dtype=jnp.bfloat16)
+    weights = seeded(ref, 1, MODEL, dtype=jnp.bfloat16)
     export_encoder(tmp_path, weights, cfg, vocab)
     eng = InferenceEngine.from_export(tmp_path, buckets=(8, 16),
                                       batch_size=2)
@@ -462,7 +475,7 @@ def test_server_serves_the_hybrid_on_groups(tmp_path, vocab):
     from code_intelligence_tpu.training.checkpoint import export_encoder
 
     cfg = make_config("granite_hybrid", MODEL, kv_positions=64)
-    weights = ref.init_params(jax.random.PRNGKey(2), MODEL)
+    weights = seeded(ref, 2, MODEL)
     export_encoder(tmp_path, weights, cfg, vocab)
     with pytest.raises(ValueError, match="'slots'.*GraniteHybridEncoder"):
         build_server(["--model_dir", str(tmp_path), "--port", "0"])

@@ -1,16 +1,22 @@
 """`ops/kda.py`: the delta-rule recurrence's two cores and the rule between
 them. The kernel is interpreted here (the CPU); that Mosaic takes it at the
-cell's shapes is `tests/test_pallas_tpu_compile.py`'s to say.
+cell's shapes is `tests/test_pallas_tpu_compile_latent.py`'s to say.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from code_intelligence_tpu.ops import kda
-from test_bailing_hybrid import kda_inputs
+from test_bailing_hybrid import kda_inputs, recurrence
 
 BF16, F32 = jnp.bfloat16, jnp.float32
+
+
+# the kernel's two neighbours as compiled programs (the kernel itself is
+# interpreted, and what it costs here is its run)
+xla_scan = jax.jit(kda._xla_scan, static_argnums=(6, 7, 8))
 
 
 def _the_rule_says_kernel(monkeypatch, heads):
@@ -35,8 +41,8 @@ def test_the_kernel_equals_the_scan_and_the_recurrence(seed, b, T, H, hb,
     inputs = kda_inputs(seed, b, T, H)
     assert float(jnp.abs(inputs[-1]).max()) > 1     # a state comes in
     o, S = kda._kernel_scan(*inputs, chunk, F32, sub, hb)
-    o_xla, S_xla = kda._xla_scan(*inputs, chunk, F32, sub)
-    o_want, S_want = kda.kda_recurrence(*inputs)
+    o_xla, S_xla = xla_scan(*inputs, chunk, F32, sub)
+    o_want, S_want = recurrence(*inputs)
     # float32 sums in another order; outputs are O(0.3), states O(1): the
     # existing test's tightness against the recurrence, twice it between
     # the two cores (each is that far from it)
@@ -51,7 +57,7 @@ def test_gates_at_the_lower_bound_stay_finite_in_the_kernel(dtype, atol):
     inputs = kda_inputs(7, 1, 128, at_bound=True)
     o, S = kda._kernel_scan(*inputs, 64, dtype, 16, 3)
     assert bool(jnp.isfinite(o).all()) and bool(jnp.isfinite(S).all())
-    o_want, S_want = kda.kda_recurrence(*inputs)
+    o_want, S_want = recurrence(*inputs)
     np.testing.assert_allclose(o, o_want, atol=atol)
     np.testing.assert_allclose(S, S_want, atol=atol)
 
@@ -90,7 +96,7 @@ def test_repeated_keys_do_not_cancel_in_the_kernels_solve():
     k = jnp.broadcast_to(k[:, :1], k.shape)
     args = (q, k, v, jnp.zeros_like(g), jnp.ones_like(beta), S)
     o, S1 = kda._kernel_scan(*args, 64, F32, 16, 3)
-    o_want, S_want = kda.kda_recurrence(*args)
+    o_want, S_want = recurrence(*args)
     np.testing.assert_allclose(o, o_want, atol=5e-6)
     np.testing.assert_allclose(S1, S_want, atol=5e-6)
 
@@ -149,7 +155,7 @@ def test_a_document_across_programs_equals_one_program(monkeypatch, programs):
     assert calls == [2] * (programs + 1)       # every call took the kernel
     np.testing.assert_allclose(jnp.concatenate(outs, axis=1), o_one, atol=1e-6)
     np.testing.assert_allclose(state, S_one, atol=1e-6)
-    o_want, S_want = kda.kda_recurrence(q, k, v, g, beta, S)
+    o_want, S_want = recurrence(q, k, v, g, beta, S)
     np.testing.assert_allclose(o_one, o_want, atol=5e-6)
     np.testing.assert_allclose(S_one, S_want, atol=5e-6)
 
@@ -159,7 +165,7 @@ def test_off_the_tpu_kda_scan_is_the_xla_scan(monkeypatch):
     monkeypatch.setattr(kda, "_kernel_scan", None)
     inputs = kda_inputs(5, 1, 100)
     o, S = kda.kda_scan(*inputs, mxu_dtype=F32)
-    o_xla, S_xla = kda._xla_scan(*inputs, 64, F32, 16)
+    o_xla, S_xla = kda._xla_scan(*inputs, 64, F32, 16)   # eager, as o
     np.testing.assert_array_equal(o, o_xla)
     np.testing.assert_array_equal(S, S_xla)
 

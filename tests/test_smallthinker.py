@@ -32,7 +32,7 @@ from code_intelligence_tpu.models import contract
 from code_intelligence_tpu.ops import attention, moe
 from code_intelligence_tpu.text import SPECIALS, Vocab
 from code_intelligence_tpu.utils import tracing
-from test_pallas_tpu_compile import _gqa_text, one_chip  # noqa: F401
+from encoder_programs import compiled, seeded
 
 ROOT = Path(__file__).resolve().parents[1]
 PERIOD = [0, 1, 1, 1]
@@ -52,8 +52,7 @@ T_DOC = 40   # five windows: a ring of 8 + 4 slots wraps three times
 
 @pytest.fixture(scope="module")
 def params():
-    return jax.jit(lambda key: ref.init_params(key, MODEL, TAILS))(
-        jax.random.PRNGKey(39))
+    return seeded(ref, 39, MODEL, TAILS)
 
 
 def config(**extra):
@@ -90,7 +89,7 @@ def want(params, tokens):
 def streamed(enc, params, tokens, chunk=4, between=None):
     """``tokens`` through ``enc`` in chunk programs of ``chunk``."""
     states = enc.init_states(tokens.shape[0], tokens.shape[1])
-    step = jax.jit(enc.encode)
+    step = compiled(enc)
     outs = []
     for lo in range(0, tokens.shape[1], chunk):
         out, states = step(params, tokens[:, lo:lo + chunk], states)
@@ -258,16 +257,23 @@ def test_two_shares_of_half_the_experts_add_up_to_the_whole_layer(params):
     p = params["layers"]["layer_1"]
     x = jax.random.normal(jax.random.PRNGKey(9), (40, 64))
     m = jax.random.normal(jax.random.PRNGKey(10), (40, 64))
-    with jax.default_matmul_precision("highest"):
+    @jax.jit
+    def the_references(p, x, m):
         r_experts, r_weights, _ = ref.route(x, p["router"], MODEL)
-        want = ref.routed_part(p, m, r_experts, r_weights, 0)
+        return ref.routed_part(p, m, r_experts, r_weights, 0)
+
+    with jax.default_matmul_precision("highest"):
+        want = the_references(p, x, m)
     experts, weights = moe.route(x, p["router"], None, 1, 1, 6, 1.0,
                                  score_func="softmax")
+    # ``first`` is traced: one program for both halves
+    half = jax.jit(lambda w_in, w_out, first: moe.routed_experts(
+        m, experts, weights, w_in, w_out, first, act="relu"))
     total, rows = 0.0, 0
     for first in (0, 8):
-        part, per_expert = moe.routed_experts(
-            m, experts, weights, p["experts_in"][first:first + 8],
-            p["experts_out"][first:first + 8], first, act="relu")
+        part, per_expert = half(p["experts_in"][first:first + 8],
+                                p["experts_out"][first:first + 8],
+                                jnp.int32(first))
         total = total + part
         rows += int(per_expert.sum())
     assert rows == 40 * 6
@@ -385,8 +391,8 @@ def test_the_routing_controls_are_seen(params, tokens, want, control):
     driver = load_driver("bulk_early_route_moe")
     real = moe.route, moe.routed_experts
     enc = build_encoder(config(chunk_positions=T_DOC), params)
-    with driver._moe_as(control):
-        got = enc.encode(params, tokens, enc.init_states(3, T_DOC))[0]
+    with driver._moe_as(control):      # traced under the wrappers
+        got = compiled(enc)(params, tokens, enc.init_states(3, T_DOC))[0]
     assert (moe.route, moe.routed_experts) == real
     assert _differs(got, want, 0) > 0.02
 
@@ -449,20 +455,6 @@ def test_the_kernels_tile_at_seven_heads_a_group():
         assert attention.core_is_kernel("tpu", jnp.bfloat16, 512, S, 7, 128)
     assert not attention.core_is_kernel("cpu", jnp.bfloat16, 512, 4608, 7,
                                         128)
-
-
-# `smallthinker_bulk_long_tail`'s shapes for a described v5e (the helpers
-# are `tests/test_pallas_tpu_compile.py`'s; the cases live here because
-# that file is the suite's longest): 28 / 4 heads, query blocks of 256:
-# the short group's caches, the rings and the global cache at the widest
-# batch, the ring at the narrowest too
-@pytest.mark.parametrize("rows,S,window", [
-    (16, 4096, 4096), (16, 4096, None), (16, 4608, 4096), (16, 16384, None),
-    (2, 4608, 4096)])
-def test_mosaic_takes_the_kernel_at_seven_heads_a_group(
-        one_chip, monkeypatch, rows, S, window):
-    text = _gqa_text(monkeypatch, one_chip, rows, 512, S, window, 28, 4, 128)
-    assert "tpu_custom_call" in text
 
 
 @pytest.mark.parametrize("window,S,T,tiles,dtype", [
