@@ -35,6 +35,17 @@ tokens' number) at a time for as many rounds as they need (one, unless
 more than ``N`` assignments land here: ``top_k * count / n_experts`` of
 a token's choices do on average).
 
+**The rows come back by a gather, not by a scatter-add** (which the TPU
+runs row by row). A round writes its ``N`` weighted float32 rows into
+one buffer of ``top_k * N`` rows, at the place the sort gave them; after
+the last round the sort's inverse says where each of a token's ``top_k``
+assignments lies there, the tokens' rows are gathered from it and a
+token's ``top_k`` rows summed. The buffer starts unwritten
+(``lax.empty``: no worst-case memset for a share that fills a sixteenth
+of it): an assignment held elsewhere, or a padding lane's, sorted behind
+the last held one, so whatever its place holds (a round's zeroed tail,
+or nothing a round ever wrote) is selected away and never multiplied.
+
 ``expert_layer`` is the whole block as most encoders call it (router, the
 held experts' part, the shared expert), and ``COUNTERS`` /
 ``counter_attrs`` what such encoders count on the device and how the
@@ -44,6 +55,7 @@ experts.
 
 from __future__ import annotations
 
+import math
 from typing import Optional, Sequence, Tuple
 
 import jax
@@ -128,6 +140,14 @@ def assign(experts: jnp.ndarray, first: int, count: int,
 # the gate's activation of the routed experts: SwiGLU's and ReGLU's
 _GATE_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
+# rows one gather brings back. The TPU writes a gather's rows out before
+# the sum reads them, so a token's rows come back a block of tokens at a
+# time, sized in ROWS: the four expert models' combines were fastest at
+# 512 to 1536 rows of 10 to 28 KB a block (top_k 4, 6, 8) and took 1.1
+# to 2.3 times as long at 8192, and one gather of all ``top_k * N`` rows
+# held as much again as the buffer (TPU v5e; PERF.md section 6, PR 41)
+_BACK_ROWS = 768
+
 
 def routed_experts(
     x: jnp.ndarray,        # (N, E): what the experts read
@@ -145,9 +165,12 @@ def routed_experts(
     ran. ``act`` is the gate's activation (``"silu"`` SwiGLU, ``"relu"``
     ReGLU); ``assigned`` is ``assign(experts, first, count, valid)``
     where the caller made it already (beside its router, ahead of what
-    ``x`` waits for). Named scopes: ``dispatch`` (the sort, each round's
-    gather), ``experts`` (the grouped matmuls), ``combine`` (weigh, add
-    back)."""
+    ``x`` waits for). Named scopes: ``dispatch`` (the sort and its
+    inverse, each round's gather), ``experts`` (the grouped matmuls),
+    ``combine`` (in the loop: weigh, and write the round's rows into the
+    ``(top_k * N, E)`` float32 buffer; after it: gather every token's
+    ``top_k`` rows back by the sort's inverse and sum them, rows no held
+    assignment wrote selected away, ``_BACK_ROWS`` rows at a time)."""
     N, E = x.shape
     top_k = experts.shape[1]
     count = w_in.shape[0]
@@ -157,11 +180,15 @@ def routed_experts(
             experts, first, count, valid)
         ends = jnp.cumsum(per_expert)
         total = ends[-1]
+        # the sort's inverse: where assignment (token, choice) went. A
+        # second sort, not a scatter of an iota: the TPU runs a scatter
+        # element by element
+        pos = jnp.argsort(order).astype(jnp.int32).reshape(N, top_k).T
         # padded so that every round slices N whole entries
         order = jnp.concatenate([order, jnp.zeros((N,), jnp.int32)])
         flat_w = weights.reshape(-1)
 
-    def one_round(r, y):
+    def one_round(r, rows):
         start = r * N  # N rows a round
         with jax.named_scope("dispatch"):
             picked = lax.dynamic_slice_in_dim(order, start, N)
@@ -182,11 +209,25 @@ def routed_experts(
             # grouped matmul left there: selected away, never multiplied
             out = jnp.where(live[:, None],
                             out * jnp.take(flat_w, picked)[:, None], 0.0)
-            return y.at[token].add(out)
+            return lax.dynamic_update_slice_in_dim(rows, out, start, 0)
 
-    y = lax.fori_loop(0, (total + N - 1) // N, one_round,
-                      jnp.zeros((N, E), jnp.float32))
-    return y, per_expert
+    # the weighted rows of every round, in sorted order; a round that
+    # never runs leaves its N rows unwritten
+    rows = lax.fori_loop(0, (total + N - 1) // N, one_round,
+                         lax.empty((top_k * N, E), jnp.float32))
+
+    def back(at):  # (top_k, tokens) positions -> (tokens, E)
+        # an assignment held elsewhere, or a padding lane's, sorted
+        # behind ``total``: its row is selected away like the leftovers
+        return jnp.where((at < total)[:, :, None],
+                         jnp.take(rows, at, axis=0), 0.0).sum(0)
+
+    with jax.named_scope("combine"):
+        # the most tokens, a power of two, within _BACK_ROWS rows
+        part = math.gcd(
+            N, 1 << (max(_BACK_ROWS // top_k, 1).bit_length() - 1))
+        y = lax.map(back, pos.reshape(top_k, N // part, part).swapaxes(0, 1))
+    return y.reshape(N, E), per_expert
 
 
 def expert_layer(

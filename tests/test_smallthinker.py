@@ -180,8 +180,10 @@ def _expert_layer_as_it_was(p, u, valid, dtype, *, n_group, topk_group,
         "bailing", "bailing_rounds"])
 def test_route_sort_and_apply_on_one_tensor_is_expert_layer_as_it_was(
         n_group, topk_group, top_k, scaling, shared, held, pad):
-    """Bit for bit: the three callers of ``expert_layer`` compute what
-    they computed."""
+    """The three callers of ``expert_layer`` compute what they computed
+    when the rows came back by a scatter-add (the frozen copy above):
+    the rows each expert ran to the bit, the values to float32's last
+    places, since a token's rows are now summed in another order."""
     first, count = held
     k = jax.random.split(jax.random.PRNGKey(7), 7)
     p = {"router": jax.random.normal(k[0], (64, 16)) / 8,
@@ -199,7 +201,7 @@ def test_route_sort_and_apply_on_one_tensor_is_expert_layer_as_it_was(
         p, u, valid, jnp.float32, **kw))(p, u)
     got, rows = jax.jit(lambda p, u: moe.expert_layer(
         p, u, valid, jnp.float32, **kw))(p, u)
-    np.testing.assert_array_equal(got, want)
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
     np.testing.assert_array_equal(rows, want_rows)
     # and the steps by hand, the sort made beside the router
     experts, weights = moe.route(u, p["router"], p["bias"], n_group,
@@ -213,6 +215,138 @@ def test_route_sort_and_apply_on_one_tensor_is_expert_layer_as_it_was(
         by_hand = by_hand + moe.swiglu(u, p["shared_in"], p["shared_out"],
                                        jnp.float32)
     np.testing.assert_allclose(by_hand, want, rtol=1e-6, atol=1e-6)
+
+
+def _dense_held_part(x, experts, weights, w_in, w_out, first, valid, act):
+    """``sum over chosen j in [first, first + count)  w_j E_j(x)`` a
+    token, every held expert run over ALL tokens: no sort, no rounds."""
+    gate = {"silu": jax.nn.silu, "relu": jax.nn.relu}[act]
+    y = jnp.zeros(x.shape, jnp.float32)
+    for j in range(w_in.shape[0]):
+        g, u = jnp.split(x @ w_in[j], 2, axis=-1)
+        w_j = jnp.where(experts == first + j, weights, 0.0).sum(-1)
+        y = y + w_j[:, None] * ((gate(g) * u) @ w_out[j])
+    return y if valid is None else jnp.where(valid[:, None], y, 0.0)
+
+
+# name: (n_experts, top_k, (first, count), padding lanes, act, rounds,
+# rows of the last round); N = 24 tokens
+_COMBINE_CASES = {
+    "all_held_six_rounds": (16, 6, (0, 16), 0, "relu", 6, 24),
+    "last_round_part_full": (16, 6, (0, 16), 7, "relu", 5, 6),
+    "a_share_in_one_round": (16, 4, (4, 2), 0, "silu", 1, None),
+    "a_share_in_three_rounds": (16, 8, (0, 8), 0, "silu", None, None),
+    "nothing_lands_here": (16, 2, (12, 4), 0, "silu", 0, None),
+    "padding_lanes_in_a_share": (16, 4, (8, 8), 9, "relu", None, None),
+    "every_lane_padding": (16, 6, (0, 16), 24, "relu", 0, None),
+}
+
+
+def _combine_case(name):
+    n_experts, top_k, (first, count), pad, act, rounds, last = \
+        _COMBINE_CASES[name]
+    k = jax.random.split(jax.random.PRNGKey(41), 5)
+    x = jax.random.normal(k[0], (24, 64))
+    # top_k distinct experts a token, weights that sum to 1
+    experts = jnp.argsort(jax.random.uniform(k[1], (24, n_experts)),
+                          axis=-1)[:, :top_k].astype(jnp.int32)
+    if name == "nothing_lands_here":
+        experts = experts % first    # all under the held range
+    weights = jax.nn.softmax(jax.random.normal(k[2], (24, top_k)), axis=-1)
+    w_in = jax.random.normal(k[3], (count, 64, 32)) / 8
+    w_out = jax.random.normal(k[4], (count, 16, 64)) / 4
+    valid = (jnp.arange(24) < 24 - pad) if pad else None
+    return (x, experts, weights, w_in, w_out, first, valid, act), rounds, last
+
+
+@pytest.mark.parametrize("name", list(_COMBINE_CASES))
+def test_the_rows_come_back_as_the_dense_weighted_sum(name):
+    """Whatever the rounds (none, one, several, a last one part full),
+    every token gets ``sum_k w_k E_k(x)`` over the held experts it chose,
+    a token whose choices fell in different rounds too, and a padding
+    lane exact zeros."""
+    args, rounds, last = _combine_case(name)
+    x, experts, weights, w_in, w_out, first, valid, act = args
+    got, rows = jax.jit(lambda *a: moe.routed_experts(
+        *a, first, valid, act))(x, experts, weights, w_in, w_out)
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(lambda *a: _dense_held_part(
+            *a, first, valid, act))(x, experts, weights, w_in, w_out)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    total = int(rows.sum())
+    held = (experts >= first) & (experts < first + w_in.shape[0])
+    if valid is not None:
+        held = held & valid[:, None]
+        assert float(jnp.abs(got[~np.asarray(valid)]).max()) == 0.0
+    assert total == int(held.sum())
+    if rounds is not None:
+        assert -(-total // 24) == rounds
+    if last is not None:
+        assert total - (rounds - 1) * 24 == last
+    if total == 0:
+        assert float(jnp.abs(got).max()) == 0.0
+    if total > 24:
+        # some token's rows were made in different rounds
+        order, _ = moe.assign(experts, first, w_in.shape[0], valid)
+        at = np.argsort(np.asarray(order)).reshape(24, -1) // 24
+        at = np.where(np.asarray(held), at, at.max(-1, keepdims=True))
+        assert (at.min(-1) < at.max(-1)).any()
+
+
+@pytest.mark.parametrize("name", ["all_held_six_rounds",
+                                  "a_share_in_one_round",
+                                  "padding_lanes_in_a_share"])
+def test_a_sort_handed_in_and_one_made_inside_give_the_same_bits(name):
+    args, _, _ = _combine_case(name)
+    x, experts, weights, w_in, w_out, first, valid, act = args
+    inside = jax.jit(lambda *a: moe.routed_experts(*a, first, valid, act))
+    handed = jax.jit(lambda *a: moe.routed_experts(
+        *a, first, valid, act,
+        assigned=moe.assign(a[1], first, w_in.shape[0], valid)))
+    for got, want in zip(handed(x, experts, weights, w_in, w_out),
+                         inside(x, experts, weights, w_in, w_out)):
+        np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("name", ["a_share_in_one_round",
+                                  "last_round_part_full",
+                                  "nothing_lands_here"])
+def test_rows_no_round_wrote_never_reach_the_sum(monkeypatch, name):
+    """The buffer of weighted rows starts unwritten: with NaN in every
+    row a round did not write, the sum is what it was."""
+    args, _, _ = _combine_case(name)
+    x, experts, weights, w_in, w_out, first, valid, act = args
+    want, _ = moe.routed_experts(*args)
+    monkeypatch.setattr(moe.lax, "empty", lambda shape, dtype: jnp.full(
+        shape, jnp.nan, dtype))
+    got, _ = moe.routed_experts(*args)
+    assert bool(jnp.isfinite(got).all())
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("pad", [0, 5], ids=["whole", "padded"])
+def test_the_combine_lowers_to_a_gather_and_the_scopes_stand(pad):
+    """The combine is a gather by the sort's inverse: the lowered program
+    holds no scatter, and the scopes the benchmark's readers match are
+    where they were."""
+    args, _, _ = _combine_case("all_held_six_rounds")
+    x, experts, weights, w_in, w_out, first, _, act = args
+    valid = (jnp.arange(24) < 24 - pad) if pad else None
+
+    def layer(*a):
+        with jax.named_scope("moe_7"):
+            return moe.routed_experts(*a, first, valid, act)
+
+    lowered = jax.jit(layer).lower(x, experts, weights, w_in, w_out)
+    compiled_text = lowered.compile().as_text()
+    assert "scatter" not in lowered.as_text()
+    assert "scatter" not in compiled_text
+    text = lowered.as_text(debug_info=True)
+    for name in ("moe_7/dispatch", "moe_7/while/body/dispatch",
+                 "moe_7/while/body/experts", "moe_7/while/body/combine",
+                 "moe_7/combine"):
+        assert name in text, name
+    assert "f32[144,64]" in compiled_text  # the buffer: float32 rows
 
 
 def test_all_held_six_a_token_runs_six_rounds_and_drops_nothing(
