@@ -67,8 +67,9 @@ from typing import Any, ClassVar, Mapping, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from code_intelligence_tpu.models.deepseek_v3 import share_of
-from code_intelligence_tpu.models.granite_hybrid import _matmul, _rms_norm
+from code_intelligence_tpu.models.blocks import (
+    CarriedCounts, Counts, config_from_dict, embed, held_experts, matmul,
+    rms_norm, rope_qk, split_heads, valid_lanes)
 from code_intelligence_tpu.models.windowed_caches import (
     WindowedCaches, ring_positions)
 from code_intelligence_tpu.ops import attention, mla, moe
@@ -112,8 +113,8 @@ class AfmoeConfig:
     state_dtype: Any = jnp.bfloat16    # the caches' type
 
     def __post_init__(self):
-        held = self.experts_held or (0, self.num_experts)
-        object.__setattr__(self, "experts_held", tuple(int(v) for v in held))
+        object.__setattr__(self, "experts_held", held_experts(
+            self.experts_held, self.num_experts))
         object.__setattr__(self, "state_dtype", jnp.dtype(self.state_dtype))
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
         if len(self.layer_types) != self.num_hidden_layers or set(
@@ -126,12 +127,6 @@ class AfmoeConfig:
                 "only score_func 'sigmoid' and plain rotary (rope_scaling "
                 f"null) are implemented, not {self.score_func!r} / "
                 f"{self.rope_scaling!r}")
-        first, count = self.experts_held
-        if not (0 <= first and 0 < count
-                and first + count <= self.num_experts):
-            raise ValueError(
-                f"experts_held {self.experts_held} lies outside the "
-                f"router's {self.num_experts} experts")
         if self.num_experts % self.n_group:
             raise ValueError("n_group must divide num_experts")
         if not 0 <= self.num_dense_layers <= self.num_hidden_layers:
@@ -142,14 +137,9 @@ class AfmoeConfig:
 
     @classmethod
     def from_dict(cls, model: Mapping, **extra) -> "AfmoeConfig":
-        """From a published ``config.json``'s keys; keys that do not
-        shape the encoder are passed over. A configuration of a share
-        carries ``experts_held: {"first", "count", "of"}``: its
-        ``num_experts`` then counts the experts HELD, and ``of`` is the
-        router's width."""
-        names = {f.name for f in dataclasses.fields(cls)}
-        kw = {k: v for k, v in model.items() if k in names}
-        return cls(**{**kw, **share_of(model, "num_experts"), **extra})
+        """From a published ``config.json``'s keys; of a share, its
+        ``num_experts`` counts the experts HELD."""
+        return config_from_dict(cls, model, "num_experts", **extra)
 
     @property
     def n_moe_layers(self) -> int:
@@ -168,14 +158,15 @@ class AfmoeConfig:
         return sum(t == kind for t in self.layer_types)
 
 
-class AfmoeEncoder(WindowedCaches):
+class AfmoeEncoder(WindowedCaches, CarriedCounts):
     """The encoder contract (`models/contract.py`) over AFMoE; its two
     kinds of caches and their arithmetic (``cache_positions``,
     ``window_positions``, ``init_states``, ``state_bytes_per_row``) are
-    `models/windowed_caches.py`'s."""
+    `models/windowed_caches.py`'s, the reading of its counts
+    `models/blocks.py`'s."""
 
-    # ``ops/moe.py::COUNTERS`` and the attention layers on the Pallas core
-    n_counts = len(moe.COUNTERS) + 1
+    # the attention layers whose core the program ran on the Pallas kernel
+    counts = Counts(sets=("attention_kernel_layers",))
 
     def __init__(self, config: AfmoeConfig, dtype=jnp.bfloat16):
         self.config = config
@@ -188,27 +179,6 @@ class AfmoeEncoder(WindowedCaches):
     @property
     def out_dim(self) -> int:
         return self.config.hidden_size
-
-    def state_counters(self, states):
-        """The counts the expert layers have kept since ``init_states``
-        (``ops/moe.py::COUNTERS``) and, last, the attention layers whose
-        core the group's programs ran on the Pallas kernel (a device
-        array; ``counter_attrs`` names them)."""
-        return states["counts"]
-
-    def counter_attrs(self, counted) -> dict:
-        """Span attributes from the fetched ``state_counters`` of a
-        flush's groups: ``ops/moe.py::counter_attrs`` and
-        ``attention_kernel_layers``, the attention layers on the Pallas
-        core in a group's programs (``ops/attention.py::core_is_kernel``:
-        all programs of a group run one chunk length against one cache
-        size, so one answer a group), averaged over the groups."""
-        attrs = moe.counter_attrs(counted, self.config.n_moe_layers,
-                                  self.config.experts_held[1])
-        if counted:
-            attrs["attention_kernel_layers"] = \
-                sum(int(c[-1]) for c in counted) / len(counted)
-        return attrs
 
     def encode(self, params, tokens, states, lengths=None):
         """One chunk: ``tokens`` ``(B, T)`` with the carried ``states``
@@ -223,15 +193,11 @@ class AfmoeEncoder(WindowedCaches):
         dtype = params["embedding"].dtype
         eps = cfg.rms_norm_eps
         B, T = tokens.shape
-        with jax.named_scope("embedding"):
-            h = jnp.take(params["embedding"], tokens, axis=0).astype(
-                jnp.float32)
-            if cfg.mup_enabled:
-                h = h * math.sqrt(cfg.hidden_size)
+        h = embed(params, tokens, math.sqrt(cfg.hidden_size)
+                  if cfg.mup_enabled else None)
         pos = states["pos"]
-        valid = None
-        if lengths is not None:
-            valid = (jnp.arange(T)[None, :] < lengths[:, None]).reshape(-1)
+        valid = None if lengths is None else \
+            valid_lanes(lengths, T).reshape(-1)
         k_caches, v_caches = [], []
         rows = busiest = jnp.zeros((), jnp.int32)
         for i, kind in enumerate(cfg.layer_types):
@@ -240,17 +206,17 @@ class AfmoeEncoder(WindowedCaches):
                 out, kc, vc = self._attention(
                     p, h, states["k"][i], states["v"][i], pos, dtype,
                     sliding=kind == SLIDING)
-                h = h + _rms_norm(out, p["post_attn_norm"], eps)
+                h = h + rms_norm(out, p["post_attn_norm"], eps)
             k_caches.append(kc)
             v_caches.append(vc)
             if i < cfg.num_dense_layers:
                 with jax.named_scope(f"mlp_{i}"):
-                    m = _rms_norm(h, p["pre_mlp_norm"], eps)
+                    m = rms_norm(h, p["pre_mlp_norm"], eps)
                     f = moe.swiglu(m, p["w_in"], p["w_out"], dtype)
-                    h = h + _rms_norm(f, p["post_mlp_norm"], eps)
+                    h = h + rms_norm(f, p["post_mlp_norm"], eps)
             else:
                 with jax.named_scope(f"moe_{i}"):
-                    m = _rms_norm(h, p["pre_mlp_norm"], eps)
+                    m = rms_norm(h, p["pre_mlp_norm"], eps)
                     f, per_expert = moe.expert_layer(
                         p, m.reshape(B * T, -1), valid, dtype,
                         n_group=cfg.n_group, topk_group=cfg.topk_group,
@@ -259,12 +225,12 @@ class AfmoeEncoder(WindowedCaches):
                         norm_topk_prob=cfg.route_norm,
                         first=cfg.experts_held[0],
                         shared=bool(cfg.num_shared_experts))
-                    h = h + _rms_norm(f.reshape(B, T, -1),
-                                      p["post_mlp_norm"], eps)
+                    h = h + rms_norm(f.reshape(B, T, -1),
+                                     p["post_mlp_norm"], eps)
                 rows = rows + per_expert.sum()
                 busiest = busiest + per_expert.max()
         with jax.named_scope("final_norm"):
-            out = _rms_norm(h, params["final_norm"], eps)
+            out = rms_norm(h, params["final_norm"], eps)
         ran = jnp.int32(1 if cfg.n_moe_layers else 0)
         on_kernel = sum(attention.core_is_kernel(
             jax.default_backend(), dtype, T, kc.shape[2],
@@ -272,9 +238,9 @@ class AfmoeEncoder(WindowedCaches):
             cfg.head_dim) for kc in k_caches)
         new_states = {
             "k": tuple(k_caches), "v": tuple(v_caches), "pos": pos + T,
-            # sums since init_states, then what this program's rule said
-            "counts": states["counts"].at[:-1].add(
-                jnp.stack([rows, busiest, ran])).at[-1].set(on_kernel),
+            "counts": self.counts.update(
+                states["counts"], rows, busiest, ran,
+                attention_kernel_layers=on_kernel),
         }
         return out, new_states
 
@@ -287,29 +253,21 @@ class AfmoeEncoder(WindowedCaches):
         Hq, Hkv, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
                       cfg.head_dim)
         eps = cfg.rms_norm_eps
-        a = _rms_norm(h, p["input_norm"], eps).astype(dtype)
+        a = rms_norm(h, p["input_norm"], eps).astype(dtype)
         with jax.named_scope("qkv_proj"):
-            qkv = _matmul(a, p["qkv"], dtype)
-            q = qkv[..., :Hq * d].reshape(b, T, Hq, d)
-            k = qkv[..., Hq * d:(Hq + Hkv) * d].reshape(b, T, Hkv, d)
-            v = qkv[..., (Hq + Hkv) * d:].reshape(b, T, Hkv, d)
+            q, k, v = split_heads(matmul(a, p["qkv"], dtype), Hq, Hkv, d)
         with jax.named_scope("qk_norm"):
-            q = _rms_norm(q, p["q_norm"], eps)
-            k = _rms_norm(k, p["k_norm"], eps)
+            q = rms_norm(q, p["q_norm"], eps)
+            k = rms_norm(k, p["k_norm"], eps)
         if sliding:
-            with jax.named_scope("rope"):
-                positions = pos + jnp.arange(T)
-                q = mla.apply_rope(q, positions, self._inv_freq,
-                                   interleaved=False)
-                k = mla.apply_rope(k, positions, self._inv_freq,
-                                   interleaved=False)
+            q, k = rope_qk(q, k, pos, self._inv_freq)
         with jax.named_scope("window_core" if sliding else "global_core"):
             out, k_cache, v_cache = attention.gqa_cached(
                 q, k, v, k_cache, v_cache, pos, self._scale, mxu_dtype=dtype,
                 window=cfg.sliding_window if sliding else None)
         with jax.named_scope("gate"):
             out = out.reshape(b, T, Hq * d) * jax.nn.sigmoid(
-                _matmul(a, p["gate"]))
+                matmul(a, p["gate"]))
         with jax.named_scope("o_proj"):
-            out = _matmul(out, p["o"])
+            out = matmul(out, p["o"])
         return out, k_cache, v_cache

@@ -24,7 +24,7 @@ biases but the router's and the decay gate's; ``ops/kda.py``,
       b = sigmoid(x W_b)                                   (H,)
       S' = diag(exp(g)) S;  S = S' + b k (v - k^T S')^T;  o = S^T q
       KDA = [RMSNorm_d(o_h) * sigmoid(x W_g)] W_o
-    MLA(x): ``models/deepseek_v3.py::latent_block`` with one query
+    MLA(x): ``models/blocks.py::latent_block`` with one query
       matrix (``q_lora_rank: null``), plain rotary and a head-wise
       output gate ``o_h * sigmoid(x w_h)``
     FFN, layers < first_k_dense_replace: SwiGLU of intermediate_size
@@ -79,8 +79,9 @@ from typing import Any, ClassVar, Mapping, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from code_intelligence_tpu.models.deepseek_v3 import latent_block, share_of
-from code_intelligence_tpu.models.granite_hybrid import _matmul, _rms_norm
+from code_intelligence_tpu.models.blocks import (
+    CarriedCounts, Counts, config_from_dict, embed, held_experts,
+    latent_block, matmul, rms_norm, valid_lanes)
 from code_intelligence_tpu.ops import kda, mla, moe, ssd
 
 # published switches the encoder implements one value of: a configuration
@@ -143,8 +144,8 @@ class BailingHybridConfig:
     state_dtype: Any = jnp.bfloat16    # the latent cache's, the conv tails'
 
     def __post_init__(self):
-        held = self.experts_held or (0, self.num_experts)
-        object.__setattr__(self, "experts_held", tuple(int(v) for v in held))
+        object.__setattr__(self, "experts_held", held_experts(
+            self.experts_held, self.num_experts))
         object.__setattr__(self, "state_dtype", jnp.dtype(self.state_dtype))
         for name in _SWIGLU_LIMITS:
             limits = tuple(getattr(self, name))[:self.num_hidden_layers]
@@ -154,12 +155,6 @@ class BailingHybridConfig:
                     f"{name} is non-zero in a layer held ({limits}): the "
                     "config does not say where the clamp sits, and it is "
                     "not guessed")
-        first, count = self.experts_held
-        if not (0 <= first and 0 < count
-                and first + count <= self.num_experts):
-            raise ValueError(
-                f"experts_held {self.experts_held} lies outside the "
-                f"router's {self.num_experts} experts")
         if self.num_experts % self.n_group:
             raise ValueError("n_group must divide num_experts")
         if not 0 <= self.first_k_dense_replace <= self.num_hidden_layers:
@@ -171,20 +166,16 @@ class BailingHybridConfig:
 
     @classmethod
     def from_dict(cls, model: Mapping, **extra) -> "BailingHybridConfig":
-        """From a published ``config.json``'s keys; keys that do not
-        shape the encoder are passed over, a switch the encoder
+        """From a published ``config.json``'s keys; a switch the encoder
         implements one value of (``_IMPLEMENTED``) is refused at any
-        other. A configuration of a share carries ``experts_held:
-        {"first", "count", "of"}``: its ``num_experts`` then counts the
-        experts HELD, and ``of`` is the router's width."""
+        other. Of a share, its ``num_experts`` counts the experts
+        HELD."""
         for key, value in _IMPLEMENTED.items():
             if key in model and model[key] != value:
                 raise ValueError(
                     f"{key}={model[key]!r} is not implemented (only "
                     f"{value!r})")
-        names = {f.name for f in dataclasses.fields(cls)}
-        kw = {k: v for k, v in model.items() if k in names}
-        return cls(**{**kw, **share_of(model, "num_experts"), **extra})
+        return config_from_dict(cls, model, "num_experts", **extra)
 
     def is_latent(self, layer: int) -> bool:
         return (layer + 1) % self.layer_group_size == 0
@@ -220,8 +211,12 @@ def _l2_norm(x, eps=1e-6):
     return xf * jax.lax.rsqrt(jnp.sum(xf * xf, axis=-1, keepdims=True) + eps)
 
 
-class BailingHybridEncoder:
+class BailingHybridEncoder(CarriedCounts):
     """The encoder contract (`models/contract.py`) over the hybrid."""
+
+    # the KDA and the latent layers whose core the program ran on a
+    # Pallas kernel, as each op's ``core_is_kernel`` said
+    counts = Counts(sets=("kda_kernel_layers", "attention_kernel_layers"))
 
     def __init__(self, config: BailingHybridConfig, dtype=jnp.bfloat16):
         self.config = config
@@ -274,7 +269,7 @@ class BailingHybridEncoder:
                 jnp.zeros((batch, S, cfg.latent_dim), cfg.state_dtype)
                 for _ in cfg.latent_layers),
             "pos": jnp.zeros((), jnp.int32),
-            "counts": jnp.zeros((len(moe.COUNTERS) + 2,), jnp.int32),
+            "counts": self.counts.zeros(),
         }
 
     def state_bytes_per_row(self, max_len=None) -> int:
@@ -290,28 +285,12 @@ class BailingHybridEncoder:
             * cfg.latent_dim * cfg.state_dtype.itemsize
         return fixed + grows
 
-    def state_counters(self, states):
-        """The counts the expert layers have kept since ``init_states``
-        (``ops/moe.py::COUNTERS``) and, last, the KDA and the latent
-        layers whose core the group's programs ran on a Pallas kernel (a
-        device array; ``counter_attrs`` names them)."""
-        return states["counts"]
-
     def counter_attrs(self, counted) -> dict:
-        """Span attributes from the fetched ``state_counters`` of a
-        flush's groups: ``ops/moe.py::counter_attrs``, ``kda_layers``
-        (the configuration's), ``kda_kernel_layers`` and
-        ``attention_kernel_layers`` (the KDA layers on ``ops/kda.py``'s
-        kernel and the latent layers on ``ops/mla.py``'s in a group's
-        programs, as each op's ``core_is_kernel`` said), the last two
-        averaged over the groups."""
-        attrs = moe.counter_attrs(counted, self.config.n_moe_layers,
-                                  self.config.experts_held[1])
+        """`models/blocks.py::CarriedCounts`' and ``kda_layers``, the
+        configuration's."""
+        attrs = super().counter_attrs(counted)
         if counted:
             attrs["kda_layers"] = len(self.config.kda_layers)
-            for name, at in (("kda_kernel_layers", -2),
-                             ("attention_kernel_layers", -1)):
-                attrs[name] = sum(int(c[at]) for c in counted) / len(counted)
         return attrs
 
     def encode(self, params, tokens, states, lengths=None):
@@ -325,13 +304,11 @@ class BailingHybridEncoder:
         cfg = self.config
         dtype = params["embedding"].dtype
         B, T = tokens.shape
-        with jax.named_scope("embedding"):
-            h = jnp.take(params["embedding"], tokens, axis=0).astype(
-                jnp.float32)
+        h = embed(params, tokens)
         pos = states["pos"]
         if lengths is None:
             lengths = jnp.full((B,), T, jnp.int32)
-        valid = jnp.arange(T)[None, :] < lengths[:, None]       # (B, T)
+        valid = valid_lanes(lengths, T)
         kda_states, tails, latents = [], [], []
         rows = busiest = jnp.zeros((), jnp.int32)
         for i in range(cfg.num_hidden_layers):
@@ -356,7 +333,7 @@ class BailingHybridEncoder:
                 kda_states.append(S)
                 tails.append(tail)
             h = h + out
-            u = _rms_norm(h, p["ffn_norm"], cfg.rms_norm_eps)
+            u = rms_norm(h, p["ffn_norm"], cfg.rms_norm_eps)
             if i < cfg.first_k_dense_replace:
                 with jax.named_scope(f"mlp_{i}"):
                     h = h + moe.swiglu(u, p["w_in"], p["w_out"], dtype)
@@ -374,7 +351,7 @@ class BailingHybridEncoder:
                 rows = rows + per_expert.sum()
                 busiest = busiest + per_expert.max()
         with jax.named_scope("final_norm"):
-            out = _rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
+            out = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
         ran = jnp.int32(1 if cfg.n_moe_layers else 0)
         backend = jax.default_backend()
         on_kernel = sum(mla.core_is_kernel(
@@ -387,11 +364,10 @@ class BailingHybridEncoder:
         new_states = {
             "kda": tuple(kda_states), "conv": tuple(tails),
             "latent": tuple(latents), "pos": pos + T,
-            # sums since init_states, then what this program's rules
-            # said: the KDA and the latent layers on their kernels
-            "counts": states["counts"].at[:-2].add(
-                jnp.stack([rows, busiest, ran])).at[-2:].set(
-                jnp.array([kda_on_kernel, on_kernel], jnp.int32)),
+            "counts": self.counts.update(
+                states["counts"], rows, busiest, ran,
+                kda_kernel_layers=kda_on_kernel,
+                attention_kernel_layers=on_kernel),
         }
         return out, new_states
 
@@ -403,11 +379,11 @@ class BailingHybridEncoder:
         cfg = self.config
         b, T, _ = h.shape
         H, d, D = cfg.num_attention_heads, cfg.head_dim, cfg.kda_dim
-        u = _rms_norm(h, p["norm"], cfg.rms_norm_eps).astype(dtype)
+        u = rms_norm(h, p["norm"], cfg.rms_norm_eps).astype(dtype)
         with jax.named_scope("qkv_proj"):
-            qkv = _matmul(u, p["qkv"], dtype)
+            qkv = matmul(u, p["qkv"], dtype)
             # kept in float32: the decay gate feeds an exp of a running sum
-            fgb = _matmul(u, p["gates"])
+            fgb = matmul(u, p["gates"])
         with jax.named_scope("conv1d"):
             zero = jnp.zeros((3 * D,), jnp.float32)     # use_bias: false
             qkv, new_tail = ssd.causal_conv1d(qkv, p["conv_w"], zero, tail,
@@ -431,8 +407,8 @@ class BailingHybridEncoder:
                 q, k, v, g, beta, S, _KDA_CHUNK, mxu_dtype=dtype,
                 sub=_KDA_SUB)
         with jax.named_scope("gated_norm"):
-            o = _rms_norm(o, p["o_norm"], cfg.rms_norm_eps) \
+            o = rms_norm(o, p["o_norm"], cfg.rms_norm_eps) \
                 * jax.nn.sigmoid(fgb[..., D:2 * D]).reshape(b, T, H, d)
         with jax.named_scope("o_proj"):
-            out = _matmul(o.reshape(b, T, D), p["o"])
+            out = matmul(o.reshape(b, T, D), p["o"])
         return out, S_new, new_tail.astype(tail.dtype)

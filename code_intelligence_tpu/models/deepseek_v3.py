@@ -64,70 +64,10 @@ from typing import Any, ClassVar, Mapping, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from code_intelligence_tpu.models.granite_hybrid import _matmul, _rms_norm
+from code_intelligence_tpu.models.blocks import (
+    CarriedCounts, Counts, GrowingCache, config_from_dict, embed,
+    held_experts, latent_block, rms_norm, valid_lanes)
 from code_intelligence_tpu.ops import mla, moe
-
-
-def share_of(model: Mapping, count_key: str) -> dict:
-    """What a configuration of a SHARE says to its dataclass: the file's
-    ``experts_held: {"first", "count", "of"}`` beside a ``count_key``
-    that counts the experts held becomes the router's width under
-    ``count_key`` and ``experts_held = (first, count)``; nothing for a
-    mapping without the block."""
-    held = model.get("experts_held")
-    if not isinstance(held, Mapping):
-        return {}
-    if model.get(count_key, held["count"]) != held["count"]:
-        raise ValueError(
-            f"{count_key} {model[count_key]} is not the count of "
-            f"experts_held {dict(held)}")
-    return {count_key: held["of"],
-            "experts_held": (held["first"], held["count"])}
-
-
-def latent_block(p, h, cache, pos, dtype, *, heads: int, nope: int,
-                 rope: int, v_dim: int, rank: int, eps: float, inv_freq,
-                 rope_factor: float, scale: float, q_low_rank: bool = True,
-                 head_gate: bool = False):
-    """``MLA(RMSNorm(h))`` of the module's docstring over one chunk,
-    through the latent ``cache`` at ``pos``: ``(out (b, T, E) float32,
-    cache)``. One copy for every model with latent attention; what such
-    models differ in are two placements: a query made in two steps
-    through a normed low-rank ``c_q`` (leaves ``q_a``, ``q_norm``,
-    ``q_b``) or by one matrix ``q`` (``q_low_rank=False``), and
-    ``head_gate``: each head's output times ``sigmoid(u w_h)`` (leaf
-    ``gate`` ``(E, heads)``) before ``o``. Named scopes ``q_proj``,
-    ``kv_latent``, ``rope``, ``mla_core``, ``gate`` (where gated),
-    ``o_proj``."""
-    b, T, _ = h.shape
-    u = _rms_norm(h, p["norm"], eps).astype(dtype)
-    with jax.named_scope("q_proj"):
-        if q_low_rank:
-            c_q = _rms_norm(_matmul(u, p["q_a"]), p["q_norm"], eps)
-            q = _matmul(c_q, p["q_b"], dtype)
-        else:
-            q = _matmul(u, p["q"], dtype)
-        q = q.reshape(b, T, heads, nope + rope)
-    with jax.named_scope("kv_latent"):
-        kv = _matmul(u, p["kv_a"])
-        c_kv = _rms_norm(kv[..., :rank], p["kv_norm"], eps)
-    with jax.named_scope("rope"):
-        positions = pos + jnp.arange(T)
-        q_pe = mla.apply_rope(q[..., nope:], positions, inv_freq,
-                              rope_factor)
-        k_pe = mla.apply_rope(kv[..., rank:], positions, inv_freq,
-                              rope_factor)
-    latent = jnp.concatenate([c_kv, k_pe], axis=-1)
-    with jax.named_scope("mla_core"):
-        out, cache = mla.mla_cached(
-            q[..., :nope], q_pe, latent, cache, pos, p["kv_b"], scale,
-            v_dim, mxu_dtype=dtype)
-    if head_gate:
-        with jax.named_scope("gate"):
-            out = out * jax.nn.sigmoid(_matmul(u, p["gate"]))[..., None]
-    with jax.named_scope("o_proj"):
-        out = _matmul(out.reshape(b, T, heads * v_dim), p["o"])
-    return out, cache
 
 
 @dataclasses.dataclass(frozen=True)
@@ -165,8 +105,8 @@ class DeepseekV3Config:
     state_dtype: Any = jnp.bfloat16    # the latent cache's type
 
     def __post_init__(self):
-        held = self.experts_held or (0, self.n_routed_experts)
-        object.__setattr__(self, "experts_held", tuple(int(v) for v in held))
+        object.__setattr__(self, "experts_held", held_experts(
+            self.experts_held, self.n_routed_experts))
         object.__setattr__(self, "state_dtype", jnp.dtype(self.state_dtype))
         if self.rope_scaling is not None:  # hashable, as a frozen field is
             object.__setattr__(self, "rope_scaling", tuple(sorted(
@@ -176,12 +116,6 @@ class DeepseekV3Config:
                 "only scoring_func 'sigmoid' with topk_method 'noaux_tc' is "
                 f"implemented, not {self.scoring_func!r} / "
                 f"{self.topk_method!r}")
-        first, count = self.experts_held
-        if not (0 <= first and 0 < count
-                and first + count <= self.n_routed_experts):
-            raise ValueError(
-                f"experts_held {self.experts_held} lies outside the "
-                f"router's {self.n_routed_experts} experts")
         if self.n_routed_experts % self.n_group:
             raise ValueError("n_group must divide n_routed_experts")
         if not 0 <= self.first_k_dense_replace <= self.num_hidden_layers:
@@ -189,14 +123,9 @@ class DeepseekV3Config:
 
     @classmethod
     def from_dict(cls, model: Mapping, **extra) -> "DeepseekV3Config":
-        """From a published ``config.json``'s keys; keys that do not
-        shape the encoder are passed over. A configuration of a share
-        carries ``experts_held: {"first", "count", "of"}``: its
-        ``n_routed_experts`` then counts the experts HELD, and ``of`` is
-        the router's width."""
-        names = {f.name for f in dataclasses.fields(cls)}
-        kw = {k: v for k, v in model.items() if k in names}
-        return cls(**{**kw, **share_of(model, "n_routed_experts"), **extra})
+        """From a published ``config.json``'s keys; of a share, its
+        ``n_routed_experts`` counts the experts HELD."""
+        return config_from_dict(cls, model, "n_routed_experts", **extra)
 
     @property
     def rope(self) -> Optional[dict]:
@@ -217,8 +146,14 @@ class DeepseekV3Config:
         return self.num_hidden_layers - self.first_k_dense_replace
 
 
-class DeepseekV3Encoder:
-    """The encoder contract (`models/contract.py`) over DeepSeek-V3."""
+class DeepseekV3Encoder(GrowingCache, CarriedCounts):
+    """The encoder contract (`models/contract.py`) over DeepSeek-V3; the
+    sizes its latent cache is allocated at and the reading of its counts
+    are `models/blocks.py`'s."""
+
+    cache_kind = "latent"
+    # the attention layers whose core the program ran on the Pallas kernel
+    counts = Counts(sets=("attention_kernel_layers",))
 
     def __init__(self, config: DeepseekV3Config, dtype=jnp.bfloat16):
         self.config = config
@@ -234,24 +169,6 @@ class DeepseekV3Encoder:
     def out_dim(self) -> int:
         return self.config.hidden_size
 
-    def cache_positions(self, positions=None) -> int:
-        """Positions the latent cache is allocated at for documents of
-        up to ``positions`` tokens: their own length for short ones (one
-        chunk), the configured maximum for everything longer, so that
-        every multi-chunk group runs one compiled shape."""
-        cfg = self.config
-        if positions is None:
-            return cfg.kv_positions
-        if positions > cfg.kv_positions:
-            raise ValueError(
-                f"a document of {positions} positions does not fit the "
-                f"latent cache of kv_positions={cfg.kv_positions}")
-        return positions if positions <= cfg.kv_positions // 4 \
-            else cfg.kv_positions
-
-    def window_positions(self, positions=None) -> int:
-        return 0  # no layer attends under a window: no ring
-
     def init_states(self, batch: int, positions=None):
         cfg = self.config
         S = self.cache_positions(positions)
@@ -260,7 +177,7 @@ class DeepseekV3Encoder:
                 jnp.zeros((batch, S, cfg.latent_dim), cfg.state_dtype)
                 for _ in range(cfg.num_hidden_layers)),
             "pos": jnp.zeros((), jnp.int32),
-            "counts": jnp.zeros((len(moe.COUNTERS) + 1,), jnp.int32),
+            "counts": self.counts.zeros(),
         }
 
     def state_bytes_per_row(self, max_len=None) -> int:
@@ -269,27 +186,6 @@ class DeepseekV3Encoder:
         cfg = self.config
         return cfg.num_hidden_layers * self.cache_positions(max_len) \
             * cfg.latent_dim * cfg.state_dtype.itemsize
-
-    def state_counters(self, states):
-        """The counts the expert layers have kept since ``init_states``
-        (``ops/moe.py::COUNTERS``) and, last, the attention layers whose
-        core the group's programs ran on the Pallas kernel (a device
-        array; ``counter_attrs`` names them)."""
-        return states["counts"]
-
-    def counter_attrs(self, counted) -> dict:
-        """Span attributes from the fetched ``state_counters`` of a
-        flush's groups: ``ops/moe.py::counter_attrs`` and
-        ``attention_kernel_layers``, the attention layers on the Pallas
-        core in a group's programs (``ops/mla.py::core_is_kernel``: all
-        programs of a group run one chunk length against one cache
-        size, so one answer a group), averaged over the groups."""
-        attrs = moe.counter_attrs(counted, self.config.n_moe_layers,
-                                  self.config.experts_held[1])
-        if counted:
-            attrs["attention_kernel_layers"] = \
-                sum(int(c[-1]) for c in counted) / len(counted)
-        return attrs
 
     def encode(self, params, tokens, states, lengths=None):
         """One chunk: ``tokens`` ``(B, T)`` with the carried ``states``
@@ -301,13 +197,10 @@ class DeepseekV3Encoder:
         cfg = self.config
         dtype = params["embedding"].dtype
         B, T = tokens.shape
-        with jax.named_scope("embedding"):
-            h = jnp.take(params["embedding"], tokens, axis=0).astype(
-                jnp.float32)
+        h = embed(params, tokens)
         pos = states["pos"]
-        valid = None
-        if lengths is not None:
-            valid = (jnp.arange(T)[None, :] < lengths[:, None]).reshape(-1)
+        valid = None if lengths is None else \
+            valid_lanes(lengths, T).reshape(-1)
         latents = []
         rows = busiest = jnp.zeros((), jnp.int32)
         for i in range(cfg.num_hidden_layers):
@@ -317,7 +210,7 @@ class DeepseekV3Encoder:
                                              dtype)
             h = h + out
             latents.append(cache)
-            u = _rms_norm(h, p["ffn_norm"], cfg.rms_norm_eps)
+            u = rms_norm(h, p["ffn_norm"], cfg.rms_norm_eps)
             if i < cfg.first_k_dense_replace:
                 with jax.named_scope(f"mlp_{i}"):
                     h = h + moe.swiglu(u, p["w_in"], p["w_out"], dtype)
@@ -335,7 +228,7 @@ class DeepseekV3Encoder:
                 rows = rows + per_expert.sum()
                 busiest = busiest + per_expert.max()
         with jax.named_scope("final_norm"):
-            out = _rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
+            out = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
         ran = jnp.int32(1 if cfg.n_moe_layers else 0)
         on_kernel = sum(mla.core_is_kernel(
             jax.default_backend(), dtype, T, cache.shape[1],
@@ -344,9 +237,9 @@ class DeepseekV3Encoder:
         new_states = {
             "latent": tuple(latents),
             "pos": pos + T,
-            # sums since init_states, then what this program's rule said
-            "counts": states["counts"].at[:-1].add(
-                jnp.stack([rows, busiest, ran])).at[-1].set(on_kernel),
+            "counts": self.counts.update(
+                states["counts"], rows, busiest, ran,
+                attention_kernel_layers=on_kernel),
         }
         return out, new_states
 

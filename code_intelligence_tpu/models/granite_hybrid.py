@@ -49,6 +49,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from code_intelligence_tpu.models.blocks import (
+    GrowingCache, config_from_dict, embed, matmul, rms_norm)
 from code_intelligence_tpu.ops.attention import gqa_cached
 from code_intelligence_tpu.ops.ssd import causal_conv1d, ssd_scan
 
@@ -96,12 +98,9 @@ class GraniteHybridConfig:
 
     @classmethod
     def from_dict(cls, model: Mapping, **extra) -> "GraniteHybridConfig":
-        """From a published ``config.json``'s keys; keys that do not shape
-        the encoder (``logits_scaling``, ``rope_theta``, ...) are passed
-        over."""
-        names = {f.name for f in dataclasses.fields(cls)}
-        return cls(**{**{k: v for k, v in model.items() if k in names},
-                      **extra})
+        """From a published ``config.json``'s keys (``logits_scaling``,
+        ``rope_theta``, ... do not shape the encoder)."""
+        return config_from_dict(cls, model, **extra)
 
     @property
     def d_inner(self) -> int:
@@ -129,19 +128,12 @@ class GraniteHybridConfig:
         return sum(t == kind for t in self.layer_types)
 
 
-def _rms_norm(x, w, eps):
-    xf = x.astype(jnp.float32)
-    var = jnp.mean(xf * xf, axis=-1, keepdims=True)
-    return xf * lax.rsqrt(var + eps) * w.astype(jnp.float32)
+class GraniteHybridEncoder(GrowingCache):
+    """The encoder contract (`models/contract.py`) over the hybrid; the
+    sizes its key/value cache is allocated at are
+    `models/blocks.py::GrowingCache`'s."""
 
-
-def _matmul(x, w, out_dtype=jnp.float32):
-    return jnp.dot(x.astype(w.dtype), w, preferred_element_type=jnp.float32
-                   ).astype(out_dtype)
-
-
-class GraniteHybridEncoder:
-    """The encoder contract (`models/contract.py`) over the hybrid."""
+    cache_kind = "key/value"
 
     def __init__(self, config: GraniteHybridConfig, dtype=jnp.bfloat16):
         self.config = config
@@ -154,24 +146,6 @@ class GraniteHybridEncoder:
     @property
     def out_dim(self) -> int:
         return self.config.hidden_size
-
-    def cache_positions(self, positions=None) -> int:
-        """Positions the key/value cache is allocated at for documents of
-        up to ``positions`` tokens: their own length for short ones (one
-        chunk), the configured maximum for everything longer, so that
-        every multi-chunk group runs one compiled shape."""
-        cfg = self.config
-        if positions is None:
-            return cfg.kv_positions
-        if positions > cfg.kv_positions:
-            raise ValueError(
-                f"a document of {positions} positions does not fit the "
-                f"key/value cache of kv_positions={cfg.kv_positions}")
-        return positions if positions <= cfg.kv_positions // 4 \
-            else cfg.kv_positions
-
-    def window_positions(self, positions=None) -> int:
-        return 0  # no layer attends under a window: no ring
 
     def init_states(self, batch: int, positions=None):
         cfg, dtype = self.config, self.dtype
@@ -218,9 +192,7 @@ class GraniteHybridEncoder:
         cfg = self.config
         dtype = params["embedding"].dtype
         res = cfg.residual_multiplier
-        with jax.named_scope("embedding"):
-            h = jnp.take(params["embedding"], tokens, axis=0).astype(
-                jnp.float32) * cfg.embedding_multiplier
+        h = embed(params, tokens, cfg.embedding_multiplier)
         pos = states["pos"]
         ssm, conv, k_cache, v_cache = [], [], [], []
         mamba_at = attn_at = 0
@@ -258,7 +230,7 @@ class GraniteHybridEncoder:
                     v_cache.append(vc)
                 attn_at += n
         with jax.named_scope("final_norm"):
-            out = _rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
+            out = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
         new_states = {
             "ssm": tuple(ssm), "conv": tuple(conv),
             "k": jnp.stack(k_cache), "v": jnp.stack(v_cache),
@@ -270,20 +242,20 @@ class GraniteHybridEncoder:
 
     def _mlp(self, p, h, dtype):
         cfg = self.config
-        u = _rms_norm(h, p["mlp_norm"], cfg.rms_norm_eps)
-        g, v = jnp.split(_matmul(u, p["mlp_in"], dtype), 2, axis=-1)
+        u = rms_norm(h, p["mlp_norm"], cfg.rms_norm_eps)
+        g, v = jnp.split(matmul(u, p["mlp_in"], dtype), 2, axis=-1)
         act = jax.nn.silu(g.astype(jnp.float32)) * v.astype(jnp.float32)
-        return _matmul(act, p["mlp_out"])
+        return matmul(act, p["mlp_out"])
 
     def _mamba(self, p, h, S, tail, dtype):
         cfg = self.config
         b, T, _ = h.shape
         di, ds, H = cfg.d_inner, cfg.mamba_d_state, cfg.mamba_n_heads
-        u = _rms_norm(h, p["norm"], cfg.rms_norm_eps).astype(dtype)
+        u = rms_norm(h, p["norm"], cfg.rms_norm_eps).astype(dtype)
         # one product, kept in float32: the step size feeds an exp and
         # keeps its accumulation; z and xBC go on in the compute type
         # (the conv's carried tail is xBC as the conv read it)
-        zxd = _matmul(u, p["in_proj"])
+        zxd = matmul(u, p["in_proj"])
         z = zxd[..., :di].astype(dtype)
         xBC = zxd[..., di:di + cfg.conv_dim].astype(dtype)
         dt = jax.nn.softplus(zxd[..., di + cfg.conv_dim:]
@@ -300,19 +272,19 @@ class GraniteHybridEncoder:
                 mxu_dtype=dtype)
         with jax.named_scope("gated_norm"):
             y = y.reshape(b, T, di) * jax.nn.silu(z.astype(jnp.float32))
-            y = _rms_norm(y, p["gated_norm"], cfg.rms_norm_eps)
-        return _matmul(y, p["out_proj"]), S_new.astype(S.dtype), tail
+            y = rms_norm(y, p["gated_norm"], cfg.rms_norm_eps)
+        return matmul(y, p["out_proj"]), S_new.astype(S.dtype), tail
 
     def _attention(self, p, h, k_cache, v_cache, pos, dtype):
         cfg = self.config
         b, T, _ = h.shape
         Hq, Hkv, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
                       cfg.head_dim)
-        u = _rms_norm(h, p["norm"], cfg.rms_norm_eps).astype(dtype)
-        q = _matmul(u, p["q"], dtype).reshape(b, T, Hq, d)
-        k = _matmul(u, p["k"], dtype).reshape(b, T, Hkv, d)
-        v = _matmul(u, p["v"], dtype).reshape(b, T, Hkv, d)
+        u = rms_norm(h, p["norm"], cfg.rms_norm_eps).astype(dtype)
+        q = matmul(u, p["q"], dtype).reshape(b, T, Hq, d)
+        k = matmul(u, p["k"], dtype).reshape(b, T, Hkv, d)
+        v = matmul(u, p["v"], dtype).reshape(b, T, Hkv, d)
         out, k_cache, v_cache = gqa_cached(
             q, k, v, k_cache, v_cache, pos, cfg.attention_multiplier,
             mxu_dtype=dtype)
-        return _matmul(out.reshape(b, T, Hq * d), p["o"]), k_cache, v_cache
+        return matmul(out.reshape(b, T, Hq * d), p["o"]), k_cache, v_cache

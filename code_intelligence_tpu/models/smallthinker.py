@@ -64,17 +64,12 @@ from typing import Any, ClassVar, Mapping, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from code_intelligence_tpu.models.deepseek_v3 import share_of
-from code_intelligence_tpu.models.granite_hybrid import _matmul, _rms_norm
+from code_intelligence_tpu.models.blocks import (
+    CarriedCounts, Counts, config_from_dict, embed, held_experts, matmul,
+    rms_norm, rope_qk, split_heads, valid_lanes)
 from code_intelligence_tpu.models.windowed_caches import (
     WindowedCaches, ring_positions)
 from code_intelligence_tpu.ops import attention, mla, moe
-
-# beside ``ops/moe.py::COUNTERS`` in the carried counts: the rounds the
-# experts' loop ran (summed over layers and programs) and, last, the
-# attention layers on the Pallas core
-_ROUNDS, _ON_KERNEL = len(moe.COUNTERS), len(moe.COUNTERS) + 1
-
 
 @dataclasses.dataclass(frozen=True)
 class SmallThinkerConfig:
@@ -106,8 +101,8 @@ class SmallThinkerConfig:
     state_dtype: Any = jnp.bfloat16    # the caches' type
 
     def __post_init__(self):
-        held = self.experts_held or (0, self.moe_num_primary_experts)
-        object.__setattr__(self, "experts_held", tuple(int(v) for v in held))
+        object.__setattr__(self, "experts_held", held_experts(
+            self.experts_held, self.moe_num_primary_experts))
         object.__setattr__(self, "state_dtype", jnp.dtype(self.state_dtype))
         for name in ("rope_layout", "sliding_window_layout"):
             layout = tuple(int(v) for v in getattr(self, name))
@@ -129,27 +124,20 @@ class SmallThinkerConfig:
             raise ValueError(
                 "only plain rotary (rope_scaling null) is implemented, "
                 f"not rope_scaling {self.rope_scaling!r}")
-        first, count = self.experts_held
-        if not (0 <= first and 0 < count
-                and first + count <= self.moe_num_primary_experts):
-            raise ValueError(
-                f"experts_held {self.experts_held} lies outside the "
-                f"router's {self.moe_num_primary_experts} experts")
         if self.num_attention_heads % self.num_key_value_heads:
             raise ValueError(
                 "num_key_value_heads must divide num_attention_heads")
 
     @classmethod
     def from_dict(cls, model: Mapping, **extra) -> "SmallThinkerConfig":
-        """From a published ``config.json``'s keys; keys that do not
-        shape the encoder are passed over. A configuration of a share
-        carries ``experts_held: {"first", "count", "of"}``: its
-        ``moe_num_primary_experts`` then counts the experts HELD, and
-        ``of`` is the router's width."""
-        names = {f.name for f in dataclasses.fields(cls)}
-        kw = {k: v for k, v in model.items() if k in names}
-        return cls(**{**kw, **share_of(model, "moe_num_primary_experts"),
-                      **extra})
+        """From a published ``config.json``'s keys; of a share, its
+        ``moe_num_primary_experts`` counts the experts HELD."""
+        return config_from_dict(cls, model, "moe_num_primary_experts",
+                                **extra)
+
+    @property
+    def n_moe_layers(self) -> int:
+        return self.num_hidden_layers  # no dense layer
 
     @property
     def ring_positions(self) -> int:
@@ -161,12 +149,17 @@ class SmallThinkerConfig:
         return tuple(bool(v) for v in self.sliding_window_layout)
 
 
-class SmallThinkerEncoder(WindowedCaches):
+class SmallThinkerEncoder(WindowedCaches, CarriedCounts):
     """The encoder contract (`models/contract.py`) over SmallThinker; its
     two kinds of caches, their arithmetic and ``init_states`` are
-    `models/windowed_caches.py`'s."""
+    `models/windowed_caches.py`'s, the reading of its counts
+    `models/blocks.py`'s."""
 
-    n_counts = _ON_KERNEL + 1
+    # the rounds of ``routed_experts``' loop (``expert_rounds_mean``: a
+    # layer a program), and the attention layers whose core the program
+    # ran on the Pallas kernel
+    counts = Counts(sums=("expert_rounds",),
+                    sets=("attention_kernel_layers",))
 
     def __init__(self, config: SmallThinkerConfig, dtype=jnp.bfloat16):
         self.config = config
@@ -179,31 +172,6 @@ class SmallThinkerEncoder(WindowedCaches):
     @property
     def out_dim(self) -> int:
         return self.config.hidden_size
-
-    def state_counters(self, states):
-        """``ops/moe.py::COUNTERS`` summed since ``init_states``, then
-        the rounds the experts' loop ran and, last, the attention layers
-        whose core the group's programs ran on the Pallas kernel (a
-        device array; ``counter_attrs`` names them)."""
-        return states["counts"]
-
-    def counter_attrs(self, counted) -> dict:
-        """Span attributes from the fetched ``state_counters`` of a
-        flush's groups: ``ops/moe.py::counter_attrs``;
-        ``expert_rounds_mean``, the rounds of ``routed_experts``' loop a
-        layer a program; and ``attention_kernel_layers``, as
-        ``models/afmoe.py`` has it."""
-        cfg = self.config
-        attrs = moe.counter_attrs(counted, cfg.num_hidden_layers,
-                                  cfg.experts_held[1])
-        if attrs:
-            attrs["expert_rounds_mean"] = \
-                sum(int(c[_ROUNDS]) for c in counted) \
-                / (attrs["moe_programs"] * cfg.num_hidden_layers)
-        if counted:
-            attrs["attention_kernel_layers"] = \
-                sum(int(c[_ON_KERNEL]) for c in counted) / len(counted)
-        return attrs
 
     def encode(self, params, tokens, states, lengths=None):
         """One chunk: ``tokens`` ``(B, T)`` with the carried ``states``
@@ -218,13 +186,10 @@ class SmallThinkerEncoder(WindowedCaches):
         eps = cfg.rms_norm_eps
         B, T = tokens.shape
         first, held = cfg.experts_held
-        with jax.named_scope("embedding"):
-            h = jnp.take(params["embedding"], tokens, axis=0).astype(
-                jnp.float32)
+        h = embed(params, tokens)
         pos = states["pos"]
-        valid = None
-        if lengths is not None:
-            valid = (jnp.arange(T)[None, :] < lengths[:, None]).reshape(-1)
+        valid = None if lengths is None else \
+            valid_lanes(lengths, T).reshape(-1)
         k_caches, v_caches = [], []
         rows = busiest = rounds = jnp.zeros((), jnp.int32)
         for i in range(cfg.num_hidden_layers):
@@ -246,7 +211,7 @@ class SmallThinkerEncoder(WindowedCaches):
             k_caches.append(kc)
             v_caches.append(vc)
             with jax.named_scope(f"moe_{i}"):
-                m = _rms_norm(h, p["post_norm"], eps)
+                m = rms_norm(h, p["post_norm"], eps)
                 f, per_expert = moe.routed_experts(
                     m.reshape(B * T, -1), experts, weights, p["experts_in"],
                     p["experts_out"], first, valid, act="relu",
@@ -258,17 +223,16 @@ class SmallThinkerEncoder(WindowedCaches):
             # ``routed_experts``' loop takes B * T assignments a round
             rounds = rounds + (landed + B * T - 1) // (B * T)
         with jax.named_scope("final_norm"):
-            out = _rms_norm(h, params["final_norm"], eps)
+            out = rms_norm(h, params["final_norm"], eps)
         on_kernel = sum(attention.core_is_kernel(
             jax.default_backend(), dtype, T, kc.shape[2],
             cfg.num_attention_heads // cfg.num_key_value_heads,
             cfg.head_dim) for kc in k_caches)
         new_states = {
             "k": tuple(k_caches), "v": tuple(v_caches), "pos": pos + T,
-            # sums since init_states, then what this program's rule said
-            "counts": states["counts"].at[:_ON_KERNEL].add(jnp.stack(
-                [rows, busiest, jnp.int32(1), rounds])).at[_ON_KERNEL].set(
-                    on_kernel),
+            "counts": self.counts.update(
+                states["counts"], rows, busiest, jnp.int32(1),
+                expert_rounds=rounds, attention_kernel_layers=on_kernel),
         }
         return out, new_states
 
@@ -282,23 +246,15 @@ class SmallThinkerEncoder(WindowedCaches):
         b, T, _ = h.shape
         Hq, Hkv, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
                       cfg.head_dim)
-        a = _rms_norm(h, p["input_norm"], cfg.rms_norm_eps).astype(dtype)
+        a = rms_norm(h, p["input_norm"], cfg.rms_norm_eps).astype(dtype)
         with jax.named_scope("qkv_proj"):
-            qkv = _matmul(a, p["qkv"], dtype)
-            q = qkv[..., :Hq * d].reshape(b, T, Hq, d)
-            k = qkv[..., Hq * d:(Hq + Hkv) * d].reshape(b, T, Hkv, d)
-            v = qkv[..., (Hq + Hkv) * d:].reshape(b, T, Hkv, d)
+            q, k, v = split_heads(matmul(a, p["qkv"], dtype), Hq, Hkv, d)
         if rotary:
-            with jax.named_scope("rope"):
-                positions = pos + jnp.arange(T)
-                q = mla.apply_rope(q, positions, self._inv_freq,
-                                   interleaved=False)
-                k = mla.apply_rope(k, positions, self._inv_freq,
-                                   interleaved=False)
+            q, k = rope_qk(q, k, pos, self._inv_freq)
         with jax.named_scope("window_core" if sliding else "global_core"):
             out, k_cache, v_cache = attention.gqa_cached(
                 q, k, v, k_cache, v_cache, pos, self._scale, mxu_dtype=dtype,
                 window=cfg.sliding_window_size if sliding else None)
         with jax.named_scope("o_proj"):
-            out = _matmul(out.reshape(b, T, Hq * d), p["o"])
+            out = matmul(out.reshape(b, T, Hq * d), p["o"])
         return out, k_cache, v_cache

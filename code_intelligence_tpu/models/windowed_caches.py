@@ -8,7 +8,7 @@ every such model.
 An encoder that mixes this in has ``self.config`` with ``kv_positions``,
 ``chunk_positions``, ``ring_positions``, ``sliding_layers`` (a bool a
 layer), ``num_key_value_heads``, ``head_dim`` and ``state_dtype``, and
-says how many int32 counts it carries (``n_counts``).
+says which counts it carries (``counts``, a `models/blocks.py::Counts`).
 """
 
 from __future__ import annotations
@@ -24,8 +24,6 @@ def ring_positions(window: int, chunk: int) -> int:
 
 
 class WindowedCaches:
-    n_counts: int  # the int32 counts an encoder keeps beside its caches
-
     def cache_positions(self, positions=None) -> int:
         """Positions a global layer's cache is allocated at for documents
         of up to ``positions`` tokens: their own length where one chunk
@@ -75,7 +73,7 @@ class WindowedCaches:
 
         return {"k": caches(), "v": caches(),
                 "pos": jnp.zeros((), jnp.int32),
-                "counts": jnp.zeros((self.n_counts,), jnp.int32)}
+                "counts": self.counts.zeros()}
 
     def state_bytes_per_row(self, max_len=None) -> int:
         """Bytes of keys and values one row holds for a document of

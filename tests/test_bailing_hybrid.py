@@ -31,7 +31,7 @@ from code_intelligence_tpu.models import (
     BailingHybridConfig, BailingHybridEncoder, ChunkEncoder, build_encoder,
     make_config)
 from code_intelligence_tpu.models import contract
-from code_intelligence_tpu.models.deepseek_v3 import latent_block
+from code_intelligence_tpu.models.blocks import latent_block
 from code_intelligence_tpu.ops import kda, mla, moe
 from code_intelligence_tpu.ops.ssd import causal_conv1d
 from code_intelligence_tpu.text import SPECIALS, Vocab

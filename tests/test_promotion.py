@@ -53,6 +53,11 @@ def _make_registry(tmp_path, versions=("v1", "v2"), auc=0.95):
 def _make_ctrl(tmp_path, reg, rollout, **kw):
     kw.setdefault("deployed_config_path", tmp_path / "deployed.yaml")
     kw.setdefault("min_canary_requests", 3)
+    # no wall-clock gate: shadow replay times ONE call of each engine, a
+    # few microseconds of a SmokeEngine, and one stalled call on a loaded
+    # host reads as a latency ratio above 5: the candidate is rejected
+    # before its canary, and a later rollback is a no-op
+    kw.setdefault("gates", ShadowGates(max_latency_ratio=None))
     return PromotionController(reg, rollout, tmp_path / "promo.json",
                                "org/m", **kw)
 
@@ -380,7 +385,10 @@ class TestPromotionController:
         mgr.serve("t", "b", _embed_fn)
         ctrl = _make_ctrl(tmp_path, reg, mgr)
         ctrl.begin("v2", SmokeEngine())
+        assert ctrl.state.phase == "canary", ctrl.state.history[-1]
         ctrl.rollback("trip")
+        meta = reg.get_version("org/m", "v2").meta
+        assert meta["status"] == "rolled_back" and meta["cooldown_until"]
         mgr2 = RolloutManager(SmokeEngine(), version="v1")
         ctrl2 = PromotionController(reg, mgr2, tmp_path / "promo2.json",
                                     "org/m")
