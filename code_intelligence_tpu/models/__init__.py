@@ -22,6 +22,10 @@ from code_intelligence_tpu.models.granite_hybrid import (
     GraniteHybridConfig,
     GraniteHybridEncoder,
 )
+from code_intelligence_tpu.models.longcat_flash import (
+    LongcatFlashConfig,
+    LongcatFlashEncoder,
+)
 from code_intelligence_tpu.models.smallthinker import (
     SmallThinkerConfig,
     SmallThinkerEncoder,
@@ -32,4 +36,5 @@ __all__ = ["AfmoeConfig", "AfmoeEncoder", "AWDLSTMConfig", "AWDLSTMEncoder", "AW
            "ChunkEncoder", "build_encoder", "make_config",
            "DeepseekV3Config", "DeepseekV3Encoder",
            "GraniteHybridConfig", "GraniteHybridEncoder",
+           "LongcatFlashConfig", "LongcatFlashEncoder",
            "SmallThinkerConfig", "SmallThinkerEncoder"]

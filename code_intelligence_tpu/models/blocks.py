@@ -108,15 +108,19 @@ def config_from_dict(cls, model: Mapping, count_key=None, **extra):
 def latent_block(p, h, cache, pos, dtype, *, heads: int, nope: int,
                  rope: int, v_dim: int, rank: int, eps: float, inv_freq,
                  rope_factor: float, scale: float, q_low_rank: bool = True,
-                 head_gate: bool = False):
+                 head_gate: bool = False, q_scale: float = None,
+                 kv_scale: float = None):
     """``MLA(RMSNorm(h))`` of `models/deepseek_v3.py`'s docstring over
     one chunk, through the latent ``cache`` at ``pos``: ``(out (b, T, E)
     float32, cache)``. One copy for every model with latent attention;
-    what such models differ in are two placements: a query made in two
+    what such models differ in are placements: a query made in two
     steps through a normed low-rank ``c_q`` (leaves ``q_a``, ``q_norm``,
-    ``q_b``) or by one matrix ``q`` (``q_low_rank=False``), and
+    ``q_b``) or by one matrix ``q`` (``q_low_rank=False``);
     ``head_gate``: each head's output times ``sigmoid(u w_h)`` (leaf
-    ``gate`` ``(E, heads)``) before ``o``. Named scopes ``q_proj``,
+    ``gate`` ``(E, heads)``) before ``o``; and two fixed multipliers,
+    ``q_scale`` on the normed ``c_q`` and ``kv_scale`` on the normed
+    ``c_kv`` (the cache then holds the scaled ``c_kv``; ``k_pe`` is
+    never scaled), none where they are ``None``. Named scopes ``q_proj``,
     ``kv_latent``, ``rope``, ``mla_core``, ``gate`` (where gated),
     ``o_proj``."""
     b, T, _ = h.shape
@@ -124,6 +128,8 @@ def latent_block(p, h, cache, pos, dtype, *, heads: int, nope: int,
     with jax.named_scope("q_proj"):
         if q_low_rank:
             c_q = rms_norm(matmul(u, p["q_a"]), p["q_norm"], eps)
+            if q_scale is not None:
+                c_q = c_q * q_scale
             q = matmul(c_q, p["q_b"], dtype)
         else:
             q = matmul(u, p["q"], dtype)
@@ -131,6 +137,8 @@ def latent_block(p, h, cache, pos, dtype, *, heads: int, nope: int,
     with jax.named_scope("kv_latent"):
         kv = matmul(u, p["kv_a"])
         c_kv = rms_norm(kv[..., :rank], p["kv_norm"], eps)
+        if kv_scale is not None:
+            c_kv = c_kv * kv_scale
     with jax.named_scope("rope"):
         positions = pos + jnp.arange(T)
         q_pe = mla.apply_rope(q[..., nope:], positions, inv_freq,
@@ -182,14 +190,17 @@ class Counts:
     ``states["counts"]``, written once: an encoder names its own slots
     and reads and writes them by name. ``ops/moe.py::COUNTERS`` and the
     encoder's ``sums`` add up over layers and a group's programs since
-    ``init_states``; ``sets``, after them, each program sets to what its
-    trace knows (the layers an op's ``core_is_kernel`` put on a Pallas
-    kernel: all programs of a group run one chunk length against one
-    cache size, so one answer a group)."""
+    ``init_states`` (``sums`` reach the span as a mean a layer a
+    program, ``totals`` as they are); ``sets``, after them, each program
+    sets to what its trace knows (the layers an op's ``core_is_kernel``
+    put on a Pallas kernel: all programs of a group run one chunk length
+    against one cache size, so one answer a group)."""
 
-    def __init__(self, sums: Sequence[str] = (), sets: Sequence[str] = ()):
-        self.sums, self.sets = tuple(sums), tuple(sets)
-        self.names = moe.COUNTERS + self.sums + self.sets
+    def __init__(self, sums: Sequence[str] = (), sets: Sequence[str] = (),
+                 totals: Sequence[str] = ()):
+        self.sums, self.totals, self.sets = (
+            tuple(sums), tuple(totals), tuple(sets))
+        self.names = moe.COUNTERS + self.sums + self.totals + self.sets
 
     def zeros(self):
         return jnp.zeros((len(self.names),), jnp.int32)
@@ -200,7 +211,8 @@ class Counts:
         own slots added to or set, each ``by_name``."""
         first_set = len(self.names) - len(self.sets)
         counts = counts.at[:first_set].add(jnp.stack(
-            [rows, busiest, ran, *(by_name[name] for name in self.sums)]))
+            [rows, busiest, ran,
+             *(by_name[name] for name in self.sums + self.totals)]))
         values = [by_name[name] for name in self.sets]
         # one slot is a scalar update and several are one of a slice, as
         # the programs of the ledger's cells were lowered
@@ -216,13 +228,15 @@ class Counts:
     def attrs(self, counted, n_moe_layers: int, held: int) -> dict:
         """Span attributes from the fetched vectors of a flush's groups:
         ``ops/moe.py::counter_attrs``, each of ``sums`` a layer a program
-        as ``<name>_mean``, each of ``sets`` under its name, averaged
-        over the groups."""
+        as ``<name>_mean``, each of ``totals`` summed under its name,
+        each of ``sets`` under its name, averaged over the groups."""
         attrs = moe.counter_attrs(counted, n_moe_layers, held)
         if attrs:
             layer_programs = attrs["moe_programs"] * n_moe_layers
             attrs.update({f"{name}_mean": self.total(counted, name)
                           / layer_programs for name in self.sums})
+            attrs.update({name: self.total(counted, name)
+                          for name in self.totals})
         if counted:
             attrs.update({name: self.total(counted, name) / len(counted)
                           for name in self.sets})

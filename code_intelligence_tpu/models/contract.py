@@ -63,6 +63,8 @@ from code_intelligence_tpu.models.deepseek_v3 import (
     DeepseekV3Config, DeepseekV3Encoder)
 from code_intelligence_tpu.models.granite_hybrid import (
     GraniteHybridConfig, GraniteHybridEncoder)
+from code_intelligence_tpu.models.longcat_flash import (
+    LongcatFlashConfig, LongcatFlashEncoder)
 from code_intelligence_tpu.models.smallthinker import (
     SmallThinkerConfig, SmallThinkerEncoder)
 
@@ -124,6 +126,9 @@ ENCODERS = {
     SmallThinkerConfig.architecture: (
         SmallThinkerConfig, SmallThinkerConfig.from_dict,
         _in_weights_dtype(SmallThinkerEncoder)),
+    LongcatFlashConfig.architecture: (
+        LongcatFlashConfig, LongcatFlashConfig.from_dict,
+        _in_weights_dtype(LongcatFlashEncoder)),
 }
 
 

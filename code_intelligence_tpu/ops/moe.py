@@ -17,7 +17,18 @@ groups are kept, and the ``top_k`` best experts among them are chosen;
 the weights are the UNBIASED scores of the chosen, normalised to sum 1
 and multiplied by ``scaling``. With ``score_func="softmax"`` it is the
 plain softmax router instead: the choice is made on the logits and the
-weights are the softmax over the chosen logits.
+weights are the softmax over the chosen logits. With
+``score_func="softmax_all"`` the scores are the softmax over ALL the
+router's outputs; choice on ``score + bias`` and weights the unbiased
+scores of the chosen as for the sigmoid, left as they are where
+``norm_topk_prob`` is false (LongCat-Flash).
+
+**A router may be wider than the experts that have weights.** Outputs at
+or past ``n_routed`` name ZERO-COMPUTE experts (identity: ``E(h) = h``).
+Nobody holds them and they sort behind every held expert like an
+absent chip's; ``zero_experts`` gives every token ``(sum of the weights
+it gave them) * h``, one reduction over ``top_k`` and one multiply a
+token, on whichever chip the token is.
 
 **Routing and applying are two calls.** ``routed_experts`` takes a
 router's choice and the tensor the experts READ, so a model whose router
@@ -63,6 +74,12 @@ import jax.numpy as jnp
 from jax import lax
 
 
+# the scores a router chooses on: of each output alone, the logits (the
+# softmax is taken over the chosen), or the softmax over all outputs
+_SCORES = {"sigmoid": jax.nn.sigmoid, "softmax": lambda logits: logits,
+           "softmax_all": lambda logits: jax.nn.softmax(logits, axis=-1)}
+
+
 def route(
     h: jnp.ndarray,         # (N, E) float32
     w_router: jnp.ndarray,  # (E, n_experts)
@@ -81,14 +98,18 @@ def route(
     ``"softmax"``: the choice is made on the logits (+ ``bias``, where
     there is one) and the weights are the softmax over the CHOSEN logits
     (= the softmax over all, taken at the chosen and normalised:
-    ``norm_topk_prob`` holds by construction)."""
-    if score_func not in ("sigmoid", "softmax"):
-        raise ValueError(f"score_func {score_func!r}: 'sigmoid' or 'softmax'")
+    ``norm_topk_prob`` holds by construction); ``"softmax_all"``: the
+    scores are the softmax over all ``n_experts`` outputs, chosen and
+    weighed as the sigmoid's (``norm_topk_prob`` false leaves the
+    weights the unbiased scores themselves)."""
+    if score_func not in _SCORES:
+        raise ValueError(
+            f"score_func {score_func!r}: one of {sorted(_SCORES)}")
     logits = jnp.dot(
         h.astype(jnp.float32), w_router.astype(jnp.float32),
         precision=lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
-    scores = jax.nn.sigmoid(logits) if score_func == "sigmoid" else logits
+    scores = _SCORES[score_func](logits)
     choice = scores if bias is None else scores + bias.astype(jnp.float32)
     N, n_experts = choice.shape
     if n_group > 1:
@@ -228,6 +249,27 @@ def routed_experts(
             N, 1 << (max(_BACK_ROWS // top_k, 1).bit_length() - 1))
         y = lax.map(back, pos.reshape(top_k, N // part, part).swapaxes(0, 1))
     return y.reshape(N, E), per_expert
+
+
+def zero_experts(
+    x: jnp.ndarray,        # (N, E) float32: what the experts read
+    experts: jnp.ndarray,  # (N, top_k) int32, over all the router's outputs
+    weights: jnp.ndarray,  # (N, top_k) float32
+    n_routed: int,
+    valid: Optional[jnp.ndarray] = None,  # (N,) bool
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``(y (N, E) float32, choices () int32)``: what a token's
+    zero-compute (identity) experts give it, ``(sum of the weights of
+    its choices at or past n_routed) * x``, and how many such choices
+    the ``valid`` tokens made. Held by no chip and never sorted: every
+    chip computes this for its own tokens. Named scope
+    ``zero_experts``."""
+    with jax.named_scope("zero_experts"):
+        chosen = experts >= n_routed
+        if valid is not None:
+            chosen = chosen & valid[:, None]
+        weight = jnp.sum(jnp.where(chosen, weights, 0.0), axis=-1)
+        return weight[:, None] * x, jnp.sum(chosen, dtype=jnp.int32)
 
 
 def expert_layer(

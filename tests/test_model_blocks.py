@@ -17,7 +17,7 @@ from code_intelligence_tpu.ops import moe
 
 MODELS = Path(blocks.__file__).resolve().parent
 ARCHITECTURES = ("granite_hybrid", "deepseek_v3", "afmoe", "bailing_hybrid",
-                 "smallthinker", "awd_lstm")
+                 "smallthinker", "longcat_flash", "awd_lstm")
 
 
 def _imported(source: str):
@@ -67,12 +67,19 @@ EXPERT_MODELS = {
         TINY, moe_num_primary_experts=4, rope_layout=[0, 1],
         sliding_window_layout=[0, 1], chunk_positions=4,
         sliding_window_size=4),
+    "longcat_flash": dict(
+        vocab_size=50, hidden_size=16, kv_positions=16, num_layers=2,
+        n_routed_experts=4, zero_expert_num=2, moe_topk=2),
 }
+# an encoder's own slots: (sums, totals, sets)
 OWN_SLOTS = {
-    "deepseek_v3": ((), ("attention_kernel_layers",)),
-    "afmoe": ((), ("attention_kernel_layers",)),
-    "bailing_hybrid": ((), ("kda_kernel_layers", "attention_kernel_layers")),
-    "smallthinker": (("expert_rounds",), ("attention_kernel_layers",)),
+    "deepseek_v3": ((), (), ("attention_kernel_layers",)),
+    "afmoe": ((), (), ("attention_kernel_layers",)),
+    "bailing_hybrid": ((), (), ("kda_kernel_layers",
+                                "attention_kernel_layers")),
+    "smallthinker": (("expert_rounds",), (), ("attention_kernel_layers",)),
+    "longcat_flash": (("expert_rounds",), ("zero_choices", "valid_choices"),
+                      ("attention_kernel_layers",)),
 }
 
 
@@ -81,33 +88,36 @@ def test_an_encoders_counts_are_one_layout_read_by_name(architecture):
     enc = build_encoder(make_config(architecture,
                                     EXPERT_MODELS[architecture]))
     counts = enc.counts
-    sums, sets = OWN_SLOTS[architecture]
-    assert counts.names == moe.COUNTERS + sums + sets
+    sums, totals, sets = OWN_SLOTS[architecture]
+    assert counts.names == moe.COUNTERS + sums + totals + sets
     vector = enc.state_counters(enc.init_states(1))
     assert vector.shape == (len(counts.names),)
     assert vector.dtype == jnp.int32
     assert not np.asarray(vector).any()
 
     one = jnp.int32(1)
-    own = {name: 10 + i for i, name in enumerate(sums + sets)}
+    own = {name: 10 + i for i, name in enumerate(sums + totals + sets)}
     once = counts.update(vector, 6 * one, 4 * one, one, **own)
     twice = counts.update(once, 6 * one, 4 * one, one, **own)
     # a sum adds up over a group's programs, a set slot is the last
     # program's answer
     assert [counts.total([np.asarray(twice)], name)
             for name in moe.COUNTERS] == [12, 8, 2]
-    for name in sums:
+    for name in sums + totals:
         assert counts.total([np.asarray(twice)], name) == 2 * own[name]
     for name in sets:
         assert counts.total([np.asarray(twice)], name) == own[name]
 
     # two groups of a flush: every set slot under its own name, averaged
-    # over the groups; every sum of the encoder's own as a mean
+    # over the groups; every sum of the encoder's own as a mean a layer a
+    # program, every total as it is
     attrs = enc.counter_attrs([np.asarray(once), np.asarray(twice)])
     assert {"routed_rows", "moe_programs", "expert_rows_max",
-            "expert_rows_mean", *sets,
+            "expert_rows_mean", *sets, *totals,
             *(f"{name}_mean" for name in sums)} <= set(attrs)
     assert attrs["routed_rows"] == 18 and attrs["moe_programs"] == 3
+    for name in totals:
+        assert attrs[name] == 3 * own[name]
     for name in sets:
         assert attrs[name] == own[name]
     assert enc.counter_attrs([]) == {}
@@ -118,6 +128,12 @@ def test_a_slot_is_written_by_its_name_and_a_missing_one_is_an_error():
     zero = jnp.int32(0)
     got = counts.update(counts.zeros(), zero, zero, zero, c=3, a=1, b=2)
     assert np.asarray(got).tolist() == [0, 0, 0, 1, 2, 3]
+    # totals lie between the sums and the sets and add up like the sums
+    both = blocks.Counts(sums=("a",), totals=("t",), sets=("b",))
+    got = both.update(both.update(both.zeros(), zero, zero, zero,
+                                  a=1, t=5, b=2), zero, zero, zero,
+                      a=1, t=5, b=7)
+    assert np.asarray(got).tolist() == [0, 0, 0, 2, 10, 7]
     for own in ({"a": 1, "b": 2}, {"b": 2, "c": 3}, {"a": 1, "B": 2, "c": 3}):
         with pytest.raises(KeyError):
             counts.update(counts.zeros(), zero, zero, zero, **own)
@@ -142,6 +158,7 @@ GROWING = {
         layer_types=["mamba", "attention"], num_attention_heads=2,
         num_key_value_heads=1, mamba_n_heads=4, mamba_d_head=8)),
     "deepseek_v3": ("latent", EXPERT_MODELS["deepseek_v3"]),
+    "longcat_flash": ("latent", EXPERT_MODELS["longcat_flash"]),
 }
 
 

@@ -56,6 +56,17 @@ def test_the_latent_kernel_compiles_at_the_long_caches(
     assert "conditional" not in text
 
 
+# `longcat_bulk_long_tail`: 64 heads (eight steps of 8 heads) against the
+# long group's caches of 16,384 positions and the short group's of 4,096,
+# at rows its programs narrow to
+@pytest.mark.parametrize("rows,S", [(16, 16384), (2, 16384), (8, 4096)])
+def test_the_latent_kernel_compiles_at_sixty_four_heads(
+        one_chip, monkeypatch, rows, S):
+    text = _mla_text(monkeypatch, one_chip, rows, 512, S, H=64)
+    assert "tpu_custom_call" in text and "mla_cached_core" in text
+    assert "conditional" not in text
+
+
 # ops/kda.py::kda_scan at the same cell's shapes (32 heads of 128 | 128,
 # chunks of 64 in sub-blocks of 16, float32 operands as the encoder holds
 # them, bfloat16 in-chunk products), at the rows its programs narrow to
