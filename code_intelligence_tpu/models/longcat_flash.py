@@ -239,8 +239,9 @@ class LongcatFlashEncoder(GrowingCache, CarriedCounts):
             landed = per_expert.sum()
             rows = rows + landed
             busiest = busiest + per_expert.max()
-            # ``routed_experts``' loop takes B * T assignments a round
-            rounds = rounds + (landed + N - 1) // N
+            rounds = rounds + moe.rounds_run(
+                landed, N, cfg.moe_topk, cfg.experts_held[1],
+                p["router"].shape[1])
             zero_choices = zero_choices + zeros
         with jax.named_scope("final_norm"):
             out = rms_norm(h, params["final_norm"], eps)
@@ -287,7 +288,7 @@ class LongcatFlashEncoder(GrowingCache, CarriedCounts):
                 score_func="softmax_all")
         y, per_expert = moe.routed_experts(
             u, experts, weights, p["experts_in"], p["experts_out"],
-            cfg.experts_held[0], valid)
+            cfg.experts_held[0], p["router"].shape[1], valid)
         z, zeros = moe.zero_experts(
             u, experts, weights, cfg.n_routed_experts, valid)
         return y + z, per_expert, zeros

@@ -214,14 +214,14 @@ class SmallThinkerEncoder(WindowedCaches, CarriedCounts):
                 m = rms_norm(h, p["post_norm"], eps)
                 f, per_expert = moe.routed_experts(
                     m.reshape(B * T, -1), experts, weights, p["experts_in"],
-                    p["experts_out"], first, valid, act="relu",
-                    assigned=assigned)
+                    p["experts_out"], first, p["router"].shape[1], valid,
+                    act="relu", assigned=assigned)
                 h = h + f.reshape(B, T, -1)
             landed = per_expert.sum()
             rows = rows + landed
             busiest = busiest + per_expert.max()
-            # ``routed_experts``' loop takes B * T assignments a round
-            rounds = rounds + (landed + B * T - 1) // (B * T)
+            rounds = rounds + moe.rounds_run(
+                landed, B * T, experts.shape[1], held, p["router"].shape[1])
         with jax.named_scope("final_norm"):
             out = rms_norm(h, params["final_norm"], eps)
         on_kernel = sum(attention.core_is_kernel(

@@ -44,18 +44,31 @@ not a worst-case bound). No token is ever dropped and there is no
 capacity factor: assignments are sorted by expert and taken ``N`` (the
 tokens' number) at a time for as many rounds as they need (one, unless
 more than ``N`` assignments land here: ``top_k * count / n_experts`` of
-a token's choices do on average).
+a token's choices do on average, ``n_experts`` the router's width, which
+the caller has). A share never gathers ``top_k * N`` rows of ``x`` to
+use a sixteenth of them, and a round's ``N``-row operands sit in the
+chip's fast memory: larger rounds move the same bytes and lose that.
+
+**Where every expert is held the rounds are one pass** (``one_pass``:
+more than ``top_k - 1`` of a token's ``top_k`` choices land here on
+average): all ``top_k * N`` assignments at once, no loop, no slice of
+the sort and no update of a buffer. XLA's grouped matmul passes over an
+expert without rows unread, so rounds never re-read the experts; what
+the pass saves is what the rounds moved beside the matmuls.
 
 **The rows come back by a gather, not by a scatter-add** (which the TPU
 runs row by row). A round writes its ``N`` weighted float32 rows into
 one buffer of ``top_k * N`` rows, at the place the sort gave them; after
 the last round the sort's inverse says where each of a token's ``top_k``
 assignments lies there, the tokens' rows are gathered from it and a
-token's ``top_k`` rows summed. The buffer starts unwritten
-(``lax.empty``: no worst-case memset for a share that fills a sixteenth
-of it): an assignment held elsewhere, or a padding lane's, sorted behind
-the last held one, so whatever its place holds (a round's zeroed tail,
-or nothing a round ever wrote) is selected away and never multiplied.
+token's ``top_k`` rows summed. The one pass's output, as the grouped
+matmul leaves it, IS that buffer: its rows are weighed as they come back
+(the same float32 product, the same sum), not in a pass of their own.
+The buffer starts unwritten (``lax.empty``: no worst-case memset for a
+share that fills a sixteenth of it): an assignment held elsewhere, or a
+padding lane's, sorted behind the last held one, so whatever its place
+holds (a round's zeroed tail, or nothing a round ever wrote) is selected
+away and never multiplied.
 
 ``expert_layer`` is the whole block as most encoders call it (router, the
 held experts' part, the shared expert), and ``COUNTERS`` /
@@ -170,6 +183,24 @@ _GATE_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 _BACK_ROWS = 768
 
 
+def one_pass(top_k: int, count: int, n_experts: int) -> bool:
+    """Whether ``routed_experts`` takes all ``top_k * N`` sorted
+    assignments at once: where the ``top_k * count / n_experts`` of a
+    token's choices that land on ``count`` held experts of a router
+    ``n_experts`` wide round up to ``top_k``, as they do where every
+    expert is held. A share takes them ``N`` a round."""
+    return top_k * count > (top_k - 1) * n_experts
+
+
+def rounds_run(landed: jnp.ndarray, N: int, top_k: int, count: int,
+               n_experts: int) -> jnp.ndarray:
+    """The rounds ``routed_experts`` took for the ``landed`` assignments
+    (the sum of its second result) of ``N`` tokens: what an encoder
+    counts as ``expert_rounds``."""
+    R = top_k * N if one_pass(top_k, count, n_experts) else N
+    return (landed + R - 1) // R
+
+
 def routed_experts(
     x: jnp.ndarray,        # (N, E): what the experts read
     experts: jnp.ndarray,  # (N, top_k) int32, over all n_experts
@@ -177,21 +208,25 @@ def routed_experts(
     w_in: jnp.ndarray,     # (count, E, 2 * F): [gate | up] of held experts
     w_out: jnp.ndarray,    # (count, F, E)
     first: int,
+    n_experts: int,        # the router's width
     valid: Optional[jnp.ndarray] = None,  # (N,) bool
     act: str = "silu",
     assigned: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """``(y (N, E) float32, rows (count,) int32)``: the held experts'
     part of every token's weighted sum, and the rows each held expert
-    ran. ``act`` is the gate's activation (``"silu"`` SwiGLU, ``"relu"``
-    ReGLU); ``assigned`` is ``assign(experts, first, count, valid)``
-    where the caller made it already (beside its router, ahead of what
-    ``x`` waits for). Named scopes: ``dispatch`` (the sort and its
-    inverse, each round's gather), ``experts`` (the grouped matmuls),
-    ``combine`` (in the loop: weigh, and write the round's rows into the
-    ``(top_k * N, E)`` float32 buffer; after it: gather every token's
-    ``top_k`` rows back by the sort's inverse and sum them, rows no held
-    assignment wrote selected away, ``_BACK_ROWS`` rows at a time)."""
+    ran. ``n_experts`` decides between rounds of ``N`` assignments and
+    one pass without a loop (``one_pass``: every expert held); ``act``
+    is the gate's activation (``"silu"`` SwiGLU, ``"relu"`` ReGLU);
+    ``assigned`` is ``assign(experts, first, count, valid)`` where the
+    caller made it already (beside its router, ahead of what ``x`` waits
+    for). Named scopes: ``dispatch`` (the sort and its inverse, each
+    round's gather), ``experts`` (the grouped matmuls), ``combine``
+    (in the loop: weigh a round's rows and write them into the float32
+    buffer; after it: gather every token's ``top_k`` rows back by the
+    sort's inverse, weigh them there if the one pass made them, and sum
+    them, rows no held assignment wrote selected away, ``_BACK_ROWS``
+    rows at a time)."""
     N, E = x.shape
     top_k = experts.shape[1]
     count = w_in.shape[0]
@@ -205,26 +240,28 @@ def routed_experts(
         # second sort, not a scatter of an iota: the TPU runs a scatter
         # element by element
         pos = jnp.argsort(order).astype(jnp.int32).reshape(N, top_k).T
-        # padded so that every round slices N whole entries
-        order = jnp.concatenate([order, jnp.zeros((N,), jnp.int32)])
-        flat_w = weights.reshape(-1)
 
-    def one_round(r, rows):
-        start = r * N  # N rows a round
-        with jax.named_scope("dispatch"):
-            picked = lax.dynamic_slice_in_dim(order, start, N)
-            token = picked // top_k
-            live = start + jnp.arange(N) < total
-            # this round's share of each expert's rows
-            sizes = jnp.diff(jnp.clip(ends - start, 0, N), prepend=0)
-            xs = jnp.take(x, token, axis=0).astype(dtype)
+    def held(xs, sizes):
+        """The held experts over the sorted rows ``xs``, ``sizes`` rows
+        an expert: float32, not weighed."""
         with jax.named_scope("experts"):
             g, u = jnp.split(lax.ragged_dot(
                 xs, w_in, sizes, preferred_element_type=dtype), 2, axis=-1)
             gated = _GATE_ACTS[act](g.astype(jnp.float32)) \
                 * u.astype(jnp.float32)
-            out = lax.ragged_dot(gated.astype(dtype), w_out, sizes,
-                                 preferred_element_type=jnp.float32)
+            return lax.ragged_dot(gated.astype(dtype), w_out, sizes,
+                                  preferred_element_type=jnp.float32)
+
+    def one_round(r, rows):
+        start = r * N  # N rows a round
+        with jax.named_scope("dispatch"):
+            picked = lax.dynamic_slice_in_dim(padded, start, N)
+            token = picked // top_k
+            live = start + jnp.arange(N) < total
+            # this round's share of each expert's rows
+            sizes = jnp.diff(jnp.clip(ends - start, 0, N), prepend=0)
+            xs = jnp.take(x, token, axis=0).astype(dtype)
+        out = held(xs, sizes)
         with jax.named_scope("combine"):
             # rows past the round's last assignment hold whatever the
             # grouped matmul left there: selected away, never multiplied
@@ -232,22 +269,48 @@ def routed_experts(
                             out * jnp.take(flat_w, picked)[:, None], 0.0)
             return lax.dynamic_update_slice_in_dim(rows, out, start, 0)
 
-    # the weighted rows of every round, in sorted order; a round that
-    # never runs leaves its N rows unwritten
-    rows = lax.fori_loop(0, (total + N - 1) // N, one_round,
-                         lax.empty((top_k * N, E), jnp.float32))
+    if one_pass(top_k, count, n_experts):
+        # every expert held: one pass, and its output as the grouped
+        # matmul leaves it is what the rows come back from, each weighed
+        # on its way (no pass to weigh them where they lie, no gather of
+        # ``top_k * N`` single weights)
+        with jax.named_scope("dispatch"):
+            # in bounds as the sort made them: nothing to fill
+            xs = jnp.take(x, order // top_k, axis=0,
+                          mode="clip").astype(dtype)
+        rows = held(xs, per_expert)
+        late = weights.T
+    else:
+        with jax.named_scope("dispatch"):
+            # padded so that every round slices N whole entries
+            padded = jnp.concatenate([order, jnp.zeros((N,), jnp.int32)])
+            flat_w = weights.reshape(-1)
+        # the weighted rows of every round, in sorted order; a round
+        # that never runs leaves its N rows unwritten
+        rows = lax.fori_loop(0, (total + N - 1) // N, one_round,
+                             lax.empty((top_k * N, E), jnp.float32))
+        late = None  # weighed already
 
-    def back(at):  # (top_k, tokens) positions -> (tokens, E)
+    def back(block):  # (top_k, tokens) positions [, weights] -> (tokens, E)
+        at, w = block
         # an assignment held elsewhere, or a padding lane's, sorted
         # behind ``total``: its row is selected away like the leftovers
-        return jnp.where((at < total)[:, :, None],
-                         jnp.take(rows, at, axis=0), 0.0).sum(0)
+        here = (at < total)[:, :, None]
+        got = jnp.take(rows, at, axis=0)
+        if w is not None:
+            got = got * w[:, :, None]
+        return jnp.where(here, got, 0.0).sum(0)
 
     with jax.named_scope("combine"):
         # the most tokens, a power of two, within _BACK_ROWS rows
         part = math.gcd(
             N, 1 << (max(_BACK_ROWS // top_k, 1).bit_length() - 1))
-        y = lax.map(back, pos.reshape(top_k, N // part, part).swapaxes(0, 1))
+
+        def blocks(of):  # (top_k, N) -> (N // part, top_k, part)
+            return of.reshape(top_k, N // part, part).swapaxes(0, 1)
+
+        y = lax.map(back, (blocks(pos),
+                           None if late is None else blocks(late)))
     return y.reshape(N, E), per_expert
 
 
@@ -296,7 +359,8 @@ def expert_layer(
             u, p["router"], p["bias"], n_group, topk_group, top_k, scaling,
             norm_topk_prob)
     y, per_expert = routed_experts(
-        u, experts, weights, p["experts_in"], p["experts_out"], first, valid)
+        u, experts, weights, p["experts_in"], p["experts_out"], first,
+        p["router"].shape[1], valid)
     if shared:
         with jax.named_scope("shared_expert"):
             y = y + swiglu(u, p["shared_in"], p["shared_out"], dtype)

@@ -496,7 +496,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
     np.testing.assert_array_equal(np.sort(experts, -1), np.sort(chosen, -1))
     # ``first`` is traced: one program a share's size, not one a share
     share = jax.jit(lambda w_in, w_out, first: moe.routed_experts(
-        x, experts, weights, w_in, w_out, first))
+        x, experts, weights, w_in, w_out, first, 16))
     for count in (8, 4):
         total, rows = shared, 0
         for first in range(0, 16, count):
@@ -531,12 +531,13 @@ def test_no_token_is_dropped_when_every_choice_lands_here():
     experts, weights = moe.route(x, p["router"], bias, 4, 2, 4, 2.5)
     assert sorted(set(np.asarray(experts).ravel())) == [4, 5, 6, 7]
     got, per_expert = jax.jit(lambda x, e, w: moe.routed_experts(
-        x, e, w, p["experts_in"], p["experts_out"], 4))(x, experts, weights)
+        x, e, w, p["experts_in"], p["experts_out"], 4, 16))(
+            x, experts, weights)
     assert per_expert.tolist() == [24, 24, 24, 24]
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
     valid = jnp.arange(24) < 17
     got, per_expert = jax.jit(lambda x, e, w, valid: moe.routed_experts(
-        x, e, w, p["experts_in"], p["experts_out"], 4, valid=valid))(
+        x, e, w, p["experts_in"], p["experts_out"], 4, 16, valid))(
             x, experts, weights, valid)
     assert per_expert.tolist() == [17, 17, 17, 17]
     np.testing.assert_allclose(got[:17], want[:17], rtol=2e-5, atol=2e-5)

@@ -173,7 +173,7 @@ def test_the_shares_add_up_to_the_uncut_branch():
     assert int(zero_choices) == int((np.asarray(chosen) >= 32).sum()) > 0
     # ``first`` is traced: one program a share's size, not one a share
     share = jax.jit(lambda w_in, w_out, first: moe.routed_experts(
-        x, experts, weights, w_in, w_out, first))
+        x, experts, weights, w_in, w_out, first, 48))
     for count in (1, 4, 16):
         total, rows = identity, 0
         for first in range(0, 32, count):
@@ -201,7 +201,8 @@ def test_a_token_of_identity_experts_alone_routes_no_row():
     experts, weights = route(x, dict(p, bias=bias))
     assert (np.asarray(experts) >= 32).all()
     got, per_expert = jax.jit(lambda x, e, w: moe.routed_experts(
-        x, e, w, p["experts_in"], p["experts_out"], 8))(x, experts, weights)
+        x, e, w, p["experts_in"], p["experts_out"], 8, 48))(
+            x, experts, weights)
     assert per_expert.tolist() == [0, 0, 0, 0]
     assert float(jnp.abs(got).max()) == 0.0
     z, choices = moe.zero_experts(x, experts, weights, 32)
@@ -231,7 +232,7 @@ def test_no_token_is_dropped_when_every_choice_is_held(lanes):
     experts, weights = route(x, dict(p, bias=bias))
     assert sorted(set(np.asarray(experts).ravel())) == [8, 9, 10, 11]
     got, per_expert = jax.jit(lambda x, e, w, valid: moe.routed_experts(
-        x, e, w, p["experts_in"], p["experts_out"], 8, valid=valid))(
+        x, e, w, p["experts_in"], p["experts_out"], 8, 48, valid))(
             x, experts, weights, jnp.arange(24) < lanes)
     assert per_expert.tolist() == [lanes] * 4
     np.testing.assert_allclose(got[:lanes], want[:lanes], rtol=2e-5,
@@ -255,7 +256,7 @@ def test_identity_choices_sort_behind_the_held_experts():
     assert 0 < landed < 512
     assert not zero.ravel()[np.asarray(order)[:landed]].any()
     held = jax.jit(lambda x, e, w: moe.routed_experts(
-        x, e, w, p["experts_in"], p["experts_out"], 8))
+        x, e, w, p["experts_in"], p["experts_out"], 8, 48))
     text = held.lower(x, experts, weights).as_text()
     assert "2048x64xf32" in text and "scatter" not in text
     got, rows = held(x, experts, weights)
@@ -276,7 +277,8 @@ def test_encoder_equals_the_reference(params, tokens):
 
 
 def test_the_uncut_encoder_equals_the_uncut_reference(tokens):
-    """All 32 experts held: the buffer form, several rounds a layer."""
+    """All 32 experts with weights held, of a router 48 wide: the buffer
+    form, several rounds a layer."""
     whole = seeded(ref, 44, UNCUT, TAILS)
     enc = build_encoder(config(UNCUT), whole)
     assert enc.config.experts_held == (0, 32)
@@ -284,6 +286,7 @@ def test_the_uncut_encoder_equals_the_uncut_reference(tokens):
     got, states = compiled(enc)(whole, tokens, enc.init_states(3, 24))
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
     counts = dict(zip(enc.counts.names, np.asarray(states["counts"])))
+    assert not moe.one_pass(4, 32, 48)  # 2.7 of a token's 4 land here
     assert counts["expert_rounds"] > 2  # more than one round a layer
 
 

@@ -175,9 +175,10 @@ def _expert_layer_as_it_was(p, u, valid, dtype, *, n_group, topk_group,
     (1, 1, 4, 2.448, True, (0, 16), 0),   # models/afmoe.py
     (1, 1, 4, 2.448, False, (8, 8), 5),
     (4, 2, 8, 2.5, True, (0, 8), 0),      # models/bailing_hybrid.py
-    (4, 2, 8, 2.5, True, (0, 16), 3),     # more than N land here: rounds
+    (4, 2, 8, 2.5, True, (0, 16), 3),     # every expert held: one pass
+    (4, 2, 8, 2.5, True, (0, 12), 3),     # more than N land here: rounds
 ], ids=["deepseek", "deepseek_padded", "afmoe_whole", "afmoe_no_shared",
-        "bailing", "bailing_rounds"])
+        "bailing", "bailing_one_pass", "bailing_rounds"])
 def test_route_sort_and_apply_on_one_tensor_is_expert_layer_as_it_was(
         n_group, topk_group, top_k, scaling, shared, held, pad):
     """The three callers of ``expert_layer`` compute what they computed
@@ -209,7 +210,7 @@ def test_route_sort_and_apply_on_one_tensor_is_expert_layer_as_it_was(
     assigned = moe.assign(experts, first, count, valid)
     assert assigned[0].shape == (24 * top_k,)
     by_hand, _ = moe.routed_experts(
-        u, experts, weights, p["experts_in"], p["experts_out"], first,
+        u, experts, weights, p["experts_in"], p["experts_out"], first, 16,
         assigned=assigned)
     if shared:
         by_hand = by_hand + moe.swiglu(u, p["shared_in"], p["shared_out"],
@@ -229,26 +230,32 @@ def _dense_held_part(x, experts, weights, w_in, w_out, first, valid, act):
     return y if valid is None else jnp.where(valid[:, None], y, 0.0)
 
 
-# name: (n_experts, top_k, (first, count), padding lanes, act, rounds,
-# rows of the last round); N = 24 tokens
+# name: (n_experts, top_k, (first, count), padding lanes, act, the N = 24
+# tokens a round takes, 1 for a share and ``top_k`` where every expert is
+# held (``moe.one_pass``), rounds, rows of the last round). The router may
+# be wider than the experts a token chooses among (the first 16): its
+# width decides between rounds and one pass, the choices fill the rounds
 _COMBINE_CASES = {
-    "all_held_six_rounds": (16, 6, (0, 16), 0, "relu", 6, 24),
-    "last_round_part_full": (16, 6, (0, 16), 7, "relu", 5, 6),
-    "a_share_in_one_round": (16, 4, (4, 2), 0, "silu", 1, None),
-    "a_share_in_three_rounds": (16, 8, (0, 8), 0, "silu", None, None),
-    "nothing_lands_here": (16, 2, (12, 4), 0, "silu", 0, None),
-    "padding_lanes_in_a_share": (16, 4, (8, 8), 9, "relu", None, None),
-    "every_lane_padding": (16, 6, (0, 16), 24, "relu", 0, None),
+    "all_held_one_pass": (16, 6, (0, 16), 0, "relu", 6, 1, 144),
+    "one_pass_part_full": (16, 6, (0, 16), 7, "relu", 6, 1, 102),
+    "six_rounds_of_a_wide_router": (64, 6, (0, 16), 0, "relu", 1, 6, 24),
+    "last_round_part_full": (64, 6, (0, 16), 7, "relu", 1, 5, 6),
+    "a_share_in_one_round": (16, 4, (4, 2), 0, "silu", 1, 1, None),
+    "a_share_in_three_rounds": (16, 8, (0, 8), 0, "silu", 1, None, None),
+    "nothing_lands_here": (16, 2, (12, 4), 0, "silu", 1, 0, None),
+    "padding_lanes_in_a_share": (16, 4, (8, 8), 9, "relu", 1, None, None),
+    "every_lane_padding": (16, 6, (0, 16), 24, "relu", 6, 0, None),
 }
 
 
 def _combine_case(name):
-    n_experts, top_k, (first, count), pad, act, rounds, last = \
+    n_experts, top_k, (first, count), pad, act, m, rounds, last = \
         _COMBINE_CASES[name]
+    assert moe.one_pass(top_k, count, n_experts) == (m == top_k)
     k = jax.random.split(jax.random.PRNGKey(41), 5)
     x = jax.random.normal(k[0], (24, 64))
     # top_k distinct experts a token, weights that sum to 1
-    experts = jnp.argsort(jax.random.uniform(k[1], (24, n_experts)),
+    experts = jnp.argsort(jax.random.uniform(k[1], (24, 16)),
                           axis=-1)[:, :top_k].astype(jnp.int32)
     if name == "nothing_lands_here":
         experts = experts % first    # all under the held range
@@ -256,19 +263,20 @@ def _combine_case(name):
     w_in = jax.random.normal(k[3], (count, 64, 32)) / 8
     w_out = jax.random.normal(k[4], (count, 16, 64)) / 4
     valid = (jnp.arange(24) < 24 - pad) if pad else None
-    return (x, experts, weights, w_in, w_out, first, valid, act), rounds, last
+    return (x, experts, weights, w_in, w_out, first, n_experts, valid,
+            act), 24 * m, rounds, last
 
 
 @pytest.mark.parametrize("name", list(_COMBINE_CASES))
 def test_the_rows_come_back_as_the_dense_weighted_sum(name):
-    """Whatever the rounds (none, one, several, a last one part full),
-    every token gets ``sum_k w_k E_k(x)`` over the held experts it chose,
-    a token whose choices fell in different rounds too, and a padding
-    lane exact zeros."""
-    args, rounds, last = _combine_case(name)
-    x, experts, weights, w_in, w_out, first, valid, act = args
+    """Whatever the rounds (none, one pass, several, a last one part
+    full), every token gets ``sum_k w_k E_k(x)`` over the held experts it
+    chose, a token whose choices fell in different rounds too, and a
+    padding lane exact zeros."""
+    args, size, rounds, last = _combine_case(name)
+    x, experts, weights, w_in, w_out, first, n_experts, valid, act = args
     got, rows = jax.jit(lambda *a: moe.routed_experts(
-        *a, first, valid, act))(x, experts, weights, w_in, w_out)
+        *a, first, n_experts, valid, act))(x, experts, weights, w_in, w_out)
     with jax.default_matmul_precision("highest"):
         want = jax.jit(lambda *a: _dense_held_part(
             *a, first, valid, act))(x, experts, weights, w_in, w_out)
@@ -279,29 +287,33 @@ def test_the_rows_come_back_as_the_dense_weighted_sum(name):
         held = held & valid[:, None]
         assert float(jnp.abs(got[~np.asarray(valid)]).max()) == 0.0
     assert total == int(held.sum())
+    assert int(moe.rounds_run(rows.sum(), 24, experts.shape[1],
+                              w_in.shape[0], n_experts)) == -(-total // size)
     if rounds is not None:
-        assert -(-total // 24) == rounds
+        assert -(-total // size) == rounds
     if last is not None:
-        assert total - (rounds - 1) * 24 == last
+        assert total - (rounds - 1) * size == last
     if total == 0:
         assert float(jnp.abs(got).max()) == 0.0
-    if total > 24:
+    if total > size:
         # some token's rows were made in different rounds
         order, _ = moe.assign(experts, first, w_in.shape[0], valid)
-        at = np.argsort(np.asarray(order)).reshape(24, -1) // 24
+        at = np.argsort(np.asarray(order)).reshape(24, -1) // size
         at = np.where(np.asarray(held), at, at.max(-1, keepdims=True))
         assert (at.min(-1) < at.max(-1)).any()
 
 
-@pytest.mark.parametrize("name", ["all_held_six_rounds",
+@pytest.mark.parametrize("name", ["all_held_one_pass",
+                                  "six_rounds_of_a_wide_router",
                                   "a_share_in_one_round",
                                   "padding_lanes_in_a_share"])
 def test_a_sort_handed_in_and_one_made_inside_give_the_same_bits(name):
-    args, _, _ = _combine_case(name)
-    x, experts, weights, w_in, w_out, first, valid, act = args
-    inside = jax.jit(lambda *a: moe.routed_experts(*a, first, valid, act))
+    args, _, _, _ = _combine_case(name)
+    x, experts, weights, w_in, w_out, first, n_experts, valid, act = args
+    inside = jax.jit(lambda *a: moe.routed_experts(
+        *a, first, n_experts, valid, act))
     handed = jax.jit(lambda *a: moe.routed_experts(
-        *a, first, valid, act,
+        *a, first, n_experts, valid, act,
         assigned=moe.assign(a[1], first, w_in.shape[0], valid)))
     for got, want in zip(handed(x, experts, weights, w_in, w_out),
                          inside(x, experts, weights, w_in, w_out)):
@@ -314,8 +326,7 @@ def test_a_sort_handed_in_and_one_made_inside_give_the_same_bits(name):
 def test_rows_no_round_wrote_never_reach_the_sum(monkeypatch, name):
     """The buffer of weighted rows starts unwritten: with NaN in every
     row a round did not write, the sum is what it was."""
-    args, _, _ = _combine_case(name)
-    x, experts, weights, w_in, w_out, first, valid, act = args
+    args, _, _, _ = _combine_case(name)
     want, _ = moe.routed_experts(*args)
     monkeypatch.setattr(moe.lax, "empty", lambda shape, dtype: jnp.full(
         shape, jnp.nan, dtype))
@@ -325,35 +336,39 @@ def test_rows_no_round_wrote_never_reach_the_sum(monkeypatch, name):
 
 
 @pytest.mark.parametrize("pad", [0, 5], ids=["whole", "padded"])
-def test_the_combine_lowers_to_a_gather_and_the_scopes_stand(pad):
+@pytest.mark.parametrize("name,rounds", [
+    ("all_held_one_pass", ""), ("six_rounds_of_a_wide_router", "while/body/")])
+def test_the_combine_lowers_to_a_gather_and_the_scopes_stand(name, rounds,
+                                                             pad):
     """The combine is a gather by the sort's inverse: the lowered program
     holds no scatter, and the scopes the benchmark's readers match are
-    where they were."""
-    args, _, _ = _combine_case("all_held_six_rounds")
-    x, experts, weights, w_in, w_out, first, _, act = args
+    where they were, under the loop where there are rounds and beside
+    it where every expert is held."""
+    args, _, _, _ = _combine_case(name)
+    x, experts, weights, w_in, w_out, first, n_experts, _, act = args
     valid = (jnp.arange(24) < 24 - pad) if pad else None
 
     def layer(*a):
         with jax.named_scope("moe_7"):
-            return moe.routed_experts(*a, first, valid, act)
+            return moe.routed_experts(*a, first, n_experts, valid, act)
 
     lowered = jax.jit(layer).lower(x, experts, weights, w_in, w_out)
     compiled_text = lowered.compile().as_text()
     assert "scatter" not in lowered.as_text()
     assert "scatter" not in compiled_text
     text = lowered.as_text(debug_info=True)
-    for name in ("moe_7/dispatch", "moe_7/while/body/dispatch",
-                 "moe_7/while/body/experts", "moe_7/while/body/combine",
-                 "moe_7/combine"):
-        assert name in text, name
+    for scope in ("moe_7/dispatch", f"moe_7/{rounds}dispatch",
+                  f"moe_7/{rounds}experts", f"moe_7/{rounds}combine",
+                  "moe_7/combine"):
+        assert scope in text, scope
     assert "f32[144,64]" in compiled_text  # the buffer: float32 rows
 
 
-def test_all_held_six_a_token_runs_six_rounds_and_drops_nothing(
+def test_all_held_six_a_token_is_one_pass_and_drops_nothing(
         monkeypatch, params):
-    """Every expert held: 6 x 24 assignments through six rounds of 24
-    rows; with seven padding lanes left out, 6 x 17 = 102 through five
-    rounds, the last of six rows; equal to the reference's dense loop."""
+    """Every expert held: 6 x 24 assignments in one pass and no loop's
+    turn, as with seven padding lanes left out (6 x 17 = 102 rows);
+    equal to the reference's dense loop."""
     p = params["layers"]["layer_1"]
     x = jax.random.normal(jax.random.PRNGKey(7), (24, 64))
     m = jax.random.normal(jax.random.PRNGKey(8), (24, 64))
@@ -371,15 +386,15 @@ def test_all_held_six_a_token_runs_six_rounds_and_drops_nothing(
     experts, weights = moe.route(x, p["router"], None, 1, 1, 6, 1.0,
                                  score_func="softmax")
     got, per_expert = moe.routed_experts(
-        m, experts, weights, p["experts_in"], p["experts_out"], 0,
+        m, experts, weights, p["experts_in"], p["experts_out"], 0, 16,
         act="relu", assigned=moe.assign(experts, 0, 16))
-    assert int(per_expert.sum()) == 6 * 24 and trips == [6]
+    assert int(per_expert.sum()) == 6 * 24 and trips == []
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
     valid = jnp.arange(24) < 17
     got, per_expert = moe.routed_experts(
-        m, experts, weights, p["experts_in"], p["experts_out"], 0, valid,
+        m, experts, weights, p["experts_in"], p["experts_out"], 0, 16, valid,
         act="relu")
-    assert int(per_expert.sum()) == 6 * 17 and trips == [6, 5]
+    assert int(per_expert.sum()) == 6 * 17 and trips == []
     np.testing.assert_allclose(got[:17], want[:17], rtol=2e-5, atol=2e-5)
     assert float(jnp.abs(got[17:]).max()) == 0.0
 
@@ -402,7 +417,7 @@ def test_two_shares_of_half_the_experts_add_up_to_the_whole_layer(params):
                                  score_func="softmax")
     # ``first`` is traced: one program for both halves
     half = jax.jit(lambda w_in, w_out, first: moe.routed_experts(
-        m, experts, weights, w_in, w_out, first, act="relu"))
+        m, experts, weights, w_in, w_out, first, 16, act="relu"))
     total, rows = 0.0, 0
     for first in (0, 8):
         part, per_expert = half(p["experts_in"][first:first + 8],
@@ -432,11 +447,11 @@ def test_the_gates_activation_reaches_the_routed_experts():
     with jax.default_matmul_precision("highest"):
         g, u = jnp.split(x @ w_in[0], 2, axis=-1)
         np.testing.assert_allclose(
-            moe.routed_experts(x, experts, weights, w_in, w_out, 0,
+            moe.routed_experts(x, experts, weights, w_in, w_out, 0, 1,
                                act="relu")[0],
             ref.reglu(x, w_in[0], w_out[0]), rtol=2e-5, atol=2e-5)
         np.testing.assert_allclose(
-            moe.routed_experts(x, experts, weights, w_in, w_out, 0)[0],
+            moe.routed_experts(x, experts, weights, w_in, w_out, 0, 1)[0],
             (jax.nn.silu(g) * u) @ w_out[0], rtol=2e-5, atol=2e-5)
 
 
@@ -478,7 +493,7 @@ def test_streamed_through_rings_that_wrap_equals_one_program(
     counts = np.asarray(states["counts"])
     assert counts[2] == T_DOC // chunk           # programs
     assert counts[0] == 8 * 6 * 3 * T_DOC        # every assignment ran
-    assert counts[3] == 8 * 6 * (T_DOC // chunk)  # six rounds a layer
+    assert counts[3] == 8 * (T_DOC // chunk)     # one pass a layer
 
 
 def _differs(got, want, start=12):
@@ -573,10 +588,10 @@ def test_the_sort_is_made_before_attention_in_program_order(params, tokens):
     for name in ("route_0/router", "route_0/dispatch", "attention_0/qkv_proj",
                  "attention_0/global_core", "attention_1/rope",
                  "attention_1/window_core", "attention_1/o_proj",
-                 "moe_7/dispatch", "moe_7/while/body/dispatch",
-                 "moe_7/while/body/experts", "moe_7/while/body/combine"):
+                 "moe_7/dispatch", "moe_7/experts", "moe_7/combine"):
         assert name in lowered, name
     assert "attention_0/rope" not in lowered     # NoPE
+    assert "moe_7/while/body/experts" not in lowered  # one pass, no loop
 
 
 # -- the attention kernel at seven heads a group --------------------------------
@@ -672,10 +687,8 @@ def test_chunked_through_both_kinds_of_state_with_narrowing(
 
 def test_counts_ride_the_finalize_span(params, engine):
     """Every assignment of every valid token ran (6 a token a layer, all
-    held), and the rounds a layer a program are ``ceil(6 x valid / N)``:
-    chunks of 4 in programs of rows 4, 4, 2, 1, 1 with 3 + 3 + 3, 2 + 3
-    + 3 = 8 (two full lanes of the 12-token document), 8, 4, 4 valid
-    tokens of 16, 16, 8, 4, 4 lanes."""
+    held), in one pass a layer a program whatever its valid tokens:
+    chunks of 4 in programs of rows 4, 4, 2, 1, 1."""
     rng = np.random.default_rng(11)
     seqs = [rng.integers(20, 300, n).astype(np.int32) for n in (5, 12, 20)]
     log = []
@@ -692,12 +705,7 @@ def test_counts_ride_the_finalize_span(params, engine):
     assert a["moe_programs"] == 5
     assert a["expert_rows_mean"] == pytest.approx(
         8 * 6 * 37 / (5 * 8 * 16))
-    valid = [4 + 4 + 4, 1 + 4 + 4, 4 + 4, 4, 4]
-    lanes = [16, 16, 8, 4, 4]
-    assert sum(valid) == 37
-    assert a["expert_rounds_mean"] == pytest.approx(
-        sum(-(-6 * v // n) for v, n in zip(valid, lanes)) / 5)
-    assert 1 < a["expert_rounds_mean"] <= 6
+    assert a["expert_rounds_mean"] == 1.0
     assert a["attention_kernel_layers"] == 0     # the rule sees the CPU
 
 
