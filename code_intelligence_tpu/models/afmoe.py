@@ -165,8 +165,10 @@ class AfmoeEncoder(WindowedCaches, CarriedCounts):
     `models/windowed_caches.py`'s, the reading of its counts
     `models/blocks.py`'s."""
 
-    # the attention layers whose core the program ran on the Pallas kernel
-    counts = Counts(sets=("attention_kernel_layers",))
+    # the attention layers whose core the program ran on the Pallas
+    # kernel, and the expert layers whose grouped matmuls it did
+    counts = Counts(sets=("attention_kernel_layers",
+                          "expert_kernel_layers"))
 
     def __init__(self, config: AfmoeConfig, dtype=jnp.bfloat16):
         self.config = config
@@ -240,7 +242,9 @@ class AfmoeEncoder(WindowedCaches, CarriedCounts):
             "k": tuple(k_caches), "v": tuple(v_caches), "pos": pos + T,
             "counts": self.counts.update(
                 states["counts"], rows, busiest, ran,
-                attention_kernel_layers=on_kernel),
+                attention_kernel_layers=on_kernel,
+                expert_kernel_layers=moe.kernel_layers(
+                    params["layers"], B * T, cfg.num_experts_per_tok)),
         }
         return out, new_states
 

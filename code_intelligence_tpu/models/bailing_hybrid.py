@@ -215,8 +215,10 @@ class BailingHybridEncoder(CarriedCounts):
     """The encoder contract (`models/contract.py`) over the hybrid."""
 
     # the KDA and the latent layers whose core the program ran on a
-    # Pallas kernel, as each op's ``core_is_kernel`` said
-    counts = Counts(sets=("kda_kernel_layers", "attention_kernel_layers"))
+    # Pallas kernel, as each op's ``core_is_kernel`` said, and the expert
+    # layers whose grouped matmuls it did (``gmm_is_kernel``)
+    counts = Counts(sets=("kda_kernel_layers", "attention_kernel_layers",
+                          "expert_kernel_layers"))
 
     def __init__(self, config: BailingHybridConfig, dtype=jnp.bfloat16):
         self.config = config
@@ -367,7 +369,9 @@ class BailingHybridEncoder(CarriedCounts):
             "counts": self.counts.update(
                 states["counts"], rows, busiest, ran,
                 kda_kernel_layers=kda_on_kernel,
-                attention_kernel_layers=on_kernel),
+                attention_kernel_layers=on_kernel,
+                expert_kernel_layers=moe.kernel_layers(
+                    params["layers"], B * T, cfg.num_experts_per_tok)),
         }
         return out, new_states
 

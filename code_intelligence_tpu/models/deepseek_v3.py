@@ -152,8 +152,10 @@ class DeepseekV3Encoder(GrowingCache, CarriedCounts):
     are `models/blocks.py`'s."""
 
     cache_kind = "latent"
-    # the attention layers whose core the program ran on the Pallas kernel
-    counts = Counts(sets=("attention_kernel_layers",))
+    # the attention layers whose core the program ran on the Pallas
+    # kernel, and the expert layers whose grouped matmuls it did
+    counts = Counts(sets=("attention_kernel_layers",
+                          "expert_kernel_layers"))
 
     def __init__(self, config: DeepseekV3Config, dtype=jnp.bfloat16):
         self.config = config
@@ -239,7 +241,9 @@ class DeepseekV3Encoder(GrowingCache, CarriedCounts):
             "pos": pos + T,
             "counts": self.counts.update(
                 states["counts"], rows, busiest, ran,
-                attention_kernel_layers=on_kernel),
+                attention_kernel_layers=on_kernel,
+                expert_kernel_layers=moe.kernel_layers(
+                    params["layers"], B * T, cfg.num_experts_per_tok)),
         }
         return out, new_states
 

@@ -155,10 +155,11 @@ class LongcatFlashEncoder(GrowingCache, CarriedCounts):
     # the rounds of ``routed_experts``' loop (a layer a program); the
     # valid tokens' choices and those of them that fell on zero-compute
     # experts; the attention sublayers whose core the program ran on the
-    # Pallas kernel
+    # Pallas kernel, and the layers whose routed experts' grouped matmuls
+    # it did
     counts = Counts(sums=("expert_rounds",),
                     totals=("zero_choices", "valid_choices"),
-                    sets=("attention_kernel_layers",))
+                    sets=("attention_kernel_layers", "expert_kernel_layers"))
 
     def __init__(self, config: LongcatFlashConfig, dtype=jnp.bfloat16):
         self.config = config
@@ -258,7 +259,9 @@ class LongcatFlashEncoder(GrowingCache, CarriedCounts):
                 states["counts"], rows, busiest, jnp.int32(1),
                 expert_rounds=rounds, zero_choices=zero_choices,
                 valid_choices=chose * (cfg.moe_topk * cfg.num_layers),
-                attention_kernel_layers=on_kernel),
+                attention_kernel_layers=on_kernel,
+                expert_kernel_layers=moe.kernel_layers(
+                    params["layers"], N, cfg.moe_topk)),
         }
         return out, new_states
 

@@ -156,10 +156,11 @@ class SmallThinkerEncoder(WindowedCaches, CarriedCounts):
     `models/blocks.py`'s."""
 
     # the rounds of ``routed_experts``' loop (``expert_rounds_mean``: a
-    # layer a program), and the attention layers whose core the program
-    # ran on the Pallas kernel
+    # layer a program), the attention layers whose core the program ran
+    # on the Pallas kernel, and the expert layers whose grouped matmuls
+    # it did
     counts = Counts(sums=("expert_rounds",),
-                    sets=("attention_kernel_layers",))
+                    sets=("attention_kernel_layers", "expert_kernel_layers"))
 
     def __init__(self, config: SmallThinkerConfig, dtype=jnp.bfloat16):
         self.config = config
@@ -232,7 +233,9 @@ class SmallThinkerEncoder(WindowedCaches, CarriedCounts):
             "k": tuple(k_caches), "v": tuple(v_caches), "pos": pos + T,
             "counts": self.counts.update(
                 states["counts"], rows, busiest, jnp.int32(1),
-                expert_rounds=rounds, attention_kernel_layers=on_kernel),
+                expert_rounds=rounds, attention_kernel_layers=on_kernel,
+                expert_kernel_layers=moe.kernel_layers(
+                    params["layers"], B * T, experts.shape[1])),
         }
         return out, new_states
 

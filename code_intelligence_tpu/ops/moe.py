@@ -38,23 +38,40 @@ too and hand it in (``assigned``). ``expert_layer`` is the two on one
 tensor.
 
 ``routed_experts`` runs the held experts as ONE grouped matmul over the
-rows routed to them (``jax.lax.ragged_dot``: on the TPU a kernel whose
-grid follows the group sizes, so device time follows the rows routed and
-not a worst-case bound). No token is ever dropped and there is no
-capacity factor: assignments are sorted by expert and taken ``N`` (the
-tokens' number) at a time for as many rounds as they need (one, unless
-more than ``N`` assignments land here: ``top_k * count / n_experts`` of
-a token's choices do on average, ``n_experts`` the router's width, which
-the caller has). A share never gathers ``top_k * N`` rows of ``x`` to
-use a sixteenth of them, and a round's ``N``-row operands sit in the
-chip's fast memory: larger rounds move the same bytes and lose that.
+rows routed to them, twice (``[gate | up]``, then down), so device time
+follows the rows routed and not a worst-case bound. **Two cores, one
+arithmetic, chosen by ``ops/gmm.py::gmm_is_kernel``** from what the call
+can observe (backend, the weights' type, the rows handed in, the held
+experts' number and widths; no caller selects one): on the TPU in
+bfloat16 two Pallas kernels of ours whose grid is the list of (group,
+row tile) pairs the group sizes give, a product taken only where the
+group has a row, the gate's activation in the first product's epilogue
+(no ``(rows, 2 F)`` array, no pass over it), every held expert's weights
+streamed once a product; everywhere else (the CPU, float32: the parity
+tests' path) ``jax.lax.ragged_dot`` with the activation between the two
+in XLA. The rounding points are the same: ``g`` and ``u`` to the
+weights' type, the activation and their product in float32, the result
+to the weights' type, the second product float32. Neither core reads an
+expert without rows or multiplies a row past the last one routed here.
+``experts_on_kernel`` is the rule at the rows a call of the held experts
+is handed, ``kernel_layers`` a model's layers it says yes for: what an
+encoder counts as ``expert_kernel_layers``.
+
+No token is ever dropped and there is no capacity factor: assignments
+are sorted by expert and taken ``N`` (the tokens' number) at a time for
+as many rounds as they need (one, unless more than ``N`` assignments
+land here: ``top_k * count / n_experts`` of a token's choices do on
+average, ``n_experts`` the router's width, which the caller has). A
+share never gathers ``top_k * N`` rows of ``x`` to use a sixteenth of
+them, and a round's ``N``-row operands sit in the chip's fast memory:
+larger rounds move the same bytes and lose that.
 
 **Where every expert is held the rounds are one pass** (``one_pass``:
 more than ``top_k - 1`` of a token's ``top_k`` choices land here on
 average): all ``top_k * N`` assignments at once, no loop, no slice of
-the sort and no update of a buffer. XLA's grouped matmul passes over an
-expert without rows unread, so rounds never re-read the experts; what
-the pass saves is what the rounds moved beside the matmuls.
+the sort and no update of a buffer. Neither core reads an expert
+without rows, so rounds never re-read the experts; what the pass saves
+is what the rounds moved beside the matmuls.
 
 **The rows come back by a gather, not by a scatter-add** (which the TPU
 runs row by row). A round writes its ``N`` weighted float32 rows into
@@ -85,6 +102,8 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from code_intelligence_tpu.ops import gmm
 
 
 # the scores a router chooses on: of each output alone, the logits (the
@@ -201,6 +220,28 @@ def rounds_run(landed: jnp.ndarray, N: int, top_k: int, count: int,
     return (landed + R - 1) // R
 
 
+def experts_on_kernel(N: int, top_k: int, w_in: jnp.ndarray,
+                      n_experts: int) -> bool:
+    """Whether ``routed_experts`` over ``N`` tokens runs the held experts
+    ``w_in`` on ``ops/gmm.py``'s kernels: ``gmm.gmm_is_kernel`` at the
+    rows a call of them is handed (``top_k * N`` in one pass, ``N`` a
+    round)."""
+    count, E, F2 = w_in.shape
+    R = top_k * N if one_pass(top_k, count, n_experts) else N
+    return gmm.gmm_is_kernel(jax.default_backend(), w_in.dtype, R, count, E,
+                             F2 // 2)
+
+
+def kernel_layers(layers, N: int, top_k: int) -> int:
+    """The expert layers among ``layers`` (a model's leaves by layer,
+    an expert layer's as ``expert_layer`` names them) whose held experts
+    ``routed_experts`` over ``N`` tokens runs on the kernels: an
+    encoder's ``expert_kernel_layers``."""
+    return sum(
+        experts_on_kernel(N, top_k, p["experts_in"], p["router"].shape[1])
+        for p in layers.values() if "experts_in" in p)
+
+
 def routed_experts(
     x: jnp.ndarray,        # (N, E): what the experts read
     experts: jnp.ndarray,  # (N, top_k) int32, over all n_experts
@@ -221,7 +262,8 @@ def routed_experts(
     ``assigned`` is ``assign(experts, first, count, valid)`` where the
     caller made it already (beside its router, ahead of what ``x`` waits
     for). Named scopes: ``dispatch`` (the sort and its inverse, each
-    round's gather), ``experts`` (the grouped matmuls), ``combine``
+    round's gather), ``experts`` (the grouped matmuls, whichever core
+    ``gmm_is_kernel`` chose), ``combine``
     (in the loop: weigh a round's rows and write them into the float32
     buffer; after it: gather every token's ``top_k`` rows back by the
     sort's inverse, weigh them there if the one pass made them, and sum
@@ -245,6 +287,9 @@ def routed_experts(
         """The held experts over the sorted rows ``xs``, ``sizes`` rows
         an expert: float32, not weighed."""
         with jax.named_scope("experts"):
+            if experts_on_kernel(N, top_k, w_in, n_experts):
+                return gmm.gated_experts(xs, w_in, w_out, sizes,
+                                         _GATE_ACTS[act])
             g, u = jnp.split(lax.ragged_dot(
                 xs, w_in, sizes, preferred_element_type=dtype), 2, axis=-1)
             gated = _GATE_ACTS[act](g.astype(jnp.float32)) \

@@ -8,6 +8,7 @@ is collected from it.
 """
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -33,3 +34,17 @@ def seeded(ref, seed, model, tails=None, dtype=jnp.float32, layer=None):
         return params["layers"][layer] if layer else params
 
     return jax.jit(sample)(jax.random.PRNGKey(seed))
+
+
+def the_rule_says_grouped_kernels(monkeypatch):
+    """``ops/gmm.py``'s rule answered by the test (it sees the CPU and
+    float32 here), and tiles that divide the tiny programs (up to 8
+    rows, whole widths): the held experts' two products run on the
+    Pallas kernels, interpreted. An encoder built after this call traces
+    them."""
+    from code_intelligence_tpu.ops import gmm
+
+    monkeypatch.setattr(gmm, "gmm_is_kernel", lambda *a: True)
+    monkeypatch.setattr(
+        gmm, "_kernel_tiles",
+        lambda R, count, E, F: (math.gcd(R, 8), math.gcd(R, 8), F, E))

@@ -34,7 +34,8 @@ from code_intelligence_tpu.ops import attention
 from code_intelligence_tpu.ops.attention import gqa_cached
 from code_intelligence_tpu.text import SPECIALS, Vocab
 from code_intelligence_tpu.utils import tracing
-from encoder_programs import compiled, seeded
+from encoder_programs import (
+    compiled, seeded, the_rule_says_grouped_kernels)
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 MODEL = {
@@ -536,6 +537,18 @@ def test_the_encoder_on_the_kernel_equals_the_reference(
         "attention_kernel_layers"] == 5
 
 
+def test_the_encoder_on_the_grouped_matmul_kernels_equals_the_reference(
+        monkeypatch, params, tokens, want):
+    """Every expert layer's two grouped products through ``ops/gmm.py``'s
+    kernels (interpreted), and the count says four layers."""
+    the_rule_says_grouped_kernels(monkeypatch)
+    enc = build_encoder(config(), params)
+    got, states = streamed(enc, params, tokens)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    assert enc.counter_attrs([np.asarray(states["counts"])])[
+        "expert_kernel_layers"] == 4
+
+
 # -- which core: the rule ------------------------------------------------------
 
 BF16, F32 = jnp.bfloat16, jnp.float32
@@ -639,7 +652,7 @@ def test_it_satisfies_the_contract_and_counts_its_state(encoder):
         == (4 * 12 + 64) * per_slot
     states = encoder.init_states(2, 40)
     got = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(states))
-    assert got - 4 - 4 * 4 == 2 * encoder.state_bytes_per_row(40)
+    assert got - 4 - 5 * 4 == 2 * encoder.state_bytes_per_row(40)
     with pytest.raises(ValueError, match="kv_positions=64"):
         encoder.cache_positions(65)
     # no sliding layer: no ring
@@ -700,7 +713,7 @@ def test_the_table_has_a_fourth_row():
     assert contract.ENCODERS["afmoe"][0] is AfmoeConfig
     enc = build_encoder(config())
     assert isinstance(enc, AfmoeEncoder) and isinstance(enc, ChunkEncoder)
-    assert enc.state_counters(enc.init_states(1)).shape == (4,)
+    assert enc.state_counters(enc.init_states(1)).shape == (5,)
     assert enc.counter_attrs([]) == {}
 
 

@@ -36,7 +36,8 @@ from code_intelligence_tpu.ops import kda, mla, moe
 from code_intelligence_tpu.ops.ssd import causal_conv1d
 from code_intelligence_tpu.text import SPECIALS, Vocab
 from code_intelligence_tpu.utils import tracing
-from encoder_programs import compiled, seeded
+from encoder_programs import (
+    compiled, seeded, the_rule_says_grouped_kernels)
 
 MODEL = {
     "vocab_size": 300, "hidden_size": 64, "intermediate_size": 96,
@@ -247,6 +248,20 @@ def test_a_document_across_chunk_programs_equals_one_program(
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=5e-5)
 
 
+def test_the_encoder_on_the_grouped_matmul_kernels_equals_the_reference(
+        monkeypatch, params, tokens, want):
+    """Every expert layer's two grouped products through ``ops/gmm.py``'s
+    kernels (interpreted), three chunk programs, the last one padded
+    (its padding lanes sort behind every routed row, where no tile is
+    visited), and the count says six layers."""
+    the_rule_says_grouped_kernels(monkeypatch)
+    enc = build_encoder(config(), params)
+    got, states = streamed(enc, params, tokens, 3)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=5e-5)
+    assert enc.counter_attrs([np.asarray(states["counts"])])[
+        "expert_kernel_layers"] == 6
+
+
 def test_padding_lanes_leave_state_and_tails_as_they_were(
         params, encoder, tokens):
     """A program of padding alone (``lengths`` 0) after a real one: the
@@ -455,6 +470,7 @@ def test_counts_ride_the_spans(params, engine):
     # what the two rules say here: the CPU, float32, sizes under a lane
     assert (a["kda_layers"], a["kda_kernel_layers"],
             a["attention_kernel_layers"]) == (6, 0, 0)
+    assert a["expert_kernel_layers"] == 0
     (group,) = [s for s in spans if s["name"] == "engine.group"]
     g = group["attrs"]
     assert (g["chunks"], g["kv_positions"]) == (3, 64)
@@ -527,7 +543,7 @@ def test_it_satisfies_the_contract_and_counts_its_state(encoder):
         got = sum(a.size * a.dtype.itemsize
                   for a in jax.tree.leaves(states))
         # less the position counter and the five counts
-        assert got - 4 - 5 * 4 == 2 * encoder.state_bytes_per_row(n)
+        assert got - 4 - 6 * 4 == 2 * encoder.state_bytes_per_row(n)
     assert encoder.state_bytes_per_row() == encoder.state_bytes_per_row(256)
     with pytest.raises(ValueError, match="kv_positions=256"):
         encoder.cache_positions(257)
@@ -609,7 +625,7 @@ def test_the_table_has_a_fifth_row():
     enc = build_encoder(config())
     assert isinstance(enc, BailingHybridEncoder)
     assert isinstance(enc, ChunkEncoder)
-    assert enc.state_counters(enc.init_states(1)).shape == (5,)
+    assert enc.state_counters(enc.init_states(1)).shape == (6,)
     assert enc.counter_attrs([]) == {}
 
 

@@ -33,7 +33,8 @@ from code_intelligence_tpu.models import contract
 from code_intelligence_tpu.ops import mla, moe
 from code_intelligence_tpu.text import SPECIALS, Vocab
 from code_intelligence_tpu.utils import tracing
-from encoder_programs import compiled, seeded
+from encoder_programs import (
+    compiled, seeded, the_rule_says_grouped_kernels)
 
 YARN = {"beta_fast": 32, "beta_slow": 1, "factor": 40, "mscale": 1,
         "mscale_all_dim": 1, "original_max_position_embeddings": 4096,
@@ -589,6 +590,24 @@ def test_the_encoder_on_the_kernel_equals_the_reference(
     assert int(states["counts"][2]) == 3    # programs: summed, not set
 
 
+def test_the_encoder_on_the_grouped_matmul_kernels_equals_the_reference(
+        monkeypatch, params, tokens):
+    """Every expert layer's two grouped products through ``ops/gmm.py``'s
+    kernels (interpreted), and the count says two layers."""
+    the_rule_says_grouped_kernels(monkeypatch)
+    enc = build_encoder(config(), params)
+    want, _ = reference(params, tokens)
+    states = enc.init_states(3, 64)
+    outs = []
+    for lo in (0, 8, 16):
+        out, states = compiled(enc)(params, tokens[:, lo:lo + 8], states)
+        outs.append(out)
+    got = jnp.concatenate(outs, 1)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    assert enc.counter_attrs([np.asarray(states["counts"])])[
+        "expert_kernel_layers"] == 2
+
+
 def test_a_dropped_cache_is_seen(params, encoder, tokens):
     want, _ = reference(params, tokens)
     step = compiled(encoder)
@@ -756,9 +775,10 @@ def test_the_kernel_count_rides_the_finalize_span(params, engine):
     (fin,) = [s for t in log for s in t["spans"]
               if s["name"] == "engine.finalize"]
     assert fin["attrs"]["attention_kernel_layers"] == 0
+    assert fin["attrs"]["expert_kernel_layers"] == 0
     assert fin["attrs"]["moe_programs"] == 2
     enc = engine.encoder
-    assert enc.state_counters(enc.init_states(1)).shape == (4,)
+    assert enc.state_counters(enc.init_states(1)).shape == (5,)
 
 
 def test_a_document_past_the_cache_is_refused(engine):
@@ -787,7 +807,7 @@ def test_it_satisfies_the_contract_and_counts_its_state(encoder):
     assert encoder.cache_positions(17) == encoder.cache_positions() == 64
     states = encoder.init_states(2, 16)
     got = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(states))
-    assert got - 4 - 4 * 4 == 2 * encoder.state_bytes_per_row(16)
+    assert got - 4 - 5 * 4 == 2 * encoder.state_bytes_per_row(16)
     with pytest.raises(ValueError, match="kv_positions=64"):
         encoder.cache_positions(65)
 

@@ -37,7 +37,8 @@ from code_intelligence_tpu.models import blocks, contract
 from code_intelligence_tpu.ops import mla, moe
 from code_intelligence_tpu.text import SPECIALS, Vocab
 from code_intelligence_tpu.utils import tracing
-from encoder_programs import compiled, seeded
+from encoder_programs import (
+    compiled, seeded, the_rule_says_grouped_kernels)
 
 MODEL = {
     "vocab_size": 300, "hidden_size": 64, "ffn_hidden_size": 96,
@@ -274,6 +275,19 @@ def test_encoder_equals_the_reference(params, tokens):
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
     assert int(states["pos"]) == 24
     assert len(states["latent"]) == 4  # two caches a layer
+
+
+def test_the_encoder_on_the_grouped_matmul_kernels_equals_the_reference(
+        monkeypatch, params, tokens):
+    """Every expert layer's two grouped products through ``ops/gmm.py``'s
+    kernels (interpreted), and the count says two layers."""
+    the_rule_says_grouped_kernels(monkeypatch)
+    enc = build_encoder(config(), params)
+    want, _ = reference(params, tokens)
+    got, states = jax.jit(enc.encode)(params, tokens, enc.init_states(3, 24))
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    assert enc.counter_attrs([np.asarray(states["counts"])])[
+        "expert_kernel_layers"] == 2
 
 
 def test_the_uncut_encoder_equals_the_uncut_reference(tokens):
@@ -539,7 +553,7 @@ def test_it_satisfies_the_contract_and_counts_its_state(encoder):
     assert encoder.window_positions(64) == 0
     states = encoder.init_states(2, 16)
     got = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(states))
-    assert got - 4 - 7 * 4 == 2 * encoder.state_bytes_per_row(16)
+    assert got - 4 - 8 * 4 == 2 * encoder.state_bytes_per_row(16)
     with pytest.raises(ValueError, match="kv_positions=64"):
         encoder.cache_positions(65)
 
@@ -598,7 +612,7 @@ def test_the_table_from_architecture_to_config_and_encoder():
     enc = build_encoder(cfg)
     assert type(enc) is LongcatFlashEncoder and enc.dtype == jnp.bfloat16
     assert isinstance(enc, ChunkEncoder)
-    assert enc.state_counters(enc.init_states(1)).shape == (7,)
+    assert enc.state_counters(enc.init_states(1)).shape == (8,)
     assert enc.counter_attrs([]) == {}
     with pytest.raises(ValueError) as e:
         make_config("longcat", {})

@@ -73,13 +73,16 @@ EXPERT_MODELS = {
 }
 # an encoder's own slots: (sums, totals, sets)
 OWN_SLOTS = {
-    "deepseek_v3": ((), (), ("attention_kernel_layers",)),
-    "afmoe": ((), (), ("attention_kernel_layers",)),
+    "deepseek_v3": ((), (), ("attention_kernel_layers",
+                             "expert_kernel_layers")),
+    "afmoe": ((), (), ("attention_kernel_layers", "expert_kernel_layers")),
     "bailing_hybrid": ((), (), ("kda_kernel_layers",
-                                "attention_kernel_layers")),
-    "smallthinker": (("expert_rounds",), (), ("attention_kernel_layers",)),
+                                "attention_kernel_layers",
+                                "expert_kernel_layers")),
+    "smallthinker": (("expert_rounds",), (), ("attention_kernel_layers",
+                                              "expert_kernel_layers")),
     "longcat_flash": (("expert_rounds",), ("zero_choices", "valid_choices"),
-                      ("attention_kernel_layers",)),
+                      ("attention_kernel_layers", "expert_kernel_layers")),
 }
 
 

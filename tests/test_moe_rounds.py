@@ -17,7 +17,7 @@ from benchmark.harness.cell import load_reference
 from benchmark.reference import smallthinker as plain
 from code_intelligence_tpu import models
 from code_intelligence_tpu.models import build_encoder, make_config
-from code_intelligence_tpu.ops import moe
+from code_intelligence_tpu.ops import gmm, moe
 
 ROOT = Path(__file__).resolve().parents[1]
 N, E, F, TOP_K, WIDTH = 24, 64, 16, 8, 16
@@ -89,6 +89,37 @@ def test_rounds_and_one_pass_give_the_plain_weighted_sum(share, pad, draw):
     assert rounds == -(-total // (m * N)) <= -(-TOP_K // m)
     if m == TOP_K:
         assert rounds == (total > 0)
+
+
+@pytest.mark.parametrize("pad", [0, 7], ids=["whole", "padded"])
+@pytest.mark.parametrize("share", ["1/4", "3/8", "7/8", "1"])
+def test_the_grouped_matmul_kernels_give_the_plain_weighted_sum(
+        monkeypatch, share, pad):
+    """``held`` on ``ops/gmm.py``'s kernels (interpreted: the rule's
+    answer and a tile of 8 rows are the test's, so a share's rounds, its
+    last part-full one and the one pass of every expert held all meet
+    groups that straddle tiles and rows past the last one routed): the
+    plain reference's sum at the tightness of the case above."""
+    first, count = SHARES[share]
+    monkeypatch.setattr(gmm, "gmm_is_kernel", lambda *a: True)
+    monkeypatch.setattr(gmm, "_kernel_tiles", lambda *a: (8, 8, F, E // 2))
+    x, experts, weights, w_in, w_out, first, valid = _case(
+        first, count, pad, "skewed")
+    lowered = jax.jit(lambda *a: moe.routed_experts(
+        *a, first, WIDTH, valid, "relu")).lower(
+            x, experts, weights, w_in, w_out)
+    assert "ragged_dot" not in lowered.as_text()
+    assert "gated_gmm/pallas_call" in lowered.as_text(debug_info=True)
+    got, rows = lowered.compile()(x, experts, weights, w_in, w_out)
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(lambda x, e, w, w_in, w_out: plain.routed_part(
+            {"experts_in": w_in, "experts_out": w_out}, x, e, w, first))(
+                x, experts, weights, w_in, w_out)
+    np.testing.assert_allclose(got[:N - pad], want[:N - pad], rtol=2e-5,
+                               atol=2e-5)
+    if pad:
+        assert float(jnp.abs(got[N - pad:]).max()) == 0.0
+    assert int(rows.sum()) == min(count, TOP_K) * (N - pad)
 
 
 def _lowered(first, count, n_experts):

@@ -120,3 +120,49 @@ def test_the_latent_core_stays_on_xla_where_the_rule_says_so(
         one_chip, monkeypatch, T, S, dtype):
     text = _mla_text(monkeypatch, one_chip, 16, T, S, dtype)
     assert "tpu_custom_call" not in text
+
+
+def _experts_text(monkeypatch, one_chip, N, top_k, count, n_experts, E, F,
+                  dtype=jnp.bfloat16):
+    """The compiled text of one ``routed_experts`` call over ``N`` tokens
+    as the expert encoders make it; the rule asks the backend, so the
+    test answers for it."""
+    from code_intelligence_tpu.ops import moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def layer(x, experts, weights, w_in, w_out):
+        return moe.routed_experts(x, experts, weights, w_in, w_out, 0,
+                                  n_experts)
+
+    shapes = [((N, E), jnp.float32), ((N, top_k), jnp.int32),
+              ((N, top_k), jnp.float32), ((count, E, 2 * F), dtype),
+              ((count, F, E), dtype)]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    return jax.jit(layer).lower(*args).compile().as_text()
+
+
+# the held experts of the five expert cells (`ops/gmm.py`), at their
+# widest program (16 rows of 512 tokens) and at the two rows the long
+# group narrows to: SmallThinker's one pass over 6 N rows, the four
+# shares' rounds of N
+@pytest.mark.parametrize("N,top_k,count,n_experts,E,F", [
+    (8192, 6, 64, 64, 2560, 768), (1024, 6, 64, 64, 2560, 768),
+    (8192, 8, 128, 512, 2560, 768), (1024, 8, 128, 512, 2560, 768),
+    (8192, 8, 16, 256, 7168, 2048), (1024, 8, 16, 256, 7168, 2048),
+    (8192, 4, 32, 256, 3072, 3072), (8192, 12, 16, 768, 6144, 2048),
+], ids=["smallthinker", "smallthinker_2_rows", "ling", "ling_2_rows",
+        "deepseek", "deepseek_2_rows", "trinity", "longcat"])
+def test_the_grouped_matmul_kernels_compile_at_the_cells_widths(
+        one_chip, monkeypatch, N, top_k, count, n_experts, E, F):
+    text = _experts_text(monkeypatch, one_chip, N, top_k, count, n_experts,
+                         E, F)
+    assert "gated_gmm" in text and "ragged-dot" not in text
+    # the two products, and nothing else of the layer, are Mosaic's
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 2
+
+
+def test_the_grouped_matmuls_stay_on_xla_in_float32(one_chip, monkeypatch):
+    text = _experts_text(monkeypatch, one_chip, 1024, 6, 64, 64, 2560, 768,
+                         jnp.float32)
+    assert "gated_gmm" not in text and "ragged-dot" in text

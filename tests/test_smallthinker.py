@@ -32,7 +32,8 @@ from code_intelligence_tpu.models import contract
 from code_intelligence_tpu.ops import attention, moe
 from code_intelligence_tpu.text import SPECIALS, Vocab
 from code_intelligence_tpu.utils import tracing
-from encoder_programs import compiled, seeded
+from encoder_programs import (
+    compiled, seeded, the_rule_says_grouped_kernels)
 
 ROOT = Path(__file__).resolve().parents[1]
 PERIOD = [0, 1, 1, 1]
@@ -651,6 +652,18 @@ def test_the_encoder_on_the_kernel_equals_the_reference(
         "attention_kernel_layers"] == 8
 
 
+def test_the_encoder_on_the_grouped_matmul_kernels_equals_the_reference(
+        monkeypatch, params, tokens, want):
+    """Every expert layer's two grouped products through ``ops/gmm.py``'s
+    kernels (interpreted), and the count says eight layers."""
+    the_rule_says_grouped_kernels(monkeypatch)
+    enc = build_encoder(config(), params)
+    got, states = streamed(enc, params, tokens)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    assert enc.counter_attrs([np.asarray(states["counts"])])[
+        "expert_kernel_layers"] == 8
+
+
 # -- through the engine's normal path -------------------------------------------
 
 @pytest.fixture(scope="module")
@@ -707,6 +720,7 @@ def test_counts_ride_the_finalize_span(params, engine):
         8 * 6 * 37 / (5 * 8 * 16))
     assert a["expert_rounds_mean"] == 1.0
     assert a["attention_kernel_layers"] == 0     # the rule sees the CPU
+    assert a["expert_kernel_layers"] == 0
 
 
 def test_a_document_past_the_cache_is_refused(engine):
@@ -743,7 +757,7 @@ def test_it_satisfies_the_contract_and_counts_its_state(encoder):
         == (6 * 12 + 2 * 64) * per_slot
     states = encoder.init_states(2, 40)
     got = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(states))
-    assert got - 4 - 5 * 4 == 2 * encoder.state_bytes_per_row(40)
+    assert got - 4 - 6 * 4 == 2 * encoder.state_bytes_per_row(40)
     with pytest.raises(ValueError, match="kv_positions=64"):
         encoder.cache_positions(65)
     # no sliding layer: no ring
@@ -816,7 +830,7 @@ def test_the_table_has_the_row():
     assert contract.ENCODERS["smallthinker"][0] is SmallThinkerConfig
     enc = build_encoder(config())
     assert isinstance(enc, SmallThinkerEncoder)
-    assert enc.state_counters(enc.init_states(1)).shape == (5,)
+    assert enc.state_counters(enc.init_states(1)).shape == (6,)
     assert enc.counter_attrs([]) == {}
 
 
