@@ -26,6 +26,10 @@ from code_intelligence_tpu.models.longcat_flash import (
     LongcatFlashConfig,
     LongcatFlashEncoder,
 )
+from code_intelligence_tpu.models.qwen3_next import (
+    Qwen3NextConfig,
+    Qwen3NextEncoder,
+)
 from code_intelligence_tpu.models.smallthinker import (
     SmallThinkerConfig,
     SmallThinkerEncoder,
@@ -37,4 +41,5 @@ __all__ = ["AfmoeConfig", "AfmoeEncoder", "AWDLSTMConfig", "AWDLSTMEncoder", "AW
            "DeepseekV3Config", "DeepseekV3Encoder",
            "GraniteHybridConfig", "GraniteHybridEncoder",
            "LongcatFlashConfig", "LongcatFlashEncoder",
+           "Qwen3NextConfig", "Qwen3NextEncoder",
            "SmallThinkerConfig", "SmallThinkerEncoder"]

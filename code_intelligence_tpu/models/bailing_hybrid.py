@@ -80,7 +80,7 @@ import jax
 import jax.numpy as jnp
 
 from code_intelligence_tpu.models.blocks import (
-    CarriedCounts, Counts, config_from_dict, embed, held_experts,
+    CarriedCounts, Counts, config_from_dict, embed, held_experts, l2_norm,
     latent_block, matmul, rms_norm, valid_lanes)
 from code_intelligence_tpu.ops import kda, mla, moe, ssd
 
@@ -203,12 +203,6 @@ class BailingHybridConfig:
     @property
     def n_moe_layers(self) -> int:
         return self.num_hidden_layers - self.first_k_dense_replace
-
-
-def _l2_norm(x, eps=1e-6):
-    """``x / sqrt(sum x^2 + eps)`` over the last axis, float32."""
-    xf = x.astype(jnp.float32)
-    return xf * jax.lax.rsqrt(jnp.sum(xf * xf, axis=-1, keepdims=True) + eps)
 
 
 class BailingHybridEncoder(CarriedCounts):
@@ -396,8 +390,8 @@ class BailingHybridEncoder(CarriedCounts):
         with jax.named_scope("gates"):
             q, k, v = (qkv[..., j * D:(j + 1) * D].reshape(b, T, H, d)
                        for j in range(3))
-            q = _l2_norm(q) * d ** -0.5
-            k = _l2_norm(k)
+            q = l2_norm(q) * d ** -0.5
+            k = l2_norm(k)
             rate = jnp.exp(p["A_log"].astype(jnp.float32))[:, None]
             g = cfg.kda_lower_bound * jax.nn.sigmoid(rate * (
                 fgb[..., :D] + p["dt_bias"].astype(jnp.float32)

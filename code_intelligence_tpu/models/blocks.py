@@ -25,6 +25,13 @@ def rms_norm(x, w, eps):
     return xf * lax.rsqrt(var + eps) * w.astype(jnp.float32)
 
 
+def l2_norm(x, eps=1e-6):
+    """``x / sqrt(sum x^2 + eps)`` over the last axis, float32: the
+    delta-rule layers' norm of a head's ``q`` and ``k``."""
+    xf = x.astype(jnp.float32)
+    return xf * lax.rsqrt(jnp.sum(xf * xf, axis=-1, keepdims=True) + eps)
+
+
 def matmul(x, w, out_dtype=jnp.float32):
     return jnp.dot(x.astype(w.dtype), w, preferred_element_type=jnp.float32
                    ).astype(out_dtype)
@@ -55,14 +62,25 @@ def split_heads(qkv, Hq: int, Hkv: int, d: int):
     return q, k, v
 
 
-def rope_qk(q, k, pos, inv_freq):
-    """Rotary on all ``head_dim`` dims of ``q`` and ``k``
-    (``rotate_half`` pairs) at the chunk's positions ``pos + t``, under
-    the named scope ``rope``."""
+def rope_qk(q, k, pos, inv_freq, width=None):
+    """Rotary on the leading ``width`` dims of each head of ``q`` and
+    ``k`` (all ``head_dim`` of them by default; ``rotate_half`` pairs
+    within the slice, ``inv_freq`` its ``width // 2`` frequencies; the
+    dims after it pass as they are, in float32) at the chunk's positions
+    ``pos + t``, under the named scope ``rope``."""
     with jax.named_scope("rope"):
         positions = pos + jnp.arange(q.shape[1])
-        return (mla.apply_rope(q, positions, inv_freq, interleaved=False),
-                mla.apply_rope(k, positions, inv_freq, interleaved=False))
+
+        def turned(x):
+            if width is None:
+                return mla.apply_rope(x, positions, inv_freq,
+                                      interleaved=False)
+            return jnp.concatenate(
+                [mla.apply_rope(x[..., :width], positions, inv_freq,
+                                interleaved=False),
+                 x[..., width:].astype(jnp.float32)], axis=-1)
+
+        return turned(q), turned(k)
 
 
 def share_of(model: Mapping, count_key: str) -> dict:

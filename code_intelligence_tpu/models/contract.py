@@ -65,6 +65,8 @@ from code_intelligence_tpu.models.granite_hybrid import (
     GraniteHybridConfig, GraniteHybridEncoder)
 from code_intelligence_tpu.models.longcat_flash import (
     LongcatFlashConfig, LongcatFlashEncoder)
+from code_intelligence_tpu.models.qwen3_next import (
+    Qwen3NextConfig, Qwen3NextEncoder)
 from code_intelligence_tpu.models.smallthinker import (
     SmallThinkerConfig, SmallThinkerEncoder)
 
@@ -129,6 +131,9 @@ ENCODERS = {
     LongcatFlashConfig.architecture: (
         LongcatFlashConfig, LongcatFlashConfig.from_dict,
         _in_weights_dtype(LongcatFlashEncoder)),
+    Qwen3NextConfig.architecture: (
+        Qwen3NextConfig, Qwen3NextConfig.from_dict,
+        _in_weights_dtype(Qwen3NextEncoder)),
 }
 
 

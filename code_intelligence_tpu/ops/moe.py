@@ -88,7 +88,8 @@ holds (a round's zeroed tail, or nothing a round ever wrote) is selected
 away and never multiplied.
 
 ``expert_layer`` is the whole block as most encoders call it (router, the
-held experts' part, the shared expert), and ``COUNTERS`` /
+held experts' part, the shared expert, gated a token where a layer has a
+``shared_gate``), and ``COUNTERS`` /
 ``counter_attrs`` what such encoders count on the device and how the
 counts become span attributes: one copy for every model with routed
 experts.
@@ -393,22 +394,31 @@ def expert_layer(
     norm_topk_prob: bool,
     first: int,
     shared: bool,
+    score_func: str = "sigmoid",
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """One expert layer over the flat tokens ``u``: ``(the held experts'
     share + the shared expert (N, E) float32, rows each held expert
-    ran)``: ``route`` and ``routed_experts`` on one tensor. Named
-    scopes ``router``, ``routed_experts``'s three, and
-    ``shared_expert``."""
+    ran)``: ``route`` and ``routed_experts`` on one tensor. A layer
+    without the leaf ``bias`` routes without one; a layer with the leaf
+    ``shared_gate`` ``(E, 1)`` weighs its shared expert a token by
+    ``sigmoid(u w_sg)`` (float32). Named scopes ``router``,
+    ``routed_experts``'s three, and ``shared_expert``."""
     with jax.named_scope("router"):
         experts, weights = route(
-            u, p["router"], p["bias"], n_group, topk_group, top_k, scaling,
-            norm_topk_prob)
+            u, p["router"], p.get("bias"), n_group, topk_group, top_k,
+            scaling, norm_topk_prob, score_func)
     y, per_expert = routed_experts(
         u, experts, weights, p["experts_in"], p["experts_out"], first,
         p["router"].shape[1], valid)
     if shared:
         with jax.named_scope("shared_expert"):
-            y = y + swiglu(u, p["shared_in"], p["shared_out"], dtype)
+            out = swiglu(u, p["shared_in"], p["shared_out"], dtype)
+            if "shared_gate" in p:
+                out = out * jax.nn.sigmoid(jnp.dot(
+                    u.astype(jnp.float32),
+                    p["shared_gate"].astype(jnp.float32),
+                    precision=lax.Precision.HIGHEST))
+            y = y + out
     return y, per_expert
 
 

@@ -38,3 +38,15 @@ def test_mosaic_takes_the_kernel_at_seven_heads_a_group(
         one_chip, monkeypatch, rows, S, window):
     text = _gqa_text(monkeypatch, one_chip, rows, 512, S, window, 28, 4, 128)
     assert "tpu_custom_call" in text
+
+
+# `qwen3_next_bulk_long_tail`: 16 / 2 heads of 256 (eight query heads a
+# key/value head, query blocks of 256: a tile of 2048 rows by 1024 keys
+# at 256 lanes), the global cache of the long group at the widest and the
+# narrowest batch and the short group's own-length caches
+@pytest.mark.parametrize("rows,S", [
+    (16, 16384), (2, 16384), (16, 3072), (16, 4096)])
+def test_mosaic_takes_the_kernel_at_head_256_and_eight_heads_a_group(
+        one_chip, monkeypatch, rows, S):
+    text = _gqa_text(monkeypatch, one_chip, rows, 512, S, None, 16, 2, 256)
+    assert "tpu_custom_call" in text
