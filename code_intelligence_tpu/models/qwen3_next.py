@@ -209,13 +209,13 @@ class Qwen3NextEncoder(GrowingCache, CarriedCounts):
     cache_kind = "key/value"
     # the rounds of ``routed_experts``' loop (a layer a program); the
     # rows still going whose matrix states and conv tails a chunk program
-    # was handed by the one before it; the attention layers whose core
-    # the program ran on a Pallas kernel, as the op's ``core_is_kernel``
-    # said, and the expert layers whose grouped matmuls it did
-    # (``gmm_is_kernel``); the recurrence has one core, plain XLA
+    # was handed by the one before it; the linear and the attention
+    # layers whose core the program ran on a Pallas kernel, as each op's
+    # ``core_is_kernel`` said, and the expert layers whose grouped
+    # matmuls it did (``gmm_is_kernel``)
     counts = Counts(sums=("expert_rounds",),
                     totals=("gdn_state_handovers",),
-                    sets=("attention_kernel_layers",
+                    sets=("gdn_kernel_layers", "attention_kernel_layers",
                           "expert_kernel_layers"))
 
     def __init__(self, config: Qwen3NextConfig, dtype=jnp.bfloat16):
@@ -334,6 +334,10 @@ class Qwen3NextEncoder(GrowingCache, CarriedCounts):
             backend, dtype, T, kc.shape[2],
             cfg.num_attention_heads // cfg.num_key_value_heads, cfg.head_dim)
             for kc in k_caches)
+        gdn_on_kernel = len(gdn_states) * gdn.core_is_kernel(
+            backend, dtype, T, cfg.linear_num_key_heads,
+            cfg.linear_num_value_heads, cfg.linear_key_head_dim,
+            cfg.linear_value_head_dim, _GDN_CHUNK)
         new_states = {
             "gdn": tuple(gdn_states), "conv": tuple(tails),
             "k": tuple(k_caches), "v": tuple(v_caches), "pos": pos + T,
@@ -345,6 +349,7 @@ class Qwen3NextEncoder(GrowingCache, CarriedCounts):
                 # padding row's lengths are 0)
                 gdn_state_handovers=jnp.where(
                     pos > 0, jnp.sum(lengths > 0), 0).astype(jnp.int32),
+                gdn_kernel_layers=gdn_on_kernel,
                 attention_kernel_layers=on_kernel,
                 expert_kernel_layers=moe.kernel_layers(
                     params["layers"], N, cfg.num_experts_per_tok)),

@@ -1,6 +1,6 @@
 """Mosaic takes the latent attention core (`ops/mla.py::mla_cached`) and the
-delta-rule core (`ops/kda.py::kda_scan`) at their cells' shapes, and no
-Mosaic call appears where the rules say XLA
+two delta-rule cores (`ops/kda.py::kda_scan`, `ops/gdn.py::gdn_scan`) at
+their cells' shapes, and no Mosaic call appears where the rules say XLA
 (`tests/pallas_tpu_compile.py` has the how and the why).
 """
 
@@ -109,6 +109,44 @@ def test_the_delta_rule_stays_on_xla_in_float32(one_chip, monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     compiled = _kda_compiled(one_chip, 2, jnp.float32)
     assert "tpu_custom_call" not in compiled.as_text()
+
+
+# ops/gdn.py::gdn_scan at `qwen3_next_bulk_long_tail`'s shapes (32 value
+# heads on 16 key heads of 128 | 128, chunks of 64, float32 operands as
+# the encoder holds them, bfloat16 in-chunk products)
+def _gdn_compiled(one_chip, rows, mxu_dtype=jnp.bfloat16):
+    from code_intelligence_tpu.ops.gdn import gdn_scan
+
+    T, Hk, Hv, d = 512, 16, 32, 128
+    shapes = [(rows, T, Hk, d)] * 2 + [(rows, T, Hv, d)] \
+        + [(rows, T, Hv)] * 2 + [(rows, Hv, d, d)]
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+            for s in shapes]
+    return jax.jit(lambda *a: gdn_scan(
+        *a, chunk=64, mxu_dtype=mxu_dtype)).lower(*args).compile()
+
+
+# the kernel at every row count the cell's set-up compiles (16 narrowing
+# to 8, 4, 2): Mosaic takes the strided loads of a key head's and its two
+# value heads' chunks, the turned decays and the blocks' VMEM; the `(b, T
+# * H, d)` view of the operands is the same bytes, so nothing the size of
+# an operand is copied around it
+@pytest.mark.parametrize("rows", [16, 8, 4, 2])
+def test_the_scalar_decay_kernel_compiles_at_the_cells_shapes(
+        one_chip, monkeypatch, rows):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = _gdn_compiled(one_chip, rows)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "gdn_scan_core" in text
+    assert "while" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1024 ** 2
+
+
+def test_the_scalar_decay_rule_stays_on_xla_in_float32(one_chip, monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = _gdn_compiled(one_chip, 2, jnp.float32)
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text and "while" in text
 
 
 # where the rule says XLA no Mosaic call appears: the single-chunk groups

@@ -30,7 +30,7 @@ from code_intelligence_tpu.models import (
     ChunkEncoder, Qwen3NextConfig, Qwen3NextEncoder, build_encoder,
     make_config)
 from code_intelligence_tpu.models import blocks, contract, qwen3_next
-from code_intelligence_tpu.ops import attention, mla, moe
+from code_intelligence_tpu.ops import attention, gdn, mla, moe
 from code_intelligence_tpu.text import SPECIALS, Vocab
 from code_intelligence_tpu.utils import tracing
 from encoder_programs import (
@@ -452,9 +452,9 @@ def test_counts_ride_the_spans(params, engine):
         want += sum(int(((c >= 4) & (c < 12)).sum()) for c in chosen)
     assert a["routed_rows"] == want > 0
     assert a["moe_programs"] == 3       # chunks of 16: rows 4, 2, 2
-    # what the two rules say here: the CPU, float32, sizes under a lane
-    assert (a["attention_kernel_layers"], a["expert_kernel_layers"]) \
-        == (0, 0)
+    # what the three rules say here: the CPU, float32, sizes under a lane
+    assert (a["gdn_kernel_layers"], a["attention_kernel_layers"],
+            a["expert_kernel_layers"]) == (0, 0, 0)
     # two documents still going in the second program, one in the third
     assert a["gdn_state_handovers"] == 3
     # a half share of top 4: two of a token's choices land here
@@ -464,6 +464,32 @@ def test_counts_ride_the_spans(params, engine):
     assert (g["chunks"], g["kv_positions"]) == (3, 48)
     programs = [s for s in spans if s["name"] == "engine.program"]
     assert len(programs) == 3
+
+
+def test_gdn_kernel_layers_is_what_the_rule_says(params, vocab, monkeypatch):
+    """The rule patched true and chunk programs of 64 tokens (one whole
+    chunk of the recurrence): every linear layer of every program runs
+    the interpreted kernel, the count says ``len(cfg.gdn_layers)``, and
+    the rows are the XLA scan's."""
+    seqs = [np.random.default_rng(12).integers(20, 300, n).astype(np.int32)
+            for n in (50, 100)]
+
+    def build():
+        return InferenceEngine(params, config(), vocab, buckets=(64,),
+                               batch_size=2)
+
+    want = build().embed_ids_batch(seqs)
+    monkeypatch.setattr(gdn, "core_is_kernel", lambda *a: True)
+    ran = []
+    real = gdn._kernel_scan
+    monkeypatch.setattr(gdn, "_kernel_scan",
+                        lambda *a: ran.append(a[0].shape) or real(*a))
+    engine = build()
+    _, a = _traced_finalize(engine, seqs)
+    assert a["gdn_kernel_layers"] == len(engine.config.gdn_layers) == 3
+    assert ran and len(ran) % 3 == 0
+    np.testing.assert_allclose(engine.embed_ids_batch(seqs), want,
+                               rtol=2e-4, atol=2e-5)
 
 
 def test_a_document_past_the_cache_is_refused(engine):
@@ -505,8 +531,12 @@ def test_it_satisfies_the_contract_and_counts_its_state(encoder):
         states = encoder.init_states(2, n)
         got = sum(a.size * a.dtype.itemsize
                   for a in jax.tree.leaves(states))
-        # less the position counter and the seven counts
-        assert got - 4 - 7 * 4 == 2 * encoder.state_bytes_per_row(n)
+        # less the position counter and the eight counts, which
+        # ``carried_state_mb_per_row`` never counted
+        assert encoder.counts.names[-3:] == (
+            "gdn_kernel_layers", "attention_kernel_layers",
+            "expert_kernel_layers")
+        assert got - 4 - 8 * 4 == 2 * encoder.state_bytes_per_row(n)
     assert encoder.state_bytes_per_row() == encoder.state_bytes_per_row(256)
     with pytest.raises(ValueError, match="kv_positions=256"):
         encoder.cache_positions(257)
@@ -552,6 +582,14 @@ def test_published_widths_carry_40_megabytes_a_row():
     assert attention._kernel_tiles(512, 16384, 8) == (256, 1024)
     assert not attention.core_is_kernel("cpu", jnp.bfloat16, 512, 16384, 8,
                                         256)
+    # and the recurrence's: the kernel on the chip in every program of
+    # the cell (16 | 32 heads of 128 | 128, chunks of 64, bucket 512)
+    sizes = (512, cfg.linear_num_key_heads, cfg.linear_num_value_heads,
+             cfg.linear_key_head_dim, cfg.linear_value_head_dim,
+             qwen3_next._GDN_CHUNK)
+    assert sizes == (512, 16, 32, 128, 128, 64)
+    assert gdn.core_is_kernel("tpu", jnp.bfloat16, *sizes)
+    assert not gdn.core_is_kernel("cpu", jnp.bfloat16, *sizes)
 
 
 def test_config_from_the_published_keys_and_the_share():
@@ -590,7 +628,7 @@ def test_the_table_has_an_eighth_row():
     enc = build_encoder(config())
     assert isinstance(enc, Qwen3NextEncoder)
     assert isinstance(enc, ChunkEncoder)
-    assert enc.state_counters(enc.init_states(1)).shape == (7,)
+    assert enc.state_counters(enc.init_states(1)).shape == (8,)
     assert enc.counter_attrs([]) == {}
 
 
