@@ -235,7 +235,7 @@ def leg_train(work: Path, w: Widths, corpus_dir: Path, max_tokens: int,
     if "--data_parallel" not in extra_args:
         argv += ["--data_parallel", "1"]  # one chip even on a 4-chip host
     accountant = flight_recorder.get_accountant()
-    before = len(accountant.report())
+    before = accountant.compiles_mark()
     t0 = time.perf_counter()
     summary = cli.main(argv)
     wall = time.perf_counter() - t0
@@ -253,8 +253,10 @@ def leg_train(work: Path, w: Widths, corpus_dir: Path, max_tokens: int,
     last = sum(losses[-5:]) / 5
     assert last < first, f"{name}: loss did not fall ({first} -> {last})"
     assert math.isfinite(summary["val_loss"]), summary
-    ledger = accountant.report()[before:]  # this leg's XLA compiles
-    compiles = dict(Counter(c["fn"] for c in ledger))
+    ledger = accountant.report(before)  # this leg's XLA compiles
+    # the instrumented steps (they carry a shape label): the ledger also
+    # holds every other program the leg compiled, by jax's name for it
+    compiles = dict(Counter(c["fn"] for c in ledger if c["shape"]))
     # two scanned dispatches, one scanned validation: each program once,
     # and never the single-window programs (no tail windows by sizing)
     assert compiles.get("train.steps") == 1, compiles

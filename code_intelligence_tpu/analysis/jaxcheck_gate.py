@@ -57,7 +57,7 @@ def run_jaxcheck_gate() -> dict:
                    and "h2d_d2h_bytes" in rendered
                    and watch.d2h_bytes == 0 and not watch.host_syncs),
             "d2h_bytes": watch.d2h_bytes,
-            "backstop_compile_events": watch.backstop_compile_events,
+            "stray_compiles": len(watch.stray_compiles),
         }
     except CompileWatchViolation as e:
         pins["clean_steady"] = {"ok": False, "error": str(e)[:300]}

@@ -4,9 +4,10 @@ Three dynamic checks that piggyback on hooks the framework already has,
 asserted inside tier-1 tests (and usable around any suspect scope):
 
 * :class:`recompile_guard` — reads the flight-recorder
-  ``XLAAccountant`` ledger (every ``InstrumentedJit``-wrapped step
-  records each newly compiled input signature there) and fails when a
-  guarded scope compiles more new shapes than its declared budget.
+  ``XLAAccountant`` ledger (every compile of the process is an entry
+  there, an ``InstrumentedJit``-wrapped step's under its instrumented
+  name) and fails when a guarded scope compiles more new shapes than
+  its declared budget.
   ``budget=0`` is the steady-state assertion: a warmed-up serve/train
   loop must never pay another compile.
 * :func:`no_implicit_transfers` — ``jax.transfer_guard("disallow")`` as
@@ -25,13 +26,13 @@ asserted inside tier-1 tests (and usable around any suspect scope):
   the failure names the owning component(s) of the growth.
 * :class:`CompileWatch` — the jaxcheck lint's runtime counterpart: a
   steady-state dispatch sentinel for one warmed-up step function.
-  :meth:`CompileWatch.steady_state` snapshots the accountant ledger and
-  a ``jax.monitoring`` backend-compile event counter, patches the
+  :meth:`CompileWatch.steady_state` marks the accountant ledger (the
+  process's one ``jax.monitoring`` listener feeds it), patches the
   concrete ``jax.Array`` host-materialization surface (``.item()`` /
   ``__array__`` / ``__float__`` / ``__int__`` / ``__bool__``) plus
   ``jax.device_get`` / ``jax.device_put``, and fails at scope exit when
-  the scope recompiled (named via the ledger, or unattributed via the
-  event backstop) or materialized device values on the host outside an
+  the scope recompiled (the watched step, or any other program: both
+  named by the ledger, with their stage seconds) or materialized device values on the host outside an
   explicit ``jax.device_get``. The CPU backend's d2h is zero-copy, so
   ``transfer_guard`` alone cannot see ``.item()`` there — the method
   patch is what makes the audit meaningful device-free. Transfer volume
@@ -105,7 +106,8 @@ class recompile_guard:
 
     ``fn`` narrows the check to one instrumented function name (e.g.
     ``"slots.step"``, ``"train.steps"``); ``None`` applies the budget to
-    every function in the ledger individually. ``budget`` is the number
+    every function in the ledger individually (every program of the
+    process, once the accountant listens). ``budget`` is the number
     of NEW compiles allowed inside the scope (0 = steady state).
 
     The guard observes, it never blocks: compilation proceeds normally
@@ -113,8 +115,7 @@ class recompile_guard:
     :meth:`check`), listing the offending shapes so the failure message
     is actionable. If accounting is disabled
     (``CI_TPU_NO_XLA_ACCOUNTING=1``) or the wrapped step has fallen back
-    to unaccounted passthrough, the guard sees nothing — it audits the
-    instrumented path, not raw jax.
+    to unaccounted passthrough, the guard sees nothing of that step.
     """
 
     def __init__(self, fn: Optional[str] = None, budget: int = 1,
@@ -122,7 +123,7 @@ class recompile_guard:
         self.fn = fn
         self.budget = int(budget)
         self._acct = accountant
-        self._before: Dict[str, int] = {}
+        self._mark = 0
 
     def _accountant(self):
         if self._acct is None:
@@ -131,25 +132,16 @@ class recompile_guard:
             self._acct = flight_recorder.get_accountant()
         return self._acct
 
-    def _counts(self) -> Dict[str, List[dict]]:
-        per: Dict[str, List[dict]] = {}
-        for c in self._accountant().report():
-            per.setdefault(c["fn"], []).append(c)
-        return per
-
     def __enter__(self) -> "recompile_guard":
-        self._before = {k: len(v) for k, v in self._counts().items()}
+        self._mark = self._accountant().compiles_mark()
         return self
 
     def new_compiles(self) -> Dict[str, List[dict]]:
         """fn -> compile records that happened inside the scope."""
-        out = {}
-        for name, compiles in self._counts().items():
-            if self.fn is not None and name != self.fn:
-                continue
-            fresh = compiles[self._before.get(name, 0):]
-            if fresh:
-                out[name] = fresh
+        out: Dict[str, List[dict]] = {}
+        for c in self._accountant().report(self._mark):
+            if self.fn is None or c["fn"] == self.fn:
+                out.setdefault(c["fn"], []).append(c)
         return out
 
     def check(self) -> None:
@@ -190,39 +182,6 @@ def no_implicit_transfers():
 # ---------------------------------------------------------------------------
 
 
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-_compile_events = 0
-_compile_events_lock = _REAL_LOCK()
-_compile_listener_registered = False
-
-
-def _ensure_compile_listener() -> bool:
-    """Register the global ``jax.monitoring`` backend-compile counter
-    once per process. The counter is a BACKSTOP, not a precise meter:
-    one user-visible compile fires several internal compile events, and
-    events carry no function name — but a warmed loop must produce ZERO
-    of them, which is the only property the watch asserts with it."""
-    global _compile_listener_registered
-    if _compile_listener_registered:
-        return True
-    from jax import monitoring
-
-    def _on_event(event: str, duration: float, **kw) -> None:
-        if event == _COMPILE_EVENT:
-            global _compile_events
-            with _compile_events_lock:
-                _compile_events += 1
-
-    monitoring.register_event_duration_secs_listener(_on_event)
-    _compile_listener_registered = True
-    return True
-
-
-def _compile_event_count() -> int:
-    with _compile_events_lock:
-        return _compile_events
-
-
 class _Sanctioned(threading.local):
     def __init__(self):
         self.active = False
@@ -236,9 +195,10 @@ class CompileWatch:
     ``fn`` names the instrumented step under watch (e.g.
     ``"slots.step"``) — recompile attribution comes from the
     flight-recorder accountant ledger, exactly like
-    :class:`recompile_guard`; a ``jax.monitoring`` backend-compile
-    event counter backstops compiles the ledger cannot name (a stray
-    un-instrumented ``jnp`` op compiling mid-loop).
+    :class:`recompile_guard`; the same ledger names every OTHER program
+    that compiled inside the scope (a stray un-instrumented ``jnp`` op
+    compiling mid-loop), since the accountant's ``jax.monitoring``
+    listener records every compile of the process.
 
     Host syncs are caught by patching the concrete ``jax.Array``
     class's materialization surface (``.item()``, ``__array__``,
@@ -267,7 +227,7 @@ class CompileWatch:
         self._meta = _REAL_LOCK()
         # scope results (persist after exit so tests can assert gauges)
         self.new_compiles: Dict[str, List[dict]] = {}
-        self.backstop_compile_events = 0
+        self.stray_compiles: List[dict] = []  # of any other program
         self.h2d_bytes = 0
         self.d2h_bytes = 0
         self.host_syncs: List[Dict[str, object]] = []
@@ -303,11 +263,10 @@ class CompileWatch:
     def _export(self) -> None:
         if self.registry is None:
             return
-        total = 0
-        for c in self._accountant().report():
-            if self.fn is None or c["fn"] == self.fn:
-                total += 1
-        self.registry.set("jit_recompiles_total", total)
+        acct = self._accountant()
+        self.registry.set(
+            "jit_recompiles_total",
+            acct.compiles_mark() if self.fn is None else acct.count(self.fn))
         self.registry.set("h2d_d2h_bytes", self.h2d_bytes,
                           labels={"dir": "h2d"})
         self.registry.set("h2d_d2h_bytes", self.d2h_bytes,
@@ -342,26 +301,20 @@ class CompileWatch:
 
     # -- the audited scope ----------------------------------------------
 
-    def _ledger_counts(self) -> Dict[str, int]:
-        per: Dict[str, int] = {}
-        for c in self._accountant().report():
-            per[c["fn"]] = per.get(c["fn"], 0) + 1
-        return per
-
     @contextlib.contextmanager
     def steady_state(self):
-        """Audit the scope: zero new compiles (named or backstop), zero
-        unsanctioned host materializations. Raises
+        """Audit the scope: zero new compiles (the watched step's or any
+        other program's), zero unsanctioned host materializations. Raises
         :class:`CompileWatchViolation` at exit naming the watched fn."""
         import jax
 
-        have_listener = _ensure_compile_listener()
-        # the concrete on-device array class; grabbed BEFORE the event
-        # snapshot (the asarray itself may compile a conversion program
+        acct = self._accountant()
+        acct.listen()
+        # the concrete on-device array class; grabbed BEFORE the ledger
+        # is marked (the asarray itself may compile a conversion program
         # on first use) and BEFORE patching
         array_cls = type(jax.numpy.asarray(0))
-        before_ledger = self._ledger_counts()
-        before_events = _compile_event_count()
+        mark = acct.compiles_mark()
         watch = self
 
         def _patched(kind: str, orig):
@@ -409,21 +362,12 @@ class CompileWatch:
                 setattr(array_cls, name, orig)
             jax.device_get = real_device_get
             jax.device_put = real_device_put
-            after_ledger = self._ledger_counts()
-            self.new_compiles = {}
-            named = 0
-            for name, n in after_ledger.items():
-                if self.fn is not None and name != self.fn:
-                    continue
-                fresh = n - before_ledger.get(name, 0)
-                if fresh > 0:
-                    records = [c for c in self._accountant().report()
-                               if c["fn"] == name][-fresh:]
-                    self.new_compiles[name] = records
-                    named += fresh
-            if have_listener:
-                self.backstop_compile_events = (
-                    _compile_event_count() - before_events)
+            self.new_compiles, self.stray_compiles = {}, []
+            for c in acct.report(mark):
+                if self.fn is None or c["fn"] == self.fn:
+                    self.new_compiles.setdefault(c["fn"], []).append(c)
+                else:
+                    self.stray_compiles.append(c)
             self._export()
         self.check()
 
@@ -434,11 +378,19 @@ class CompileWatch:
             problems.append(
                 f"{len(records)} steady-state recompile(s) of {name} "
                 f"[{shapes}]")
-        if not self.new_compiles and self.backstop_compile_events:
+        if self.stray_compiles:
+            def told(c: dict) -> str:
+                stage_s = c.get("stage_s", {})
+                return (f"{c['fn']} (" + ", ".join(
+                    f"{s} {stage_s.get(s, 0.0):.3f} s"
+                    for s in ("trace", "lower", "compile"))
+                    + f", cache {c.get('cache', 'off')})")
             problems.append(
-                f"{self.backstop_compile_events} backend compile "
-                f"event(s) with no instrumented attribution (an "
-                f"un-instrumented op compiled mid-loop)")
+                f"{len(self.stray_compiles)} other program(s) compiled "
+                f"mid-loop: "
+                + ", ".join(told(c) for c in self.stray_compiles[:4])
+                + (f" (+{len(self.stray_compiles) - 4} more)"
+                   if len(self.stray_compiles) > 4 else ""))
         if self.host_syncs:
             kinds = ", ".join(
                 f"{s['kind']} {s['shape']}" for s in self.host_syncs[:4])

@@ -53,7 +53,8 @@ from code_intelligence_tpu.models import AWDLSTMConfig, build_encoder
 from code_intelligence_tpu.text import Tokenizer, Vocab, build_issue_text
 from code_intelligence_tpu.text import rules as text_rules
 from code_intelligence_tpu.text.rules import TK_UNK
-from code_intelligence_tpu.utils import profiling, resilience, tracing
+from code_intelligence_tpu.utils import (
+    flight_recorder, profiling, resilience, tracing)
 
 from code_intelligence_tpu.constants import EMBED_TRUNCATE_DIM  # noqa: F401 (re-export)
 
@@ -114,6 +115,11 @@ class InferenceEngine:
         self.mesh = mesh
         self.config = config
         self.vocab = vocab
+        # the process's compile ledger (utils/flight_recorder.py): every
+        # program this engine compiles is a named record on the wall
+        # clock there, and a traced chunk program says what it paid
+        self._compiles = flight_recorder.get_accountant()
+        self._compiles.listen()
         # Accept encoder-only params ({"embedding": ..., "lstm_0_w_ih": ...})
         # or a full-LM params tree ({"encoder": {...}, "decoder_b": ...}).
         if "embedding" in params:
@@ -720,7 +726,9 @@ class InferenceEngine:
         (the group's first-chunk batch), ``bucket``, ``valid_tokens``
         (the chunk's own) and ``lane_steps`` (``rows`` x ``bucket``); with
         (``rows``, ``bucket``) a capture's module ``jit_fwd_b<rows>_l<bucket>``
-        is laid against them. Over a group's programs ``lane_steps`` and
+        is laid against them; ``compile_s`` where the jitted call traced,
+        lowered or compiled anything (the compile ledger grew on this
+        thread across it: a shape's first call, and never a warmed one). Over a group's programs ``lane_steps`` and
         ``valid_tokens`` sum to the group's ``lane_steps_run`` and
         ``valid_tokens``."""
         B = self.batch_size  # the first chunk's shape; pad the remainder
@@ -758,6 +766,7 @@ class InferenceEngine:
                 tokens[r, : len(chunk)] = chunk
                 lengths[r] = len(chunk)
             if trace_ctx is not None:
+                compiled = self._compiles.stages_mark()
                 tp0 = time.perf_counter()
             pool, h_leaves = self._fwd(batch, bucket)(
                 self._enc_params, jnp.asarray(tokens), jnp.asarray(lengths), tuple(h_leaves), pool
@@ -767,7 +776,8 @@ class InferenceEngine:
                     "engine.program", tp0, time.perf_counter(), trace_ctx,
                     rows=batch, batch=B, bucket=bucket,
                     valid_tokens=int(lengths.sum()),
-                    lane_steps=batch * bucket)
+                    lane_steps=batch * bucket,
+                    **self._compiles.compile_attrs(compiled))
             rows_run += batch
             cache_steps += batch * bucket * (ci + 1)
             window_steps += batch * min(bucket * (ci + 1), ring)

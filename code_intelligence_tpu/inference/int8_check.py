@@ -34,6 +34,7 @@ otherwise surface only as a quality droop in production metrics.
 from __future__ import annotations
 
 import json
+import math
 from pathlib import Path
 from typing import Optional
 
@@ -112,19 +113,20 @@ def _auc_band(f32_engine, int8_engine, max_auc_drop: float) -> dict:
 
 def _step_hbm_evidence(report, start_f32: int, start_int8: int) -> dict:
     """Accountant ``compiled_hbm_bytes`` for each engine's ragged step
-    (PR 4 InstrumentedJit): windowed by report position since both
-    engines share the process-global accountant. Evidence, not the pin
+    (PR 4 InstrumentedJit): windowed by the ledger's marks (an entry's
+    ``seq``) since both engines share the process-global accountant. Evidence, not the pin
     — the tiny gate engine's activation share dominates its step args,
     so the hard >=3x lives on the WEIGHT footprint; here we only require
     int8 not be LARGER when both numbers exist (the accountant can be
     disabled via CI_TPU_NO_XLA_ACCOUNTING)."""
     def window_hbm(start, stop):
-        vals = [e.get("hbm_bytes", 0) for e in report[start:stop]
-                if e.get("fn") == "slots.step_ragged"]
+        vals = [e.get("hbm_bytes", 0) for e in report
+                if start < e["seq"] <= stop
+                and e.get("fn") == "slots.step_ragged"]
         return max(vals) if vals else 0
 
     hbm_f = window_hbm(start_f32, start_int8)
-    hbm_q = window_hbm(start_int8, len(report))
+    hbm_q = window_hbm(start_int8, math.inf)
     return {
         "step_hbm_bytes_f32": int(hbm_f),
         "step_hbm_bytes_int8": int(hbm_q),
@@ -151,9 +153,9 @@ def run_int8_check(fixture: Optional[Path] = None,
     ids = [rng.randint(5, hi, l).astype(np.int32) for l in lengths]
 
     acct = flight_recorder.get_accountant()
-    start_f32 = len(acct.report())
+    start_f32 = acct.compiles_mark()
     ref = f32_engine.embed_ids_batch(ids, scheduler="ragged")
-    start_int8 = len(acct.report())
+    start_int8 = acct.compiles_mark()
     got = int8_engine.embed_ids_batch(ids, scheduler="ragged")
     parity = float(np.max(np.abs(ref - got))) if ids else 0.0
     parity_ok = bool(np.allclose(got, ref, atol=atol, rtol=rtol))
@@ -168,7 +170,7 @@ def run_int8_check(fixture: Optional[Path] = None,
              / max(int8_engine.weight_bytes, 1))
     footprint_ok = bool(ratio >= min_footprint_ratio)
     auc = _auc_band(f32_engine, int8_engine, max_auc_drop)
-    hbm = _step_hbm_evidence(acct.report(), start_f32, start_int8)
+    hbm = _step_hbm_evidence(acct.report(start_f32), start_f32, start_int8)
     return {
         "fixture": str(fixture),
         "n_docs": len(ids),

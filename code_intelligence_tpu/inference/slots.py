@@ -330,8 +330,7 @@ class SlotScheduler:
             return
         self.registry.set(
             "jit_recompiles_total",
-            sum(1 for c in flight_recorder.get_accountant().report()
-                if c["fn"] == self._step_name))
+            flight_recorder.get_accountant().count(self._step_name))
         self.registry.set("h2d_d2h_bytes", self.h2d_bytes,
                           labels={"dir": "h2d"})
         self.registry.set("h2d_d2h_bytes", self.d2h_bytes,

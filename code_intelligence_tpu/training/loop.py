@@ -127,6 +127,7 @@ class LMTrainer:
     ):
         self.mcfg = model_config
         self.tcfg = train_config
+        flight.get_accountant().listen()  # every compile, a named record
         self.mesh = mesh if mesh is not None else make_mesh()
         if (self.mesh.size > 1 and jax.default_backend() == "tpu"
                 and model_config.qrnn_use_pallas):
@@ -536,7 +537,10 @@ class LMTrainer:
         # fit() has chosen one trace for it, by the tracer's parent rule.
         # The first dispatch of each compiled shape is flagged
         # compile=True, separating XLA compile time from steady-state
-        # step time. Bounded and guarded (utils/tracing.py): the hot loop
+        # step time, and gains compile_s, the seconds of the trace,
+        # lowering and backend stage it paid (set from the instrumented
+        # step's compile path, utils/flight_recorder.py: no statement
+        # here). Bounded and guarded (utils/tracing.py): the hot loop
         # never pays more than a few dict ops per DISPATCH (k steps), and
         # never raises.
         tracer = tracing.get_tracer()

@@ -24,6 +24,11 @@ def enable_compile_cache() -> str:
     inside the checkout. On the CPU backend nothing is enabled unless the
     variable asks for it: CPU programs compile in seconds, and the test
     suite must not leave a cache in the checkout."""
+    from code_intelligence_tpu.utils import flight_recorder
+
+    # whoever asks for the cache wants to know what it held: the compile
+    # ledger listens from here on (hit, miss and seconds a program)
+    flight_recorder.get_accountant().listen()
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed

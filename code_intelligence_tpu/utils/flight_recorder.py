@@ -42,6 +42,7 @@ which imports this for ``/debug/flight``).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import logging
@@ -49,11 +50,14 @@ import math
 import os
 import threading
 import time
+import weakref
 from collections import deque
 from pathlib import Path
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+
+from code_intelligence_tpu.utils import tracing
 
 log = logging.getLogger(__name__)
 
@@ -543,26 +547,253 @@ def _hbm_of(compiled) -> int:
         return 0
 
 
+#: the three stages of a compile, as ``jax.monitoring`` times them
+_STAGE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+_STAGES = tuple(_STAGE_EVENTS.values())
+#: what the persistent cache says of a program before the end of its
+#: backend stage. A miss is announced when its entry is WRITTEN: with no
+#: cache directory, or for a program under the cache's thresholds of
+#: compile time and size, neither fires and the compile reads ``off``
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_misses": "miss",
+    "/jax/compilation_cache/cache_hits": "hit",
+}
+_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+# the accountants the process's ONE set of jax.monitoring listeners
+# feeds: registered once, forwarding through weak references, so that
+# a listener never keeps an accountant alive (a test's private one dies
+# with the test) and a compile pays three calls however many listen
+_listening: Tuple["weakref.ref[XLAAccountant]", ...] = ()
+_listeners_registered = False
+_listen_lock = threading.Lock()
+
+
+def _forward(method: str) -> Callable[..., None]:
+    def on_event(event, *args, **kw) -> None:
+        for ref in _listening:
+            try:
+                acct = ref()
+                if acct is not None:
+                    getattr(acct, method)(event, *args, **kw)
+            except Exception:  # an observer: never reaches the compile
+                log.debug("compile listener failed (ignored)",
+                          exc_info=True)
+    return on_event
+
+
+def union_seconds(intervals) -> float:
+    """Length of the union of ``(start, end)`` intervals: a function
+    traced inside another's tracing counts once."""
+    total, reach = 0.0, -math.inf
+    for start, end in sorted(intervals):
+        if end > reach:
+            total += end - max(start, reach)
+            reach = end
+    return total
+
+
+class _Ring:
+    """The last ``capacity`` records, each numbered as it came (``seq``,
+    from 1), and how many there have ever been: a reader that took
+    ``seen`` as its mark finds what came after it, whatever has fallen
+    off the ring or been taken out of it since."""
+
+    def __init__(self, capacity: int):
+        self.items: deque = deque(maxlen=int(capacity))
+        self.seen = 0
+
+    def append(self, item: Dict[str, Any]) -> None:
+        self.seen += 1
+        item["seq"] = self.seen
+        self.items.append(item)
+
+    def since(self, mark: int) -> list:
+        out = []
+        for item in reversed(self.items):
+            if item["seq"] <= mark:
+                break
+            out.append(item)
+        return out[::-1]
+
+
 class XLAAccountant:
     """Per-process compile ledger. One global instance (``get_accountant``)
-    is shared by the trainer, fine-tuner, and slot scheduler so the
-    ``/debug/flight`` endpoint shows every compiled program in the
-    process, whichever component owns the HTTP listener."""
+    is shared by the engine, trainer, fine-tuner, slot scheduler and the
+    runtime audits, so the ``/debug/flight`` endpoint shows every
+    compiled program in the process, whichever component owns the HTTP
+    listener.
 
-    def __init__(self, registry=None):
+    Two bounded rings, both fed by ``jax.monitoring`` once
+    :meth:`listen` has been called (RUNBOOK §18):
+
+    * **stage records**, one a stage of a program (a function traced
+      INSIDE another's tracing or lowering is part of that record):
+      ``seq``, ``stage`` (``trace`` | ``lower`` | ``compile``), ``fn``
+      (the event's ``fun_name`` with ``jit(...)`` stripped, so a
+      program's three stages share a key),
+      ``start_unix`` / ``end_unix`` (the event's own, on
+      ``time.time()``), ``thread``, and on ``compile`` records ``cache``
+      (``hit`` | ``miss`` | ``off``: what the persistent cache said on
+      this thread since the previous backend stage) and ``retrieval_s``.
+    * **compiles**, one a backend stage (:meth:`report`): ``seq``, ``fn``,
+      ``shape``, ``at``, ``compile_seconds``, ``stage_s`` (the seconds
+      of the program's own three stages), ``cache``, ``retrieval_s``,
+      and ``flops`` / ``hbm_bytes`` where an :func:`instrument`-ed
+      function's ahead-of-time compile claimed the entry
+      (:meth:`note_compile`: then ``fn`` is the instrumented name and
+      ``program`` the one jax knows it by).
+    """
+
+    def __init__(self, registry=None, capacity: int = 4096):
         self._lock = threading.Lock()
         self.registry = None
-        self.compiles: List[Dict[str, Any]] = []
+        self._compiles = _Ring(capacity)
+        self._stages = _Ring(capacity)
+        self._by_fn: Dict[str, int] = {}  # compiles ever, by fn
+        # per thread: the cache's verdict awaiting its backend stage,
+        # and the entries an instrumented compile holds back from export
+        self._tls = threading.local()
         self.enabled = os.environ.get("CI_TPU_NO_XLA_ACCOUNTING", "") != "1"
         if registry is not None:
             self.bind_registry(registry)
 
+    # -- the listener ----------------------------------------------------
+
+    def listen(self) -> bool:
+        """Feed this accountant from ``jax.monitoring`` (idempotent; the
+        process's listeners are registered on the first call). False,
+        and nothing registered, where accounting is disabled or jax is
+        not installed: the module stays importable without it."""
+        global _listening, _listeners_registered
+        if not self.enabled:
+            return False
+        if any(ref() is self for ref in _listening):
+            return True
+        try:
+            from jax import monitoring
+
+            with _listen_lock:
+                if not _listeners_registered:
+                    monitoring.register_event_time_span_listener(
+                        _forward("_on_stage"))
+                    monitoring.register_event_listener(
+                        _forward("_on_cache_event"))
+                    monitoring.register_event_duration_secs_listener(
+                        _forward("_on_duration"))
+                    _listeners_registered = True
+                _listening = tuple(
+                    ref for ref in _listening if ref() is not None
+                ) + (weakref.ref(self),)
+            return True
+        except Exception:
+            log.debug("compile listener not registered (ignored)",
+                      exc_info=True)
+            return False
+
+    def _on_cache_event(self, event: str, **kw) -> None:
+        verdict = _CACHE_EVENTS.get(event)
+        if verdict is not None:
+            self._tls.cache = verdict
+
+    def _on_duration(self, event: str, duration: float, **kw) -> None:
+        if event == _RETRIEVAL_EVENT:
+            self._tls.retrieval_s = float(duration)
+
+    def _on_stage(self, event: str, start: float, end: float,
+                  fun_name: str = "", **kw) -> None:
+        stage = _STAGE_EVENTS.get(event)
+        if stage is None:
+            return
+        fn = str(fun_name)
+        if fn.startswith("jit(") and fn.endswith(")"):
+            fn = fn[4:-1]
+        thread = threading.get_ident()
+        rec = {"stage": stage, "fn": fn, "start_unix": float(start),
+               "end_unix": float(end), "thread": thread}
+        if stage == "compile":
+            tls = self._tls
+            rec["cache"] = getattr(tls, "cache", None) or "off"
+            rec["retrieval_s"] = getattr(tls, "retrieval_s", None) or 0.0
+            tls.cache = tls.retrieval_s = None
+        c = None
+        with self._lock:
+            items = self._stages.items
+            # a record stands for the traces made inside its interval:
+            # every jnp function a model calls is a jitted function
+            # traced inside its tracing, and lowering a jax.random call
+            # traces threefry's adds and xors by the thousand. A union
+            # counts them once anyway, and the ring has no room for them
+            while items and items[-1]["stage"] == "trace" \
+                    and items[-1]["thread"] == thread \
+                    and items[-1]["start_unix"] >= rec["start_unix"]:
+                items.pop()
+            if stage == "compile":
+                # the program's own trace and lowering: this thread's
+                # newest of each since it last reached its backend stage
+                own = {"compile": rec["end_unix"] - rec["start_unix"]}
+                for r in reversed(items):
+                    if r["thread"] != thread or r["fn"] != fn:
+                        continue
+                    if r["stage"] == "compile" or len(own) == 3:
+                        break
+                    own.setdefault(r["stage"],
+                                   r["end_unix"] - r["start_unix"])
+                stage_s = {s: round(own.get(s, 0.0), 6) for s in _STAGES}
+                c = {"fn": fn, "shape": "", "at": rec["end_unix"],
+                     "compile_seconds": round(sum(stage_s.values()), 6),
+                     "flops": 0.0, "hbm_bytes": 0, "stage_s": stage_s,
+                     "cache": rec["cache"],
+                     "retrieval_s": rec["retrieval_s"]}
+                self._compiles.append(c)
+                self._by_fn[fn] = self._by_fn.get(fn, 0) + 1
+            self._stages.append(rec)
+        if c is None:
+            return
+        held = getattr(self._tls, "held", None)
+        if held is not None:
+            held.append(c)
+        else:
+            self._export(c)
+
+    # -- the stage records' read side -----------------------------------
+
+    def stages_mark(self) -> int:
+        """How many stage records there have ever been: hand it to
+        :meth:`stage_records` or :meth:`compile_attrs` later."""
+        with self._lock:
+            return self._stages.seen
+
+    def stage_records(self, since: int = 0) -> List[Dict[str, Any]]:
+        """The retained stage records appended after mark ``since``,
+        oldest first."""
+        with self._lock:
+            return self._stages.since(since)
+
+    def compile_attrs(self, since: int) -> Dict[str, float]:
+        """``{"compile_s": seconds}`` where THIS thread traced, lowered
+        or compiled anything after mark ``since`` (the union of the
+        records' intervals), else ``{}``: what a span around a jitted
+        call says of the compile it paid."""
+        thread = threading.get_ident()
+        spans = [(r["start_unix"], r["end_unix"])
+                 for r in self.stage_records(since) if r["thread"] == thread]
+        return {"compile_s": round(union_seconds(spans), 6)} if spans else {}
+
+    # -- the registry ---------------------------------------------------
+
     def bind_registry(self, registry) -> None:
-        """Attach a ``utils.metrics.Registry`` (idempotent); re-plays
-        already-recorded compiles into it so late binding (a metrics
-        server started after warmup) still sees the full ledger."""
+        """Attach a ``utils.metrics.Registry`` (idempotent) and start
+        listening; re-plays already-recorded compiles into it so late
+        binding (a metrics server started after warmup) still sees the
+        full ledger."""
         if registry is None or self.registry is registry:
             return
+        self.listen()
         try:
             registry.gauge("compile_seconds",
                            "XLA compile wall time per compiled shape")
@@ -572,10 +803,11 @@ class XLAAccountant:
                            "memory_analysis HBM footprint (args+outputs+"
                            "temps-aliased) per compiled shape")
             registry.counter("compiles_total", "XLA compiles by function")
+            registry.counter("compile_cache_misses_total",
+                             "compiles the persistent cache did not "
+                             "hold, by function")
             self.registry = registry
-            with self._lock:
-                replay = list(self.compiles)
-            for c in replay:
+            for c in self.report():
                 self._export(c)
         except Exception:
             log.debug("accountant bind_registry failed (ignored)",
@@ -591,23 +823,67 @@ class XLAAccountant:
             reg.set("compiled_flops", c["flops"], labels=labels)
             reg.set("compiled_hbm_bytes", c["hbm_bytes"], labels=labels)
             reg.inc("compiles_total", labels={"fn": c["fn"]})
+            if c.get("cache") == "miss":
+                reg.inc("compile_cache_misses_total",
+                        labels={"fn": c["fn"]})
         except Exception:
             log.debug("accountant export failed (ignored)", exc_info=True)
 
+    # -- the compiles ---------------------------------------------------
+
+    @contextlib.contextmanager
+    def _claiming(self):
+        """Around an instrumented function's ahead-of-time compile: the
+        entries the listener makes on this thread are held back from the
+        registry and yielded, so that :meth:`note_compile` can claim the
+        program's own (the last: ``compile()`` is the scope's last step)
+        before it is exported under the instrumented name."""
+        held = self._tls.held = []
+        try:
+            yield held
+        finally:
+            self._tls.held = None
+            for c in held:
+                self._export(c)
+
     def note_compile(self, fn_name: str, shape: str, seconds: float,
-                     flops: float, hbm_bytes: int) -> None:
-        c = {"fn": fn_name, "shape": shape, "at": time.time(),
-             "compile_seconds": round(float(seconds), 6),
-             "flops": float(flops), "hbm_bytes": int(hbm_bytes)}
+                     flops: float, hbm_bytes: int,
+                     entry: Optional[Dict[str, Any]] = None) -> None:
+        """One instrumented compile. ``entry``, where the listener made
+        one for the same compile, gains the instrumented name, the shape
+        label, flops and HBM (it is exported by whoever held it back);
+        without one (no listener) the compile is appended and exported
+        here, as it always was."""
+        mine = {"fn": fn_name, "shape": shape,
+                "compile_seconds": round(float(seconds), 6),
+                "flops": float(flops), "hbm_bytes": int(hbm_bytes)}
         with self._lock:
-            self.compiles.append(c)
-        self._export(c)
+            if entry is not None:
+                self._by_fn[entry["fn"]] -= 1
+                entry.update(mine, program=entry["fn"])
+            else:
+                self._compiles.append(dict(mine, at=time.time()))
+            self._by_fn[fn_name] = self._by_fn.get(fn_name, 0) + 1
+        if entry is None:
+            self._export(mine)
         log.info("XLA compile %s[%s]: %.3fs, %.3g flops, %d HBM bytes",
                  fn_name, shape, seconds, flops, hbm_bytes)
 
-    def report(self) -> List[Dict[str, Any]]:
+    def compiles_mark(self) -> int:
+        """How many compiles there have ever been: hand it to
+        :meth:`report` later for what came after."""
         with self._lock:
-            return list(self.compiles)
+            return self._compiles.seen
+
+    def report(self, since: int = 0) -> List[Dict[str, Any]]:
+        """The retained compiles (after mark ``since``), oldest first."""
+        with self._lock:
+            return [dict(c) for c in self._compiles.since(since)]
+
+    def count(self, fn: str) -> int:
+        """Compiles ever recorded under ``fn``."""
+        with self._lock:
+            return self._by_fn.get(fn, 0)
 
     def wrap(self, jitted, name: str) -> "InstrumentedJit":
         return InstrumentedJit(jitted, name, self)
@@ -661,12 +937,7 @@ class InstrumentedJit:
                         canon = _args_sig(args, _canon_leaf_sig)
                         compiled = self._canon.get(canon)
                         if compiled is None:
-                            t0 = time.perf_counter()
-                            compiled = self._jitted.lower(*args).compile()
-                            dt = time.perf_counter() - t0
-                            self._acct.note_compile(
-                                self._name, _shape_label(args, canon), dt,
-                                _flops_of(compiled), _hbm_of(compiled))
+                            compiled = self._compile(args, canon)
                             self._canon[canon] = compiled
                         self._cache[sig] = compiled
                     except Exception:
@@ -677,6 +948,24 @@ class InstrumentedJit:
                         self._fallback = True
                         return self._jitted(*args)
         return compiled(*args)
+
+    def _compile(self, args, canon):
+        """Lower and compile ahead of time: ONE ledger entry, the
+        listener's where there is one, under this wrapper's name; and
+        the span that paid says so (``compile_s`` on ``train.dispatch``,
+        ``slots.device_steps``, ...: the compile path only, nothing on a
+        warmed call)."""
+        acct = self._acct
+        mark = acct.stages_mark()
+        t0 = time.perf_counter()
+        with acct._claiming() as held:
+            compiled = self._jitted.lower(*args).compile()
+            acct.note_compile(
+                self._name, _shape_label(args, canon),
+                time.perf_counter() - t0, _flops_of(compiled),
+                _hbm_of(compiled), entry=held[-1] if held else None)
+        tracing.set_attrs(**acct.compile_attrs(mark))
+        return compiled
 
     def _cache_size(self) -> int:
         """Compiled-PROGRAM count (canonical layouts), mirroring jit's

@@ -527,6 +527,18 @@ def current_context() -> Optional[SpanContext]:
         return None
 
 
+def set_attrs(**attrs) -> None:
+    """Attributes onto the innermost open span on THIS thread; nothing
+    without one. For what a deep module learns only while the span runs
+    (the compile a dispatch paid, utils/flight_recorder.py)."""
+    try:
+        s = _stack()
+        if s and attrs:
+            s[-1].set(**attrs)
+    except Exception:
+        pass
+
+
 def span(name: str, parent: Optional[SpanContext] = None, **attrs):
     """Ambient span: attaches to the explicit parent's tracer, else the
     thread's current trace. No-op (free) when neither exists — deep
