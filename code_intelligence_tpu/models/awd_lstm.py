@@ -416,9 +416,15 @@ class AWDLSTMEncoder(nn.Module):
 class AWDLSTMLM(nn.Module):
     """Encoder + (tied) decoder producing next-token logits.
 
-    Returns ``(logits, raw_output, dropped_output, new_states)`` — the raw
-    and dropped activations feed fastai's AR/TAR activation regularizers
-    (``language_model_learner`` defaults alpha=2, beta=1).
+    ``__call__`` returns ``(logits, raw_output, dropped_output,
+    new_states)`` — the raw and dropped activations feed fastai's AR/TAR
+    activation regularizers (``language_model_learner`` defaults alpha=2,
+    beta=1). Its logits are for every caller but the trainer
+    (`training/convert_fastai.py`'s parity check, a notebook): since
+    PR 50 `training/loop.py` asks ``features`` for the encoder's outputs
+    and the decoder's leaves and takes the product inside
+    `ops/lm_loss.py::decoder_cross_entropy`, with the cross-entropy, so
+    that a train step holds no float32 array of the logits' shape.
     """
 
     config: AWDLSTMConfig
@@ -438,12 +444,17 @@ class AWDLSTMLM(nn.Module):
                 "decoder_b", nn.initializers.zeros, (self.config.vocab_size,)
             )
 
-    def __call__(
+    def features(
         self,
         tokens: jnp.ndarray,
         states: Tuple[LSTMState, ...],
         deterministic: bool = True,
     ):
+        """``(raw_output, dropped_output, new_states, dec_w, dec_b)``:
+        everything of ``__call__`` but the decoder's product. ``dec_w (V,
+        E)`` and ``dec_b (V,)`` (``None`` without ``out_bias``) are the
+        decoder's leaves at the compute dtype, the tied embedding where
+        ``tie_weights``."""
         cfg = self.config
         raw, dropped, new_states = self.encoder(tokens, states, deterministic)
         if cfg.tie_weights:
@@ -451,7 +462,20 @@ class AWDLSTMLM(nn.Module):
         else:
             dec_w = self.decoder_w
         with jax.named_scope("decoder"):
-            logits = jnp.einsum("bte,ve->btv", dropped, dec_w.astype(cfg.dtype))
-            if cfg.out_bias:
-                logits = logits + self.decoder_b.astype(cfg.dtype)
+            dec_w = dec_w.astype(cfg.dtype)
+            dec_b = self.decoder_b.astype(cfg.dtype) if cfg.out_bias else None
+        return raw, dropped, new_states, dec_w, dec_b
+
+    def __call__(
+        self,
+        tokens: jnp.ndarray,
+        states: Tuple[LSTMState, ...],
+        deterministic: bool = True,
+    ):
+        raw, dropped, new_states, dec_w, dec_b = self.features(
+            tokens, states, deterministic)
+        with jax.named_scope("decoder"):
+            logits = jnp.einsum("bte,ve->btv", dropped, dec_w)
+            if dec_b is not None:
+                logits = logits + dec_b
         return logits, raw, dropped, new_states

@@ -8,7 +8,15 @@ jit-compiled train step over a ``("data", "model")`` mesh:
   the carry never leaves device HBM between steps (SURVEY.md §7
   "stateful truncated BPTT under pjit");
 * loss = cross-entropy + fastai's AR/TAR activation regularizers
-  (``language_model_learner`` defaults alpha=2, beta=1);
+  (``language_model_learner`` defaults alpha=2, beta=1). The decoder's
+  60,000-way product lives in `ops/lm_loss.py::decoder_cross_entropy`
+  since PR 50, with the cross-entropy and the accuracy, as one op with a
+  backward of its own: the step asks the model for its ``features`` (the
+  encoder's outputs and the decoder's leaves) and never holds a float32
+  array of the logits' shape. Which core runs the op (Pallas kernels on
+  one TPU chip in bfloat16, the einsum under ``optax`` everywhere else)
+  is the op's own choice; ``AWDLSTMLM.__call__``'s logits are for every
+  other caller;
 * one-cycle LR + momentum schedules (`train.py:109-111`), with a runtime
   ``lr_scale`` knob so ReduceLROnPlateau works without recompiling;
 * all dropout randomness is jit-internal (`jax.random.fold_in`).
@@ -33,6 +41,7 @@ from flax import struct
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from code_intelligence_tpu.models import AWDLSTMConfig, AWDLSTMLM, init_lstm_states
+from code_intelligence_tpu.ops.lm_loss import decoder_cross_entropy, loss_is_kernel
 from code_intelligence_tpu.ops.pallas_lstm import fits_resident
 from code_intelligence_tpu.parallel import (
     batch_sharding,
@@ -148,6 +157,14 @@ class LMTrainer:
         # fits_resident and the mesh: train_cell_is_resident
         step_config, self.resident_lstm_layers = train_cell_config(
             model_config, self.mesh.size)
+        # likewise the decoder's product and the cross-entropy: Pallas
+        # kernels or the einsum under optax is the op's own choice
+        # (ops/lm_loss.py::loss_is_kernel); 1 where a step of this
+        # trainer's shapes runs the kernels, for the spans to say
+        self.loss_kernel = int(loss_is_kernel(
+            jax.default_backend(), model_config.dtype,
+            train_config.batch_size * train_config.bptt,
+            model_config.emb_sz, model_config.vocab_size, self.mesh.size))
         # seq_axis: the model's QRNN layers time-shard their recurrence over
         # this mesh (parallel/seq_parallel.py); without it mesh stays out of
         # the module so jit caching keys only on config
@@ -238,27 +255,35 @@ class LMTrainer:
     # Compiled steps
     # ------------------------------------------------------------------
 
-    def _loss(self, params, x, y, lstm_states, dropout_rng):
-        logits, raw, dropped, new_states = self.model.apply(
-            {"params": params},
-            x,
-            lstm_states,
-            deterministic=False,
-            rngs={"dropout": dropout_rng},
-        )
-        # named like the model's own parts (models/awd_lstm.py), so a
+    def _decoded(self, params, x, y, lstm_states, **apply_kwargs):
+        """``(ce, accuracy, raw, dropped, new_states)`` of one window:
+        the model up to the decoder's leaves (``AWDLSTMLM.features``),
+        then the decoder's product, the cross-entropy and the accuracy
+        as `ops/lm_loss.py`'s one op. The train and the validation step
+        share it."""
+        raw, dropped, new_states, dec_w, dec_b = self.model.apply(
+            {"params": params}, x, lstm_states, method="features",
+            **apply_kwargs)
+        # named like the model's own parts (models/awd_lstm.py; the op
+        # names its own: the product `decoder`, the rest `loss`), so a
         # capture shows the step as embedding / lstm_i / decoder / loss /
         # optimizer instead of fusion numbers
+        ce, hit = decoder_cross_entropy(dropped, dec_w, dec_b, y,
+                                        devices=self.mesh.size)
         with jax.named_scope("loss"):
-            ce = optax.softmax_cross_entropy_with_integer_labels(
-                logits.astype(jnp.float32), y
-            ).mean()
+            ce, acc = ce.mean(), jnp.mean(hit.astype(jnp.float32))
+        return ce, acc, raw, dropped, new_states
+
+    def _loss(self, params, x, y, lstm_states, dropout_rng):
+        ce, acc, raw, dropped, new_states = self._decoded(
+            params, x, y, lstm_states, deterministic=False,
+            rngs={"dropout": dropout_rng})
+        with jax.named_scope("loss"):
             # fastai RNNRegularizer (alpha=AR on dropped, beta=TAR on raw).
             ar = self.tcfg.alpha * jnp.mean(jnp.square(dropped.astype(jnp.float32)))
             tar = self.tcfg.beta * jnp.mean(
                 jnp.square((raw[:, 1:] - raw[:, :-1]).astype(jnp.float32))
             )
-            acc = jnp.mean((jnp.argmax(logits, -1) == y).astype(jnp.float32))
         return ce + ar + tar, (new_states, ce, acc)
 
     def _pin_carry(self, lstm_states):
@@ -355,13 +380,8 @@ class LMTrainer:
 
     def _eval_step_body(self):
         def eval_step(params, lstm_states, x, y):
-            logits, _, _, new_states = self.model.apply(
-                {"params": params}, x, lstm_states, deterministic=True
-            )
-            ce = optax.softmax_cross_entropy_with_integer_labels(
-                logits.astype(jnp.float32), y
-            ).mean()
-            acc = jnp.mean((jnp.argmax(logits, -1) == y).astype(jnp.float32))
+            ce, acc, _, _, new_states = self._decoded(
+                params, x, y, lstm_states, deterministic=True)
             return ce, acc, new_states
 
         return eval_step
@@ -544,9 +564,10 @@ class LMTrainer:
         # never pays more than a few dict ops per DISPATCH (k steps), and
         # never raises.
         tracer = tracing.get_tracer()
-        resident = self.resident_lstm_layers
+        resident, loss_kernel = self.resident_lstm_layers, self.loss_kernel
         fit_span = tracer.start_span("train.fit", epochs=epochs,
-                                     resident_lstm_layers=resident)
+                                     resident_lstm_layers=resident,
+                                     loss_kernel=loss_kernel)
         fit_id = fit_span.trace_id
         ep_span = None
         with self.mesh:
@@ -591,7 +612,8 @@ class LMTrainer:
                         timer.start()
                         with tracer.span("train.step", fit_id=fit_id,
                                          epoch=_epoch, compile=not compiled,
-                                         resident_lstm_layers=resident):
+                                         resident_lstm_layers=resident,
+                                         loss_kernel=loss_kernel):
                             state, metrics = self.train_step(state, x, y)
                         dt = timer.stop()
                         if not compiled:
@@ -620,7 +642,8 @@ class LMTrainer:
                         with tracer.span("train.dispatch", fit_id=fit_id,
                                          epoch=_epoch, windows=n,
                                          compile=not compiled,
-                                         resident_lstm_layers=resident), \
+                                         resident_lstm_layers=resident,
+                                         loss_kernel=loss_kernel), \
                                 profiling.annotate("train.dispatch"):
                             state, ms = self.train_steps(state, xs, ys)
                             # ONE transfer for the whole chunk — per-element

@@ -3,10 +3,14 @@ repo runs: the LSTM's forward, backward and a layer's gradient
 (`tests/pallas_tpu_compile.py` has the how and the why).
 """
 
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 
+from code_intelligence_tpu.ops import lm_loss
 from code_intelligence_tpu.ops.pallas_lstm import (
     fused_lstm_backward,
     fused_lstm_forward,
@@ -69,3 +73,32 @@ def test_a_layers_gradient_compiles_outside_the_train_step(
     text = jax.jit(jax.grad(loss, argnums=(0, 3, 4, 5))).lower(
         *args).compile().as_text()
     assert text.count("tpu_custom_call") >= 2  # forward and adjoint
+
+
+# the decoder's product and the cross-entropy, forward and backward
+# (`ops/lm_loss.py`): the flagship's 104 x 67 rows, width and vocabulary
+@pytest.mark.parametrize("bias", [True, False], ids=["bias", "no_bias"])
+def test_the_decoders_loss_compiles_at_the_flagships_shapes(
+        one_chip, monkeypatch, bias):
+    """`jax.value_and_grad` of the kernel core at the tile the rule picks:
+    both kernels are Mosaic's, and no float32 array of the logits' shape
+    is left. The kernels ask the backend whether to interpret, so the
+    test answers for it."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n, e, v = 104 * 67, 800, 60000
+    assert lm_loss.loss_is_kernel("tpu", jnp.bfloat16, n, e, v, 1)
+
+    def loss(h, w, b, y):
+        ce, hit = lm_loss.decoder_cross_entropy(h, w, b if bias else None, y)
+        return ce.mean(), hit
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((104, 67, e), jnp.bfloat16), ((v, e), jnp.bfloat16),
+        ((v,), jnp.bfloat16), ((104, 67), jnp.int32))]
+    text = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 2) if bias else (0, 1), has_aux=True)).lower(
+            *args).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2  # forward and backward
+    sizes = [math.prod(map(int, dims.split(",")))
+             for dims in re.findall(r"f32\[([\d,]+)\]", text)]
+    assert sizes and max(sizes) < n * v // 8  # (60000, 800) is the largest
