@@ -18,6 +18,7 @@ from code_intelligence_tpu.models.deepseek_v3 import (
     DeepseekV3Config,
     DeepseekV3Encoder,
 )
+from code_intelligence_tpu.models.evabyte import EvaByteConfig, EvaByteEncoder
 from code_intelligence_tpu.models.granite_hybrid import (
     GraniteHybridConfig,
     GraniteHybridEncoder,
@@ -39,6 +40,7 @@ __all__ = ["AfmoeConfig", "AfmoeEncoder", "AWDLSTMConfig", "AWDLSTMEncoder", "AW
            "BailingHybridConfig", "BailingHybridEncoder",
            "ChunkEncoder", "build_encoder", "make_config",
            "DeepseekV3Config", "DeepseekV3Encoder",
+           "EvaByteConfig", "EvaByteEncoder",
            "GraniteHybridConfig", "GraniteHybridEncoder",
            "LongcatFlashConfig", "LongcatFlashEncoder",
            "Qwen3NextConfig", "Qwen3NextEncoder",

@@ -36,7 +36,18 @@ with ``--scheduler groups``):
     allocated)`` of each. 0 where an encoder has no state of that kind
     (both, where the whole state is of fixed size). One row may hold
     both, layer by layer; the engine counts each and looks inside
-    neither.
+    neither. For a state that is neither, both answers are UPPER
+    BOUNDS: an encoder whose block of a window's slots EMPTIES at every
+    multiple of the window, beside summaries that grow at a fraction of
+    the document's rate and become visible a block at a time
+    (`models/evabyte.py`), answers the second with the block's slots
+    and the first with the positions the summaries are allocated FOR
+    (their slots times the chunk they summarise), so that the engine's
+    allocation, its in-flight bound and ``state_bytes_per_row`` hold;
+    its cores meet less than ``min(positions reached, allocated)`` of
+    either (a query a quarter into its block meets a quarter of the
+    block, and one summary for every chunk before it), and what they met
+    is what the encoder counts on the device (``state_counters``).
 ``state_counters(states)`` and ``counter_attrs(counted)``
     counts the encoder keeps ON THE DEVICE in its carried state (rows
     routed to experts): the first picks them out of a group's last
@@ -61,6 +72,7 @@ from code_intelligence_tpu.models.bailing_hybrid import (
     BailingHybridConfig, BailingHybridEncoder)
 from code_intelligence_tpu.models.deepseek_v3 import (
     DeepseekV3Config, DeepseekV3Encoder)
+from code_intelligence_tpu.models.evabyte import EvaByteConfig, EvaByteEncoder
 from code_intelligence_tpu.models.granite_hybrid import (
     GraniteHybridConfig, GraniteHybridEncoder)
 from code_intelligence_tpu.models.longcat_flash import (
@@ -134,6 +146,9 @@ ENCODERS = {
     Qwen3NextConfig.architecture: (
         Qwen3NextConfig, Qwen3NextConfig.from_dict,
         _in_weights_dtype(Qwen3NextEncoder)),
+    EvaByteConfig.architecture: (
+        EvaByteConfig, EvaByteConfig.from_dict,
+        _in_weights_dtype(EvaByteEncoder)),
 }
 
 

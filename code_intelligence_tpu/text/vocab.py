@@ -93,4 +93,67 @@ class Vocab:
 
     @classmethod
     def load(cls, path: PathLike) -> "Vocab":
-        return cls(json.loads(Path(path).read_text())["itos"])
+        """The vocabulary ``save`` wrote: a table of tokens, or the byte
+        vocabulary where the file says ``bytes``."""
+        data = json.loads(Path(path).read_text())
+        if "bytes" in data:
+            return ByteVocab(**data["bytes"])
+        return cls(data["itos"])
+
+
+class ByteVocab:
+    """A vocabulary that is not a table: a byte model reads the UTF-8
+    bytes of the text, so there is nothing to look up and no file of
+    merges. Ids ``0 .. n_special - 1`` are the model's specials (``<pad>``
+    0, ``<bos>`` 1; a byte model spells every other mark of the product
+    out), byte ``b`` is id ``b + n_special``.
+
+    ``numericalize`` takes what ``Vocab``'s takes, the program's own
+    token sequence (pre-rules, word split, post-rules), so the product's
+    field and markdown marks stay what the encoder reads: ``xxbos``
+    becomes ``<bos>``, every other token is spelled in UTF-8 with one
+    0x20 between neighbours. A document is then about five positions a
+    word."""
+
+    pad_id, bos_id = 0, 1
+
+    def __init__(self, n_special: int = 64):
+        if n_special < 2:
+            raise ValueError("<pad> and <bos> need two special ids")
+        self.n_special = int(n_special)
+
+    def __len__(self) -> int:
+        return self.n_special + 256
+
+    def numericalize(self, tokens: Sequence[str]) -> np.ndarray:
+        out, run = [], []
+
+        def spell():
+            if run:
+                text = np.frombuffer(" ".join(run).encode(
+                    "utf-8", "replace"), np.uint8)
+                out.append(text.astype(np.int32) + self.n_special)
+                run.clear()
+
+        for tok in tokens:
+            if tok == R.TK_BOS:
+                spell()
+                out.append(np.asarray([self.bos_id], np.int32))
+            else:
+                run.append(tok)
+        spell()
+        return np.concatenate(out) if out else np.zeros((0,), np.int32)
+
+    def content_hash(self) -> str:
+        """As ``Vocab.content_hash``: differs from every table's (the
+        first entry spells no token a table can hold) and between byte
+        vocabularies of different offsets."""
+        h = hashlib.blake2b(digest_size=8)
+        h.update(b"\x00bytes\x00%d" % self.n_special)
+        return h.hexdigest()
+
+    def save(self, path: PathLike) -> None:
+        Path(path).write_text(json.dumps(
+            {"bytes": {"n_special": self.n_special}}))
+
+    load = Vocab.load
