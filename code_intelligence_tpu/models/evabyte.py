@@ -175,7 +175,8 @@ class EvaByteEncoder(WindowedCaches):
     # a group's programs in int32, which a group of 16 rows of 32,768
     # positions fills to half: 1.04e9 pairs), the
     # summaries written a head a layer, and the layers whose core ran on
-    # a Pallas kernel (none: ``ops/eva.py`` has the XLA core alone)
+    # the Pallas kernel (``eva.core_is_kernel``'s answer for the
+    # program's shapes: every layer or none; 0 on the CPU)
     counts = Counts(totals=("eva_singleton_pairs", "eva_summary_pairs",
                             "eva_summaries_written"),
                     sets=("eva_kernel_layers",))
@@ -282,7 +283,10 @@ class EvaByteEncoder(WindowedCaches):
                 eva_summary_pairs=lanes @ met[1],
                 eva_summaries_written=chunks.any(axis=-1).sum(
                     dtype=jnp.int32),
-                eva_kernel_layers=0))  # the joint core is XLA's
+                eva_kernel_layers=cfg.num_hidden_layers * eva.core_is_kernel(
+                    jax.default_backend(), dtype, T,
+                    states["k"][0].shape[2], states["k_sum"][0].shape[2],
+                    cfg.head_dim)))
         return out, new_states
 
     # -- layers ----------------------------------------------------------

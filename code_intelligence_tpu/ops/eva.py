@@ -46,21 +46,109 @@ slots, then the summaries of the blocks passed; nothing allocated and
 not yet visible is scored. Scores and softmax are float32; the two
 products take ``mxu_dtype`` inputs (``mixedp_attn``).
 
-**One core so far**, XLA's (``lax.fori_loop`` over key blocks, its
-float32 score tiles through HBM between fusions): a Pallas core, picked
-by a ``core_is_kernel`` of observables as ``ops/attention.py`` picks
-its own, is a later change (the encoder's ``eva_kernel_layers`` is where
-it will show).
+**Two cores, one arithmetic, chosen here** (``core_is_kernel``, from
+what the program can observe; no caller selects one), as
+``ops/attention.py`` chooses its own:
+
+* the Pallas kernel (``_kernel_core``) on the TPU for bfloat16 operands,
+  heads of 128 and a block cache of more than one chunk program: a grid
+  over rows, groups of heads and, innermost, key blocks of BOTH kinds,
+  the block cache's (a chunk program's worth of slots each) first and
+  the summary cache's after, each cache under its own ``BlockSpec``. A
+  head's scores (keys down, queries across: the softmax's reductions are
+  elementwise over vector registers), running maximum, sum and weighted
+  values stay in VMEM from the first key block to the last and only the
+  normalised output returns to HBM. ``q``, the chunk's ``k`` and ``v``
+  and the output are read and written in the projections' own layout
+  ``(b, T, H * d)`` (a head is a 128-lane column group of a block), and
+  the kernel writes the chunk into its block of the cache itself: XLA
+  turns nothing head-major around the call. What is visible comes in by
+  scalar prefetch from ``_reach`` and from nowhere else; the masks are
+  made in the kernel, a block past what the chunk can see is neither
+  fetched nor computed, and a block every query sees whole skips the
+  mask;
+* the XLA core (``_xla_core``) everywhere else: the CPU, float32
+  operands (the parity tests), a document one program holds (one key
+  block: plain softmax), a shape no tile divides. The chunk written by a
+  ``dynamic_update_slice``, then the same blocks under two
+  ``lax.fori_loop`` s, its float32 score tiles going through HBM between
+  fusions.
+
+Both count the keys their masks admitted (``met``).
 """
 
 from __future__ import annotations
 
-from typing import Tuple
+import functools
+from typing import Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from code_intelligence_tpu.ops.attention import _MASKED
+from code_intelligence_tpu.ops.attention import _KERNEL_VMEM_LIMIT, _MASKED
+
+# the kernel's tiles: heads a grid step and summaries a key block (a key
+# block of the block cache is a chunk program's worth of slots: the
+# chunk's own block is the one the kernel writes). Two sweeps on the chip
+# (v5e, 8 rows x 512 queries, 32 heads of 128, W 2048, S 2048; PERF.md
+# §6, PR 52). The kernel alone, ms a layer weighted over the cell's
+# positions (device time of the call in a capture), scores keys down:
+# heads 4 / 8 / 16 at key blocks of 512 | 512: 1.708 / 1.631 / 1.593;
+# summaries 512 / 1024 / 2048 a key block at 8 heads: 1.631 / 1.820 /
+# 1.996 (a larger tile buys nothing once the column arithmetic is gone,
+# and the last block's masked part grows); the block cache in key blocks
+# of 256 / 1024 / 2048: 1.778 / 1.961 / 2.354. Scores queries down (the
+# first build): 2.141 at 512 | 512, 1.785 at 1024 | 1024. In a two-layer
+# stage at the cell's widths, ms a (8, 512) program 17,408 positions in,
+# the XLA core 40.47: queries down 33.95 (512) / 32.98 (1024), keys down
+# 32.40, keys down with the chunk written by the kernel 28.69 / 28.78 /
+# 28.78 at summaries 512 / 1024 / 2048 a block, 28.70 at 16 heads, 28.94
+# at 4: what XLA turned head-major round the call was worth more than
+# any tile
+_TILE_HEADS = 8
+_TILE_SUMMARIES = 512
+
+
+def _kernel_tiles(T: int, S: int, H: int) -> Optional[Tuple[int, int]]:
+    """``(heads a step, summary_block)`` of the kernel for a chunk of
+    ``T`` queries against ``S`` summaries, ``H`` heads; ``None`` where no
+    aligned tile divides the shape. A function of the shapes alone: the
+    most heads up to ``_TILE_HEADS`` that divide ``H``; the most
+    summaries up to ``_TILE_SUMMARIES``, whole 128s of them (keys lie
+    down a score tile, 16 bfloat16 rows a register) or the whole (small)
+    cache."""
+    most = min(_TILE_SUMMARIES, S)
+    if most == S and S % 16 == 0:
+        summaries = S
+    else:
+        summaries = next((n for n in range(most - most % 128, 0, -128)
+                          if S % n == 0), None)
+    if T % 128 or summaries is None:
+        return None
+    return (next(n for n in range(min(_TILE_HEADS, H), 0, -1) if H % n == 0),
+            summaries)
+
+
+def core_is_kernel(backend: str, mxu_dtype, T: int, W: int, S: int,
+                   head_dim: int) -> bool:
+    """Pallas kernel or XLA core, for ONE call of ``eva_cached``: the
+    rule, from what the program can observe and nothing a user sets.
+
+    The kernel runs where it exists and pays: on the TPU (off it the
+    kernel is the interpreter, a test device); for bfloat16 operands
+    (float32 is the parity tests'); for a head size that fills the
+    lanes (a head is a 128-lane column group of a block of queries) and
+    a chunk of whole 128s of queries (they lie across a score tile's
+    lanes); where a tile divides the summaries; and for a block cache of
+    more than one chunk program (one is a document one program holds:
+    plain softmax, nothing to keep between key blocks and never a
+    summary)."""
+    return (backend == "tpu" and jnp.dtype(mxu_dtype) == jnp.bfloat16
+            and head_dim % 128 == 0 and W > T
+            and _kernel_tiles(T, S, 1) is not None)
 
 
 def chunk_summaries(
@@ -128,7 +216,8 @@ def eva_cached(
     a program's own summaries are not visible to it). ``met`` is
     ``(singletons, summaries)``, each ``(T,)`` int32: the keys of either
     kind the mask ADMITTED for each of the chunk's queries, counted from
-    the masks as they were applied."""
+    the masks as they were applied. ``key_block`` is the XLA core's; the
+    kernel's tiles follow the shapes."""
     b, T, H, d = q.shape
     W = k_block.shape[2]
     if window % chunk:
@@ -139,6 +228,11 @@ def eva_cached(
             f"a block cache of {W} slots does not hold whole chunk programs "
             f"of {T} inside a block of {window}: a program that straddled "
             "a block would see two sets of summaries")
+    S = k_sum.shape[2]
+    if core_is_kernel(jax.default_backend(), mxu_dtype, T, W, S, d):
+        return _kernel_core(q, k, v, k_block, v_block, k_sum, v_sum, pos,
+                            scale, window, chunk, mxu_dtype,
+                            _kernel_tiles(T, S, H))
     at = pos % W
     k_block = lax.dynamic_update_slice_in_dim(
         k_block, k.swapaxes(1, 2).astype(k_block.dtype), at, axis=2)
@@ -220,3 +314,226 @@ def _xla_core(q, k_block, v_block, k_sum, v_sum, pos, scale, window, chunk,
         0, (seen + sb - 1) // sb, passed_block, (softmax, none))
     out = acc / l[..., None]
     return out.swapaxes(1, 2), (singletons, summaries)
+
+
+def _kernel_core(q, k, v, k_block, v_block, k_sum, v_sum, pos, scale,
+                 window, chunk, mxu_dtype, tiles):
+    """``eva_cached``'s result from one ``pallas_call``, the write of the
+    chunk into the block cache included. What is visible comes from
+    ``_reach`` and from nowhere else: its three values go in beside
+    ``pos`` by scalar prefetch."""
+    T, W = q.shape[1], k_block.shape[2]
+    slots, stale, seen = _reach(pos, T, W, window, chunk)
+    reach = jnp.stack([jnp.asarray(x, jnp.int32).reshape(())
+                       for x in (pos, slots, stale, seen)])
+    return _kernel_call(reach, q, k, v, k_block, v_block, k_sum, v_sum,
+                        scale=scale, mxu_dtype=jnp.dtype(mxu_dtype),
+                        tiles=tiles,
+                        interpret=jax.default_backend() != "tpu")
+
+
+# jitted and inlined: an encoder's layers call it with one signature, so a
+# chunk program traces the kernel's body once and not once a layer (the
+# trace of the cell's 8-layer program 0.9 -> 0.3 s on a CPU host, the XLA
+# core's 0.4; set-up pays it 16 programs a run, warm or cold), and every
+# call site keeps its own named scope
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "scale", "mxu_dtype", "tiles", "interpret"))
+def _kernel_call(reach, q, k, v, k_block, v_block, k_sum, v_sum, *, scale,
+                 mxu_dtype, tiles, interpret):
+    """The grid is (rows, groups of heads, key blocks), the key blocks
+    innermost and in the XLA core's order, the block cache's ``W / T``
+    first (the chunk's own among them, read from what the step writes)
+    and the summary cache's after. A step folds one block of keys of
+    either kind into each of its heads' running maximum, sum and
+    accumulator. ``reach`` is ``(pos, slots, stale, summaries)``.
+
+    **A tile's scores lie keys down, queries across** (``(keys, T)``),
+    as ``ops/mla.py``'s do and for its reason: the softmax's maximum and
+    sum over the keys are then elementwise over vector registers, and a
+    query's maximum, sum and fade are lanes of one row, not a column of
+    one lane each (queries down, that column arithmetic was worth more
+    than the tile's: PERF.md §6, PR 52). So the accumulator is ``(d, T)``
+    and is turned once, when the last key block is done. Off the TPU the
+    kernel is interpreted."""
+    b, T, H, d = q.shape
+    W, S = k_block.shape[2], k_sum.shape[2]
+    G, sb = tiles
+    if H % G or W % T or S % sb:
+        raise ValueError(f"tiles {tiles} do not divide H={H}, W={W}, S={S}")
+    n_own, n_sum = W // T, S // sb
+
+    def own_live(reach_ref):
+        """Blocks of the block cache that hold a slot the chunk sees."""
+        return jnp.clip(lax.div(reach_ref[1] + T - 1, T), 1, n_own)
+
+    def sum_live(reach_ref):
+        """Blocks of the summary cache that hold a visible summary."""
+        return jnp.clip(lax.div(reach_ref[3] + sb - 1, sb), 0, n_sum)
+
+    def chunks_block(reach_ref):
+        """The key block of the block cache the chunk itself is."""
+        return lax.div(lax.rem(reach_ref[0], W), T)
+
+    def kernel(reach_ref, q_ref, kn_ref, vn_ref, ko_ref, vo_ref, ks_ref,
+               vs_ref, o_ref, met_ref, kc_ref, vc_ref, qh_ref, m_ref, l_ref,
+               acc_ref):
+        j = pl.program_id(2)
+        first = lax.rem(reach_ref[0], W)   # the chunk's first query's slot
+        stale = reach_ref[2] != 0
+        seen = reach_ref[3]
+
+        @pl.when(j == 0)
+        def _():
+            m_ref[...] = jnp.full_like(m_ref, _MASKED)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+            met_ref[...] = jnp.zeros_like(met_ref)
+            # a head's queries, and the chunk's keys and values as the
+            # cache holds them (its block of the cache, written here),
+            # as tiles of their own: the loop over heads indexes the
+            # leading axis
+            for h in range(G):
+                qh_ref[h] = q_ref[:, h * d:(h + 1) * d]
+                kc_ref[h] = kn_ref[:, h * d:(h + 1) * d]
+                vc_ref[h] = vn_ref[:, h * d:(h + 1) * d]
+
+        def absorb(keys_ref, vals_ref, ok):
+            """One block of keys of either kind into each head's running
+            softmax; ``ok`` the block's mask, ``None`` for a block every
+            query sees whole."""
+            def head(h, _):
+                s = lax.dot_general(
+                    keys_ref[h].astype(mxu_dtype), qh_ref[h],
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                if ok is not None:
+                    s = jnp.where(ok, s, _MASKED)
+                m = m_ref[h]
+                m_new = jnp.maximum(m, s.max(axis=0, keepdims=True))
+                p = jnp.exp(s - m_new)
+                fade = jnp.exp(m - m_new)
+                l_ref[h] = l_ref[h] * fade + p.sum(axis=0, keepdims=True)
+                acc_ref[h] = acc_ref[h] * fade + lax.dot_general(
+                    vals_ref[h].astype(mxu_dtype), p.astype(mxu_dtype),
+                    (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                m_ref[h] = m_new
+
+            lax.fori_loop(0, G, head, None)
+
+        def admitted(ok):
+            """The keys a mask admitted a query: its own sum."""
+            return ok.astype(jnp.int32).sum(axis=0, keepdims=True)
+
+        # the block cache, a chunk program's worth of slots a key block:
+        # the blocks before the chunk's own every query sees whole (and
+        # those after it where a stale slot is admitted); the chunk's own
+        # is read from what this step writes, under the mask
+        slot0 = j * T
+        run = j < own_live(reach_ref)
+        own = j == chunks_block(reach_ref)
+
+        @pl.when(run & own)
+        def _():
+            at = slot0 + lax.broadcasted_iota(jnp.int32, (T, 1), 0)
+            query = first + lax.broadcasted_iota(jnp.int32, (1, T), 1)
+            ok = (at <= query) | stale
+            absorb(kc_ref, vc_ref, ok)
+            met_ref[0] = met_ref[0] + admitted(ok)
+
+        whole = stale | (slot0 + T - 1 <= first)
+
+        @pl.when(run & jnp.logical_not(own) & whole)
+        def _():
+            absorb(ko_ref, vo_ref, None)
+            met_ref[0] = met_ref[0] + T
+
+        # the summary cache's blocks: those of the blocks passed
+        i = j - n_own
+        sum0 = i * sb
+
+        def passed_masked():
+            at = sum0 + lax.broadcasted_iota(jnp.int32, (sb, 1), 0)
+            ok = at < seen
+            absorb(ks_ref, vs_ref, ok)
+            met_ref[1] = met_ref[1] + admitted(ok)
+
+        def passed_whole():
+            absorb(ks_ref, vs_ref, None)
+            met_ref[1] = met_ref[1] + sb
+
+        run = (i >= 0) & (i < sum_live(reach_ref))
+        whole = sum0 + sb <= seen
+        pl.when(run & whole)(passed_whole)
+        pl.when(run & jnp.logical_not(whole))(passed_masked)
+
+        @pl.when(j == n_own + n_sum - 1)
+        def _():
+            for h in range(G):
+                o_ref[:, h * d:(h + 1) * d] = (acc_ref[h] / l_ref[h]).T
+
+    def q_map(r, g, j, reach_ref):
+        return (r, 0, g)
+
+    def own_map(r, g, j, reach_ref):
+        # past the last block the chunk sees: the same block again, which
+        # is not fetched again; never the chunk's own (stale in HBM)
+        at = jnp.minimum(j, own_live(reach_ref) - 1)
+        c = chunks_block(reach_ref)
+        return (r, g, jnp.where(at == c, jnp.maximum(c - 1, 0), at), 0)
+
+    def chunk_map(r, g, j, reach_ref):
+        return (r, g, chunks_block(reach_ref), 0)
+
+    def sum_map(r, g, j, reach_ref):
+        return (r, g, jnp.clip(j - n_own, 0,
+                               jnp.maximum(sum_live(reach_ref) - 1, 0)), 0)
+
+    def flat(x, dtype):
+        """The projections' own layout: a head a group of 128 columns."""
+        return x.astype(dtype).reshape(b, T, H * d)
+
+    out, met, k_block, v_block = pl.pallas_call(
+        kernel,
+        out_shape=(jax.ShapeDtypeStruct((b, T, H * d), jnp.float32),
+                   jax.ShapeDtypeStruct((b, H // G, 2, 1, T), jnp.int32),
+                   jax.ShapeDtypeStruct(k_block.shape, k_block.dtype),
+                   jax.ShapeDtypeStruct(v_block.shape, v_block.dtype)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, H // G, n_own + n_sum),
+            in_specs=[
+                pl.BlockSpec((None, T, G * d), q_map),
+                pl.BlockSpec((None, T, G * d), q_map),
+                pl.BlockSpec((None, T, G * d), q_map),
+                pl.BlockSpec((None, G, T, d), own_map),
+                pl.BlockSpec((None, G, T, d), own_map),
+                pl.BlockSpec((None, G, sb, d), sum_map),
+                pl.BlockSpec((None, G, sb, d), sum_map),
+            ],
+            out_specs=(
+                pl.BlockSpec((None, T, G * d), q_map),
+                pl.BlockSpec((None, None, 2, 1, T),
+                             lambda r, g, j, reach_ref: (r, g, 0, 0, 0)),
+                pl.BlockSpec((None, G, T, d), chunk_map),
+                pl.BlockSpec((None, G, T, d), chunk_map),
+            ),
+            scratch_shapes=[
+                pltpu.VMEM((G, T, d), mxu_dtype),
+                pltpu.VMEM((G, 1, T), jnp.float32),
+                pltpu.VMEM((G, 1, T), jnp.float32),
+                pltpu.VMEM((G, d, T), jnp.float32),
+            ]),
+        # the block caches are written where they lie
+        input_output_aliases={4: 2, 5: 3},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_KERNEL_VMEM_LIMIT),
+        interpret=interpret,
+        name="eva_core",
+    )(reach, flat(q, mxu_dtype), flat(k, k_block.dtype),
+      flat(v, v_block.dtype), k_block, v_block, k_sum, v_sum)
+    # every row and head admits the same keys: one of them is the count
+    return (out.reshape(b, T, H, d), k_block, v_block,
+            (met[0, 0, 0, 0], met[0, 0, 1, 0]))

@@ -237,6 +237,37 @@ def test_through_the_engine_with_narrowing_and_counts_on_the_span(
         < g["cache_steps_run"] * g["bucket"]
 
 
+def test_through_the_engine_on_the_pallas_core(monkeypatch, params):
+    """The same four documents through chunk programs of 16 (eight for
+    the longest, two key blocks of the 32-slot block cache and four of
+    the summaries) with every layer's joint core, and its write of the
+    chunk into the block cache, on the Pallas kernel,
+    interpreted: the rule answers as it would on the chip for what it is
+    shown here (a block cache of more than one program), the rows are the
+    reference's, the pairs the kernel's masks admitted are the mask's
+    arithmetic, and the span says both layers took the kernel."""
+    from code_intelligence_tpu.ops import eva
+
+    monkeypatch.setattr(eva, "core_is_kernel",
+                        lambda backend, dtype, T, W, S, d: W > T)
+    monkeypatch.setattr(eva, "_kernel_tiles", lambda *a: (2, 8))
+    engine = InferenceEngine(
+        params, config(chunk_positions=16), ByteVocab(), buckets=(8, 16),
+        batch_size=4)
+    rng = np.random.default_rng(7)
+    seqs = [rng.integers(64, 320, n).astype(np.int32)
+            for n in (120, 9, 40, 70)]
+    got, spans, a = _traced_finalize(engine, seqs)
+    np.testing.assert_allclose(got, reference_rows(params, seqs),
+                               rtol=1e-4, atol=5e-5)
+    every = [p for s in seqs for p in range(len(s))]
+    assert a["eva_kernel_layers"] == MODEL["num_hidden_layers"] == 2
+    assert a["eva_singleton_pairs"] == sum(p % W + 1 for p in every)
+    assert a["eva_summary_pairs"] == sum(p // W * (W // C) for p in every)
+    (group,) = [s for s in spans if s["name"] == "engine.group"]
+    assert (group["attrs"]["chunks"], group["attrs"]["bucket"]) == (8, 16)
+
+
 def test_short_documents_take_one_program_and_no_summary(params, engine):
     seqs = [np.arange(64, 64 + n, dtype=np.int32) for n in (5, 12, 16)]
     got, _, a = _traced_finalize(engine, seqs)

@@ -1,9 +1,8 @@
 """Mosaic takes `ops/attention.py::gqa_cached`'s kernel at Granite's and
 SmallThinker's shapes, and no Mosaic call appears where the rule says XLA
 (`tests/pallas_tpu_compile.py` has the how and the why; Trinity's shapes
-are in `tests/test_pallas_tpu_compile_gqa_trinity.py`); and the EVA
-stage's widest chunk program, which has no kernel of ours, fits the chip
-beside a second group's state.
+are in `tests/test_pallas_tpu_compile_gqa_trinity.py`; the EVA stage's
+in `tests/test_pallas_tpu_compile_eva.py`).
 """
 
 import pytest
@@ -52,48 +51,3 @@ def test_mosaic_takes_the_kernel_at_head_256_and_eight_heads_a_group(
         one_chip, monkeypatch, rows, S):
     text = _gqa_text(monkeypatch, one_chip, rows, 512, S, None, 16, 2, 256)
     assert "tpu_custom_call" in text
-
-
-# `evabyte_bulk_threads_32kb`: no kernel of ours yet, but the fullest
-# chip of the benchmark: the (8, 512) chunk program of the 8-layer stage
-# against a group's state at 32,768 positions fits the v5e beside a
-# second group's state (two are alive while the newer is enqueued)
-def test_the_eva_stages_widest_program_fits_beside_a_second_groups_state(
-        one_chip):
-    import json
-    from pathlib import Path
-
-    import jax
-    import jax.numpy as jnp
-
-    from benchmark.reference import evabyte as ref
-    from code_intelligence_tpu.models import build_encoder, make_config
-
-    model = json.loads((Path(__file__).resolve().parents[1] / "benchmark"
-                        / "configs/evabyte_6_5b_pp4_stage0.json").read_text())
-    enc = build_encoder(make_config(
-        "evabyte", model, kv_positions=model["serve"]["kv_positions"]))
-    rows = model["serve"]["batch_size"]
-
-    def on_chip(tree):
-        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=one_chip), tree)
-
-    params = on_chip(jax.eval_shape(lambda k: ref.init_params(
-        k, model, model["weights"], jnp.bfloat16), jax.random.PRNGKey(0)))
-    states = on_chip(jax.eval_shape(lambda: enc.init_states(rows, 32768)))
-    tokens = jax.ShapeDtypeStruct((rows, 512), jnp.int32, sharding=one_chip)
-    lengths = jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip)
-    compiled = jax.jit(
-        lambda p, t, s, n: enc.encode(p, t, s, lengths=n),
-        donate_argnums=(2,)).lower(params, tokens, states, lengths).compile()
-    assert "tpu_custom_call" not in compiled.as_text()
-    memory = compiled.memory_analysis()
-    state = rows * enc.state_bytes_per_row(32768)
-    assert state == 8 * 536870912
-    # weights and one group's state in, the state aliased out
-    assert memory.argument_size_in_bytes - state == pytest.approx(
-        2 * model["parameters"]["held"], rel=1e-3)
-    assert memory.alias_size_in_bytes >= state
-    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
-        + state < 0.85 * 16909336064
