@@ -19,6 +19,10 @@ from code_intelligence_tpu.models.deepseek_v3 import (
     DeepseekV3Encoder,
 )
 from code_intelligence_tpu.models.evabyte import EvaByteConfig, EvaByteEncoder
+from code_intelligence_tpu.models.glm_moe_dsa import (
+    GlmMoeDsaConfig,
+    GlmMoeDsaEncoder,
+)
 from code_intelligence_tpu.models.granite_hybrid import (
     GraniteHybridConfig,
     GraniteHybridEncoder,
@@ -41,6 +45,7 @@ __all__ = ["AfmoeConfig", "AfmoeEncoder", "AWDLSTMConfig", "AWDLSTMEncoder", "AW
            "ChunkEncoder", "build_encoder", "make_config",
            "DeepseekV3Config", "DeepseekV3Encoder",
            "EvaByteConfig", "EvaByteEncoder",
+           "GlmMoeDsaConfig", "GlmMoeDsaEncoder",
            "GraniteHybridConfig", "GraniteHybridEncoder",
            "LongcatFlashConfig", "LongcatFlashEncoder",
            "Qwen3NextConfig", "Qwen3NextEncoder",

@@ -127,7 +127,7 @@ def latent_block(p, h, cache, pos, dtype, *, heads: int, nope: int,
                  rope: int, v_dim: int, rank: int, eps: float, inv_freq,
                  rope_factor: float, scale: float, q_low_rank: bool = True,
                  head_gate: bool = False, q_scale: float = None,
-                 kv_scale: float = None):
+                 kv_scale: float = None, attend=None):
     """``MLA(RMSNorm(h))`` of `models/deepseek_v3.py`'s docstring over
     one chunk, through the latent ``cache`` at ``pos``: ``(out (b, T, E)
     float32, cache)``. One copy for every model with latent attention;
@@ -140,7 +140,12 @@ def latent_block(p, h, cache, pos, dtype, *, heads: int, nope: int,
     ``c_kv`` (the cache then holds the scaled ``c_kv``; ``k_pe`` is
     never scaled), none where they are ``None``. Named scopes ``q_proj``,
     ``kv_latent``, ``rope``, ``mla_core``, ``gate`` (where gated),
-    ``o_proj``."""
+    ``o_proj``. A model that SELECTS what a query attends hands in
+    ``attend(u, c_q, q_nope, q_pe, latent, cache) -> (out (b, T, heads,
+    v_dim), cache)`` in place of the core: it reads the normed ``u`` and
+    the normed ``c_q`` the queries are made of, and ``cache`` is then
+    whatever that model carries a layer (the scopes inside are its
+    own)."""
     b, T, _ = h.shape
     u = rms_norm(h, p["norm"], eps).astype(dtype)
     with jax.named_scope("q_proj"):
@@ -164,10 +169,13 @@ def latent_block(p, h, cache, pos, dtype, *, heads: int, nope: int,
         k_pe = mla.apply_rope(kv[..., rank:], positions, inv_freq,
                               rope_factor)
     latent = jnp.concatenate([c_kv, k_pe], axis=-1)
-    with jax.named_scope("mla_core"):
-        out, cache = mla.mla_cached(
-            q[..., :nope], q_pe, latent, cache, pos, p["kv_b"], scale,
-            v_dim, mxu_dtype=dtype)
+    if attend is not None:
+        out, cache = attend(u, c_q, q[..., :nope], q_pe, latent, cache)
+    else:
+        with jax.named_scope("mla_core"):
+            out, cache = mla.mla_cached(
+                q[..., :nope], q_pe, latent, cache, pos, p["kv_b"], scale,
+                v_dim, mxu_dtype=dtype)
     if head_gate:
         with jax.named_scope("gate"):
             out = out * jax.nn.sigmoid(matmul(u, p["gate"]))[..., None]

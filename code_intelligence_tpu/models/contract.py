@@ -29,7 +29,11 @@ with ``--scheduler groups``):
     the two kinds of state that depend on the document's length, each as
     the positions a row is allocated for documents of ``positions``
     tokens. The first is the cache that GROWS with the document (keys
-    and values of layers that attend to all of it, a latent cache); the
+    and values of layers that attend to all of it, a latent cache;
+    where a row holds two such caches of different widths a layer, a
+    latent cache and the index keys a learned selection scores
+    (`models/glm_moe_dsa.py`), both are written a position a token and
+    this is the one length of both); the
     second the RING of layers that attend under a sliding window, which
     grows like the first until it holds the window and one chunk and
     then stops: a chunk program's cores meet ``min(positions reached,
@@ -44,7 +48,11 @@ with ``--scheduler groups``):
     and the first with the positions the summaries are allocated FOR
     (their slots times the chunk they summarise), so that the engine's
     allocation, its in-flight bound and ``state_bytes_per_row`` hold;
-    its cores meet less than ``min(positions reached, allocated)`` of
+    an encoder whose every query attends only the positions a learned
+    indexer selects answers the first with what it allocates and scores
+    (the positions reached) though its core admits ``min(reached,
+    index_topk)`` of them;
+    such cores meet less than ``min(positions reached, allocated)`` of
     either (a query a quarter into its block meets a quarter of the
     block, and one summary for every chunk before it), and what they met
     is what the encoder counts on the device (``state_counters``).
@@ -73,6 +81,8 @@ from code_intelligence_tpu.models.bailing_hybrid import (
 from code_intelligence_tpu.models.deepseek_v3 import (
     DeepseekV3Config, DeepseekV3Encoder)
 from code_intelligence_tpu.models.evabyte import EvaByteConfig, EvaByteEncoder
+from code_intelligence_tpu.models.glm_moe_dsa import (
+    GlmMoeDsaConfig, GlmMoeDsaEncoder)
 from code_intelligence_tpu.models.granite_hybrid import (
     GraniteHybridConfig, GraniteHybridEncoder)
 from code_intelligence_tpu.models.longcat_flash import (
@@ -149,6 +159,9 @@ ENCODERS = {
     EvaByteConfig.architecture: (
         EvaByteConfig, EvaByteConfig.from_dict,
         _in_weights_dtype(EvaByteEncoder)),
+    GlmMoeDsaConfig.architecture: (
+        GlmMoeDsaConfig, GlmMoeDsaConfig.from_dict,
+        _in_weights_dtype(GlmMoeDsaEncoder)),
 }
 
 

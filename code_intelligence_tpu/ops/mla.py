@@ -82,6 +82,25 @@ selects one):
   at a time, which needs no copy of the queries in another order, was
   17 % slower in the core on the chip: PERF.md §6, PR 30.)
 
+**A third core, for a caller that SELECTS what a query attends**
+(``mla_cached(..., admit=...)`` / ``mla_core``; ``ops/dsa.py`` is the
+caller): ``admit`` ``(b, T, S)`` says, a query and a cached position,
+whether the pair is attended, in place of "every position up to the
+query's own". Two queries of one chunk then admit different keys of one
+block, which neither core above can take: both skip a key block for the
+whole chunk or for no query and make their mask from iotas. ``mla_core``
+is the masked, expanded form in XLA: one ``lax.fori_loop`` over the key
+blocks that hold the positions reached, all heads a step, a block's
+latent rows expanded, scored, masked and folded into a running maximum,
+sum and weighted values (the kernel's arithmetic). ``admit=None`` lowers
+to the program ``mla_cached`` always was. **Heads of 192 + 64 | 256 did
+not join the kernel's rule** (``nope % 128 == 0`` fails at 192, though
+``[192 | 64]`` is exactly two lane tiles): the kernel has no masked form
+yet, the one model with such heads always hands in ``admit``, and no
+measurement on the chip stands behind a tile at that head size (PERF.md
+§6, PR 53); a masked kernel would take ``W_k`` padded a head to 256
+columns and add the shared ``k_pe`` into lanes 192..255, no concatenate.
+
 Rotary follows the published implementation: YaRN's blended inverse
 frequencies (``yarn_inv_freq``), pairs ``(x[2i], x[2i+1])`` rotated by
 angle ``position * f_i`` and written de-interleaved (all first elements,
@@ -248,15 +267,23 @@ def mla_cached(
     q_block: int = 128,
     key_block: int = 512,
     mxu_dtype=jnp.bfloat16,
+    admit: Optional[jnp.ndarray] = None,  # (b, T, S) bool
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """``(out (b, T, H, v) float32, cache)`` with the chunk's latent rows
     appended at ``pos``. The block sizes are the XLA core's, parameters
     so that the CPU tests can run every blocked path at a tiny size (the
-    engine takes the defaults); the kernel's tiles follow the shapes."""
+    engine takes the defaults); the kernel's tiles follow the shapes.
+    ``admit``, where a caller has one (``ops/dsa.py``), is the set of
+    cached positions each query attends, in place of "every position up
+    to its own": the core is then ``mla_core``. ``None`` lowers to the
+    program this function always was."""
     b, T, H, nope = q_nope.shape
     S, rank = cache.shape[1], cache.shape[2] - q_pe.shape[-1]
     cache = lax.dynamic_update_slice_in_dim(
         cache, latent.astype(cache.dtype), pos, axis=1)
+    if admit is not None:
+        return mla_core(q_nope, q_pe, cache, pos, w_kvb, scale, v_dim, admit,
+                        key_block, mxu_dtype), cache
     if core_is_kernel(jax.default_backend(), mxu_dtype, T, S, H, nope, v_dim,
                       rank):
         out = _kernel_core(q_nope, q_pe, cache, pos, w_kvb, scale, v_dim,
@@ -265,6 +292,71 @@ def mla_cached(
         out = _xla_core(q_nope, q_pe, cache, pos, w_kvb, scale, v_dim,
                         mxu_dtype, head_block, q_block, key_block)
     return out, cache
+
+
+def mla_core(q_nope, q_pe, cache, pos, w_kvb, scale, v_dim, admit,
+             key_block: int = 512, mxu_dtype=jnp.bfloat16) -> jnp.ndarray:
+    """``out (b, T, H, v)`` float32 of the chunk's queries against a
+    ``cache`` the chunk is already written into, each query's softmax
+    over the cached positions ``admit`` ``(b, T, S)`` holds true for it
+    (at least one, none after its own): the MASKED, EXPANDED form, in
+    XLA. Two queries of one chunk may admit different keys of one block,
+    so neither core above can take it: both skip a key block "for the
+    whole chunk or for no query" and make their mask from iotas.
+
+    One loop over the key blocks that hold the ``pos + T`` positions
+    reached, its trip count the program's own (``lax.fori_loop``: one
+    body a layer to compile, where a ``lax.switch`` over static prefixes
+    is a body a prefix, 64 of them at 32,768 positions), all heads a
+    step: a block's latent rows are expanded, scored, masked and folded
+    into every query's running maximum, sum and weighted values, the
+    kernel's arithmetic (the probabilities meet the values unnormalised
+    in ``mxu_dtype``; the sum divides once, at the end). A block a query
+    admits nothing of adds what its first admitted key then multiplies
+    by ``exp(_MASKED - score) = 0``."""
+    b, T, H, nope = q_nope.shape
+    rope = q_pe.shape[-1]
+    S, rank = cache.shape[1], cache.shape[2] - rope
+    kb = _blocks(S, key_block)
+    w = w_kvb.reshape(rank, H, nope + v_dim)
+    w_k, w_v = w[..., :nope].astype(mxu_dtype), w[..., nope:].astype(mxu_dtype)
+    qn, qp = q_nope.astype(mxu_dtype), q_pe.astype(mxu_dtype)
+
+    def step(j, carry):
+        m, l, acc = carry
+        rows = lax.dynamic_slice_in_dim(cache, j * kb, kb, axis=1)
+        c = rows[..., :rank].astype(mxu_dtype)
+        k_nope = jnp.einsum("bsc,chd->bshd", c, w_k,
+                            preferred_element_type=mxu_dtype)
+        v = jnp.einsum("bsc,chd->bshd", c, w_v,
+                       preferred_element_type=mxu_dtype)
+        s = (jnp.einsum("bthd,bshd->bhts", qn, k_nope,
+                        preferred_element_type=jnp.float32)
+             + jnp.einsum("bthr,bsr->bhts", qp,
+                          rows[..., rank:].astype(mxu_dtype),
+                          preferred_element_type=jnp.float32)) * scale
+        seen = lax.dynamic_slice_in_dim(admit, j * kb, kb, axis=2)
+        s = jnp.where(seen[:, None], s, _MASKED)
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        p = jnp.exp(s - m_new[..., None])
+        fade = jnp.exp(m - m_new)
+        l = l * fade + p.sum(axis=-1)
+        acc = acc * fade[..., None] + jnp.einsum(
+            "bhts,bshd->bhtd", p.astype(mxu_dtype), v,
+            preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    _, l, acc = lax.fori_loop(0, reached_blocks(pos, T, S, kb), step, (
+        jnp.full((b, H, T), _MASKED, jnp.float32),
+        jnp.zeros((b, H, T), jnp.float32),
+        jnp.zeros((b, H, T, v_dim), jnp.float32)))
+    return (acc / l[..., None]).swapaxes(1, 2)
+
+
+def reached_blocks(pos, T: int, S: int, kb: int):
+    """Key blocks of ``kb`` that hold the ``pos + T`` positions a chunk
+    reaches, of a cache of ``S``: the trip count of a loop over them."""
+    return jnp.clip((pos + T + kb - 1) // kb, 1, S // kb)
 
 
 def _blocks(n: int, block: int) -> int:

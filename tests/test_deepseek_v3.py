@@ -395,7 +395,8 @@ def test_no_name_selects_a_core():
 
     assert list(inspect.signature(mla.mla_cached).parameters) == [
         "q_nope", "q_pe", "latent", "cache", "pos", "w_kvb", "scale",
-        "v_dim", "head_block", "q_block", "key_block", "mxu_dtype"]
+        "v_dim", "head_block", "q_block", "key_block", "mxu_dtype",
+        "admit"]    # PR 53: WHAT a query attends (a mask), not which core
     words = {"pallas", "kernel", "core", "xla", "interpret", "tile", "tiles"}
     assert not [f.name for f in dataclasses.fields(DeepseekV3Config)
                 if words & set(f.name.split("_"))]
